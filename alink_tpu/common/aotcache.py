@@ -30,10 +30,10 @@ the serving/fleet bucket programs):
   wall time, so ``/compilez``, ``doctor.py`` and ``fleetz.py`` can
   attribute a warm restart instead of mistaking it for silence;
 * guarded fallback — programs ``jax.export`` cannot serialize (or
-  deserialize) skip the executable store without breaking anything,
-  and the XLA persistent compilation cache is armed under
-  ``<dir>/xla`` so even those programs skip the XLA-compile half of
-  their cold start on the next process.
+  deserialize) skip the executable store without breaking anything;
+  the session's XLA persistent compilation cache
+  (``mlenv.place_compile_cache``) still saves them the XLA-compile
+  half of their cold start on the next process.
 
 The whole module is inert unless BOTH ``ALINK_TPU_AOT_CACHE`` (default
 on) and ``ALINK_TPU_AOT_CACHE_DIR`` (default unset) are set: with no
@@ -71,7 +71,6 @@ FORMAT = 1
 _lock = threading.Lock()
 _warned: set = set()
 _stats = {"loads": 0, "stores": 0, "refusals": 0, "export_skipped": 0}
-_xla_armed = [False]
 
 
 # ---------------------------------------------------------------------------
@@ -248,36 +247,6 @@ def _warn_once(key: str, msg: str) -> None:
 
 
 # ---------------------------------------------------------------------------
-# the guarded XLA persistent-compilation-cache fallback
-# ---------------------------------------------------------------------------
-
-def _arm_xla_fallback() -> None:
-    """Best-effort: point jax's own persistent compilation cache at
-    ``<dir>/xla`` so programs the executable store cannot export (or
-    that refuse on a fingerprint) still skip the XLA-compile half of
-    their cold start on the next process.  Purely additive — failure to
-    arm never affects the executable store."""
-    if _xla_armed[0] or not active():
-        return
-    _xla_armed[0] = True
-    try:
-        import jax
-        xdir = os.path.join(aot_dir(), "xla")
-        os.makedirs(xdir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", xdir)
-        for opt, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                         ("jax_persistent_cache_min_entry_size_bytes", 0)):
-            try:
-                jax.config.update(opt, val)
-            except Exception:
-                pass
-    except Exception as e:
-        _warn_once("xla-fallback",
-                   f"aotcache: could not arm the XLA persistent "
-                   f"compilation cache fallback: {e!r}")
-
-
-# ---------------------------------------------------------------------------
 # store
 # ---------------------------------------------------------------------------
 
@@ -296,7 +265,6 @@ def store(plan: ExecutionPlan, fn: Callable, example_args: Tuple, *,
     True iff an artifact was published."""
     if not active():
         return False
-    _arm_xla_fallback()
     try:
         from jax import export as jax_export
         exported = jax_export.export(fn)(*example_args)
@@ -305,8 +273,9 @@ def store(plan: ExecutionPlan, fn: Callable, example_args: Tuple, *,
         _stats["export_skipped"] += 1
         _warn_once(f"export:{cache}",
                    f"aotcache: jax.export cannot serialize programs of "
-                   f"cache {cache!r} ({e!r}) — the XLA persistent-cache "
-                   f"fallback still covers their recompiles")
+                   f"cache {cache!r} ({e!r}) — the session's XLA "
+                   f"persistent compilation cache still covers their "
+                   f"recompiles")
         try:
             from .metrics import get_registry, metrics_enabled
             if metrics_enabled():
@@ -416,7 +385,6 @@ def load(plan: ExecutionPlan, *, cache: str, site: str = "",
     that install into an in-memory cache record at install time)."""
     if not active():
         return None
-    _arm_xla_fallback()
     digest = plan.digest()
     path = artifact_path(cache, digest)
     t0 = time.perf_counter()

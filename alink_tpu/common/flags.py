@@ -557,8 +557,7 @@ FLAGS.register(
     accessor="alink_tpu.common.aotcache.aot_enabled")
 FLAGS.register(
     "ALINK_TPU_AOT_CACHE_DIR", "str", "",
-    "AOT artifact root (<dir>/<cache>/<plan-digest>.aot plus the "
-    "<dir>/xla persistent-compilation-cache fallback); empty (the "
+    "AOT artifact root (<dir>/<cache>/<plan-digest>.aot); empty (the "
     "default) disables the executable store entirely", "performance",
     key_neutral="a host-side storage path: it decides WHERE validated "
                 "artifacts live, never which program a cache key "

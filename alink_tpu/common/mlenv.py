@@ -34,20 +34,12 @@ def mesh_device_request() -> int:
 
 
 def _jax_backend_initialized() -> bool:
-    """Best-effort: has any jax backend already been instantiated? XLA
-    flags latch at backend init, so widening the host platform is only
-    possible before this returns True."""
+    """Has any jax backend already been instantiated? XLA flags latch at
+    backend init, so widening the host platform is only possible before
+    this returns True."""
     import sys
     xb = sys.modules.get("jax._src.xla_bridge")
-    if xb is None:
-        return False
-    try:
-        backends = getattr(xb, "_backends", None)
-    except Exception:           # unknown internals: assume initialized
-        return True
-    if backends is None:        # attribute renamed/missing: conservative
-        return True
-    return bool(backends)       # present-but-empty dict = not initialized
+    return xb is not None and bool(xb._backends)
 
 
 def ensure_host_platform_devices(n: int) -> bool:
@@ -73,6 +65,31 @@ def ensure_host_platform_devices(n: int) -> bool:
     return True
 
 
+#: where compiled programs persist when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset: one fixed directory inside the checkout. The path is part of
+#: jax's cache key, so a directory that moves (a temp name, a pid, a
+#: timestamp) never hits.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def place_compile_cache() -> str:
+    """Place jax's persistent compilation cache and return the directory
+    in force. ``JAX_COMPILATION_CACHE_DIR`` decides it from outside (jax
+    reads the variable itself; nothing here overrides it); unset, every
+    session — examples, bench, ``chip_smoke.py``, servers — shares
+    :data:`COMPILE_CACHE_DIR`. The one place in the repo that sets
+    ``jax_compilation_cache_dir``."""
+    import jax
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    if jax.config.jax_compilation_cache_dir != COMPILE_CACHE_DIR:
+        jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
+
+
 class MLEnvironment:
     """One session: device mesh + lazy-objects manager + RNG seed stream."""
 
@@ -80,6 +97,7 @@ class MLEnvironment:
                  devices=None):
         import jax
 
+        place_compile_cache()
         if devices is None:
             req = mesh_device_request()
             if req > 0:

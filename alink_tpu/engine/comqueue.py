@@ -181,9 +181,9 @@ def _maybe_cost(ckey: Optional[tuple], lower_thunk: Callable) -> Optional[dict]:
     Computed only under ``ALINK_TPU_TRACE`` (``lower_thunk`` re-traces the
     program, seconds for the big optimizer programs); once computed it is
     served from the memo so later traced execs pay a dict lookup. An
-    unavailable cost model memoizes as ``{}`` — degraded jax versions must
-    not re-pay the lowering on every traced exec just to learn None
-    again."""
+    unavailable cost model memoizes as ``{}`` — a backend without one
+    must not re-pay the lowering on every traced exec just to learn
+    None again."""
     if ckey is None:
         return None
     cost = _PROGRAM_CACHE_COSTS.get(ckey)
@@ -1395,12 +1395,13 @@ class IterativeComQueue:
         # single-process: leave leaves ON DEVICE — ComQueueResult fetches
         # per access, so a fit that only reads coef + loss_curve does not
         # pull the whole carry (L-BFGS sk/yk ring buffers, per-row
-        # margins, ...) through a slow host<->device link
+        # margins, ...) from the device to the host
         result = ComQueueResult(stacked, nw, totals)
         if mx:
             reg = get_registry()
-            # one scalar fetch; on deferred backends this flushes the run,
-            # which the caller's first result read would have done anyway
+            # one scalar fetch; it waits for the (asynchronously
+            # dispatched) run, which the caller's first result read would
+            # have done anyway
             steps = int(result.step_count)
             # a resumed run only EXECUTED the supersteps past its snapshot
             # (and no init pass); charge collectives/supersteps for those

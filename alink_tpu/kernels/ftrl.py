@@ -214,8 +214,12 @@ def chained_corr(Mk, D, k: int):
         def _init():
             out_ref[...] = jnp.zeros_like(out_ref)
 
+        # HIGHEST: on the MXU the default precision rounds the f32
+        # deltas to bf16 (measured on a v5e: the kernel then misses the
+        # XLA path's Precision.HIGHEST einsum at the 1e-3 level)
         out_ref[...] += jnp.dot(m_ref[...][0], d_ref[...][0],
-                                preferred_element_type=out_ref.dtype)
+                                preferred_element_type=out_ref.dtype,
+                                precision=jax.lax.Precision.HIGHEST)
 
     return pl.pallas_call(
         kernel,

@@ -10,12 +10,15 @@ check/warn machinery used to live inlined in
   (the CPU tier-1 rig's mode: ``pl.pallas_call(interpret=True)``
   executes the kernel with jnp semantics, so parity tests run without
   hardware);
-* **demotion, never silence** — when a requested kernel cannot run
-  (backend unavailable, Mosaic compile rejection, trace failure), the
-  call site demotes to its XLA formulation with ONE RuntimeWarning per
-  (kernel, reason) per process. A demoted run is always numerically
-  valid — the XLA path is the reference the kernel is parity-pinned
-  against — but it must never be *silently* slower;
+* **demotion off the chip, refusal on it** — off-TPU, when a requested
+  kernel cannot run (backend unavailable, interpreter or trace
+  failure), the call site demotes to its XLA formulation with ONE
+  RuntimeWarning per (kernel, reason) per process. A demoted run is
+  always numerically valid — the XLA path is the reference the kernel
+  is parity-pinned against — but it must never be *silently* slower.
+  On a TPU backend a requested kernel that Mosaic refuses RAISES
+  (:func:`refuse_on_tpu`): a chip run never executes the XLA path
+  under the kernel's name;
 * **flag-off byte-identity** — with the gating flag off, the call site
   executes its pre-existing statements verbatim: the lowered HLO is
   byte-identical to pre-kernel-tier programs (pinned per flag by the
@@ -26,8 +29,8 @@ check/warn machinery used to live inlined in
   compile, outside any try/except around the traced call.
   :func:`eager_probe` compiles+runs a tiny instance of the kernel in a
   genuinely eager context (a fresh thread — jax trace contexts are
-  thread-local) once per shape class, so compile-time failures demote
-  exactly like trace-time ones.
+  thread-local) once per shape class, so compile-time failures are
+  handled exactly like trace-time ones.
 """
 
 from __future__ import annotations
@@ -36,7 +39,8 @@ import warnings as _warnings
 from typing import Callable, Dict, Tuple
 
 __all__ = ["pallas_interpret", "pallas_available", "interpret_mode",
-           "demote_once", "eager_probe", "reset_demotions"]
+           "demote_once", "refuse_on_tpu", "eager_probe",
+           "reset_demotions"]
 
 
 def pallas_interpret() -> bool:
@@ -105,6 +109,19 @@ def demote_once(kernel: str, reason: str, detail: str = "",
         RuntimeWarning, stacklevel=3)
 
 
+def refuse_on_tpu(kernel: str, detail: str, cause: BaseException) -> None:
+    """Raise when a REQUESTED kernel failed on a TPU backend; return
+    (so the caller demotes) anywhere else. The demotion contract exists
+    for rigs that cannot run Mosaic at all; on the chip a refused
+    kernel is a fact the run must report, not an XLA run labelled with
+    the kernel's name."""
+    import jax
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"Pallas kernel {kernel!r} was requested and failed on TPU "
+            f"({detail}): {type(cause).__name__}: {cause}") from cause
+
+
 def reset_demotions() -> None:
     """Test hook: re-arm the once-per-(kernel, reason) warnings."""
     _DEMOTION_WARNED.clear()
@@ -131,15 +148,15 @@ def eager_probe(kernel: str, key: Tuple, probe: Callable[[], None]) -> bool:
     """EAGERLY compile+run ``probe`` (a tiny instance of the kernel at
     this call's shape class) before the kernel is traced into a
     compiled program. One probe per (kernel, shape class) per process;
-    a probe failure demotes via :func:`demote_once` and is memoized so
-    the XLA path is chosen at trace time from then on.
+    off-TPU a probe failure demotes via :func:`demote_once` and is
+    memoized so the XLA path is chosen at trace time from then on; on
+    TPU it raises (:func:`refuse_on_tpu`).
 
     ``pl.pallas_call`` only stages the primitive at trace time — a
     Mosaic failure would otherwise surface at the engine's compile,
     outside any try/except around the traced call. The eager probe is
-    what makes the demotion contract real for compile-time failures
-    (VMEM overflow, lane-alignment rejections), not just trace-time
-    ones."""
+    what puts compile-time failures (VMEM overflow, lane-alignment
+    rejections) under the same contract as trace-time ones."""
     memo_key = (kernel,) + tuple(key)
     ok = _PROBED.get(memo_key)
     if ok is None:
@@ -147,6 +164,7 @@ def eager_probe(kernel: str, key: Tuple, probe: Callable[[], None]) -> bool:
             run_eagerly(probe)
             ok = True
         except Exception as e:  # pragma: no cover - backend-specific
+            refuse_on_tpu(kernel, f"probe at shape class {key}", e)
             ok = False
             demote_once(kernel, "probe-failed",
                         f"shape class {key}: {type(e).__name__}: {e}")

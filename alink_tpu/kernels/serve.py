@@ -76,9 +76,10 @@ def resolve_serve_kernel(mapper_name: str, dim8: int, ship_dt,
     ``supported=False`` (softmax): the fused/low-precision tier serves
     the binary/regression family only — a request on an unsupported
     mapper records a fallback and serves the exact f32 XLA path.
-    An unavailable backend or a failed eager probe demotes ``fused``
-    (recorded); the dtype path is pure XLA-or-Pallas arithmetic and
-    needs no backend gate."""
+    An unavailable backend or (off-TPU) a failed eager probe demotes
+    ``fused`` (recorded); on TPU a failed probe raises
+    (``runtime.refuse_on_tpu``). The dtype path is pure XLA-or-Pallas
+    arithmetic and needs no backend gate."""
     from ..serving.predictor import record_serve_fallback
     dtype = serve_dtype()
     fused = serve_fused_requested()

@@ -204,8 +204,9 @@ def level_hist(binned, stats, node_id, n_nodes: int, n_bins: int,
 #              (B_blk, m) dot into the output block — exact f32
 #              accumulation, no hi/lo split, no HBM one-hot
 #              materialization. Gated on backend availability (TPU, or
-#              interpret mode for tests); demotes to "xla" with a
-#              one-time warning when lowering fails.
+#              interpret mode for tests); off-TPU it demotes to "xla"
+#              with a one-time warning when lowering fails, on TPU a
+#              failed lowering raises (kernels/runtime.refuse_on_tpu).
 #
 # The mode is resolved at TRACE time and folded into the engine
 # program-cache key by the tree trainers, so toggling recompiles instead
@@ -266,8 +267,8 @@ def _pallas_level_hist(binned, stats, node_id, n_nodes: int, n_bins: int):
     ``(Q, blk) @ (blk, m)`` dot into its feature's output block. Exact
     f32 accumulation (no bf16 quantization, no hi/lo split); the only
     HBM traffic is the binned rows, the stats, and the output —
-    the one-hot never materializes outside VMEM. Falls back to the XLA
-    fused formulation (one-time warning) if lowering/tracing fails."""
+    the one-hot never materializes outside VMEM. Compiles on a v5e and
+    agrees with :func:`level_hist` (PR 21 chip run); not timed."""
     from jax.experimental import pallas as pl
 
     n, F = binned.shape
@@ -285,33 +286,45 @@ def _pallas_level_hist(binned, stats, node_id, n_nodes: int, n_bins: int):
     nid2 = node_id[:, None].astype(jnp.int32)               # (n, 1)
 
     def kernel(b_ref, nid_ref, s_ref, out_ref):
+        f = pl.program_id(0)
         r = pl.program_id(1)
 
         @pl.when(r == 0)
         def _init():
             out_ref[...] = jnp.zeros_like(out_ref)
 
-        b = b_ref[...][:, 0].astype(jnp.int32)              # (blk,)
-        nid = nid_ref[...][:, 0]                            # (blk,)
-        s = s_ref[...]                                      # (blk, m)
-        q = nid * n_bins + b                                # combined id
-        oh = (q[:, None] == jnp.arange(Q)[None, :]).astype(jnp.float32)
-        acc = jnp.dot(oh.T, s, preferred_element_type=jnp.float32)
-        out_ref[...] += acc.reshape(1, n_nodes, n_bins, m)
+        # the block carries the WHOLE feature axis and this grid step's
+        # column is selected in-kernel: a (blk, 1) column block of a
+        # (n, F) array is not lane-aligned, and Mosaic refuses it. The
+        # price is that each row block is read once per feature.
+        rows = b_ref[...].astype(jnp.int32)                 # (blk, F)
+        col = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 1)
+        b = jnp.sum(jnp.where(col == f, rows, 0), axis=1,
+                    keepdims=True)                          # (blk, 1)
+        q = nid_ref[...] * n_bins + b                       # combined id
+        oh = (q == jax.lax.broadcasted_iota(jnp.int32, (blk, Q), 1)
+              ).astype(jnp.float32)                         # (blk, Q)
+        # contract the row axis; HIGHEST keeps the f32 stats exact on
+        # the MXU (the default precision would round them to bf16)
+        acc = jax.lax.dot_general(
+            oh, s_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST)            # (Q, m)
+        out_ref[...] += acc[None]
 
     from ....kernels.runtime import interpret_mode
     out = pl.pallas_call(
         kernel,
         grid=(F, npad // blk),
-        in_specs=[pl.BlockSpec((blk, 1), lambda f, r: (r, f)),
+        in_specs=[pl.BlockSpec((blk, F), lambda f, r: (r, 0)),
                   pl.BlockSpec((blk, 1), lambda f, r: (r, 0)),
                   pl.BlockSpec((blk, m), lambda f, r: (r, 0))],
-        out_specs=pl.BlockSpec((1, n_nodes, n_bins, m),
-                               lambda f, r: (f, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((F, n_nodes, n_bins, m), jnp.float32),
+        out_specs=pl.BlockSpec((1, Q, m), lambda f, r: (f, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((F, Q, m), jnp.float32),
         interpret=interpret_mode(),
     )(binned, nid2, s32)
-    return out.transpose(1, 0, 2, 3).astype(stats.dtype)
+    return out.reshape(F, n_nodes, n_bins, m).transpose(
+        1, 0, 2, 3).astype(stats.dtype)
 
 
 _PALLAS_PROBED: dict = {}      # (n_nodes, n_bins, m) -> bool (compiled ok)
@@ -323,10 +336,10 @@ def _pallas_probe(n_nodes: int, n_bins: int, m: int) -> bool:
     program. ``pl.pallas_call`` only stages the primitive at trace time —
     a Mosaic/interpreter failure would otherwise surface at
     ``queue.exec()``'s compile, OUTSIDE any try/except around the traced
-    call — so the probe is what makes the demotion contract real for
-    compile-time failures (VMEM overflow at deep levels, lane-alignment
-    rejections), not just trace-time ones. One probe per shape class per
-    process; probe failure demotes with the one-time warning."""
+    call — so the probe is what catches compile-time failures (VMEM
+    overflow at deep levels, lane-alignment rejections), not just
+    trace-time ones. One probe per shape class per process; off-TPU a
+    probe failure demotes with the one-time warning, on TPU it raises."""
     key = (n_nodes, n_bins, m)
     ok = _PALLAS_PROBED.get(key)
     if ok is None:
@@ -335,7 +348,8 @@ def _pallas_probe(n_nodes: int, n_bins: int, m: int) -> bool:
                 np.zeros((8, 1), np.int32), np.zeros((8, m), np.float32),
                 np.zeros((8,), np.int32), n_nodes, n_bins)
             np.asarray(out)              # force the eager compile+run
-        from ....kernels.runtime import demote_once, run_eagerly
+        from ....kernels.runtime import (demote_once, refuse_on_tpu,
+                                         run_eagerly)
         try:
             # run_eagerly (kernels/runtime.py): the dispatch call site
             # sits inside the engine's shard_map/jit trace, where even
@@ -345,6 +359,7 @@ def _pallas_probe(n_nodes: int, n_bins: int, m: int) -> bool:
             run_eagerly(probe)
             ok = True
         except Exception as e:  # pragma: no cover - backend-specific
+            refuse_on_tpu("fused_hist", f"probe at level shape {key}", e)
             ok = False
             demote_once(
                 "fused_hist", "probe-failed", gate=_PALLAS_WARNED,
@@ -367,7 +382,8 @@ def _hist_dispatch(hist_mode, pre, binned, stats, node_id, n_nodes, n_bins):
             return _pallas_level_hist(binned, stats, node_id, n_nodes,
                                       n_bins)
         except Exception as e:  # pragma: no cover - backend-specific
-            from ....kernels.runtime import demote_once
+            from ....kernels.runtime import demote_once, refuse_on_tpu
+            refuse_on_tpu("fused_hist", "trace", e)
             demote_once(
                 "fused_hist", "trace-failed", gate=_PALLAS_WARNED,
                 message=f"ALINK_TPU_FUSED_HIST=pallas failed to trace "

@@ -564,6 +564,15 @@ class CompiledPredictor:
     def buckets(self) -> Tuple[int, ...]:
         return self._buckets
 
+    @property
+    def devices(self) -> Tuple:
+        """The devices holding the ACTIVE model's placements — where this
+        predictor's programs run (one for the default single-device
+        programs, the whole mesh when sharded, one per replica)."""
+        devs = {d for placement in self._active._placements
+                for a in placement for d in a.devices()}
+        return tuple(sorted(devs, key=lambda d: d.id))
+
     # -- program cache --------------------------------------------------
     def bucket_for(self, n: int) -> int:
         """Smallest bucket >= n (requests larger than the top bucket are

@@ -121,6 +121,18 @@ def mesh_fingerprint(mesh) -> Optional[Tuple]:
 
 # -- canonical fixed-order reductions ---------------------------------------
 
+def _zeros_carry(shape, like):
+    """A zero ``lax.scan`` carry typed like ``like``: inside a
+    ``shard_map`` the accumulated terms vary over the mesh axes, and the
+    scan's carry type (varying axes included) must not change between
+    its input and its output."""
+    import jax
+    import jax.numpy as jnp
+    acc0 = jnp.zeros(shape, like.dtype)
+    vma = tuple(jax.typeof(like).vma)
+    return jax.lax.pcast(acc0, vma, to="varying") if vma else acc0
+
+
 def seq_chunk_sum(terms, axis: int):
     """Sum ``terms`` over ``axis`` in a FIXED left-to-right order
     (chunked ``lax.scan`` of elementwise adds): unlike ``jnp.sum`` /
@@ -132,7 +144,7 @@ def seq_chunk_sum(terms, axis: int):
     import jax.numpy as jnp
     t = jnp.moveaxis(terms, axis, 0)
     ext = t.shape[0]
-    acc0 = jnp.zeros(t.shape[1:], t.dtype)
+    acc0 = _zeros_carry(t.shape[1:], t)
     if ext <= 16 * SERVE_CHUNK:
         # small extents unroll in-trace: same strict order, none of the
         # scan loop's per-step dispatch overhead (the serial bucket-1
@@ -171,7 +183,7 @@ def scan_sum(terms, axis: int):
     def body(acc, x):
         return acc + x, None
 
-    acc, _ = jax.lax.scan(body, jnp.zeros(t.shape[1:], t.dtype), t)
+    acc, _ = jax.lax.scan(body, _zeros_carry(t.shape[1:], t), t)
     return acc
 
 
@@ -200,7 +212,7 @@ def lane_partials(terms, lanes: int):
     def body(acc, x):
         return acc + x, None
 
-    acc, _ = jax.lax.scan(body, jnp.zeros((rows, lanes), terms.dtype), t)
+    acc, _ = jax.lax.scan(body, _zeros_carry((rows, lanes), terms), t)
     return acc
 
 

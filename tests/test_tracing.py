@@ -3,7 +3,7 @@
 Covers the Tracer contract (contextvars nesting, thread lanes, the bounded
 flight recorder, instant events, Chrome/JSONL exporters), the
 ALINK_TPU_TRACE gate (including StepTimer's single-source-of-truth
-emission), the compat.compiled_cost_analysis shim across return shapes,
+emission), compat.compiled_cost_analysis's flat-dict-or-None contract,
 and the end-to-end acceptance path: an L-BFGS train with tracing +
 checkpointing produces a Chrome trace whose span tree nests
 exec -> chunk -> superstep-phase spans with checkpoint instant events,
@@ -380,19 +380,9 @@ class TestCostShim:
         assert cost is not None
         assert cost["flops"] > 0
         assert cost["bytes accessed"] > 0
-        # compiled stage too (the historically list-shaped return)
+        # compiled stage too
         cost_c = compiled_cost_analysis(low.compile())
         assert cost_c is not None and cost_c["flops"] > 0
-
-    def test_list_return_normalized(self):
-        from alink_tpu.common.compat import compiled_cost_analysis
-
-        class FakeListed:
-            def cost_analysis(self):
-                return [{"flops": 7.0, "bytes accessed": 3.0,
-                         "weird": object()}]
-        cost = compiled_cost_analysis(FakeListed())
-        assert cost == {"flops": 7.0, "bytes accessed": 3.0}
 
     def test_degrades_to_none_never_raises(self):
         from alink_tpu.common.compat import compiled_cost_analysis

@@ -85,7 +85,9 @@ def _child() -> int:
     cache = f"serve.{pred.name}"
     serve_events = [e for e in doc.get("events") or []
                     if e.get("cache") == cache]
+    import jax
     result = {
+        "platform": jax.devices()[0].platform,
         "warmed_programs": warmed,
         "first_response_s": first_response_s,
         "startup_to_response_s": time.perf_counter() - t_start,
@@ -118,7 +120,8 @@ def main() -> int:
     run_dir = tempfile.mkdtemp(prefix="alink-coldstart-run-")
     results = {}
     for role in ("cold", "warm"):
-        env = bootenv.cpu_mesh_env(4)
+        env = bootenv.warm_restart_cache_env(bootenv.cpu_mesh_env(4),
+                                             cache_dir)
         env[_MARK] = "1"
         env["ALINK_TPU_AOT_CACHE_DIR"] = cache_dir
         env.pop("ALINK_TPU_AOT_CACHE", None)

@@ -52,13 +52,14 @@ def render(workloads: dict, source_name: str) -> str:
         sps = _fmt_sps(w["samples_per_sec_per_chip"])
         vs = f"{w['vs_baseline']:.1f}x"
         peak = (f"{w['pct_chip_peak_flops']:.2f}%"
-                if "pct_chip_peak_flops" in w else "—")
+                if w.get("pct_chip_peak_flops") is not None else "—")
         rows.append(f"| {label} | {sps} | {vs} | {peak} |")
         if key == "ftrl_criteo" and "batch_mode_samples_per_sec_per_chip" in w:
             bsps = _fmt_sps(w["batch_mode_samples_per_sec_per_chip"])
             bvs = f"{w['batch_mode_vs_baseline']:.1f}x"
             bpeak = (f"{w['batch_mode_pct_chip_peak_flops']:.2f}%"
-                     if "batch_mode_pct_chip_peak_flops" in w else "—")
+                     if w.get("batch_mode_pct_chip_peak_flops") is not None
+                     else "—")
             rows.append("| FTRL stream, `update_mode=\"batch\"` + "
                         f"field-aware hashing | {bsps} | {bvs} | {bpeak} |")
     captured = ("driver-captured" if source_name.startswith("BENCH_r")

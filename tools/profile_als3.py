@@ -1,7 +1,7 @@
 """Profiler round 3 (fixed): per-kernel cost via in-program iteration
 deltas. Each iteration's result enters a FULL reduction (`.sum()`), so
-XLA cannot dead-code-eliminate any of the kernel, and the perturbed
-input defeats the device service's execution memoization."""
+XLA cannot dead-code-eliminate any of the kernel, and every timed call
+gets a perturbed input."""
 import time
 
 import numpy as np

@@ -140,8 +140,12 @@ def run_child(n_devices: int, requests: int, swaps: int) -> dict:
     srv.close()
     observed = {str(r) for r in srep.responses}
     torn = len(observed - expected)
+    import jax
     return {
         "devices": n_devices,
+        # the platform this child REALLY ran on (the parent forces the
+        # host platform: a chip belongs to the parent process)
+        "platform": jax.devices()[0].platform,
         "qps": round(rep.qps, 1),
         "qps_per_chip": round(rep.qps / n_devices, 1),
         "p50_ms": round(rep.p50_s * 1e3, 3),
@@ -196,6 +200,10 @@ def measure(devices=(1, 4, 8), requests: int = 4000,
                                for r in rows.values()),
         "model_swaps": sum(r["model_swaps"] for r in rows.values()),
         "bound": "serving-host",
+        # every *_per_chip figure in this row comes from children on
+        # THIS platform, not from the parent's devices
+        "platform": "/".join(sorted({r["platform"]
+                                     for r in rows.values()})),
         "cores": cores,
         # on a host-platform mesh, N virtual chips SHARE the host's
         # cores: dividing a fixed compute roof by N is rig-pessimistic
