@@ -310,6 +310,14 @@ def batch_scope(ctxs: List[Optional[RequestContext]]) -> Iterator[None]:
         _batch_var.reset(token)
 
 
+def batch_trace_id() -> Optional[str]:
+    """The trace id of the first request riding the active batch scope
+    (``None`` outside one): the identifier the batch's tracer spans
+    share with that request's timeline."""
+    ctxs = _batch_var.get()
+    return ctxs[0].trace_id if ctxs else None
+
+
 def batch_mark(phase: str) -> None:
     """Mark a phase boundary on every request in the active batch
     scope (no-op outside one — direct ``predict_table`` callers)."""

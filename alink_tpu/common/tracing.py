@@ -35,11 +35,22 @@ Switches (``common.metrics.env_flag`` parsing: unset -> default,
 
   * ``ALINK_TPU_TRACE``        — default OFF. Master switch for every
     instrumented producer (``trace_span``/``trace_instant`` below are
-    no-ops without it). Tracing never changes compiled programs — all
-    events are host-side (asserted by a lowered-HLO test).
+    no-ops without it, unless a profiler session runs: next paragraph).
+    Tracing never changes compiled programs — all events are host-side
+    (asserted by a lowered-HLO test).
   * ``ALINK_TPU_TRACE_BUFFER`` — flight-recorder capacity in events
     (default 65536; ~200 bytes/event, so the default bounds memory at a
     few tens of MB).
+
+The bridge to the device's clock: while a ``jax.profiler`` session is
+active (``jax.profiler.start_trace`` / ``jax.profiler.trace``), every
+producer records as if ``ALINK_TPU_TRACE`` were on, every event carries
+``profiled: true``, and every real span (not the retroactive
+``complete``, whose ends are already past) also enters a
+``jax.profiler.TraceAnnotation`` named ``alink:<name>``. The span then
+sits in the profiler's ``.xplane.pb`` on its host thread's line, stamped
+by the profiler's clock, beside the device's ``XLA Ops``. This module
+still imports no JAX: the session test looks JAX up in ``sys.modules``.
 
 Instrumented producers (engine exec/chunk phases, batch ``link_from``,
 stream micro-batches, FTRL, checkpoint save/restore, fault injection) all
@@ -53,6 +64,7 @@ from __future__ import annotations
 import contextvars
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -62,8 +74,10 @@ from .metrics import env_flag
 
 __all__ = [
     "Tracer", "Span", "get_tracer", "set_tracer", "tracing_enabled",
+    "profiler_active", "recording",
     "trace_span", "trace_instant", "trace_complete", "events_to_chrome",
     "TRACE_ENV", "TRACE_BUFFER_ENV", "DEFAULT_BUFFER_EVENTS",
+    "PROFILER_PREFIX",
 ]
 
 TRACE_ENV = "ALINK_TPU_TRACE"
@@ -72,11 +86,50 @@ DEFAULT_BUFFER_EVENTS = 65536
 
 TRACE_FORMAT = "alink_tpu_trace_v1"
 
+#: what a span's name is prefixed with in the profiler's trace
+PROFILER_PREFIX = "alink:"
+
 
 def tracing_enabled() -> bool:
     """``ALINK_TPU_TRACE`` switch (default off). Read live, so tests and
     long-lived processes can toggle it per run."""
     return env_flag(TRACE_ENV, default=False)
+
+
+_annotation_cls = None     # jax.profiler.TraceAnnotation, once JAX is there
+
+
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` while a profiler session is
+    active, else ``None``. Never imports JAX: a process that has not
+    loaded it cannot be profiling with it."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        jax = sys.modules.get("jax")
+        # mid-import ``jax`` is in sys.modules before ``jax.profiler`` is
+        cls = getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
+        if cls is None:
+            return None
+        _annotation_cls = cls
+    return cls if cls.is_enabled() else None
+
+
+def profiler_active() -> bool:
+    """Whether a ``jax.profiler`` session is recording right now."""
+    return _profiler_annotation() is not None
+
+
+def recording() -> bool:
+    """Whether the call-site helpers record: ``ALINK_TPU_TRACE`` is on or
+    a profiler session is active."""
+    return tracing_enabled() or _profiler_annotation() is not None
+
+
+def _annotation_args(args: Dict[str, Any]) -> Dict[str, Any]:
+    """The scalar args of a span, as the profiler can carry them."""
+    return {k: v for k, v in args.items()
+            if isinstance(v, (bool, int, float, str))}
 
 
 def _buffer_capacity() -> int:
@@ -101,7 +154,7 @@ class Span:
     cache status only known at the end of the region."""
 
     __slots__ = ("name", "cat", "args", "id", "parent", "tid",
-                 "_tracer", "_start_ns", "_token")
+                 "_tracer", "_start_ns", "_token", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: Optional[Dict[str, Any]]):
@@ -114,6 +167,7 @@ class Span:
         self.tid = 0
         self._start_ns = 0
         self._token = None
+        self._annotation = None
 
     def set(self, **kw) -> "Span":
         """Attach/overwrite args on the open span (chainable)."""
@@ -126,11 +180,21 @@ class Span:
         self.id = self._tracer._next_id()
         self.tid = threading.get_ident()
         self._token = _current_span.set(self)
+        cls = _profiler_annotation()
+        if cls is not None:
+            # args known now ride along; ``set`` later reaches the ring only
+            self._annotation = cls(PROFILER_PREFIX + self.name,
+                                   **_annotation_args(self.args))
+            self._annotation.__enter__()
         self._start_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         end_ns = time.perf_counter_ns()
+        profiled = self._annotation is not None
+        if profiled:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
         if self._token is not None:
             _current_span.reset(self._token)
             self._token = None
@@ -138,7 +202,7 @@ class Span:
             ph="X", name=self.name, cat=self.cat,
             ts_ns=self._start_ns, dur_ns=end_ns - self._start_ns,
             tid=self.tid, id=self.id, parent=self.parent,
-            args=self.args or None)
+            args=self.args or None, profiled=profiled)
         return False
 
 
@@ -173,10 +237,11 @@ class Tracer:
 
     Events are plain dicts ``{ph, name, cat, ts, dur, tid, id, parent,
     args}`` with ``ts``/``dur`` in microseconds relative to the tracer's
-    start. ``ph`` follows the Chrome Trace Event phases this module
-    emits: ``X`` (complete span) and ``i`` (instant). The buffer holds
-    the newest ``capacity`` events; older ones are dropped and counted
-    (``dropped``), never grown past the bound.
+    start, plus ``profiled: True`` on those recorded while a profiler
+    session was active. ``ph`` follows the Chrome Trace Event phases
+    this module emits: ``X`` (complete span) and ``i`` (instant). The
+    buffer holds the newest ``capacity`` events; older ones are dropped
+    and counted (``dropped``), never grown past the bound.
     """
 
     def __init__(self, capacity: Optional[int] = None):
@@ -201,7 +266,8 @@ class Tracer:
 
     def _record(self, *, ph: str, name: str, cat: str, ts_ns: int,
                 dur_ns: Optional[int], tid: int, id: Optional[int],
-                parent: Optional[int], args: Optional[Dict[str, Any]]):
+                parent: Optional[int], args: Optional[Dict[str, Any]],
+                profiled: bool = False):
         ev: Dict[str, Any] = {
             "ph": ph, "name": name, "cat": cat,
             "ts": (ts_ns - self._origin_ns) / 1e3,  # microseconds
@@ -215,6 +281,8 @@ class Tracer:
             ev["parent"] = parent
         if args:
             ev["args"] = args
+        if profiled:
+            ev["profiled"] = True
         with self._lock:
             if tid not in self._thread_names:
                 t = threading.current_thread()
@@ -235,7 +303,8 @@ class Tracer:
         self._record(ph="i", name=name, cat=cat,
                      ts_ns=time.perf_counter_ns(), dur_ns=None,
                      tid=threading.get_ident(), id=self._next_id(),
-                     parent=cur.id if cur is not None else None, args=args)
+                     parent=cur.id if cur is not None else None, args=args,
+                     profiled=profiler_active())
 
     def complete(self, name: str, dur_s: float, cat: str = "host",
                  args: Optional[Dict[str, Any]] = None) -> None:
@@ -243,14 +312,17 @@ class Tracer:
         ``dur_s``. For regions timed with an existing ``perf_counter``
         pair where entering a context manager is awkward (e.g. generator
         bodies that must not hold a context across a ``yield`` — the
-        caller's context would inherit the open span)."""
+        caller's context would inherit the open span). In-memory only:
+        a profiler annotation stamps its own ends, so a span that is
+        already over cannot be mirrored into the profiler's trace."""
         cur = _current_span.get()
         end_ns = time.perf_counter_ns()
         dur_ns = max(0, int(dur_s * 1e9))
         self._record(ph="X", name=name, cat=cat, ts_ns=end_ns - dur_ns,
                      dur_ns=dur_ns, tid=threading.get_ident(),
                      id=self._next_id(),
-                     parent=cur.id if cur is not None else None, args=args)
+                     parent=cur.id if cur is not None else None, args=args,
+                     profiled=profiler_active())
 
     # -- reading / management ---------------------------------------------
     def events(self) -> List[Dict[str, Any]]:
@@ -337,6 +409,8 @@ def events_to_chrome(meta: Dict[str, Any],
             args["span_id"] = ev["id"]
         if "parent" in ev:
             args["parent_id"] = ev["parent"]
+        if ev.get("profiled"):
+            args["profiled"] = True
         if args:
             ce["args"] = args
         out.append(ce)
@@ -377,24 +451,27 @@ def set_tracer(tracer: Tracer) -> Tracer:
 
 def trace_span(name: str, cat: str = "host",
                args: Optional[Dict[str, Any]] = None):
-    """A span on the process tracer, or a shared no-op when
-    ``ALINK_TPU_TRACE`` is off. The disabled fast path costs one env
-    lookup and allocates nothing."""
-    if not tracing_enabled():
+    """A span on the process tracer, or a shared no-op when neither
+    ``ALINK_TPU_TRACE`` is on nor a profiler session runs. The disabled
+    fast path costs one env lookup and one session test, and allocates
+    nothing."""
+    if not recording():
         return _NULL_SPAN
     return get_tracer().span(name, cat=cat, args=args)
 
 
 def trace_instant(name: str, cat: str = "host",
                   args: Optional[Dict[str, Any]] = None) -> None:
-    """An instant event on the process tracer; no-op when tracing is off."""
-    if tracing_enabled():
+    """An instant event on the process tracer; no-op when not
+    :func:`recording`."""
+    if recording():
         get_tracer().instant(name, cat=cat, args=args)
 
 
 def trace_complete(name: str, dur_s: float, cat: str = "host",
                    args: Optional[Dict[str, Any]] = None) -> None:
     """A retroactive span (ends now, lasted ``dur_s``) on the process
-    tracer; no-op when tracing is off. See :meth:`Tracer.complete`."""
-    if tracing_enabled():
+    tracer; no-op when not :func:`recording`. See
+    :meth:`Tracer.complete`."""
+    if recording():
         get_tracer().complete(name, dur_s, cat=cat, args=args)

@@ -37,9 +37,8 @@ from ....common.faults import maybe_crash
 from ....common.metrics import get_registry, metrics_enabled
 from ....common.mtable import MTable
 from ....common.params import InValidator, ParamInfo, Params, RangeValidator
-from ....common.profiling2 import (hbm_snapshot, mark as profile_mark,
-                                   open_window)
-from ....common.tracing import trace_complete, trace_instant
+from ....common.profiling2 import hbm_snapshot
+from ....common.tracing import trace_complete, trace_instant, trace_span
 from ....common.types import TableSchema
 from ....params.shared import (HasFeatureCols, HasLabelCol, HasPredictionCol,
                                HasPredictionDetailCol, HasReservedCols,
@@ -162,7 +161,11 @@ def _ftrl_step_factory(mesh, alpha, beta, l1, l2, donate=False):
     fn = shard_map(shard_fn, mesh=mesh,
                    in_specs=(P(None, "d"), P(), P("d"), P("d")),
                    out_specs=(P("d"), P("d"), P()))
-    weights_fn = shard_map(lambda z, n: weights(z, n), mesh=mesh,
+    def ftrl_weights(z, n):          # the snapshot program: jit_ftrl_weights
+        with jax.named_scope("ftrl_weights"):
+            return weights(z, n)
+
+    weights_fn = shard_map(ftrl_weights, mesh=mesh,
                            in_specs=(P("d"), P("d")), out_specs=P("d"))
     # weights_fn never donates: the snapshot path reads w from the LIVE
     # (z, n) and the state must survive for the next micro-batch
@@ -271,39 +274,46 @@ def _ftrl_sparse_step_factory(mesh, alpha, beta, l1, l2, donate=False,
         def body(carry, xvy):
             z, n = carry
             xi, xv, yy = xvy                  # (K, w), (K, w), (K,)
-            local = (xi >= lo) & (xi < lo + shard)
-            li = jnp.clip(xi - lo, 0, shard - 1)
-            zs = jnp.where(local, _sgather(z, li.reshape(-1)).reshape(K, w),
-                           0.0)
-            ns = jnp.where(local, _sgather(n, li.reshape(-1)).reshape(K, w),
-                           0.0)
+            # the named scopes group the round's device ops by what they
+            # do in the profiler's viewer (op metadata only)
+            with jax.named_scope("ftrl_gather"):
+                local = (xi >= lo) & (xi < lo + shard)
+                li = jnp.clip(xi - lo, 0, shard - 1)
+                zs = jnp.where(
+                    local, _sgather(z, li.reshape(-1)).reshape(K, w), 0.0)
+                ns = jnp.where(
+                    local, _sgather(n, li.reshape(-1)).reshape(K, w), 0.0)
             dzs, dns, margins = [], [], []
-            for k in range(K):
-                zk, nk = zs[k], ns[k]
-                for j in range(k):
-                    Mkj = ((xi[k][:, None] == xi[j][None, :])
-                           & local[k][:, None] & local[j][None, :]
-                           ).astype(zk.dtype)
-                    # HIGHEST: the default matmul precision would round
-                    # the f32 deltas to bf16 on the MXU and break the
-                    # exact-strict-semantics claim under collisions
-                    # (negligible cost at w ~ 40)
-                    zk = zk + jnp.matmul(
-                        Mkj, dzs[j], precision=jax.lax.Precision.HIGHEST)
-                    nk = nk + jnp.matmul(
-                        Mkj, dns[j], precision=jax.lax.Precision.HIGHEST)
-                wj = jnp.where(local[k], weights(zk, nk), 0.0)
-                margin = manifest_psum(jnp.sum(xv[k] * wj), "d",
-                                       name="ftrl_margin",
-                                       num_workers=mesh.size)
-                p = 1.0 / (1.0 + jnp.exp(-jnp.clip(margin, -35.0, 35.0)))
-                g = (p - yy[k]) * xv[k]
-                sigma = (jnp.sqrt(nk + g * g) - jnp.sqrt(nk)) / alpha
-                dzs.append(jnp.where(local[k], g - sigma * wj, 0.0))
-                dns.append(jnp.where(local[k], g * g, 0.0))
-                margins.append(margin)
-            z = _sscatter(z, li.reshape(-1), jnp.stack(dzs).reshape(-1))
-            n = _sscatter(n, li.reshape(-1), jnp.stack(dns).reshape(-1))
+            with jax.named_scope("ftrl_update"):
+                for k in range(K):
+                    zk, nk = zs[k], ns[k]
+                    for j in range(k):
+                        Mkj = ((xi[k][:, None] == xi[j][None, :])
+                               & local[k][:, None] & local[j][None, :]
+                               ).astype(zk.dtype)
+                        # HIGHEST: the default matmul precision would
+                        # round the f32 deltas to bf16 on the MXU and
+                        # break the exact-strict-semantics claim under
+                        # collisions (negligible cost at w ~ 40)
+                        zk = zk + jnp.matmul(
+                            Mkj, dzs[j],
+                            precision=jax.lax.Precision.HIGHEST)
+                        nk = nk + jnp.matmul(
+                            Mkj, dns[j],
+                            precision=jax.lax.Precision.HIGHEST)
+                    wj = jnp.where(local[k], weights(zk, nk), 0.0)
+                    margin = manifest_psum(jnp.sum(xv[k] * wj), "d",
+                                           name="ftrl_margin",
+                                           num_workers=mesh.size)
+                    p = 1.0 / (1.0 + jnp.exp(-jnp.clip(margin, -35.0, 35.0)))
+                    g = (p - yy[k]) * xv[k]
+                    sigma = (jnp.sqrt(nk + g * g) - jnp.sqrt(nk)) / alpha
+                    dzs.append(jnp.where(local[k], g - sigma * wj, 0.0))
+                    dns.append(jnp.where(local[k], g * g, 0.0))
+                    margins.append(margin)
+            with jax.named_scope("ftrl_scatter"):
+                z = _sscatter(z, li.reshape(-1), jnp.stack(dzs).reshape(-1))
+                n = _sscatter(n, li.reshape(-1), jnp.stack(dns).reshape(-1))
             return (z, n), jnp.stack(margins)
 
         (z, n), margins = jax.lax.scan(
@@ -1007,14 +1017,7 @@ class FtrlTrainStreamOp(StreamOperator, HasVectorCol, HasFeatureCols, HasLabelCo
             # on the sharded weights waits for each shard in turn).
             # weights_fn reads the LIVE state and never donates, so
             # (z, n) survive for the next micro-batch.
-            _pt0 = time.perf_counter()
             w_full = np.asarray(jax.device_get(weights_fn(z_host, n_host)))
-            # measured-profiling device mark (ALINK_TPU_PROFILE):
-            # dispatch is asynchronous, so the drain's queued device
-            # work completes at this fetch and its wall is the drain's
-            # block-until-ready delta, not a pure transfer
-            profile_mark("ftrl.snapshot", "device",
-                         time.perf_counter() - _pt0)
             hbm_snapshot("ftrl.snapshot")
             if mon_on and batch is not None:
                 # weight drift vs the PREVIOUS emitted snapshot — the
@@ -1249,7 +1252,7 @@ class FtrlTrainStreamOp(StreamOperator, HasVectorCol, HasFeatureCols, HasLabelCo
                     seen += 1
                     if seen <= resume_skip:
                         continue   # committed before the crash
-                    yield (t, mt, batch_size)
+                    yield (t, mt, batch_size, seen)
 
             # COO pad width, shared across encode workers. Monotone
             # (grows in steps of 8); with ALINK_TPU_STREAM_WORKERS > 1 a
@@ -1270,19 +1273,18 @@ class FtrlTrainStreamOp(StreamOperator, HasVectorCol, HasFeatureCols, HasLabelCo
                 overlaps the device running batch t (VERDICT r2 #4;
                 Flink's pipelined operators,
                 FtrlTrainStreamOp.java:120-135)."""
-                t, mt, batch_size = item
-                enc = encode(mt, max(batch_size, mt.num_rows),
-                             width_cell[0])
+                t, mt, batch_size, seen = item
+                # ``seen`` is the consumer's ``b_done + 1`` for this
+                # micro-batch: one number on the spans of both threads
+                tag = {"batch": seen, "rows": mt.num_rows}
+                with trace_span("ftrl.encode", cat="stream", args=tag):
+                    enc = encode(mt, max(batch_size, mt.num_rows),
+                                 width_cell[0])
                 if enc[0] == "sparse":
                     with width_lock:
                         width_cell[0] = max(width_cell[0], enc[4])
-                # measured-profiling transfer mark: the H2D micro-batch
-                # ship (runs on the prefetch thread; the collector is
-                # thread-safe and workloads run serially)
-                _pt0 = time.perf_counter()
-                shipped = put_replicated(enc)
-                profile_mark("ftrl.encode", "transfer",
-                             time.perf_counter() - _pt0)
+                with trace_span("ftrl.ship", cat="stream", args=tag):
+                    shipped = put_replicated(enc)
                 return (t, mt, shipped, batch_size)
 
             from ..prefetch import prefetch_map
@@ -1330,14 +1332,14 @@ class FtrlTrainStreamOp(StreamOperator, HasVectorCol, HasFeatureCols, HasLabelCo
                     meta["fb_S"] = int(fb_S)
                     meta["fb_num_fields"] = int(fb_meta.num_fields)
                     meta["fb_field_size"] = int(fb_meta.field_size)
-                _pt0 = time.perf_counter()
-                zh, nh = jax.device_get([z, n])
-                profile_mark("ftrl.checkpoint", "device",
-                             time.perf_counter() - _pt0)
-                hbm_snapshot("ftrl.checkpoint")
-                save_checkpoint(ck_dir, b_done,
-                                {"z": np.asarray(zh), "n": np.asarray(nh)},
-                                meta=meta, scope="ftrl", keep_last=ck_keep)
+                with trace_span("ftrl.checkpoint", cat="stream",
+                                args={"batch": b_done}):
+                    zh, nh = jax.device_get([z, n])
+                    hbm_snapshot("ftrl.checkpoint")
+                    save_checkpoint(
+                        ck_dir, b_done,
+                        {"z": np.asarray(zh), "n": np.asarray(nh)},
+                        meta=meta, scope="ftrl", keep_last=ck_keep)
                 if mon_on:
                     # the snapshot fetch just synced the device queue, so
                     # the pending pv scalars are free to read now; a
@@ -1374,9 +1376,7 @@ class FtrlTrainStreamOp(StreamOperator, HasVectorCol, HasFeatureCols, HasLabelCo
                 # checkpoint this boundary published
                 monitor.evaluate()
 
-            # telemetry is per-micro-batch (HOST dispatch latency: device
-            # work is async, so the histogram reads as dispatch+encode
-            # pressure, not device time) — resolved once per drain
+            # telemetry is per-micro-batch — resolved once per drain
             mx = metrics_enabled()
             reg = get_registry() if mx else None
             m_lbl = {"op": "FtrlTrainStreamOp", "mode": update_mode}
@@ -1392,11 +1392,13 @@ class FtrlTrainStreamOp(StreamOperator, HasVectorCol, HasFeatureCols, HasLabelCo
                 hook = self._device_snapshot_hook
                 if hook is None or z is None:
                     return False
-                consumed = bool(hook(weights_fn(z, n),
-                                     {"fb_S": fb_S, "dim": dim,
-                                      "has_intercept": bool(has_icpt),
-                                      "batch": batch,
-                                      "event_time": t_ev}))
+                with trace_span("ftrl.snapshot", cat="stream",
+                                args={"batch": batch, "to": "device"}):
+                    consumed = bool(hook(weights_fn(z, n),
+                                         {"fb_S": fb_S, "dim": dim,
+                                          "has_intercept": bool(has_icpt),
+                                          "batch": batch,
+                                          "event_time": t_ev}))
                 if consumed:
                     hbm_snapshot("ftrl.snapshot")
                     if mx:
@@ -1409,11 +1411,12 @@ class FtrlTrainStreamOp(StreamOperator, HasVectorCol, HasFeatureCols, HasLabelCo
                 # wrapped in a throwaway collector so a compile-time
                 # trace doesn't ALSO record directly — the replay is
                 # the single source of truth for this call.
-                # measured-profiling dispatch mark: the time the step
-                # dispatch held the consumer thread (device work is
+                # The span is the time the step call held the consumer
+                # thread: host cost, plus the runtime's back-pressure
+                # once its in-flight limit is reached (device work is
                 # async; it materializes at the snapshot fetch)
-                _pt0 = time.perf_counter()
-                try:
+                with trace_span("ftrl.dispatch", cat="stream",
+                                args={"batch": b_done + 1}):
                     if mx:
                         from ....engine.communication import (
                             collecting, record_manifest)
@@ -1421,9 +1424,6 @@ class FtrlTrainStreamOp(StreamOperator, HasVectorCol, HasFeatureCols, HasLabelCo
                         with collecting([]):
                             return step(*args)
                     return step(*args)
-                finally:
-                    profile_mark("ftrl.drain", "dispatch",
-                                 time.perf_counter() - _pt0)
             # ordered pool: workers=1 (default) is byte-for-byte the old
             # single-prefetch-thread drain; ALINK_TPU_STREAM_WORKERS=N
             # parallelizes the host encode N-wide with order preserved
@@ -1529,24 +1529,21 @@ class FtrlTrainStreamOp(StreamOperator, HasVectorCol, HasFeatureCols, HasLabelCo
                   if len(pv_pending) >= 512:
                       flush_pv()
               # retroactive span (generator body; see stream/core.py on
-              # why an open span must not cross a yield): encode overlap
-              # happens in the prefetch thread, so this span reads as the
-              # consumer-side dispatch latency of one micro-batch
+              # why an open span must not cross a yield), so in-memory
+              # only: encode overlap happens in the prefetch thread, so
+              # this span reads as the consumer-side cost of one
+              # micro-batch after the item arrived (no get_wait in it)
               trace_complete("ftrl.batch", time.perf_counter() - t0,
                              cat="stream",
                              args={"mode": update_mode, "rows": mt.num_rows,
                                    "batch": b_done + 1})
               if mx:
-                  reg.observe("alink_ftrl_batch_seconds",
-                              time.perf_counter() - t0, m_lbl)
                   reg.inc("alink_ftrl_rows_total", mt.num_rows, m_lbl)
                   reg.inc("alink_stream_batches_total", 1,
                           {"op": "FtrlTrainStreamOp"})
                   reg.inc("alink_stream_rows_total", mt.num_rows,
                           {"op": "FtrlTrainStreamOp"})
               if t + 1e-12 >= next_emit:
-                  trace_instant("ftrl.snapshot", cat="stream",
-                                args={"event_time": t, "batch": b_done + 1})
                   if device_emit(t, b_done + 1):
                       if mon_on:
                           flush_pv()
@@ -1556,7 +1553,10 @@ class FtrlTrainStreamOp(StreamOperator, HasVectorCol, HasFeatureCols, HasLabelCo
                       # mangles the EMITTED table (the serving feeder's
                       # poisoned-snapshot path) without touching state
                       _poison = maybe_crash("feeder.snapshot")
-                      snap = snapshot(z, n, fb_S, batch=b_done + 1)
+                      with trace_span("ftrl.snapshot", cat="stream",
+                                      args={"batch": b_done + 1,
+                                            "to": "host"}):
+                          snap = snapshot(z, n, fb_S, batch=b_done + 1)
                       if _poison:
                           snap = _corrupt_snapshot_table(snap)
                       if mon_on:
@@ -1590,16 +1590,17 @@ class FtrlTrainStreamOp(StreamOperator, HasVectorCol, HasFeatureCols, HasLabelCo
                 z, n = alloc(layout)
             if mx:
                 reg.inc("alink_ftrl_snapshots_total", 1)
-            trace_instant("ftrl.snapshot", cat="stream",
-                          args={"batch": b_done, "final": True})
             if device_emit(next_emit if next_emit is not None else interval,
                            b_done if b_done > 0 else None):
                 if mon_on:
                     flush_pv()
             else:
                 _poison = maybe_crash("feeder.snapshot")
-                snap = snapshot(z, n, fb_S,
-                                batch=b_done if b_done > 0 else None)
+                with trace_span("ftrl.snapshot", cat="stream",
+                                args={"batch": b_done, "to": "host",
+                                      "final": True}):
+                    snap = snapshot(z, n, fb_S,
+                                    batch=b_done if b_done > 0 else None)
                 if _poison:
                     snap = _corrupt_snapshot_table(snap)
                 if mon_on:
@@ -1607,17 +1608,7 @@ class FtrlTrainStreamOp(StreamOperator, HasVectorCol, HasFeatureCols, HasLabelCo
                 yield (next_emit if next_emit is not None else interval,
                        snap)
 
-        def gen_profiled():
-            # drain-level capture window (ALINK_TPU_PROFILE): wall of
-            # the whole drain + the xprof capture scope. Opened/closed
-            # manually — a `with` must not be held across the yields
-            _pw = open_window("ftrl.drain", capture=True)
-            try:
-                yield from gen()
-            finally:
-                _pw.close()
-
-        self._stream_fn = gen_profiled
+        self._stream_fn = gen
         return self
 
 

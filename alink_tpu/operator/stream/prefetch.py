@@ -39,7 +39,14 @@ Env knobs:
 Observability: the channel exports an ``alink_prefetch_depth`` gauge
 (items currently buffered, labelled by consumer) so a stalled producer
 (gauge pinned at 0) or a stalled consumer (pinned at the bound) is
-visible in ``tools/run_report.py`` output.
+visible in ``tools/run_report.py`` output. Three spans (``common/
+tracing.py``; recorded under ``ALINK_TPU_TRACE`` or a profiler session)
+say who waits for whom: ``prefetch.pull`` bounds the upstream's own time
+for one item on the producer's thread; ``prefetch.put_wait`` is the
+producer blocked on a full channel (the consumer is behind);
+``prefetch.get_wait`` is the consumer blocked on an empty one (the
+producer is behind). The two waits open only on the branch that really
+blocks, so a put or get that finds room or an item records nothing.
 """
 
 from __future__ import annotations
@@ -50,6 +57,7 @@ from collections import deque
 from typing import Callable, Iterable, Iterator, Optional, TypeVar
 
 from ...common.faults import maybe_crash
+from ...common.tracing import trace_span
 
 T = TypeVar("T")
 U = TypeVar("U")
@@ -59,6 +67,20 @@ _SENTINEL = object()
 # end-of-stream sentinel so "nothing arrived within the latency budget"
 # and "the stream is over" stay distinguishable
 _EMPTY = object()
+
+
+def _pulled(it: Iterable[T]) -> Iterator[T]:
+    """``it``, each ``next()`` of it inside a ``prefetch.pull`` span: the
+    source's own time for one item. The span closes before the item is
+    handed on, so it never stays open across the ``yield``."""
+    it = iter(it)
+    while True:
+        with trace_span("prefetch.pull", cat="stream"):
+            try:
+                item = next(it)
+            except StopIteration:
+                return
+        yield item
 
 
 def prefetch_depth(default: int = 2) -> int:
@@ -104,16 +126,22 @@ class _Channel:
             get_registry().set_gauge("alink_prefetch_depth", depth,
                                      {"consumer": self._gauge_label})
 
+    def _full(self) -> bool:
+        """A put has to wait (lock held): bounded, at the bound, and
+        nobody has stopped or closed the channel."""
+        return (not self._stopped and not self._closed
+                and 0 < self._maxsize <= len(self._buf))
+
     def put(self, item) -> bool:
         """Enqueue; False when the consumer has stopped OR the channel
         is already closed (a producer racing ``close()`` must not
         strand an item no getter will ever see — the serving tier's
         submit-vs-shutdown race)."""
         with self._not_full:
-            while not self._stopped and not self._closed \
-                    and self._maxsize > 0 \
-                    and len(self._buf) >= self._maxsize:
-                self._not_full.wait()
+            if self._full():
+                with trace_span("prefetch.put_wait", cat="stream"):
+                    while self._full():
+                        self._not_full.wait()
             if self._stopped or self._closed:
                 return False
             self._buf.append(item)
@@ -136,6 +164,26 @@ class _Channel:
         deadline = None if timeout is None \
             else time.monotonic() + max(0.0, timeout)
         with self._not_empty:
+            if not self._buf:
+                ended = self._await_item(deadline)
+                if ended is not None:
+                    return ended
+            item = self._buf.popleft()
+            self._gauge(len(self._buf))
+            self._not_full.notify()
+            return item
+
+    def _await_item(self, deadline: Optional[float]):
+        """Wait (lock held, buffer empty) until the buffer holds an item:
+        ``None`` then, else the marker ``get`` returns. The
+        ``prefetch.get_wait`` span opens only where the wait is real, so
+        a poll (``timeout=0``) and a get on an ended channel record
+        nothing."""
+        if self._stopped or self._closed:
+            return _SENTINEL
+        if deadline is not None and deadline <= time.monotonic():
+            return _EMPTY
+        with trace_span("prefetch.get_wait", cat="stream"):
             while not self._buf:
                 if self._stopped or self._closed:
                     return _SENTINEL
@@ -146,10 +194,7 @@ class _Channel:
                 if remaining <= 0:
                     return _EMPTY
                 self._not_empty.wait(remaining)
-            item = self._buf.popleft()
-            self._gauge(len(self._buf))
-            self._not_full.notify()
-            return item
+        return None
 
     def depth(self) -> int:
         """Items currently buffered (the admission-control reading the
@@ -294,7 +339,7 @@ def prefetch_map(it: Iterable[T], fn: Callable[[T], U],
         # propagation — the contract the single-thread path always had)
         def _mapped():
             try:
-                for item in it:
+                for item in _pulled(it):
                     yield fn(item)
             finally:
                 close = getattr(it, "close", None)
@@ -313,7 +358,7 @@ def prefetch_map(it: Iterable[T], fn: Callable[[T], U],
     def dispatcher():
         seq = 0
         try:
-            for item in it:
+            for item in _pulled(it):
                 if not in_ch.put((seq, item)):
                     return
                 seq += 1
@@ -372,14 +417,22 @@ def prefetch_map(it: Iterable[T], fn: Callable[[T], U],
     for th in threads:
         th.start()
     next_seq = 0
+
+    def _ended() -> bool:
+        """Upstream is over and ``next_seq`` lies past its last item
+        (``done`` held)."""
+        return state["total"] is not None and next_seq >= state["total"]
+
     try:
         while True:
             with done:
-                while next_seq not in results:
-                    if state["total"] is not None \
-                            and next_seq >= state["total"]:
-                        return
-                    done.wait()
+                if next_seq not in results and not _ended():
+                    # the consumer starves: no worker has item next_seq yet
+                    with trace_span("prefetch.get_wait", cat="stream"):
+                        while next_seq not in results and not _ended():
+                            done.wait()
+                if next_seq not in results:
+                    return
                 kind, val = results.pop(next_seq)
                 done.notify_all()     # admission-gated workers wake here
             if kind == "err":

@@ -62,7 +62,7 @@ from ..common.plan import serving_event_plan
 from ..common.faults import maybe_crash
 from ..common.metrics import get_registry, metrics_enabled
 from ..common.mtable import MTable
-from ..common.tracing import trace_complete, trace_span
+from ..common.tracing import trace_span
 from .plan import ServingPlan
 from .sharded import (SERVE_LANES, mesh_fingerprint,
                       serve_sharded_enabled, serving_mesh)
@@ -800,8 +800,6 @@ class CompiledPredictor:
         return _merge_parts(parts)
 
     def _predict_chunk(self, data: MTable, replica: int = 0) -> MTable:
-        import jax
-        t0 = time.perf_counter()
         # deterministic fault site (common/faults.py): error = a
         # catchable transient dispatch failure (what trips the serving
         # circuit breaker), delay:MS = latency injection, kill = the
@@ -811,21 +809,44 @@ class CompiledPredictor:
         ver = self._active           # one consistent model per dispatch
         n = data.num_rows
         bucket = self.bucket_for(n)
-        kind, arrays = ver.kernel.encode(data, bucket)
-        placed = self._place_inputs(ver, kind, arrays, replica)
+        # one bucket program, by phase: every span of the batch carries
+        # the same tag (the first request's trace id inside a server
+        # batch scope)
+        tag = {"rows": n, "bucket": bucket, "model_version": ver.version}
+        trace_id = reqtrace.batch_trace_id()
+        if trace_id is not None:
+            tag["trace_id"] = trace_id
+        with trace_span("serve.batch", cat="serve", args=tag):
+            result = self._run_bucket(ver, data, bucket, replica, tag)
+        if metrics_enabled():
+            reg = get_registry()
+            lbl = {"predictor": self.name}
+            reg.inc("alink_serve_batches_total", 1, lbl)
+            reg.observe("alink_serve_batch_occupancy", n / bucket, lbl)
+        return result
+
+    def _run_bucket(self, ver: _ModelVersion, data: MTable, bucket: int,
+                    replica: int, tag: dict) -> MTable:
+        import jax
+        with trace_span("serve.encode", cat="serve", args=tag):
+            kind, arrays = ver.kernel.encode(data, bucket)
+        with trace_span("serve.place", cat="serve", args=tag):
+            placed = self._place_inputs(ver, kind, arrays, replica)
         prog, manifest = self._program(ver, kind, bucket, arrays, placed)
-        if manifest:
-            from ..engine.communication import collecting, record_manifest
-            record_manifest(manifest)
-            # the replayed manifest is the ONLY accounting: should the
-            # call retrace (jax version didn't warm the call cache from
-            # the AOT lower), its trace-time records land in a discarded
-            # sink instead of double-charging the registry — the FTRL
-            # drain's collecting([]) idiom
-            with collecting([]):
+        with trace_span("serve.dispatch", cat="serve", args=tag):
+            if manifest:
+                from ..engine.communication import (collecting,
+                                                    record_manifest)
+                record_manifest(manifest)
+                # the replayed manifest is the ONLY accounting: should
+                # the call retrace (jax version didn't warm the call
+                # cache from the AOT lower), its trace-time records land
+                # in a discarded sink instead of double-charging the
+                # registry — the FTRL drain's collecting([]) idiom
+                with collecting([]):
+                    out = prog(ver.arrays_for(replica), *placed)
+            else:
                 out = prog(ver.arrays_for(replica), *placed)
-        else:
-            out = prog(ver.arrays_for(replica), *placed)
         if not isinstance(out, (tuple, list)):
             out = (out,)
         # request-timeline phase boundaries (ISSUE 18): dispatch work
@@ -834,19 +855,13 @@ class CompiledPredictor:
         # server batch scope — pure host bookkeeping either way.
         reqtrace.batch_mark("dispatch")
         # ONE batched host fetch, then slice the padding rows off
-        host = jax.device_get(list(out))
+        with trace_span("serve.fetch", cat="serve", args=tag):
+            host = jax.device_get(list(out))
         reqtrace.batch_mark("device")
-        sliced = tuple(np.asarray(a)[:n] for a in host)
-        result = ver.kernel.decode(sliced, data)
+        with trace_span("serve.decode", cat="serve", args=tag):
+            sliced = tuple(np.asarray(a)[:data.num_rows] for a in host)
+            result = ver.kernel.decode(sliced, data)
         reqtrace.batch_mark("decode")
-        trace_complete("serve.batch", time.perf_counter() - t0, cat="serve",
-                       args={"rows": n, "bucket": bucket,
-                             "model_version": ver.version})
-        if metrics_enabled():
-            reg = get_registry()
-            lbl = {"predictor": self.name}
-            reg.inc("alink_serve_batches_total", 1, lbl)
-            reg.observe("alink_serve_batch_occupancy", n / bucket, lbl)
         return result
 
     def predict_row(self, row: Tuple) -> Tuple:

@@ -43,7 +43,7 @@ from ..common.adminz import acquire_admin, release_admin
 from ..common.faults import FaultInjected
 from ..common.metrics import get_registry, metrics_enabled
 from ..common.mtable import MTable
-from ..common.tracing import trace_complete, trace_instant
+from ..common.tracing import trace_complete, trace_instant, trace_span
 from ..operator.stream.prefetch import _Channel, _EMPTY, _SENTINEL
 from .loadgen import percentile as _percentile
 from .predictor import (CompiledPredictor, serve_min_fill,
@@ -56,6 +56,18 @@ from .resilience import (OPEN, CircuitBreaker, DeadlineExceeded,
 
 _P99_RING = 4096        # rolling latency window behind the p99 gauge
 _P99_EVERY = 128        # gauge refresh cadence (requests)
+
+
+def _batch_tag(batch: List["RequestFuture"]) -> dict:
+    """What the tracer spans of one serving batch share: its rows and,
+    where ``reqtrace`` minted one, the first request's trace id (the
+    per-request timeline stays ``reqtrace``'s; these spans are per
+    batch)."""
+    ctx = batch[0].ctx
+    tag = {"rows": len(batch)}
+    if ctx is not None:
+        tag["trace_id"] = ctx.trace_id
+    return tag
 
 
 class RequestFuture:
@@ -304,43 +316,54 @@ class PredictServer:
     def _loop(self, replica: int, inflight: List[RequestFuture]) -> None:
         while True:
             del inflight[:]
-            first = self._ch.get()
-            if first is _SENTINEL:
-                return
-            inflight.append(first)
-            if first.ctx is not None:
-                first.ctx.mark("dequeue")
-            deadline = None
-            closing = False
-            while len(inflight) < self.max_batch:
-                got = self._ch.drain(self.max_batch - len(inflight))
-                if got:
-                    inflight.extend(got)
-                    for f in got:
-                        if f.ctx is not None:
-                            f.ctx.mark("dequeue")
-                    continue
-                # queue drained: dispatch NOW unless the batch is under
-                # min_fill and latency budget remains
-                if len(inflight) >= self.min_fill:
-                    break
-                if deadline is None:
-                    deadline = time.monotonic() + self.window_s
-                remaining = deadline - time.monotonic()
-                if remaining <= 0:
-                    break
-                nxt = self._ch.get(timeout=remaining)
-                if nxt is _EMPTY:
-                    break
-                if nxt is _SENTINEL:
-                    closing = True
-                    break
-                inflight.append(nxt)
-                if nxt.ctx is not None:
-                    nxt.ctx.mark("dequeue")
+            # queue wait and window hold of ONE batch (the loop idling on
+            # an empty queue is its child span ``prefetch.get_wait``)
+            with trace_span("serve.collect", cat="serve") as span:
+                closing = self._collect(inflight)
+                if not inflight:
+                    return
+                span.set(**_batch_tag(inflight))
             self._serve(inflight, replica)
             if closing:
                 return
+
+    def _collect(self, inflight: List[RequestFuture]) -> bool:
+        """Fill ``inflight`` with the next micro-batch (left empty when
+        the channel ended first); True when the channel closed under the
+        window hold, so this batch is the loop's last."""
+        first = self._ch.get()
+        if first is _SENTINEL:
+            return True
+        inflight.append(first)
+        if first.ctx is not None:
+            first.ctx.mark("dequeue")
+        deadline = None
+        while len(inflight) < self.max_batch:
+            got = self._ch.drain(self.max_batch - len(inflight))
+            if got:
+                inflight.extend(got)
+                for f in got:
+                    if f.ctx is not None:
+                        f.ctx.mark("dequeue")
+                continue
+            # queue drained: dispatch NOW unless the batch is under
+            # min_fill and latency budget remains
+            if len(inflight) >= self.min_fill:
+                break
+            if deadline is None:
+                deadline = time.monotonic() + self.window_s
+            remaining = deadline - time.monotonic()
+            if remaining <= 0:
+                break
+            nxt = self._ch.get(timeout=remaining)
+            if nxt is _EMPTY:
+                break
+            if nxt is _SENTINEL:
+                return True
+            inflight.append(nxt)
+            if nxt.ctx is not None:
+                nxt.ctx.mark("dequeue")
+        return False
 
     # -- deadline / cancellation shedding ---------------------------------
     def _admit(self, batch: List[RequestFuture],
@@ -434,9 +457,11 @@ class PredictServer:
             if br is not None and route != "fallback" and not settled:
                 settled = True
                 br.on_failure(probe=(route == "probe"))
+        tag = _batch_tag(batch)
         try:
-            data = MTable([f.row for f in batch],
-                          self.predictor.data_schema)
+            with trace_span("serve.assemble", cat="serve", args=tag):
+                data = MTable([f.row for f in batch],
+                              self.predictor.data_schema)
             if route == "fallback":
                 out = self._fallback(data)
             else:
@@ -467,10 +492,11 @@ class PredictServer:
             # vectorized fan-out: pull the output columns once, hand
             # each future its row tuple (out.row(i) would re-resolve
             # every column per request)
-            cols = [out.col(nm) for nm in out.col_names]
-            done_t = time.perf_counter()
-            for i, fut in enumerate(batch):
-                fut.set_result(tuple(c[i] for c in cols))
+            with trace_span("serve.fanout", cat="serve", args=tag):
+                cols = [out.col(nm) for nm in out.col_names]
+                done_t = time.perf_counter()
+                for i, fut in enumerate(batch):
+                    fut.set_result(tuple(c[i] for c in cols))
         except FaultInjected:
             _settle_failure()
             raise
@@ -482,7 +508,8 @@ class PredictServer:
                     fut.set_exception(e)
             with self._stats_lock:
                 self._failed += len(batch)
-        self._account(batch, done_t)
+        with trace_span("serve.account", cat="serve", args=tag):
+            self._account(batch, done_t)
 
     def _fallback(self, data: MTable) -> MTable:
         """Breaker-open degradation: the batch serves through the HOST

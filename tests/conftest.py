@@ -28,3 +28,18 @@ def _default_env():
 @pytest.fixture
 def rng():
     return np.random.RandomState(2026)
+
+
+@pytest.fixture
+def quiet_tracer(monkeypatch):
+    """A tracer of the test's own with ``ALINK_TPU_TRACE`` unset: only the
+    test (the flag, a profiler session) makes the call-site helpers
+    record."""
+    from alink_tpu.common.tracing import Tracer, set_tracer
+    monkeypatch.delenv("ALINK_TPU_TRACE", raising=False)
+    tr = Tracer()
+    prev = set_tracer(tr)
+    try:
+        yield tr
+    finally:
+        set_tracer(prev)

@@ -5,6 +5,8 @@ collected results; FTRL example DAG FTRLExample.java:18-113).
 """
 
 import json
+import threading
+import time
 
 import numpy as np
 import pytest
@@ -684,3 +686,200 @@ def test_ftrl_strict_chunked_scan_exact_under_collisions():
     np.testing.assert_allclose(np.asarray(margins), ms, rtol=2e-5,
                                atol=1e-7)
     assert len(np.asarray(margins)) == B
+
+
+# -- the program's own spans (ISSUE 25) ---------------------------------------
+
+def _tiny_ftrl(table, warm, **kw):
+    """The final model's coefficients of a four-micro-batch sparse drain."""
+    from alink_tpu.operator.common.linear.base import LinearModelDataConverter
+    ftrl = FtrlTrainStreamOp(
+        warm, label_col="label", vector_col="vec", alpha=0.5,
+        l1=0.001, l2=0.001, time_interval=2.0, **kw).link_from(
+        MemSourceStreamOp(table, batch_size=64))
+    snaps = list(ftrl.micro_batches())
+    lt = snaps[-1].schema.types[2]
+    return [LinearModelDataConverter(lt).load_model(s).coef for s in snaps]
+
+
+@pytest.fixture(scope="module")
+def tiny_ftrl_inputs():
+    table = _sparse_lr_fixture(n=256, dim=24, nnz=5, seed=3)
+    warm = LogisticRegressionTrainBatchOp(
+        vector_col="vec", label_col="label", max_iter=3).link_from(
+        MemSourceBatchOp(table.first_n(64)))
+    return table, warm
+
+
+def test_ftrl_drain_records_one_span_of_each_kind_per_micro_batch(
+        quiet_tracer, monkeypatch, tiny_ftrl_inputs, tmp_path):
+    table, warm = tiny_ftrl_inputs
+    monkeypatch.setenv("ALINK_TPU_TRACE", "1")
+    _tiny_ftrl(table, warm, checkpoint_dir=str(tmp_path / "ck"),
+               checkpoint_every_batches=2)
+    evs = [e for e in quiet_tracer.events() if e["ph"] == "X"]
+    threads = quiet_tracer._meta()["threads"]
+    by = {}
+    for e in evs:
+        by.setdefault(e["name"], []).append(e)
+    # every micro-batch: exactly one encode, ship, dispatch and batch, all
+    # under one batch number; producer side on a prefetch thread, consumer
+    # side on the caller's
+    me = threading.get_ident()
+    for name in ("ftrl.encode", "ftrl.ship", "ftrl.dispatch", "ftrl.batch"):
+        assert sorted(e["args"]["batch"] for e in by[name]) == [1, 2, 3, 4], name
+    for name in ("ftrl.encode", "ftrl.ship"):
+        for e in by[name]:
+            assert threads[str(e["tid"])].startswith("alink-prefetch-"), name
+            assert e["tid"] != me and e["args"]["rows"] == 64
+    for name in ("ftrl.dispatch", "ftrl.batch"):
+        assert {e["tid"] for e in by[name]} == {me}, name
+    for b in (1, 2, 3, 4):
+        enc, ship, disp = (next(e for e in by[n] if e["args"]["batch"] == b)
+                           for n in ("ftrl.encode", "ftrl.ship",
+                                     "ftrl.dispatch"))
+        # encoded, then shipped, then dispatched: one micro-batch's order
+        assert enc["ts"] + enc["dur"] <= ship["ts"]
+        assert ship["ts"] + ship["dur"] <= disp["ts"]
+    # the upstream's own time: one pull an item, and the one that found
+    # the stream's end
+    pulls = by["prefetch.pull"]
+    assert len(pulls) == 5
+    assert all(threads[str(e["tid"])].startswith("alink-prefetch-")
+               for e in pulls)
+    # snapshots at event times 2 and 3 (the interval's boundaries), and the
+    # final one; checkpoints after micro-batches 2 and 4
+    snaps = by["ftrl.snapshot"]
+    assert [e["args"]["to"] for e in snaps] == ["host"] * len(snaps)
+    assert snaps[-1]["args"].get("final") is True and len(snaps) >= 2
+    assert [e["args"]["batch"] for e in by["ftrl.checkpoint"]] == [2, 4]
+    assert {e["tid"] for e in snaps + by["ftrl.checkpoint"]} == {me}
+    # waits are recorded only on the side that waited, and never for free
+    for e in by.get("prefetch.get_wait", []) + by.get("prefetch.put_wait", []):
+        assert e["dur"] > 0
+
+
+@pytest.mark.parametrize("how", ["flag", "profiler"])
+def test_ftrl_drain_is_bitwise_the_same_traced_and_untraced(
+        how, quiet_tracer, monkeypatch, tiny_ftrl_inputs, tmp_path):
+    table, warm = tiny_ftrl_inputs
+    off = _tiny_ftrl(table, warm)
+    assert quiet_tracer.events() == [], "nothing is recorded with both off"
+    if how == "flag":
+        monkeypatch.setenv("ALINK_TPU_TRACE", "1")
+        on = _tiny_ftrl(table, warm)
+    else:
+        import jax
+        with jax.profiler.trace(str(tmp_path)):
+            on = _tiny_ftrl(table, warm)
+    names = {e["name"] for e in quiet_tracer.events()}
+    assert {"ftrl.encode", "ftrl.ship", "ftrl.dispatch", "ftrl.batch",
+            "ftrl.snapshot", "prefetch.pull"} <= names
+    assert all(bool(e.get("profiled")) == (how == "profiler")
+               for e in quiet_tracer.events())
+    assert len(on) == len(off)
+    for a, b in zip(on, off):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_device_snapshot_consumer_runs_inside_the_snapshot_span(
+        quiet_tracer, monkeypatch, tiny_ftrl_inputs):
+    table, warm = tiny_ftrl_inputs
+    monkeypatch.setenv("ALINK_TPU_TRACE", "1")
+    got = []
+    ftrl = FtrlTrainStreamOp(
+        warm, label_col="label", vector_col="vec", alpha=0.5, l1=0.001,
+        l2=0.001, time_interval=2.0)
+    ftrl.set_device_snapshot_consumer(
+        lambda w, info: got.append(info["batch"]) or True)
+    assert list(ftrl.link_from(
+        MemSourceStreamOp(table, batch_size=64)).micro_batches()) == []
+    snaps = [e for e in quiet_tracer.events() if e["name"] == "ftrl.snapshot"]
+    assert [e["args"]["batch"] for e in snaps] == got
+    assert all(e["ph"] == "X" and e["args"]["to"] == "device" for e in snaps)
+
+
+def test_channel_waits_are_spans_only_where_they_block(quiet_tracer,
+                                                       monkeypatch):
+    from alink_tpu.operator.stream.prefetch import (_Channel, _EMPTY,
+                                                    _SENTINEL)
+    monkeypatch.setenv("ALINK_TPU_TRACE", "1")
+    ch = _Channel(1)
+    assert ch.put("a") is True                    # room: no wait
+    assert ch.get() == "a"                        # an item: no wait
+    assert ch.get(timeout=0) is _EMPTY            # a poll: no wait
+    assert quiet_tracer.events() == []
+    assert ch.get(timeout=0.02) is _EMPTY         # a real, timed wait
+    (ev,) = quiet_tracer.events()
+    assert ev["name"] == "prefetch.get_wait" and ev["dur"] >= 15_000
+    quiet_tracer.clear()
+    # a full channel: the producer blocks until the consumer takes one
+    assert ch.put("b") is True
+    th = threading.Thread(target=ch.put, args=("c",), name="producer")
+    th.start()
+    time.sleep(0.02)
+    assert ch.get() == "b"
+    th.join(timeout=10)
+    assert not th.is_alive() and ch.get() == "c"
+    (ev,) = quiet_tracer.events()
+    assert ev["name"] == "prefetch.put_wait" and ev["dur"] >= 10_000
+    assert quiet_tracer._meta()["threads"][str(ev["tid"])] == "producer"
+    quiet_tracer.clear()
+    ch.close()
+    assert ch.get() is _SENTINEL                  # ended: no wait
+    assert ch.put("d") is False
+    assert quiet_tracer.events() == []
+
+
+@pytest.mark.parametrize("workers", [1, 3])
+def test_prefetch_map_pull_and_starve_spans(workers, quiet_tracer, monkeypatch):
+    from alink_tpu.operator.stream.prefetch import prefetch_map
+    monkeypatch.setenv("ALINK_TPU_TRACE", "1")
+
+    def slow_source():
+        for i in range(4):
+            time.sleep(0.01)
+            yield i
+
+    assert list(prefetch_map(slow_source(), lambda x: x * 2,
+                             workers=workers, depth=2)) == [0, 2, 4, 6]
+    evs = quiet_tracer.events()
+    threads = quiet_tracer._meta()["threads"]
+    pulls = [e for e in evs if e["name"] == "prefetch.pull"]
+    assert len(pulls) == 5 and len({e["tid"] for e in pulls}) == 1
+    assert threads[str(pulls[0]["tid"])] == (
+        "alink-prefetch-0" if workers == 1 else "alink-prefetch-dispatch")
+    assert sum(e["dur"] for e in pulls) >= 35_000   # the source's sleeps
+    # the consumer outruns a 10 ms source: it starves on this thread
+    me = threading.get_ident()
+    starved = [e for e in evs
+               if e["name"] == "prefetch.get_wait" and e["tid"] == me]
+    assert starved and sum(e["dur"] for e in starved) >= 20_000
+
+
+def test_ftrl_device_programs_carry_their_names():
+    """The step keeps the module name the benchmark's configuration reads
+    (``jit_shard_fn``), the snapshot program has one of its own, and the
+    round's ops are grouped under named scopes."""
+    import jax
+    from alink_tpu.common.mlenv import MLEnvironmentFactory
+    from alink_tpu.operator.stream.onlinelearning.ftrl import (
+        _ftrl_sparse_step_factory, _ftrl_step_factory)
+
+    env = MLEnvironmentFactory.get_default()
+    dim_pad = 8 * env.num_workers
+    f64 = np.float64
+    z = jax.ShapeDtypeStruct((dim_pad,), f64)
+    step = _ftrl_sparse_step_factory(env.mesh, 0.3, 1.0, 1e-3, 1e-3)
+    lowered = step.lower(jax.ShapeDtypeStruct((8, 4), np.int32),
+                         jax.ShapeDtypeStruct((8, 4), f64),
+                         jax.ShapeDtypeStruct((8,), f64), z, z)
+    text = lowered.as_text(debug_info=True)
+    assert "module @jit_shard_fn" in text
+    for scope in ("ftrl_gather", "ftrl_update", "ftrl_scatter"):
+        assert scope in text, scope
+    plain = lowered.as_text()
+    assert "ftrl_gather" not in plain, "scopes are op metadata alone"
+    _, weights_fn = _ftrl_step_factory(env.mesh, 0.3, 1.0, 1e-3, 1e-3)
+    wtext = weights_fn.lower(z, z).as_text(debug_info=True)
+    assert "module @jit_ftrl_weights" in wtext and "ftrl_weights" in wtext

@@ -21,7 +21,8 @@ import numpy as np
 import pytest
 
 from alink_tpu.common.metrics import MetricsRegistry, set_registry
-from alink_tpu.common.tracing import (Tracer, get_tracer, set_tracer,
+from alink_tpu.common.tracing import (Tracer, get_tracer, profiler_active,
+                                      recording, set_tracer, trace_complete,
                                       trace_instant, trace_span,
                                       tracing_enabled)
 
@@ -363,6 +364,120 @@ class TestGate:
             set_tracer(prev)
         assert tr.events() == []
         assert t.report()[0][1] == 1
+
+
+# ---------------------------------------------------------------------------
+# the bridge to the profiler's clock
+# ---------------------------------------------------------------------------
+
+def _xplane_host_events(trace_dir, prefix="alink:"):
+    """(name, start_ns, end_ns, stats) of the host planes' events whose
+    name starts with ``prefix``, from the one .xplane.pb under
+    ``trace_dir``."""
+    import glob
+    from jax.profiler import ProfileData
+    found = glob.glob(os.path.join(str(trace_dir), "plugins", "profile",
+                                   "*", "*.xplane.pb"))
+    assert len(found) == 1, found
+    out = []
+    for plane in ProfileData.from_file(found[0]).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(prefix):
+                    out.append((ev.name, int(ev.start_ns),
+                                int(ev.start_ns + ev.duration_ns),
+                                dict(ev.stats)))
+    return out
+
+
+class TestProfilerBridge:
+    def test_neither_switch_nor_session_is_the_shared_noop(self,
+                                                            quiet_tracer):
+        assert not recording() and not profiler_active()
+        a, b = trace_span("one", args={"k": 1}), trace_span("two")
+        assert a is b, "one shared no-op, nothing allocated"
+        with a as sp:
+            sp.set(k=2)
+        trace_instant("three")
+        trace_complete("four", 0.001)
+        assert quiet_tracer.events() == []
+
+    def test_a_profiler_session_turns_the_spans_on_for_its_length(
+            self, quiet_tracer, tmp_path):
+        import jax
+        with trace_span("before"):
+            pass
+        with jax.profiler.trace(str(tmp_path)):
+            assert profiler_active() and recording()
+            assert not tracing_enabled(), "no flag was set"
+            with trace_span("outer", cat="stream",
+                            args={"batch": 7, "mode": "sample",
+                                  "skipped": [1, 2]}) as sp:
+                sp.set(late=1)
+                with trace_span("inner"):
+                    time.sleep(0.002)
+                trace_instant("mark")
+                trace_complete("retro", 0.001)
+        assert not profiler_active()
+        with trace_span("after"):
+            pass
+        evs = {e["name"]: e for e in quiet_tracer.events()}
+        assert set(evs) == {"outer", "inner", "mark", "retro"}
+        assert all(e["profiled"] is True for e in evs.values())
+        assert evs["inner"]["parent"] == evs["outer"]["id"]
+        assert evs["outer"]["args"] == {"batch": 7, "mode": "sample",
+                                        "skipped": [1, 2], "late": 1}
+        # the real spans are in the profiler's own trace, on its clock;
+        # the retroactive one and the instant cannot be
+        host = {n: (a, b, st) for n, a, b, st
+                in _xplane_host_events(tmp_path)}
+        assert set(host) == {"alink:outer", "alink:inner"}
+        (oa, ob, ost), (ia, ib, _) = host["alink:outer"], host["alink:inner"]
+        assert oa <= ia < ib <= ob, "the child lies inside its parent"
+        assert ib - ia >= 2_000_000
+        # scalar args known at entry ride along as the event's stats
+        assert ost == {"batch": 7, "mode": "sample"}
+
+    def test_spans_of_other_threads_land_on_their_own_lines(
+            self, quiet_tracer, tmp_path):
+        import jax
+
+        def work():
+            with trace_span("worker.span"):
+                time.sleep(0.001)
+
+        with jax.profiler.trace(str(tmp_path)):
+            th = threading.Thread(target=work, name="alink-prefetch-9")
+            th.start()
+            th.join(timeout=10)
+            assert not th.is_alive()
+            with trace_span("caller.span"):
+                pass
+        evs = {e["name"]: e for e in quiet_tracer.events()}
+        assert evs["worker.span"]["tid"] != evs["caller.span"]["tid"]
+        assert {n for n, *_ in _xplane_host_events(tmp_path)} == {
+            "alink:worker.span", "alink:caller.span"}
+
+    def test_the_flag_alone_records_without_the_profiled_mark(
+            self, fresh_tracer):
+        with trace_span("plain"):
+            pass
+        (ev,) = fresh_tracer.events()
+        assert "profiled" not in ev
+        assert "profiled" not in fresh_tracer.to_chrome()[
+            "traceEvents"][-1].get("args", {})
+
+    def test_the_session_test_imports_no_jax(self):
+        """``tracing.py`` finds JAX in ``sys.modules`` and never imports
+        it: a process without JAX cannot be profiling with it."""
+        import re
+        from alink_tpu.common import tracing
+        with open(tracing.__file__) as f:
+            src = f.read()
+        assert not re.search(r"^\s*(import|from)\s+jax\b", src, re.M)
+        assert 'sys.modules.get("jax")' in src
 
 
 # ---------------------------------------------------------------------------
