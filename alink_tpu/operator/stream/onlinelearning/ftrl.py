@@ -38,7 +38,8 @@ from ....common.metrics import get_registry, metrics_enabled
 from ....common.mtable import MTable
 from ....common.params import InValidator, ParamInfo, Params, RangeValidator
 from ....common.profiling2 import hbm_snapshot
-from ....common.tracing import trace_complete, trace_instant, trace_span
+from ....common.tracing import (Span, trace_complete, trace_instant,
+                                 trace_span)
 from ....common.types import TableSchema
 from ....params.shared import (HasFeatureCols, HasLabelCol, HasPredictionCol,
                                HasPredictionDetailCol, HasReservedCols,
@@ -230,12 +231,24 @@ def _ftrl_sparse_step_factory(mesh, alpha, beta, l1, l2, donate=False,
     entries — replicating it is nothing; densifying it to 65k columns is
     ~0.5 GB per 1k-row batch, the VERDICT round-1 blocker). Each device
     owns one contiguous feature range of the sharded (z, n) state
-    (reference getSplitInfo ranges, FtrlTrainStreamOp.java:74-87); the
-    scan body masks each row's entries to the local range, gathers only
-    those nnz state slots, computes weights lazily at those slots, psums
-    the partial dot product (ReduceTask, :119-135) and scatter-adds the
-    nnz-sized update. Padding entries carry ``val == 0`` so every padded
-    position is algebraically a no-op (g = 0, sigma = 0).
+    (reference getSplitInfo ranges, FtrlTrainStreamOp.java:74-87); a scan
+    round masks its rows' entries to the local range, reads their slots,
+    computes weights lazily at those slots and psums the partial dot
+    product (ReduceTask, :119-135). Padding entries carry ``val == 0`` so
+    every padded position is algebraically a no-op (g = 0, sigma = 0).
+
+    The rounds never touch the state itself. A micro-batch touches far
+    fewer coordinates than it has entries (22.5 % on the Criteo shape),
+    and XLA's scatter into the state costs ~90 ns an update, serially,
+    whatever the operand's size (PERF.md section 6, PR 26). So the rounds
+    work on a table ``T`` of ``2E`` slots a state array (``E`` = entries,
+    row-major, which is sample order): slot ``e < E`` holds the value of
+    entry ``e``'s coordinate after ``e``'s round, slot ``E + k`` the
+    state's value at the ``k``-th distinct local coordinate before the
+    micro-batch. A round gathers its slots' sources from ``T`` and writes
+    its own slots with ONE contiguous update; the state is read once
+    before the rounds and written once after them, at distinct
+    coordinates only (``ftrl_workset`` / ``ftrl_scatter``).
     """
     import jax
     import jax.numpy as jnp
@@ -246,61 +259,107 @@ def _ftrl_sparse_step_factory(mesh, alpha, beta, l1, l2, donate=False,
     def weights(z, n):
         return _ftrl_weights(z, n, alpha, beta, l1, l2)
 
-    K = 4   # samples per scan step (see chunking note below)
-    _sgather, _sscatter = _state_kernels(kernel)
+    K = 4        # samples per scan round
+    BLK = 2048   # distinct coordinates a block of the state gather / write-back
+    _sgather, _ = _state_kernels(kernel)
+    HI = jax.lax.Precision.HIGHEST
 
     def shard_fn(idx, val, y, z, n):
         shard = z.shape[0]                    # block-local feature range
         lo = jax.lax.axis_index("d") * shard
         B, w = idx.shape
-        # K samples per scan step, EXACT strict semantics: the K samples'
-        # state slots come from the pre-step state in ONE gather; sample
-        # k's visible values are corrected by earlier samples' deltas
-        # through straight-line (w, w) same-feature matvecs (a shared
-        # feature between samples j < k contributes j's delta exactly —
-        # bit-identical to the per-sample scan on collision-free chunks,
-        # f32-round-identical under collisions); all K deltas land in one
-        # duplicate-safe scatter-add. This cuts the latency-bound chain
-        # through the 65k-state gather/scatter K-fold: measured 276k ->
-        # 330-340k samples/s on the Criteo shape (K=8/16 lose it again
-        # to the O(K^2) corrections; large scan unrolls also lose —
-        # unroll 32 measured 227k).
         Bp = -(-B // K) * K
         if Bp != B:               # zero rows are algebraic no-ops
             idx = jnp.concatenate([idx, jnp.zeros((Bp - B, w), idx.dtype)])
             val = jnp.concatenate([val, jnp.zeros((Bp - B, w), val.dtype)])
             y = jnp.concatenate([y, jnp.zeros((Bp - B,), y.dtype)])
+        R, S = Bp // K, K * w                 # rounds, entries a round
+        E = R * S                             # entries, row-major = sample order
+        blk = min(BLK, E)
 
-        def body(carry, xvy):
-            z, n = carry
-            xi, xv, yy = xvy                  # (K, w), (K, w), (K,)
+        with jax.named_scope("ftrl_workset"):
+            flat = idx.reshape(E)
+            here = (flat >= lo) & (flat < lo + shard)
+            # non-local entries take a sentinel past the shard: it sorts
+            # last and is never gathered from the state or written to it
+            c = jnp.where(here, flat - lo, shard).astype(jnp.int32)
+            pos = jnp.arange(E, dtype=jnp.int32)
+            sc, se = jax.lax.sort((c, pos), num_keys=1, is_stable=True)
+            se_prev = jnp.roll(se, 1)
+            first = (pos == 0) | (sc != jnp.roll(sc, 1))
+            last = ((pos == E - 1) | (sc != jnp.roll(sc, -1))) & (sc < shard)
+            # an entry reads the slot of the entry before it in (c, e)
+            # order when that one lies in an earlier round; a coordinate's
+            # first round reads the state's slot; every further entry of
+            # the same coordinate in the same round reads what the first
+            # of them reads (forward fill, a round has at most S entries)
+            have = first | (se // S != se_prev // S)
+            src = jnp.where(first, E - 1 + jnp.cumsum(first, dtype=jnp.int32),
+                            se_prev)
+            for i in range((S - 1).bit_length()):
+                src = jnp.where(have, src, jnp.roll(src, 1 << i))
+                have = have | jnp.roll(have, 1 << i)
+            _, ptr = jax.lax.sort((se, src), num_keys=1)
+            # distinct local coordinates, ascending, with the entry that
+            # holds each one's final value; the sentinel fills the rest
+            uc, ul = jax.lax.sort((jnp.where(last, sc, shard), se),
+                                  num_keys=1)
+            nblk = (jnp.sum(last) + blk - 1) // blk
+
+            def block(b):
+                at = jnp.minimum(b * blk, E - blk)
+                return at, jax.lax.dynamic_slice(uc, (at,), (blk,))
+
+            T0 = jnp.zeros((2 * E,), z.dtype)
+            vma = tuple(jax.typeof(z).vma)
+            if vma:               # a loop's carry keeps its varying axes
+                T0 = jax.lax.pcast(T0, vma, to="varying")
+
+            def load(b, T):
+                Tz, Tn = T
+                at, ci = block(b)
+                ci = jnp.minimum(ci, shard - 1)
+                return (jax.lax.dynamic_update_slice(
+                            Tz, _sgather(z, ci), (E + at,)),
+                        jax.lax.dynamic_update_slice(
+                            Tn, _sgather(n, ci), (E + at,)))
+
+            T = jax.lax.fori_loop(0, nblk, load, (T0, T0))
+
+        def body(T, xs):
+            Tz, Tn = T
+            r, xi, xv, yy, pr = xs            # (), (K, w), (K, w), (K,), (S,)
             # the named scopes group the round's device ops by what they
             # do in the profiler's viewer (op metadata only)
             with jax.named_scope("ftrl_gather"):
                 local = (xi >= lo) & (xi < lo + shard)
-                li = jnp.clip(xi - lo, 0, shard - 1)
-                zs = jnp.where(
-                    local, _sgather(z, li.reshape(-1)).reshape(K, w), 0.0)
-                ns = jnp.where(
-                    local, _sgather(n, li.reshape(-1)).reshape(K, w), 0.0)
+                zs = jnp.where(local, Tz[pr].reshape(K, w), 0.0)
+                ns = jnp.where(local, Tn[pr].reshape(K, w), 0.0)
             dzs, dns, margins = [], [], []
             with jax.named_scope("ftrl_update"):
+                fi, fl = xi.reshape(S), local.reshape(S)
+                # same[a, b]: entries a and b of the round address the
+                # same local coordinate
+                same = ((fi[:, None] == fi[None, :])
+                        & fl[:, None] & fl[None, :]).astype(zs.dtype)
+                M = same.reshape(K, w, K, w)
+                # K samples a round, EXACT strict semantics: sample k's
+                # visible values are the pre-round values corrected by
+                # earlier samples' deltas through straight-line (w, w)
+                # same-feature matvecs (bit-identical to the per-sample
+                # scan on collision-free rounds, f32-round-identical
+                # under collisions)
                 for k in range(K):
                     zk, nk = zs[k], ns[k]
                     for j in range(k):
-                        Mkj = ((xi[k][:, None] == xi[j][None, :])
-                               & local[k][:, None] & local[j][None, :]
-                               ).astype(zk.dtype)
                         # HIGHEST: the default matmul precision would
                         # round the f32 deltas to bf16 on the MXU and
                         # break the exact-strict-semantics claim under
                         # collisions (negligible cost at w ~ 40)
-                        zk = zk + jnp.matmul(
-                            Mkj, dzs[j],
-                            precision=jax.lax.Precision.HIGHEST)
-                        nk = nk + jnp.matmul(
-                            Mkj, dns[j],
-                            precision=jax.lax.Precision.HIGHEST)
+                        zk = zk + jnp.matmul(M[k, :, j], dzs[j],
+                                             precision=HI)
+                        nk = nk + jnp.matmul(M[k, :, j], dns[j],
+                                             precision=HI)
                     wj = jnp.where(local[k], weights(zk, nk), 0.0)
                     margin = manifest_psum(jnp.sum(xv[k] * wj), "d",
                                            name="ftrl_margin",
@@ -311,15 +370,33 @@ def _ftrl_sparse_step_factory(mesh, alpha, beta, l1, l2, donate=False,
                     dzs.append(jnp.where(local[k], g - sigma * wj, 0.0))
                     dns.append(jnp.where(local[k], g * g, 0.0))
                     margins.append(margin)
-            with jax.named_scope("ftrl_scatter"):
-                z = _sscatter(z, li.reshape(-1), jnp.stack(dzs).reshape(-1))
-                n = _sscatter(n, li.reshape(-1), jnp.stack(dns).reshape(-1))
-            return (z, n), jnp.stack(margins)
+                # every entry's slot takes its coordinate's value after
+                # the round: all of the round's deltas at that coordinate
+                D = jnp.stack([jnp.concatenate(dzs), jnp.concatenate(dns)],
+                              axis=-1)
+                A = jnp.matmul(same, D, precision=HI)
+                Tz = jax.lax.dynamic_update_slice(
+                    Tz, zs.reshape(S) + A[:, 0], (r * S,))
+                Tn = jax.lax.dynamic_update_slice(
+                    Tn, ns.reshape(S) + A[:, 1], (r * S,))
+            return (Tz, Tn), jnp.stack(margins)
 
-        (z, n), margins = jax.lax.scan(
-            body, (z, n), (idx.reshape(Bp // K, K, w),
-                           val.reshape(Bp // K, K, w),
-                           y.reshape(Bp // K, K)))
+        (Tz, Tn), margins = jax.lax.scan(
+            body, T, (jnp.arange(R, dtype=jnp.int32),
+                      idx.reshape(R, K, w), val.reshape(R, K, w),
+                      y.reshape(R, K), ptr.reshape(R, S)))
+
+        with jax.named_scope("ftrl_scatter"):
+            # a plain set: the unique/sorted hint makes XLA stream the
+            # whole state through the chip once a call (PERF.md section 6)
+            def store(b, zn):
+                z, n = zn
+                at, ci = block(b)
+                li = jax.lax.dynamic_slice(ul, (at,), (blk,))
+                return (z.at[ci].set(Tz[li], mode="drop"),
+                        n.at[ci].set(Tn[li], mode="drop"))
+
+            z, n = jax.lax.fori_loop(0, nblk, store, (z, n))
         return z, n, margins.reshape(Bp)[:B]
 
     fn = shard_map(shard_fn, mesh=mesh,
@@ -342,8 +419,8 @@ def _ftrl_sparse_chained_step_factory(mesh, alpha, beta, l1, l2, K=16,
     margin must be computed at weights reflecting samples 0..k-1. The
     K=4 kernel above pays that chain with k-1 PAIRS of same-feature
     matmuls per sample — O(K^2) dependent ops — which is why K=8/16
-    measured slower (docs/performance.md "Why the strict scan sits
-    near ~320k"). This kernel restructures the correction so the chain
+    measured slower (docs/performance.md "Where the strict scan's
+    time goes"). This kernel restructures the correction so the chain
     stays O(K) dependent ops:
 
       * ONE gather of the K rows' (z, n) slots at the pre-chunk state,
@@ -731,6 +808,15 @@ def _pv_stats_fn():
         return jnp.where(real, ll, 0.0).sum(), correct, nonfinite
 
     return jax.jit(stats)
+
+
+def _distinct(idx) -> int:
+    """Distinct coordinates of one shipped sparse micro-batch: what the
+    strict step's working table gathers from the state and writes back to
+    it, against the entries it folds. Counted on the host (a device
+    program's first call would compile inside a traced window), and only
+    for a recorded ``ftrl.snapshot`` span."""
+    return int(np.unique(np.asarray(idx)).size)
 
 
 # Trace-time collective manifests, memoized per (step program, arg-shape
@@ -1302,6 +1388,7 @@ class FtrlTrainStreamOp(StreamOperator, HasVectorCol, HasFeatureCols, HasLabelCo
             fb_meta = None
             next_emit = None
             b_done = 0                   # micro-batches committed to state
+            idx_last = None              # the last sparse micro-batch, shipped
             if _restored is not None:
                 _payload, _meta = _restored
                 layout = _meta["layout"]
@@ -1381,6 +1468,17 @@ class FtrlTrainStreamOp(StreamOperator, HasVectorCol, HasFeatureCols, HasLabelCo
             reg = get_registry() if mx else None
             m_lbl = {"op": "FtrlTrainStreamOp", "mode": update_mode}
 
+            def mark_workset(span):
+                """The boundary's micro-batch on its ``ftrl.snapshot``
+                span, if that span is a recorded one (a hook inside it
+                may start or stop a profiler session, so ask the span):
+                ``entries`` folded and ``slots``, the distinct coordinates
+                among them, counted on the shipped ``idx`` once the
+                snapshot has waited for the device."""
+                if idx_last is not None and isinstance(span, Span):
+                    span.set(entries=int(idx_last.size),
+                             slots=_distinct(idx_last))
+
             def device_emit(t_ev, batch) -> bool:
                 """Device-to-device emission: hand the registered
                 consumer (set_device_snapshot_consumer) the LIVE device
@@ -1393,12 +1491,13 @@ class FtrlTrainStreamOp(StreamOperator, HasVectorCol, HasFeatureCols, HasLabelCo
                 if hook is None or z is None:
                     return False
                 with trace_span("ftrl.snapshot", cat="stream",
-                                args={"batch": batch, "to": "device"}):
+                                args={"batch": batch, "to": "device"}) as sp:
                     consumed = bool(hook(weights_fn(z, n),
                                          {"fb_S": fb_S, "dim": dim,
                                           "has_intercept": bool(has_icpt),
                                           "batch": batch,
                                           "event_time": t_ev}))
+                    mark_workset(sp)
                 if consumed:
                     hbm_snapshot("ftrl.snapshot")
                     if mx:
@@ -1492,6 +1591,7 @@ class FtrlTrainStreamOp(StreamOperator, HasVectorCol, HasFeatureCols, HasLabelCo
                       allow_fb[0] = False
                       z, n = alloc(layout)
                   _, idx, val, y, width = enc
+                  idx_last = idx
                   if sparse_step[0] is None:
                       if batch_mode:
                           sparse_step[0] = _step_lookup(
@@ -1555,8 +1655,9 @@ class FtrlTrainStreamOp(StreamOperator, HasVectorCol, HasFeatureCols, HasLabelCo
                       _poison = maybe_crash("feeder.snapshot")
                       with trace_span("ftrl.snapshot", cat="stream",
                                       args={"batch": b_done + 1,
-                                            "to": "host"}):
+                                            "to": "host"}) as sp:
                           snap = snapshot(z, n, fb_S, batch=b_done + 1)
+                          mark_workset(sp)
                       if _poison:
                           snap = _corrupt_snapshot_table(snap)
                       if mon_on:
@@ -1598,9 +1699,10 @@ class FtrlTrainStreamOp(StreamOperator, HasVectorCol, HasFeatureCols, HasLabelCo
                 _poison = maybe_crash("feeder.snapshot")
                 with trace_span("ftrl.snapshot", cat="stream",
                                 args={"batch": b_done, "to": "host",
-                                      "final": True}):
+                                      "final": True}) as sp:
                     snap = snapshot(z, n, fb_S,
                                     batch=b_done if b_done > 0 else None)
+                    mark_workset(sp)
                 if _poison:
                     snap = _corrupt_snapshot_table(snap)
                 if mon_on:

@@ -662,30 +662,156 @@ def test_ftrl_strict_chunked_scan_exact_under_collisions():
                          jax.device_put(z0, shard),
                          jax.device_put(np.zeros(dim_pad), shard))
 
-    # numpy per-sample reference
-    zc, nc = z0.copy(), np.zeros(dim_pad)
-    ms = []
-    for i in range(B):
-        ii, vv, yy = idx[i], val[i], y[i]
-        zi, ni = zc[ii], nc[ii]
-        decay = (beta + np.sqrt(ni)) / alpha + l2
-        wi = np.where(np.abs(zi) <= l1, 0.0,
-                      -(zi - np.sign(zi) * l1) / decay)
-        # duplicate features within one sample: per-slot update like the
-        # device program (each slot sees the pre-sample value)
-        m = float(wi @ vv)
-        ms.append(m)
-        p = 1.0 / (1.0 + np.exp(-np.clip(m, -35, 35)))
-        g = (p - yy) * vv
-        sigma = (np.sqrt(ni + g * g) - np.sqrt(ni)) / alpha
-        np.add.at(zc, ii, g - sigma * wi)
-        np.add.at(nc, ii, g * g)
+    zc, nc, ms = _per_sample_reference(idx, val, y, z0, np.zeros(dim_pad),
+                                       alpha, beta, l1, l2)
 
     np.testing.assert_allclose(np.asarray(z), zc, rtol=2e-5, atol=1e-7)
     np.testing.assert_allclose(np.asarray(n), nc, rtol=2e-5, atol=1e-7)
     np.testing.assert_allclose(np.asarray(margins), ms, rtol=2e-5,
                                atol=1e-7)
     assert len(np.asarray(margins)) == B
+
+
+def _strict_case(name, dim_pad):
+    """(idx, val, y) of one micro-batch for a case of the strict step's
+    working table (K = 4 rows a round, width 6)."""
+    rng = np.random.RandomState(7)
+    B, w = 32, 6
+    if name == "all_distinct":            # no coordinate twice in the batch
+        idx = rng.permutation(dim_pad)[:B * w].reshape(B, w)
+    elif name == "across_rounds":         # repeats, never inside one round
+        rnd = rng.permutation(dim_pad)[:4 * w].reshape(4, w)
+        idx = np.tile(rnd, (B // 4, 1))
+    elif name == "inside_round":          # rows of a round share coordinates
+        idx = np.stack([rng.permutation(12)[:w] * (dim_pad // 12)
+                        for _ in range(B)])   # no row holds one twice
+    elif name == "inside_row":            # one row holds a coordinate twice
+        idx = rng.randint(0, dim_pad, size=(B, w))
+        idx[:, 3] = idx[:, 1]
+        idx[5, :] = idx[5, 0]
+    elif name == "all_identical":
+        idx = np.full((B, w), dim_pad - 3)
+    elif name == "padding":               # short rows: (0, 0.0) fills them
+        idx = rng.randint(0, dim_pad, size=(B, w))
+    elif name == "ragged":                # 30 rows: no multiple of K
+        B = 30
+        idx = rng.randint(0, dim_pad // 2, size=(B, w))
+    elif name == "spread":                # every shard of the mesh touched
+        idx = (np.arange(B * w).reshape(B, w) * 37) % dim_pad
+    else:
+        raise ValueError(name)
+    val = rng.rand(B, w) + 0.1
+    if name == "padding":
+        keep = rng.rand(B, w) < 0.6
+        idx, val = np.where(keep, idx, 0), np.where(keep, val, 0.0)
+    y = (rng.rand(B) < 0.5).astype(np.float64)
+    return idx.astype(np.int32), val, y
+
+
+def _per_sample_reference(idx, val, y, z0, n0, alpha, beta, l1, l2):
+    """Plain sequential FTRL-proximal, one sample at a time: every slot of
+    a row sees the pre-sample value (as the device program's rows do)."""
+    zc, nc, ms = z0.copy(), n0.copy(), []
+    for ii, vv, yy in zip(idx, val, y):
+        zi, ni = zc[ii], nc[ii]
+        decay = (beta + np.sqrt(ni)) / alpha + l2
+        wi = np.where(np.abs(zi) <= l1, 0.0,
+                      -(zi - np.sign(zi) * l1) / decay)
+        m = float(np.sum(vv * wi))
+        ms.append(m)
+        p = 1.0 / (1.0 + np.exp(-np.clip(m, -35, 35)))
+        g = (p - yy) * vv
+        sigma = (np.sqrt(ni + g * g) - np.sqrt(ni)) / alpha
+        np.add.at(zc, ii, g - sigma * wi)
+        np.add.at(nc, ii, g * g)
+    return zc, nc, np.asarray(ms)
+
+
+def _plain_scan_step(mesh, alpha, beta, l1, l2):
+    """The strict step without its working table: one sample a scan step,
+    gathered from the state and scatter-added to it."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from alink_tpu.operator.stream.onlinelearning.ftrl import _ftrl_weights
+
+    def shard_fn(idx, val, y, z, n):
+        shard = z.shape[0]
+        lo = jax.lax.axis_index("d") * shard
+
+        def body(carry, row):
+            z, n = carry
+            xi, xv, yy = row
+            local = (xi >= lo) & (xi < lo + shard)
+            li = jnp.clip(xi - lo, 0, shard - 1)
+            zk, nk = jnp.where(local, z[li], 0.0), jnp.where(local, n[li], 0.0)
+            wj = jnp.where(local,
+                           _ftrl_weights(zk, nk, alpha, beta, l1, l2), 0.0)
+            margin = jax.lax.psum(jnp.sum(xv * wj), "d")
+            p = 1.0 / (1.0 + jnp.exp(-jnp.clip(margin, -35.0, 35.0)))
+            g = (p - yy) * xv
+            sigma = (jnp.sqrt(nk + g * g) - jnp.sqrt(nk)) / alpha
+            z = z.at[li].add(jnp.where(local, g - sigma * wj, 0.0))
+            n = n.at[li].add(jnp.where(local, g * g, 0.0))
+            return (z, n), margin
+
+        (z, n), margins = jax.lax.scan(body, (z, n), (idx, val, y))
+        return z, n, margins
+
+    return jax.jit(jax.shard_map(
+        shard_fn, mesh=mesh, in_specs=(P(), P(), P(), P("d"), P("d")),
+        out_specs=(P("d"), P("d"), P())))
+
+
+@pytest.mark.parametrize("case", [
+    "all_distinct", "across_rounds", "inside_round", "inside_row",
+    "all_identical", "padding", "ragged", "spread"])
+def test_ftrl_strict_working_table_matches_per_sample(case):
+    """The strict step folds its rows through a per-micro-batch working
+    table (one state gather before the rounds, one write-back of distinct
+    coordinates after them): against sequential per-sample FTRL in
+    float64, on the 8-device mesh, it agrees to 1e-12 relative whatever
+    repeats where, leaves every untouched coordinate bit for bit, and
+    twice over the same state (a second micro-batch) still agrees. Where
+    no coordinate repeats inside a round, the table changes no bit of
+    what the plain one-sample-a-step scan computes."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from alink_tpu.common.mlenv import MLEnvironmentFactory
+    from alink_tpu.operator.stream.onlinelearning.ftrl import (
+        _ftrl_sparse_step_factory)
+
+    env = MLEnvironmentFactory.get_default()
+    alpha, beta, l1, l2 = 0.3, 1.0, 1e-3, 1e-3
+    dim_pad = 64 * env.num_workers
+    idx, val, y = _strict_case(case, dim_pad)
+    rng = np.random.RandomState(1)
+    z0, n0 = rng.randn(dim_pad) * 0.05, rng.rand(dim_pad)
+    step = _ftrl_sparse_step_factory(env.mesh, alpha, beta, l1, l2)
+    shard = NamedSharding(env.mesh, P("d"))
+    z, n = jax.device_put(z0, shard), jax.device_put(n0, shard)
+    zc, nc = z0, n0
+    for _ in range(2):
+        z, n, margins = step(idx, val, y, z, n)
+        zc, nc, ms = _per_sample_reference(idx, val, y, zc, nc,
+                                           alpha, beta, l1, l2)
+        np.testing.assert_allclose(np.asarray(z), zc, rtol=1e-12, atol=1e-15)
+        np.testing.assert_allclose(np.asarray(n), nc, rtol=1e-12, atol=1e-15)
+        np.testing.assert_allclose(np.asarray(margins), ms, rtol=1e-12,
+                                   atol=1e-15)
+        assert np.asarray(margins).shape == (len(y),)
+    untouched = np.setdiff1d(np.arange(dim_pad), idx.reshape(-1))
+    np.testing.assert_array_equal(np.asarray(z)[untouched], z0[untouched])
+    np.testing.assert_array_equal(np.asarray(n)[untouched], n0[untouched])
+    if case in ("all_distinct", "across_rounds", "spread"):
+        plain = _plain_scan_step(env.mesh, alpha, beta, l1, l2)
+        zp, np_ = jax.device_put(z0, shard), jax.device_put(n0, shard)
+        for _ in range(2):
+            zp, np_, mp = plain(idx, val, y, zp, np_)
+        for got, ref in ((z, zp), (n, np_), (margins, mp)):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 
 # -- the program's own spans (ISSUE 25) ---------------------------------------
@@ -799,6 +925,46 @@ def test_device_snapshot_consumer_runs_inside_the_snapshot_span(
     assert all(e["ph"] == "X" and e["args"]["to"] == "device" for e in snaps)
 
 
+def test_ftrl_snapshot_span_carries_entries_and_slots_only_when_recorded(
+        quiet_tracer, monkeypatch, tiny_ftrl_inputs):
+    """While a span is recorded, each ``ftrl.snapshot`` says how many
+    entries its boundary's micro-batch folded and how many distinct
+    coordinates (``slots``) they were; with nothing recording, nothing is
+    fetched or counted."""
+    from alink_tpu.operator.stream.onlinelearning import ftrl as F
+    table, warm = tiny_ftrl_inputs
+    counted = []
+    real = F._distinct
+    monkeypatch.setattr(
+        F, "_distinct",
+        lambda idx: counted.append(np.asarray(idx)) or real(idx))
+    _tiny_ftrl(table, warm)
+    assert counted == [] and quiet_tracer.events() == []
+    monkeypatch.setenv("ALINK_TPU_TRACE", "1")
+    _tiny_ftrl(table, warm)
+    snaps = [e for e in quiet_tracer.events() if e["name"] == "ftrl.snapshot"]
+    assert len(snaps) >= 2 and len(counted) == len(snaps)
+    for e, idx in zip(snaps, counted):
+        assert e["args"]["entries"] == idx.size == 64 * idx.shape[1]
+        assert e["args"]["slots"] == np.unique(idx).size < idx.size
+    # recording that begins INSIDE a boundary's span (the benchmark's hook
+    # opens its traced window there) leaves that boundary uncounted
+    monkeypatch.delenv("ALINK_TPU_TRACE")
+    quiet_tracer.clear()
+    del counted[:]
+    hooked = []
+    ftrl = FtrlTrainStreamOp(
+        warm, label_col="label", vector_col="vec", alpha=0.5, l1=0.001,
+        l2=0.001, time_interval=2.0)
+    ftrl.set_device_snapshot_consumer(
+        lambda w, info: hooked.append(monkeypatch.setenv("ALINK_TPU_TRACE",
+                                                         "1")) or True)
+    list(ftrl.link_from(MemSourceStreamOp(table, batch_size=64))
+         .micro_batches())
+    snaps = [e for e in quiet_tracer.events() if e["name"] == "ftrl.snapshot"]
+    assert len(counted) == len(snaps) == len(hooked) - 1 >= 1
+
+
 def test_channel_waits_are_spans_only_where_they_block(quiet_tracer,
                                                        monkeypatch):
     from alink_tpu.operator.stream.prefetch import (_Channel, _EMPTY,
@@ -876,10 +1042,52 @@ def test_ftrl_device_programs_carry_their_names():
                          jax.ShapeDtypeStruct((8,), f64), z, z)
     text = lowered.as_text(debug_info=True)
     assert "module @jit_shard_fn" in text
-    for scope in ("ftrl_gather", "ftrl_update", "ftrl_scatter"):
+    for scope in ("ftrl_workset", "ftrl_gather", "ftrl_update",
+                  "ftrl_scatter"):
         assert scope in text, scope
     plain = lowered.as_text()
     assert "ftrl_gather" not in plain, "scopes are op metadata alone"
     _, weights_fn = _ftrl_step_factory(env.mesh, 0.3, 1.0, 1e-3, 1e-3)
     wtext = weights_fn.lower(z, z).as_text(debug_info=True)
     assert "module @jit_ftrl_weights" in wtext and "ftrl_weights" in wtext
+
+
+def test_ftrl_strict_rounds_hold_no_scatter():
+    """The 1,024 dependent rounds read a table and write one contiguous
+    slab of it; every scatter of the step program sits after them, in the
+    write-back of distinct coordinates (a loop whose trip count the data
+    gives)."""
+    import jax
+    from alink_tpu.common.mlenv import MLEnvironmentFactory
+    from alink_tpu.operator.stream.onlinelearning.ftrl import (
+        _ftrl_sparse_step_factory)
+
+    def eqns(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for v in eqn.params.values():
+                for sub in v if isinstance(v, (list, tuple)) else (v,):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        yield from eqns(sub)
+
+    env = MLEnvironmentFactory.get_default()
+    f64 = np.float64
+    z = jax.ShapeDtypeStruct((8 * env.num_workers,), f64)
+    step = _ftrl_sparse_step_factory(env.mesh, 0.3, 1.0, 1e-3, 1e-3)
+    args = (jax.ShapeDtypeStruct((8, 4), np.int32),
+            jax.ShapeDtypeStruct((8, 4), f64),
+            jax.ShapeDtypeStruct((8,), f64), z, z)
+    whole = list(eqns(jax.make_jaxpr(step)(*args).jaxpr))
+    (scan,) = [e for e in whole if e.primitive.name == "scan"]
+    assert scan.params["length"] == 2             # 8 rows, 4 a round
+    rounds = {e.primitive.name for e in eqns(scan.params["jaxpr"].jaxpr)}
+    assert not any("scatter" in name for name in rounds), rounds
+    assert {"gather", "dynamic_update_slice", "dot_general"} <= rounds
+    loops = [e for e in whole if e.primitive.name == "while"]
+    stores = [e for e in loops if any(
+        "scatter" in b.primitive.name
+        for b in eqns(e.params["body_jaxpr"].jaxpr))]
+    assert len(loops) == 2 and len(stores) == 1
+    text = step.lower(*args).as_text()
+    assert "module @jit_shard_fn" in text and "stablehlo.scatter" in text
