@@ -47,3 +47,121 @@ class ColumnarColumn:
         out = np.empty(len(self), object)
         out[:] = list(self)
         return out
+
+
+#: lanes of a device vector register: the minor axis of a dense block
+LANES = 128
+#: rows of a block are a multiple of this (8 sublanes x 128 lanes), so a
+#: block's (S, 128) slabs tile the device's registers with no padding
+BLOCK_QUANTUM = 8 * LANES
+DEFAULT_BLOCK_ROWS = 1 << 16
+
+
+class DenseBlockColumn(ColumnarColumn):
+    """A VECTOR column of ``n_rows`` dense rows of one width, held as ONE
+    feature-major, lane-packed array ``blocks`` of shape ``(row_blocks,
+    dim, S, 128)``: row ``r`` of block ``b`` is ``blocks[b, :, r // 128,
+    r % 128]``, and rows past ``n_rows`` in the last block are zero. The
+    array may be a host ``numpy`` array or a device-resident ``jax.Array``
+    (a cached table, as a Flink user caches a ``DataSet``); trainers take
+    it as it is — ``extract_design`` hands the column through, the BSP
+    engine partitions it on its leading axis — so a table of deployment
+    size is never copied on the host or doubled on the device. Per-row
+    access (``col[i]``, iteration, ``rows()``) fetches and is for small
+    tables and tests."""
+
+    __slots__ = ("blocks", "n_rows")
+
+    def __init__(self, blocks, n_rows: int):
+        shape = tuple(blocks.shape)
+        if len(shape) != 4 or shape[3] != LANES or shape[2] % 8:
+            raise ValueError(
+                f"DenseBlockColumn: blocks must be (row_blocks, dim, S, "
+                f"{LANES}) with S a multiple of 8, got {shape}")
+        if not 0 <= int(n_rows) <= shape[0] * shape[2] * LANES:
+            raise ValueError(f"DenseBlockColumn: {n_rows} rows do not fit "
+                             f"blocks of shape {shape}")
+        self.blocks = blocks
+        self.n_rows = int(n_rows)
+
+    # -- geometry ---------------------------------------------------------
+    @property
+    def dim(self) -> int:
+        return int(self.blocks.shape[1])
+
+    @property
+    def block_rows(self) -> int:
+        return int(self.blocks.shape[2]) * LANES
+
+    @property
+    def row_blocks(self) -> int:
+        return int(self.blocks.shape[0])
+
+    @property
+    def on_device(self) -> bool:
+        return not isinstance(self.blocks, np.ndarray)
+
+    @staticmethod
+    def block_rows_for(n_rows: int, block_rows: int = 0) -> int:
+        """Rows a block holds: ``block_rows`` (default 65,536) rounded up
+        to the quantum, and no more than ``n_rows`` needs."""
+        want = int(block_rows) or DEFAULT_BLOCK_ROWS
+        need = -(-max(int(n_rows), 1) // BLOCK_QUANTUM) * BLOCK_QUANTUM
+        return min(-(-want // BLOCK_QUANTUM) * BLOCK_QUANTUM, need)
+
+    @staticmethod
+    def pack(X: np.ndarray, block_rows: int, row_blocks: int = 0
+             ) -> np.ndarray:
+        """Host rows ``(n, dim)`` (or per-row values ``(n,)``) laid out as
+        blocks ``(row_blocks, dim, S, 128)`` (``(row_blocks, S, 128)``),
+        zero past ``n``."""
+        X = np.asarray(X)
+        n = X.shape[0]
+        nb = max(int(row_blocks), -(-n // block_rows), 1)
+        flat = np.zeros((nb * block_rows,) + X.shape[1:], X.dtype)
+        flat[:n] = X
+        S = block_rows // LANES
+        if X.ndim == 1:
+            return flat.reshape(nb, S, LANES)
+        return np.ascontiguousarray(
+            flat.reshape(nb, S, LANES, X.shape[1]).transpose(0, 3, 1, 2))
+
+    @classmethod
+    def from_rows(cls, X: np.ndarray, block_rows: int = 0
+                  ) -> "DenseBlockColumn":
+        X = np.asarray(X)
+        if X.ndim != 2:
+            raise ValueError("DenseBlockColumn.from_rows: X must be (n, dim)")
+        B = cls.block_rows_for(X.shape[0], block_rows)
+        return cls(cls.pack(X, B), X.shape[0])
+
+    def to_rows(self) -> np.ndarray:
+        """Host rows ``(n_rows, dim)`` (fetches a device-resident block)."""
+        a = np.asarray(self.blocks)
+        nb, d, S, _ = a.shape
+        return a.transpose(0, 2, 3, 1).reshape(nb * S * LANES, d)[:self.n_rows]
+
+    # -- the MTable column surface ---------------------------------------
+    def __len__(self):
+        return self.n_rows
+
+    def _render_row(self, i: int):
+        from .vector import DenseVector
+        if not -self.n_rows <= i < self.n_rows:
+            raise IndexError(i)
+        b, r = divmod(i % self.n_rows, self.block_rows)
+        return DenseVector(np.asarray(
+            self.blocks[b, :, r // LANES, r % LANES], np.float64))
+
+    def _subset(self, sel):
+        return DenseBlockColumn.from_rows(self.to_rows()[sel],
+                                          self.block_rows)
+
+    def copy(self) -> "DenseBlockColumn":
+        return DenseBlockColumn(self.blocks.copy(), self.n_rows)
+
+    def materialize(self) -> np.ndarray:
+        from .vector import DenseVector
+        out = np.empty(self.n_rows, object)
+        out[:] = [DenseVector(r.astype(np.float64)) for r in self.to_rows()]
+        return out

@@ -42,8 +42,7 @@ import numpy as np
 
 from ..common.health import health_enabled
 from ..common.mlenv import MLEnvironment, MLEnvironmentFactory
-from ..common.profiling2 import (hbm_snapshot, mark as profile_mark,
-                                 profile_enabled, profile_window)
+from ..common.profiling2 import hbm_snapshot, profile_window
 from ..common.tracing import trace_instant, trace_span, tracing_enabled
 from .context import ComContext
 from .communication import CommunicateFunction
@@ -139,6 +138,19 @@ def _program_label(program_key) -> str:
     import hashlib
     return hashlib.blake2b(repr(program_key).encode(),
                            digest_size=6).hexdigest()
+
+
+def _name_program(fn: Callable, program_key, role: str = "") -> Callable:
+    """Give a program body the name XLA will carry: a queue with a
+    ``program_key`` compiles to ``jit_<label><role>`` (``jit_kmeans_lloyd``,
+    ``jit_qn_cont``) in place of a ``jit_run`` every queue would share, so
+    a device trace tells the programs apart. Uncached queues keep the
+    body's own name."""
+    if program_key is not None:
+        label = "".join(c if c.isalnum() or c == "_" else "_"
+                        for c in _program_label(program_key))
+        fn.__name__ = fn.__qualname__ = label + role
+    return fn
 
 
 class _AotMeshCall:
@@ -481,6 +493,12 @@ def _lazy_jit_cached(fn, static_argnums):
 _LAZY_JIT: Dict[tuple, Callable] = {}
 
 
+def _first_shards(tree):
+    """Worker 0's slice of every stacked leaf (one program a carry shape)."""
+    import jax
+    return jax.tree_util.tree_map(lambda x: x[0], tree)
+
+
 class ComputeFunction:
     """One per-worker compute stage (reference comqueue/ComputeFunction.java)."""
 
@@ -513,17 +531,14 @@ def _fetch_tree(tree):
     leaf flipped read-only (the memo contract above)."""
     import jax
     from ..common.compat import device_get_tree
-    if not profile_enabled():
-        return jax.tree_util.tree_map(_readonly, device_get_tree(tree))
-    # measured-profiling D2H mark: result fetches are the transfer leg
-    # of the workload attribution. The fetch itself is unchanged (same
-    # one batched device_get; leaves stay read-only — memo contract).
-    t0 = time.perf_counter()
-    got = device_get_tree(tree)
-    dt = time.perf_counter() - t0
-    nbytes = sum(getattr(leaf, "nbytes", 0)
-                 for leaf in jax.tree_util.tree_leaves(got))
-    profile_mark("comqueue.fetch", "transfer", dt, nbytes=int(nbytes))
+    # the result fetch is the engine's device->host leg: ONE span on the
+    # process tracer (recorded under ALINK_TPU_TRACE or a profiler
+    # session) carries its wall time and bytes. The fetch itself is one
+    # batched device_get; leaves stay read-only — memo contract.
+    with trace_span("comqueue.fetch", cat="engine") as sp:
+        got = device_get_tree(tree)
+        sp.set(nbytes=int(sum(getattr(leaf, "nbytes", 0) for leaf
+                              in jax.tree_util.tree_leaves(got))))
     return jax.tree_util.tree_map(_readonly, got)
 
 
@@ -557,30 +572,46 @@ class ComQueueResult:
 
     def get(self, name: str):
         """Worker 0's copy (read-only) — use for replicated
-        (post-allreduce) state.
+        (post-allreduce) state. See :meth:`get_all`."""
+        return self.get_all([name])[0]
+
+    def get_all(self, names: Sequence[str]) -> List[Any]:
+        """Worker 0's copies (read-only) of several carries, in the order
+        asked: ONE compiled slice and ONE batched ``jax.device_get`` for
+        all that are not on the host yet, so a trainer that reads its
+        centroids, weights and history pays the device link once, not once
+        a name.
 
         Slices BEFORE fetching (x[0] on device): fetching the full
         (num_workers, ...) stack and discarding all but shard 0 on host
         would pay num_workers x the bytes over the device link. Fetched
-        leaves are memoized per name, so repeated get() calls pay the
-        link once (advisor r4); multi-leaf objects fetch in ONE batched
-        ``jax.device_get``."""
+        leaves are memoized per name, so repeated reads pay the link once
+        (advisor r4)."""
         import jax
-        got = self._fetched.get(("get", name))
-        if got is None:
+        missing = [n for n in dict.fromkeys(names)
+                   if ("get", n) not in self._fetched]
+        on_device = {}
+        for n in missing:
             # memo first: after release() a get()-only name serves from
             # its memo even though the stacked entry is gone
-            if name not in self._stacked:
-                raise KeyError(f"no carry object '{name}'; "
+            if n not in self._stacked:
+                raise KeyError(f"no carry object '{n}'; "
                                f"have {sorted(self._stacked)}")
-            full = self._fetched.get(("shards", name))
+            full = self._fetched.get(("shards", n))
             if full is not None:  # already on host: slice locally
-                got = jax.tree_util.tree_map(lambda x: x[0], full)
+                self._fetched[("get", n)] = _first_shards(full)
+            elif all(isinstance(leaf, np.ndarray) for leaf in
+                     jax.tree_util.tree_leaves(self._stacked[n])):
+                # a released (or multi-host gathered) carry is host memory
+                self._fetched[("get", n)] = jax.tree_util.tree_map(
+                    lambda x: _readonly(np.asarray(x[0])), self._stacked[n])
             else:
-                got = _fetch_tree(jax.tree_util.tree_map(
-                    lambda x: x[0], self._stacked[name]))
-            self._fetched[("get", name)] = got
-        return got
+                on_device[n] = self._stacked[n]
+        if on_device:
+            got = _fetch_tree(lazy_jit(_first_shards)(on_device))
+            for n, v in got.items():
+                self._fetched[("get", n)] = v
+        return [self._fetched[("get", n)] for n in names]
 
     def release(self, keep: Sequence[str] = ()) -> "ComQueueResult":
         """Detach to host and drop every device reference so the superstep
@@ -851,17 +882,23 @@ class IterativeComQueue:
 
         parts: Dict[str, Any] = {}
         totals: Dict[str, int] = {}
-        # measured-profiling transfer mark (ALINK_TPU_PROFILE): the
-        # prepare phase is host padding + the H2D input ship — charged
-        # to the transfer bucket of the workload attribution. Host-side
-        # wall clock only; the compiled program is untouched.
-        _prep_t0 = time.perf_counter()
+        # the prepare phase is host padding + the H2D input ship. ONE span:
+        # the StepTimer's, which lands on the process tracer as
+        # ``comqueue.prepare`` under the exec's root span (and mirrors into
+        # the registry). Host-side wall clock only.
         with _ENGINE_TIMER.span("comqueue.prepare"):
             for k, arr in self._partitioned.items():
-                if isinstance(arr, jax.Array):
-                    # already device-resident (e.g. precomputed one-hot design
-                    # factors): pad on device — np.asarray would round-trip
-                    # GBs through the host
+                if isinstance(arr, (jax.Array, jax.ShapeDtypeStruct)):
+                    # already device-resident (a cached table, precomputed
+                    # one-hot design factors): it passes through untouched
+                    # when its leading axis divides over the workers — no
+                    # host round trip and no second device copy. Only a
+                    # ragged leading axis is padded, on the device; build
+                    # a resident input with whole shards (the trainers'
+                    # row masks cover rows short of a shard). A
+                    # ``ShapeDtypeStruct`` stands for such an input in
+                    # ``lowered()``: the program at a size no host holds
+                    # (compiled for a described chip, never run)
                     totals[k] = int(arr.shape[0])
                     pad = (-arr.shape[0]) % nw
                     if pad:
@@ -882,10 +919,6 @@ class IterativeComQueue:
                      for k, v in self._broadcast.items()}
             for k, n in totals.items():
                 bcast[f"__total_{k}"] = jnp.asarray(n, jnp.int32)
-        if not lower_only:
-            profile_mark("comqueue.prepare", "transfer",
-                         time.perf_counter() - _prep_t0)
-
         from ..common.profiling import log_superstep, named_stage
         from .communication import collecting
 
@@ -960,7 +993,8 @@ class IterativeComQueue:
         def build_mapped():
             # ONE construction shared by lowered() and exec(): the HLO
             # audit must inspect exactly the program exec runs
-            return shard_map(run, mesh=mesh, in_specs=(P("d"), P()),
+            return shard_map(_name_program(run, self._program_key),
+                             mesh=mesh, in_specs=(P("d"), P()),
                              out_specs=P("d"), check_vma=False)
 
         # -- checkpoint-mode chunk programs -------------------------------
@@ -992,7 +1026,8 @@ class IterativeComQueue:
                     if max_iter > 1 else carry
                 return jax.tree_util.tree_map(
                     lambda x: jnp.expand_dims(x, 0), final)
-            return shard_map(run_first, mesh=mesh,
+            return shard_map(_name_program(run_first, self._program_key,
+                                           "_first"), mesh=mesh,
                              in_specs=(P("d"), P(), P()),
                              out_specs=P("d"), check_vma=False)
 
@@ -1005,7 +1040,8 @@ class IterativeComQueue:
                 final = jax.lax.while_loop(cond, body, carry)
                 return jax.tree_util.tree_map(
                     lambda x: jnp.expand_dims(x, 0), final)
-            return shard_map(run_cont, mesh=mesh,
+            return shard_map(_name_program(run_cont, self._program_key,
+                                           "_cont"), mesh=mesh,
                              in_specs=(P("d"), P(), P("d"), P()),
                              out_specs=P("d"), check_vma=False)
 
@@ -1401,7 +1437,9 @@ class IterativeComQueue:
             reg = get_registry()
             # one scalar fetch; it waits for the (asynchronously
             # dispatched) run, which the caller's first result read would
-            # have done anyway
+            # have done anyway. The wait is taken here, outside
+            # ``comqueue.fetch``, so that span times the transfer alone
+            jax.block_until_ready(stacked["__step"])
             steps = int(result.step_count)
             # a resumed run only EXECUTED the supersteps past its snapshot
             # (and no init pass); charge collectives/supersteps for those

@@ -11,8 +11,10 @@ from typing import List, Optional
 
 import numpy as np
 
+from ....common.metrics import get_registry, metrics_enabled
 from ....common.mtable import MTable
 from ....common.params import ParamInfo, Params, RangeValidator, InValidator
+from ....common.tracing import trace_span
 from ....common.types import AlinkTypes, TableSchema
 from ....mapper.base import ModelMapper, OutputColsHelper
 from ....model.converters import SimpleModelDataConverter, decode_array, encode_array
@@ -20,7 +22,8 @@ from ....params.shared import (HasFeatureCols, HasMaxIterDefaultAs50,
                                HasPredictionCol, HasReservedCols, HasSeed,
                                HasVectorCol)
 from ...base import BatchOperator
-from ...common.clustering.kmeans import assign_clusters, kmeans_train
+from ...common.clustering.kmeans import (as_block_column, assign_table,
+                                         kmeans_train)
 from ...common.dataproc.feature_extract import extract_design, resolve_feature_cols
 from ..utils.model_map import ModelMapBatchOp
 
@@ -66,32 +69,54 @@ class _KMeansParams(HasVectorCol, HasFeatureCols, HasMaxIterDefaultAs50, HasSeed
                           validator=InValidator(["RANDOM", "K_MEANS_PARALLEL"]))
 
 
+def _design_rows(table: MTable, feature_cols, vector_col, dtype):
+    """The table's features as the KMeans programs take them: the dense
+    block column itself where the table holds one (it may live on the
+    device and no row is touched), else host rows ``(n, d)``."""
+    design = extract_design(table, feature_cols, vector_col, dtype)
+    if design["kind"] == "dense":
+        return design["X"]
+    from ....common.vector import SparseBatch
+    return SparseBatch(design["idx"], design["val"],
+                       design["dim"]).to_dense(dtype)
+
+
 class KMeansTrainBatchOp(BatchOperator, _KMeansParams):
     def link_from(self, in_op: BatchOperator) -> "KMeansTrainBatchOp":
-        t = in_op.get_output_table()
-        vector_col = self.params._m.get("vector_col")
-        feature_cols = self.params._m.get("feature_cols")
-        if not vector_col:
-            feature_cols = resolve_feature_cols(t, feature_cols)
-        import jax
-        dtype = np.float64 if jax.config.jax_enable_x64 else np.float32
-        design = extract_design(t, feature_cols, vector_col, dtype)
-        X = design["X"] if design["kind"] == "dense" else None
-        if X is None:
-            from ....common.vector import SparseBatch
-            X = SparseBatch(design["idx"], design["val"], design["dim"]).to_dense(dtype)
-        cents, wts, steps = kmeans_train(
-            X, k=self.get_k(), max_iter=self.get_max_iter(),
-            tol=self.get_epsilon(), distance_type=self.get_distance_type(),
-            init=self.get_init_mode(), seed=self.get_seed())
-        model = KMeansModelData(np.asarray(cents, np.float64),
-                                np.asarray(wts, np.float64),
-                                self.get_distance_type(), vector_col, feature_cols)
-        self._output = KMeansModelDataConverter().save_model(model)
-        self._side_outputs = [MTable({"cluster_id": np.arange(model.k),
-                                      "weight": model.weights})]
-        self._steps = steps
+        with trace_span("kmeans.fit", cat="kmeans") as fit:
+            t = in_op.get_output_table()
+            vector_col = self.params._m.get("vector_col")
+            feature_cols = self.params._m.get("feature_cols")
+            if not vector_col:
+                feature_cols = resolve_feature_cols(t, feature_cols)
+            import jax
+            dtype = np.float64 if jax.config.jax_enable_x64 else np.float32
+            X = _design_rows(t, feature_cols, vector_col, dtype)
+            info = {}
+            cents, wts, steps = kmeans_train(
+                X, k=self.get_k(), max_iter=self.get_max_iter(),
+                tol=self.get_epsilon(), distance_type=self.get_distance_type(),
+                init=self.get_init_mode(), seed=self.get_seed(), info=info)
+            with trace_span("kmeans.model", cat="kmeans"):
+                model = KMeansModelData(np.asarray(cents, np.float64),
+                                        np.asarray(wts, np.float64),
+                                        self.get_distance_type(), vector_col,
+                                        feature_cols)
+                self._output = KMeansModelDataConverter().save_model(model)
+                self._side_outputs = [MTable({"cluster_id": np.arange(model.k),
+                                              "weight": model.weights})]
+            self._steps = steps
+            self._train_info = info
+            fit.set(rows=int(t.num_rows), steps=int(steps))
+        if metrics_enabled():
+            get_registry().inc("alink_kmeans_fits_total", 1)
         return self
+
+    def get_train_info(self) -> dict:
+        """What the last fit went through (``kmeans_train``'s ``info``):
+        the k-means|| candidates and weights, the initial centroids, and
+        per superstep the centroids, cluster weights, inertia and rows."""
+        return self._train_info
 
 
 class KMeansModelMapper(ModelMapper):
@@ -116,12 +141,10 @@ class KMeansModelMapper(ModelMapper):
 
     def map_table(self, data: MTable) -> MTable:
         m = self.model
-        design = extract_design(data, m.feature_cols, m.vector_col, np.float64)
-        X = design["X"] if design["kind"] == "dense" else None
-        if X is None:
-            from ....common.vector import SparseBatch
-            X = SparseBatch(design["idx"], design["val"], design["dim"]).to_dense(np.float64)
-        ids, dists = assign_clusters(X, m.centroids, m.distance_type)
+        X = _design_rows(data, m.feature_cols, m.vector_col, np.float64)
+        # the trainer's own blocked distance, block by block
+        ids, dists = assign_table(as_block_column(X), m.centroids,
+                                  m.distance_type)
         ids = np.asarray(ids, np.int64)
         dists = np.sqrt(np.maximum(np.asarray(dists, np.float64), 0.0)) \
             if m.distance_type == "EUCLIDEAN" else np.asarray(dists, np.float64)

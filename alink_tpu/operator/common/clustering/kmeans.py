@@ -1,41 +1,67 @@
-"""KMeans internals — TPU-native.
+"""KMeans internals — TPU-native, blocked.
 
 Re-design of common/clustering/kmeans/ (call stack SURVEY §3.3):
-  KMeansPreallocateCentroid  -> init centroids (host k-means++ / random)
-  KMeansAssignCluster        -> distances as ONE matmul on the MXU
-                                (||x||^2 - 2 x.c + ||c||^2), argmin, and the
-                                k x (d+1) sum/weight buffer built with a
-                                one-hot scatter-add matmul (replaces
+  KMeansPreallocateCentroid  -> init centroids (k-means|| on the device /
+                                random rows)
+  KMeansAssignCluster        -> one pass over the worker's shard in row
+                                blocks: distances, argmin, and the
+                                k x (d+1) sum/weight buffer (replaces
                                 KMeansUtil.updateSumMatrix's per-point loop,
                                 KMeansAssignCluster.java:60-64)
   AllReduce(centroidAllReduce) -> lax.psum
   KMeansUpdateCentroids      -> sums / weights (KMeansUpdateCentroids.java:53-71)
   KMeansIterTermination      -> centroid movement < tol carry bit
 Supports EUCLIDEAN and COSINE distances (reference FastDistance pre-norms).
+
+The table is a ``DenseBlockColumn`` (common/columnar.py): feature-major,
+lane-packed blocks ``(row_blocks, d, S, 128)`` that may already live on
+the device, partitioned over the workers on the leading axis. A superstep
+walks its shard block by block (``lax.fori_loop``), so every intermediate
+is ``(k, S, 128)`` and peak memory is the table plus O(block); nothing of
+shape ``(n, k)`` or ``(n, d + 1)`` exists. Sums over a shard stay exact to
+float32 round-off at 100 million rows because (a) a block's rows are
+summed CENTRED on the cluster's current centroid (``sum w (x - c_j)``, so
+the addends are small and of both signs), and (b) the per-block partial
+sums are added with a Kahan compensation across blocks.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional, Tuple
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ....common.columnar import LANES, DenseBlockColumn
+from ....common.metrics import get_registry, metrics_enabled
 from ....common.mlenv import MLEnvironment, MLEnvironmentFactory
+from ....common.tracing import trace_span
 from ....engine import AllReduce, IterativeComQueue
 from ....engine.communication import manifest_all_gather
 
+#: the engine names each compiled program ``jit_<first word of its key>``
+LLOYD_PROGRAM = "kmeans_lloyd"
+INIT_PROGRAM = "kmeans_init"
 
-def kmeans_plus_plus_init(X: np.ndarray, k: int, seed: int,
+
+def _rows(X, idx) -> np.ndarray:
+    """Host copies of rows ``idx`` of host rows or of a blocked table."""
+    return take_rows(X, idx) if isinstance(X, DenseBlockColumn) else X[idx]
+
+
+def kmeans_plus_plus_init(X, k: int, seed: int,
                           sample_cap: int = 4096) -> np.ndarray:
     """k-means++ seeding on a bounded host sample (reference KMeansInitCentroids
     K-MEANS|| has the same role: good seeds without a full device pass)."""
     rng = np.random.RandomState(seed)
-    n = X.shape[0]
+    n = len(X)
     if n > sample_cap:
-        X = X[rng.choice(n, sample_cap, replace=False)]
+        X = _rows(X, rng.choice(n, sample_cap, replace=False))
         n = sample_cap
+    else:
+        X = _rows(X, np.arange(n))
     cents = [X[rng.randint(n)]]
     d2 = ((X - cents[0]) ** 2).sum(1)
     for _ in range(1, k):
@@ -48,9 +74,9 @@ def kmeans_plus_plus_init(X: np.ndarray, k: int, seed: int,
     return np.stack(cents)
 
 
-def random_init(X: np.ndarray, k: int, seed: int) -> np.ndarray:
+def random_init(X, k: int, seed: int) -> np.ndarray:
     rng = np.random.RandomState(seed)
-    return X[rng.choice(X.shape[0], k, replace=X.shape[0] < k)]
+    return _rows(X, rng.choice(len(X), k, replace=len(X) < k))
 
 
 def _weighted_kmeans_pp(C: np.ndarray, w: np.ndarray, k: int,
@@ -87,65 +113,280 @@ def _weighted_kmeans_pp(C: np.ndarray, w: np.ndarray, k: int,
     return cc
 
 
-def kmeans_parallel_init(X: np.ndarray, k: int, seed: int = 0,
+
+
+# -- the blocked table ---------------------------------------------------------
+
+def as_block_column(X, num_workers: int = 1) -> DenseBlockColumn:
+    """The trainer's one input form. A ``DenseBlockColumn`` passes through
+    untouched (device-resident or not); host rows ``(n, d)`` are packed
+    once into blocks, their count a multiple of the workers so the engine
+    has nothing to pad."""
+    if isinstance(X, DenseBlockColumn):
+        return X
+    X = np.asarray(X)
+    if X.ndim != 2:
+        raise ValueError("kmeans: X must be (n, d) rows or a DenseBlockColumn")
+    B = DenseBlockColumn.block_rows_for(X.shape[0])
+    nb = -(-max(X.shape[0], 1) // B)
+    nb = -(-nb // num_workers) * num_workers
+    return DenseBlockColumn(DenseBlockColumn.pack(X, B, nb), X.shape[0])
+
+
+def take_rows(col: DenseBlockColumn, idx) -> np.ndarray:
+    """Host copies ``(len(idx), d)`` of a few rows of the table (read
+    tile by tile where the table lives on the device: ``_rows_at``)."""
+    idx = np.asarray(idx, np.int64).reshape(-1)
+    b, r = np.divmod(idx, col.block_rows)
+    if col.on_device:
+        return np.asarray(_device_rows(col.blocks, b.astype(np.int32),
+                                       r.astype(np.int32)))
+    return col.blocks[b, :, r // LANES, r % LANES]
+
+
+@jax.jit
+def _unit_weights_like(blocks, n_rows):
+    nb, _, S, L = blocks.shape
+    at = jax.lax.broadcasted_iota(jnp.int32, (nb, S, L), 0) * (S * L) \
+        + jax.lax.broadcasted_iota(jnp.int32, (nb, S, L), 1) * L \
+        + jax.lax.broadcasted_iota(jnp.int32, (nb, S, L), 2)
+    return (at < n_rows).astype(blocks.dtype)
+
+
+def block_weights(col: DenseBlockColumn, sample_weight=None):
+    """Per-row weights laid out like the table's rows, ``(row_blocks, S,
+    128)``, zero on the padding past ``n_rows`` — the mask every pass
+    carries. Without ``sample_weight`` they are made where the table
+    lives (no ``(n,)`` host array); weights already so laid out pass
+    through."""
+    nb, _, S, _ = col.blocks.shape
+    if getattr(sample_weight, "shape", None) == (nb, S, LANES):
+        return sample_weight              # already laid out (one fit, twice)
+    if sample_weight is None:
+        if col.on_device:
+            return _unit_weights_like(col.blocks, col.n_rows)
+        w = np.zeros(nb * S * LANES, col.blocks.dtype)
+        w[:col.n_rows] = 1
+        return w.reshape(nb, S, LANES)
+    w = np.asarray(sample_weight, col.blocks.dtype)
+    if w.shape != (col.n_rows,):
+        raise ValueError("kmeans: sample_weight must be (n,)")
+    return DenseBlockColumn.pack(w, col.block_rows, nb)
+
+
+def block_distances(xb, C, distance_type: str = "EUCLIDEAN"):
+    """Distances of one feature-major block to every centroid: ``xb`` is
+    ``(d, *rows)``, ``C`` is ``(k, d)``, the result ``(k, *rows)``. THE
+    distance of the trainer, of ``assign_clusters`` and of the model
+    mapper: squared differences summed feature by feature (no ``|x|^2 -
+    2 x.c + |c|^2`` cancellation), or one minus the cosine."""
+    Cb = C.reshape(C.shape + (1,) * (xb.ndim - 1))
+    if distance_type == "COSINE":
+        xn = jnp.maximum(jnp.sqrt((xb * xb).sum(0)), 1e-12)
+        Cn = Cb / jnp.maximum(
+            jnp.sqrt((Cb * Cb).sum(1, keepdims=True)), 1e-12)
+        return 1.0 - (xb[None] * Cn).sum(1) / xn[None]
+    diff = xb[None] - Cb
+    return (diff * diff).sum(1)
+
+
+def assign_clusters(X, C, distance_type: str = "EUCLIDEAN"):
+    """Nearest centroid ids + distances for rows ``X`` of shape (n, d)."""
+    D = block_distances(jnp.asarray(X).T, jnp.asarray(C), distance_type)
+    return jnp.argmin(D, axis=0), jnp.min(D, axis=0)
+
+
+@functools.partial(jax.jit, static_argnames="distance_type")
+def _assign_blocks(blocks, C, distance_type):
+    def one(xb):
+        D = block_distances(xb, C, distance_type)
+        return jnp.argmin(D, 0), jnp.min(D, 0)
+    return jax.lax.map(one, blocks)
+
+
+def assign_table(col: DenseBlockColumn, C, distance_type: str = "EUCLIDEAN"
+                 ) -> Tuple[np.ndarray, np.ndarray]:
+    """Host ``(ids, distances)`` of every row of a blocked table, block by
+    block with the trainer's distance."""
+    ids, dist = _assign_blocks(col.blocks, jnp.asarray(C, col.blocks.dtype),
+                               distance_type)
+    n = col.n_rows
+    return (np.asarray(ids).reshape(-1)[:n], np.asarray(dist).reshape(-1)[:n])
+
+
+def _kahan_add(acc, comp, x):
+    """One compensated addition: the running sum and what it lost."""
+    y = x - comp
+    t = acc + y
+    return t, (t - acc) - y
+
+
+def _split_count(rows):
+    """An int32 row count as two halves that a float32 psum keeps exact
+    (each under 2^16 a worker); ``_join_count`` puts them back."""
+    return rows // 65536, rows % 65536
+
+
+def _join_count(hi, lo):
+    return hi.astype(jnp.int32) * 65536 + lo.astype(jnp.int32)
+
+
+def _block_at(arr, i):
+    return jax.lax.dynamic_index_in_dim(arr, i, 0, keepdims=False)
+
+
+def _rows_at(Xs, blk, pos):
+    """Rows ``pos`` of blocks ``blk`` of a shard, ``(len, d)``. Each row
+    is read as the aligned ``(d, 8, 128)`` register tile that holds it,
+    masked down to its one sublane and lane: the table keeps its layout.
+    (An advanced-indexing gather, or a one-element ``dynamic_slice``,
+    makes XLA lay the WHOLE table out anew: a second copy of the shard.)"""
+    d = Xs.shape[1]
+    sub = jax.lax.broadcasted_iota(jnp.int32, (8, LANES), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (8, LANES), 1)
+
+    def one(at):
+        b, r = at
+        s = r // LANES
+        zero = jnp.zeros_like(b)
+        tile = jax.lax.dynamic_slice(
+            Xs, (b, zero, s // 8 * 8, zero), (1, d, 8, LANES))[0]
+        here = (sub == s % 8) & (lane == r % LANES)
+        return jnp.where(here[None], tile, 0).sum((1, 2))
+    return jax.lax.map(one, (blk, pos))
+
+
+_device_rows = jax.jit(_rows_at)
+
+
+# -- k-means|| -----------------------------------------------------------------
+
+def _kmpp_pass(Xs, Ws, state, new, off, key, block0, last, cap: int,
+               l_blk: int):
+    """One k-means|| round over a worker's shard, block by block: fold the
+    candidates ``new`` (numbered from ``off``) into the per-row ``(d2,
+    nearest)`` state (``state`` is ``None`` in the first round, which
+    makes it), draw each block's ``l_blk`` proposals by Gumbel-top-k over
+    p ∝ d2, and — in the ``last`` round — sum the row weights under each
+    of the ``cap`` candidates. Returns ``(d2, nearest, proposal keys
+    (blocks, l_blk), proposal rows (blocks, l_blk), rows seen, candidate
+    weights (cap,))``."""
+    nbl = Xs.shape[0]
+    dt = Xs.dtype
+    ids = jnp.arange(cap, dtype=jnp.int32)[:, None, None]
+    fresh = state is None
+    if fresh:
+        state = (jnp.zeros(Ws.shape, dt), jnp.zeros(Ws.shape, jnp.int32))
+
+    def body(i, c):
+        d2, nearest, topv, topi, rows, acc, comp = c
+        xb, wb = _block_at(Xs, i), _block_at(Ws, i)
+        valid = wb != 0
+        with jax.named_scope("kmpp_sample"):
+            Dn = block_distances(xb, new)                # (l or 1, S, 128)
+            dn = jnp.where(valid, jnp.min(Dn, 0), 0)
+            j = off + jnp.argmin(Dn, 0).astype(jnp.int32)
+            if fresh:
+                d2b, nb_ = dn, j
+            else:
+                d2b, nb_ = _block_at(d2, i), _block_at(nearest, i)
+                closer = dn < d2b
+                nb_ = jnp.where(closer, j, nb_)
+                d2b = jnp.where(closer, dn, d2b)
+            # this round's draw: Gumbel-top-l over p_i ∝ d2_i
+            g = jax.random.gumbel(
+                jax.random.fold_in(key, block0 + i), d2b.shape, dt)
+            keys = jnp.where(d2b > 0,
+                             jnp.log(jnp.maximum(d2b, 1e-30)) + g, -jnp.inf)
+        with jax.named_scope("kmpp_topk"):
+            kv, ki = jax.lax.top_k(keys.reshape(-1), l_blk)
+        # candidate weights under the current nearest, the last round
+        cnt = jax.lax.cond(
+            last,
+            lambda: jnp.where(nb_[None] == ids, wb[None], 0).sum((1, 2)),
+            lambda: jnp.zeros((cap,), dt))
+        acc, comp = _kahan_add(acc, comp, cnt)
+        upd = jax.lax.dynamic_update_index_in_dim
+        return (upd(d2, d2b, i, 0), upd(nearest, nb_, i, 0),
+                upd(topv, kv, i, 0), upd(topi, ki.astype(jnp.int32), i, 0),
+                rows + valid.sum(dtype=jnp.int32), acc, comp)
+
+    zero = jnp.zeros((cap,), dt)
+    d2, nearest, topv, topi, rows, counts, _ = jax.lax.fori_loop(
+        0, nbl, body,
+        state + (jnp.zeros((nbl, l_blk), dt),
+                 jnp.zeros((nbl, l_blk), jnp.int32),
+                 jnp.asarray(0, jnp.int32), zero, zero))
+    return d2, nearest, topv, topi, rows, counts
+
+
+def kmeans_parallel_init(X, k: int, seed: int = 0,
                          rounds: int = 5, oversample: Optional[int] = None,
-                         env: Optional[MLEnvironment] = None) -> np.ndarray:
+                         env: Optional[MLEnvironment] = None,
+                         sample_weight=None,
+                         info: Optional[Dict] = None) -> np.ndarray:
     """K-MEANS|| distributed seeding (reference
     clustering/kmeans/KMeansInitCentroids.java; Bahmani et al. 2012) as a
-    BSP program — no full-data host pass.
+    BSP program over the blocked table — no full-data host pass.
 
     Each superstep samples ``l = oversample`` new candidates with
     probability proportional to the current squared distance to the
     candidate set (the exactly-l Gumbel-top-l variant of the per-point
-    Bernoulli draw), via per-shard ``top_k`` + ``all_gather`` + global
-    ``top_k``; the per-point d2/nearest state updates incrementally
-    against only the l new candidates, so the total work is
-    O(rounds * n * l * d / workers). Candidate weights (cluster sizes)
-    come out of the same program; the final weighted recluster to k runs
-    on the O(rounds*l) candidate set on the host.
+    Bernoulli draw): a ``top_k`` per row block, one over the shard's
+    block winners, an ``all_gather`` and a global ``top_k``. The per-row
+    d2/nearest state updates block by block against only the l new
+    candidates, so the work is O(rounds * n * l * d / workers). The
+    Gumbel noise of a block is keyed by (seed, round, GLOBAL block
+    index), so any worker count draws the same candidates. Candidate
+    weights (summed row weights under the nearest candidate) are counted
+    in the last round; the final weighted recluster to k runs on the
+    O(rounds*l) candidate set on the host. ``info``, when given, receives
+    the candidate set, its weights and the rows each round counted.
     """
-    X = np.asarray(X)
-    n, d = X.shape
-    dt = X.dtype
+    env_ = env or MLEnvironmentFactory.get_default()
+    nw = env_.num_workers
+    col = as_block_column(X, nw)
+    n, d = col.n_rows, col.dim
+    dt = col.blocks.dtype
+    B = col.block_rows
+    S = B // LANES
     l = int(oversample) if oversample else max(2 * k, 1)
     cap = 1 + rounds * l
     rng = np.random.RandomState(seed)
-    first = X[rng.randint(n)].astype(dt)
-    env_ = env or MLEnvironmentFactory.get_default()
-    nw = env_.num_workers
-    n_loc = -(-n // nw)              # padded shard length (static)
-    l_loc = min(l, n_loc)            # per-shard candidate proposals
+    first = take_rows(col, [rng.randint(n)])[0].astype(dt)
+    nbl = -(-col.row_blocks // nw)   # blocks a worker holds (static)
+    l_blk = min(l, B)                # proposals a block makes
+    l_loc = min(l, nbl * l_blk)      # proposals a worker makes
     l_glob = min(l, nw * l_loc)
 
-    mask_col = np.ones(n, dt)
-
     def sample(ctx):
-        Xb = ctx.get_obj("X")
-        msk = ctx.get_obj("mask")
+        Xs = ctx.get_obj("X")
+        Ws = ctx.get_obj("w")
         step = ctx.step_no
-        if ctx.is_init_step:
+        init = ctx.is_init_step
+        if init:
             cands = jnp.zeros((cap, d), dt).at[0].set(ctx.get_obj("first"))
-            d2 = ((Xb - ctx.get_obj("first")) ** 2).sum(1) * msk
-            nearest = jnp.zeros(Xb.shape[0], jnp.int32)
-            ctx.put_obj("weights", jnp.zeros((cap,), dt))
+            d2 = nearest = None
+            rows_seen = jnp.zeros((rounds,), jnp.int32)
+            new, off = cands[:1], 0
         else:
             cands = ctx.get_obj("cands")
             d2 = ctx.get_obj("d2")
             nearest = ctx.get_obj("nearest")
+            rows_seen = ctx.get_obj("rows")
             # fold in the l candidates written by the previous superstep
             off = 1 + (step - 2) * l
             new = jax.lax.dynamic_slice_in_dim(cands, off, l, 0)  # (l, d)
-            Dn = ((Xb[:, None, :] - new[None, :, :]) ** 2).sum(-1)
-            j = jnp.argmin(Dn, axis=1)
-            dn = jnp.take_along_axis(Dn, j[:, None], 1)[:, 0] * msk
-            closer = dn < d2
-            nearest = jnp.where(closer, off + j.astype(jnp.int32), nearest)
-            d2 = jnp.where(closer, dn, d2)
-        # draw this round's l candidates: Gumbel-top-l over p_i ∝ d2_i
-        g = jax.random.gumbel(ctx.rng_key(), d2.shape, dt)
-        keys = jnp.where(d2 > 0, jnp.log(jnp.maximum(d2, 1e-30)) + g, -jnp.inf)
-        kv, ki = jax.lax.top_k(keys, l_loc)
-        pts = Xb[ki]                                        # (l_loc, d)
+        key = jax.random.fold_in(
+            jax.random.wrap_key_data(ctx.get_obj("key")), step)
+        d2, nearest, topv, topi, rows, counts = _kmpp_pass(
+            Xs, Ws, None if init else (d2, nearest), new, off, key,
+            ctx.task_id * nbl, step == rounds, cap, l_blk)
+        with jax.named_scope("kmpp_topk"):
+            kv, which = jax.lax.top_k(topv.reshape(-1), l_loc)
+            blk = (which // l_blk).astype(jnp.int32)
+            pos = topi.reshape(-1)[which]
+            pts = _rows_at(Xs, blk, pos)                      # (l_loc, d)
         # register BOTH gathers before either is consumed: under
         # ALINK_TPU_FUSE_COLLECTIVES the pair coalesces into one
         # all-gather (the jnp.asarray coercion materializes the deferred
@@ -165,57 +406,118 @@ def kmeans_parallel_init(X: np.ndarray, k: int, seed: int = 0,
                 [sel, jnp.broadcast_to(cands[0], (l - l_glob, d))], 0)
         off_w = 1 + (step - 1) * l
         cands = jax.lax.dynamic_update_slice_in_dim(cands, sel, off_w, 0)
-        # running candidate weights (cluster sizes under current nearest)
-        counts = jnp.zeros((cap,), dt).at[nearest].add(msk)
-        ctx.put_obj("weights", ctx.all_reduce_sum(counts))
+        hi, lo = _split_count(rows)
+        tot = ctx.all_reduce_sum(jnp.concatenate(
+            [counts, jnp.stack([hi, lo]).astype(dt)]))
+        tot = jnp.asarray(tot)
+        ctx.put_obj("weights", tot[:cap])
+        ctx.put_obj("rows", jax.lax.dynamic_update_index_in_dim(
+            rows_seen, _join_count(tot[cap], tot[cap + 1]), step - 1, 0))
         ctx.put_obj("cands", cands)
         ctx.put_obj("d2", d2)
         ctx.put_obj("nearest", nearest)
 
-    res = (IterativeComQueue(env=env_, max_iter=rounds, seed=seed)
-           .init_with_partitioned_data("X", X)
-           .init_with_partitioned_data("mask", mask_col)
-           .init_with_broadcast_data("first", first)
-           .add(sample)
-           .set_program_key(("kmeans_par_init", cap, d, l, l_loc, l_glob,
-                             str(dt)))
-           .exec())
-    cands = np.asarray(res.get("cands"))
-    weights = np.array(res.get("weights"))
+    with trace_span("kmeans.init", cat="kmeans",
+                    args={"rows": n, "rounds": rounds}):
+        res = (IterativeComQueue(env=env_, max_iter=rounds)
+               .init_with_partitioned_data("X", col.blocks)
+               .init_with_partitioned_data(
+                   "w", block_weights(col, sample_weight))
+               .init_with_broadcast_data("first", first)
+               .init_with_broadcast_data(
+                   "key", np.asarray(jax.random.key_data(
+                       jax.random.PRNGKey(seed))))
+               .add(sample)
+               .set_program_key((INIT_PROGRAM, cap, d, l, l_blk, l_loc,
+                                 l_glob, nbl, S, str(dt)))
+               .exec())
+        cands, weights, rows_seen = (
+            np.array(v) for v in res.get_all(["cands", "weights", "rows"]))
+    _count(rows_seen.sum(dtype=np.int64), rounds)
+    if info is not None:
+        info.update(init_candidates=cands, init_weights=weights.copy(),
+                    init_rows=rows_seen)
     # candidates sampled in the final round carry no counted weight yet;
     # give them each weight 1 so the recluster can still use them
     weights[weights == 0] = 1.0
-    return _weighted_kmeans_pp(cands, weights, k, rng).astype(dt)
+    with trace_span("kmeans.recluster", cat="kmeans",
+                    args={"candidates": int(cap)}):
+        return _weighted_kmeans_pp(cands, weights, k, rng).astype(dt)
 
 
-def _distances(X, C, distance_type: str):
-    """(n, k) distance matrix as one MXU matmul."""
-    if distance_type == "COSINE":
-        Xn = X / jnp.maximum(jnp.linalg.norm(X, axis=1, keepdims=True), 1e-12)
-        Cn = C / jnp.maximum(jnp.linalg.norm(C, axis=1, keepdims=True), 1e-12)
-        return 1.0 - Xn @ Cn.T
-    x2 = (X ** 2).sum(1, keepdims=True)
-    c2 = (C ** 2).sum(1)
-    return x2 - 2.0 * (X @ C.T) + c2
+def _count(rows: int, supersteps: int) -> None:
+    if metrics_enabled():
+        reg = get_registry()
+        reg.inc("alink_kmeans_rows_total", int(rows))
+        reg.inc("alink_kmeans_supersteps_total", int(supersteps))
 
 
-def assign_clusters(X, C, distance_type: str = "EUCLIDEAN"):
-    """Nearest centroid ids + distances for a block."""
-    D = _distances(X, C, distance_type)
-    ids = jnp.argmin(D, axis=1)
-    return ids, jnp.take_along_axis(D, ids[:, None], 1)[:, 0]
+# -- Lloyd ----------------------------------------------------------------------
+
+def _lloyd_pass(Xs, Ws, C, distance_type: str):
+    """One pass over a worker's shard: the ``(k + 2, d + 1)`` buffer the
+    AllReduce sums. Rows ``:k`` hold ``sum w (x - c_j)`` and, last, the
+    cluster's summed weight; row ``k`` the weighted inertia; row ``k + 1``
+    the rows seen, split so a float psum keeps the count exact."""
+    nbl, d = Xs.shape[0], Xs.shape[1]
+    k = C.shape[0]
+    dt = Xs.dtype
+    Cb = C[:, :, None, None]
+    ids = jnp.arange(k, dtype=jnp.int32)[:, None, None]
+
+    def body(i, c):
+        acc, comp, rows = c
+        xb, wb = _block_at(Xs, i), _block_at(Ws, i)
+        with jax.named_scope("kmeans_assign"):
+            D = block_distances(xb, C, distance_type)         # (k, S, 128)
+            near = jnp.argmin(D, 0).astype(jnp.int32)
+            dmin = jnp.min(D, 0)
+        with jax.named_scope("kmeans_accumulate"):
+            wk = jnp.where(near[None] == ids, wb[None], 0)    # (k, S, 128)
+            sums = (wk[:, None] * (xb[None] - Cb)).sum((2, 3))    # (k, d)
+            blk = jnp.concatenate([sums, wk.sum((1, 2))[:, None]], 1)
+            tail = jnp.zeros((1, d + 1), dt).at[0, 0].set((dmin * wb).sum())
+            acc, comp = _kahan_add(acc, comp, jnp.concatenate([blk, tail], 0))
+        return acc, comp, rows + (wb != 0).sum(dtype=jnp.int32)
+
+    zero = jnp.zeros((k + 1, d + 1), dt)
+    acc, _, rows = jax.lax.fori_loop(
+        0, nbl, body, (zero, zero, jnp.asarray(0, jnp.int32)))
+    hi, lo = _split_count(rows)
+    tail = jnp.zeros((1, d + 1), dt).at[0, 0].set(hi).at[0, 1].set(lo)
+    return jnp.concatenate([acc, tail.astype(dt)], 0)
 
 
-def kmeans_train(X: np.ndarray, k: int, max_iter: int = 50, tol: float = 1e-4,
+def _lloyd_update(buf, C):
+    """From the all-reduced buffer of ``_lloyd_pass`` and the centroids it
+    was made against: ``(new centroids, cluster weights, inertia,
+    movement, rows seen)``. A cluster no row chose stays where it was."""
+    k, d = C.shape
+    sums, cnts = buf[:k, :d], buf[:k, d]
+    newC = jnp.where(cnts[:, None] > 0,
+                     C + sums / jnp.maximum(cnts[:, None], 1e-12), C)
+    movement = jnp.sqrt(((newC - C) ** 2).sum(1)).max()
+    return newC, cnts, buf[k, 0], movement, \
+        _join_count(buf[k + 1, 0], buf[k + 1, 1])
+
+
+def kmeans_train(X, k: int, max_iter: int = 50, tol: float = 1e-4,
                  distance_type: str = "EUCLIDEAN", init: str = "K_MEANS_PARALLEL",
                  seed: int = 0, env: Optional[MLEnvironment] = None,
                  sample_weight: Optional[np.ndarray] = None,
                  checkpoint_dir: Optional[str] = None,
                  checkpoint_every: int = 1, checkpoint_keep: int = 3,
                  resume_from: Optional[str] = None,
-                 health=None
+                 health=None, info: Optional[Dict] = None
                  ) -> Tuple[np.ndarray, np.ndarray, int]:
     """Returns (centroids (k,d), cluster_weights (k,), num_steps).
+
+    ``X`` is host rows ``(n, d)`` or a ``DenseBlockColumn`` (which may be
+    device-resident and is then used where it lies). ``info``, when
+    given, receives what a fit went through: the k-means|| candidates
+    and their weights, the initial centroids, and per superstep the
+    centroids, cluster weights, inertia (of the assignment that produced
+    them) and rows seen.
 
     ``health=`` attaches a ``common.health.HealthMonitor`` fed the Lloyd
     loop's probe series (``inertia``, ``movement``, ``empty_clusters``)
@@ -230,66 +532,59 @@ def kmeans_train(X: np.ndarray, k: int, max_iter: int = 50, tol: float = 1e-4,
     checkpointed — it is short and re-running it is cheaper than a
     snapshot per sampling round; exact resume still holds because the
     init is deterministic in ``seed``."""
-    X = np.asarray(X)
-    n, d = X.shape
-    w = np.ones(n, X.dtype) if sample_weight is None else np.asarray(sample_weight, X.dtype)
+    env_ = env or MLEnvironmentFactory.get_default()
+    col = as_block_column(X, env_.num_workers)
+    n, d = col.n_rows, col.dim
+    dt = col.blocks.dtype
+    weights = block_weights(col, sample_weight)
     init_u = init.upper()
     if init_u == "RANDOM":
-        init_c = random_init(X, k, seed)
+        init_c = random_init(col, k, seed)
     elif init_u in ("K_MEANS_PARALLEL", "KMEANS_PARALLEL"):
-        init_c = kmeans_parallel_init(X, k, seed=seed, env=env)
-    else:  # K_MEANS_PLUS_PLUS / legacy host seeding
-        init_c = kmeans_plus_plus_init(X, k, seed)
-    init_c = init_c.astype(X.dtype)
-    data = np.concatenate([X, w[:, None]], axis=1)
-    dt = X.dtype
+        init_c = kmeans_parallel_init(col, k, seed=seed, env=env_,
+                                      sample_weight=weights, info=info)
+    else:  # K_MEANS_PLUS_PLUS / legacy host seeding on a bounded sample
+        init_c = kmeans_plus_plus_init(col, k, seed)
+    init_c = np.asarray(init_c, dt)
 
     def assign(ctx):
         if ctx.is_init_step:
             ctx.put_obj("centroids", ctx.get_obj("init_centroids"))
             ctx.put_obj("movement", jnp.asarray(jnp.inf, dt))
-        block = ctx.get_obj("data")
-        Xb, wb = block[:, :d], block[:, d]
-        C = ctx.get_obj("centroids")
-        ids, dist = assign_clusters(Xb, C, distance_type)
-        onehot = jax.nn.one_hot(ids, k, dtype=dt) * wb[:, None]   # (n, k), weighted
-        sums = onehot.T @ Xb                                      # (k, d) on MXU
-        cnts = onehot.sum(0)                                      # (k,)
-        buf = jnp.concatenate([sums, cnts[:, None]], 1)
-        if ctx.probes_enabled:
-            # weighted inertia (sum of assigned distances) rides the
-            # EXISTING buf AllReduce as one extra row — a probe must not
-            # add a collective of its own (padding rows have wb == 0)
-            inertia = jnp.concatenate(
-                [(dist * wb).sum().reshape(1, 1), jnp.zeros((1, d), dt)], 1)
-            buf = jnp.concatenate([buf, inertia.astype(dt)], 0)
-        ctx.put_obj("buf", buf)
+            ctx.put_obj("hist_centroids", jnp.zeros((max_iter, k, d), dt))
+            ctx.put_obj("hist_weights", jnp.zeros((max_iter, k), dt))
+            ctx.put_obj("hist_inertia", jnp.zeros((max_iter,), dt))
+            ctx.put_obj("rows", jnp.zeros((max_iter,), jnp.int32))
+        ctx.put_obj("buf", _lloyd_pass(
+            ctx.get_obj("X"), ctx.get_obj("w"), ctx.get_obj("centroids"),
+            distance_type))
 
     def update(ctx):
-        buf = ctx.get_obj("buf")
-        C = ctx.get_obj("centroids")
-        if ctx.probes_enabled:
-            # pre-update inertia: the objective of the assignment the
-            # centroids being replaced produced (standard Lloyd bookkeeping)
-            ctx.probe("inertia", buf[k, 0])
-            buf = buf[:k]
-        sums, cnts = buf[:, :d], buf[:, d]
-        newC = jnp.where(cnts[:, None] > 0, sums / jnp.maximum(cnts[:, None], 1e-12), C)
-        movement = jnp.sqrt(((newC - C) ** 2).sum(1)).max()
+        newC, cnts, inertia, movement, rows = _lloyd_update(
+            ctx.get_obj("buf"), ctx.get_obj("centroids"))
+        at = ctx.step_no - 1
+        # pre-update inertia: the objective of the assignment the
+        # centroids being replaced produced (standard Lloyd bookkeeping)
+        ctx.probe("inertia", inertia)
         ctx.put_obj("movement", movement)
         ctx.probe("movement", movement)
         ctx.probe("empty_clusters", (cnts <= 0).sum())
         ctx.put_obj("centroids", newC)
         ctx.put_obj("cluster_weights", cnts)
+        upd = jax.lax.dynamic_update_index_in_dim
+        for name, value in (("hist_centroids", newC), ("hist_weights", cnts),
+                            ("hist_inertia", inertia), ("rows", rows)):
+            ctx.put_obj(name, upd(ctx.get_obj(name), value, at, 0))
 
-    queue = (IterativeComQueue(env=env, max_iter=max_iter, seed=seed)
-             .init_with_partitioned_data("data", data)
+    queue = (IterativeComQueue(env=env_, max_iter=max_iter)
+             .init_with_partitioned_data("X", col.blocks)
+             .init_with_partitioned_data("w", weights)
              .init_with_broadcast_data("init_centroids", init_c)
              .add(assign)
              .add(AllReduce("buf"))
              .add(update)
              .set_compare_criterion(lambda ctx: ctx.get_obj("movement") < tol)
-             .set_program_key(("kmeans", k, d, distance_type, float(tol),
+             .set_program_key((LLOYD_PROGRAM, k, d, distance_type, float(tol),
                                str(dt))))
     if checkpoint_dir:
         # knob validation (every/keep_last >= 1) lives in CheckpointConfig
@@ -303,6 +598,18 @@ def kmeans_train(X: np.ndarray, k: int, max_iter: int = 50, tol: float = 1e-4,
         from ....common.health import warn_if_disabled
         warn_if_disabled("kmeans_train(health=...)", stacklevel=3)
         queue.set_health(health)
-    result = queue.exec()
-    return (result.get("centroids"), result.get("cluster_weights"),
-            result.step_count)
+    with trace_span("kmeans.lloyd", cat="kmeans",
+                    args={"rows": n, "k": int(k), "max_iter": int(max_iter)}):
+        result = queue.exec()
+        steps, rows_seen, cents, wts, hist_c, hist_w, hist_i = result.get_all(
+            ["__step", "rows", "centroids", "cluster_weights",
+             "hist_centroids", "hist_weights", "hist_inertia"])
+        steps = int(steps)
+    _count(rows_seen[:steps].sum(dtype=np.int64), steps)
+    if info is not None:
+        info.update(init_centroids=init_c, steps=steps,
+                    rows=np.asarray(rows_seen[:steps]),
+                    centroids=np.asarray(hist_c[:steps]),
+                    weights=np.asarray(hist_w[:steps]),
+                    inertia=np.asarray(hist_i[:steps]))
+    return cents, wts, steps

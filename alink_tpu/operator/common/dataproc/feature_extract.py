@@ -22,11 +22,17 @@ def extract_design(table: MTable, feature_cols: Optional[Sequence[str]],
                    vector_col: Optional[str], dtype=np.float64,
                    vector_size: Optional[int] = None) -> Dict:
     """Returns {"kind": "dense", "X": (n,d)} or
-    {"kind": "sparse", "idx": (n,nnz), "val": (n,nnz)}, plus "dim".
+    {"kind": "sparse", "idx": (n,nnz), "val": (n,nnz)}, plus "dim". A
+    vector column held as a ``DenseBlockColumn`` (possibly on the device)
+    comes back as ``X`` itself: the column, untouched — no row is read on
+    the host.
     """
     if vector_col:
+        from ....common.columnar import DenseBlockColumn
         from ....common.vector import SparseVectorColumn
         col = table.col(vector_col)
+        if isinstance(col, DenseBlockColumn):
+            return {"kind": "dense", "X": col, "dim": col.dim}
         if isinstance(col, SparseVectorColumn):
             # columnar hasher output: zero-copy into the padded design
             return {"kind": "sparse",
@@ -123,7 +129,8 @@ def extract_dense_matrix(t, selected_cols, vector_col,
     input encoding (sparse designs go through SparseBatch.to_dense)."""
     design = extract_design(t, selected_cols, vector_col, dtype)
     if design["kind"] == "dense":
-        return design["X"]
+        X = design["X"]
+        return X if isinstance(X, np.ndarray) else X.to_rows().astype(dtype)
     from ....common.vector import SparseBatch
     return SparseBatch(design["idx"], design["val"],
                        design["dim"]).to_dense(dtype)

@@ -729,11 +729,11 @@ def _make_kmeans_stage(P: int, k: int, d: int, dtype, distance_type: str,
     import jax.numpy as jnp
 
     from ..engine.communication import manifest_psum
-    from ..operator.common.clustering.kmeans import assign_clusters
+    from ..operator.common.clustering.kmeans import (_lloyd_pass,
+                                                     _lloyd_update)
 
     def stage(ctx):
-        block = ctx.get_obj("data")
-        Xb, wb = block[:, :d], block[:, d]
+        Xs, Ws = ctx.get_obj("X"), ctx.get_obj("w")
         tol = ctx.get_obj("swh_tol")
         step = ctx.step_no
         if ctx.is_init_step:
@@ -759,32 +759,15 @@ def _make_kmeans_stage(P: int, k: int, d: int, dtype, distance_type: str,
 
             def live(pc_q):
                 C = pc_q["centroids"]
-                ids, dist = assign_clusters(Xb, C, distance_type)
-                onehot = jax.nn.one_hot(ids, k, dtype=dtype) * wb[:, None]
-                sums = onehot.T @ Xb
-                cnts = onehot.sum(0)
-                buf = jnp.concatenate([sums, cnts[:, None]], 1)
-                # the inertia row (the serial trainer's ALINK_TPU_HEALTH
-                # probe row) rides the buf psum UNCONDITIONALLY here: it
-                # is the ASHA pruning signal, and rung decisions must
-                # not flip with an observability flag. The psum reduces
-                # elementwise, so the extra row cannot perturb the
-                # centroid block — per-point parity with the serial
-                # trainer holds under either flag setting (tested).
-                inertia = jnp.concatenate(
-                    [(dist * wb).sum().reshape(1, 1),
-                     jnp.zeros((1, d), dtype)], 1)
-                buf = jnp.concatenate([buf, inertia.astype(dtype)], 0)
-                buf = jnp.asarray(manifest_psum(buf, axis,
-                                                name="sweep_buf",
-                                                num_workers=nw))
-                cur = buf[k, 0]
-                buf = buf[:k]
-                sums2, cnts2 = buf[:, :d], buf[:, d]
-                newC = jnp.where(cnts2[:, None] > 0,
-                                 sums2 / jnp.maximum(cnts2[:, None],
-                                                     1e-12), C)
-                movement = jnp.sqrt(((newC - C) ** 2).sum(1)).max()
+                # the serial trainer's own blocked pass and update, so a
+                # point's centroids are bitwise ``kmeans_train``'s. The
+                # inertia row (the ASHA pruning signal) is part of the
+                # buffer under either setting of ALINK_TPU_HEALTH: rung
+                # decisions must not flip with an observability flag.
+                buf = jnp.asarray(manifest_psum(
+                    _lloyd_pass(Xs, Ws, C, distance_type), axis,
+                    name="sweep_buf", num_workers=nw))
+                newC, cnts2, cur, movement, _ = _lloyd_update(buf, C)
                 return {"centroids": newC, "movement": movement,
                         "cluster_weights": cnts2, "conv": movement < tol_p,
                         "cur_loss": cur.astype(dtype)}
@@ -819,18 +802,21 @@ def sweep_kmeans(X: np.ndarray, k: int, points: Sequence[Dict[str, Any]],
     program); trace-shaping axes: ``k``, ``distance_type``, ``init``,
     ``max_iter``. Per-point centroids are bitwise identical to
     ``kmeans_train`` with that point's parameters."""
-    from ..operator.common.clustering.kmeans import (kmeans_parallel_init,
+    from ..common.mlenv import MLEnvironmentFactory
+    from ..operator.common.clustering.kmeans import (as_block_column,
+                                                     block_weights,
+                                                     kmeans_parallel_init,
                                                      kmeans_plus_plus_init,
                                                      random_init)
     X = np.asarray(X)
     n, d = X.shape
     dt = X.dtype
+    col = as_block_column(
+        X, (env or MLEnvironmentFactory.get_default()).num_workers)
     plan = SweepPlan("kmeans", [dict(p) for p in points],
                      base={"k": int(k), "distance_type": distance_type,
                            "init": init, "max_iter": int(max_iter)})
-    w = np.ones(n, dt) if sample_weight is None \
-        else np.asarray(sample_weight, dt)
-    data = np.concatenate([X, w[:, None]], axis=1)
+    parts = {"X": col.blocks, "w": block_weights(col, sample_weight)}
 
     P_total = plan.num_points
     # per-point model state collects as LISTS first: a k axis is
@@ -860,7 +846,8 @@ def sweep_kmeans(X: np.ndarray, k: int, points: Sequence[Dict[str, Any]],
             if g_init == "RANDOM":
                 c0 = random_init(X, g_k, s)
             elif g_init in ("K_MEANS_PARALLEL", "KMEANS_PARALLEL"):
-                c0 = kmeans_parallel_init(X, g_k, seed=s, env=env)
+                c0 = kmeans_parallel_init(col, g_k, seed=s, env=env,
+                                          sample_weight=parts["w"])
             else:
                 c0 = kmeans_plus_plus_init(X, g_k, s)
             init_stack[j] = c0.astype(dt)
@@ -872,7 +859,7 @@ def sweep_kmeans(X: np.ndarray, k: int, points: Sequence[Dict[str, Any]],
         ck_dir, rs = _group_paths(checkpoint_dir, resume_from, gi,
                                   len(groups))
         res = _run_sweep_queue(
-            kind="kmeans", stage=stage, parts={"data": data},
+            kind="kmeans", stage=stage, parts=parts,
             bcast=bcast, env=env, max_iter=g_iter, seed=int(seed),
             key_tail=(g_k, d, g_dist, str(dt)),
             num_points=P, asha=_resolve_asha(asha, g_iter),
