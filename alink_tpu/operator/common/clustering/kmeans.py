@@ -261,16 +261,52 @@ _device_rows = jax.jit(_rows_at)
 
 # -- k-means|| -----------------------------------------------------------------
 
-def _kmpp_pass(Xs, Ws, state, new, off, key, block0, last, cap: int,
-               l_blk: int):
+def _topl_fold(run, keys, i):
+    """Fold block ``i``'s ``keys`` into a shard's running best ``l``:
+    ``run`` is ``(values (l,), block (l,), position (l,))``, largest
+    first, equal values in row order, ``-inf`` where nothing is held yet.
+    A key enters only when STRICTLY greater than the smallest value held
+    (later blocks hold later rows) and goes behind the entries it
+    equals, so after the last block ``run`` is what ``lax.top_k`` over
+    all the shard's keys gives, ties to the lower row. A block none of
+    whose keys beats that threshold costs one max over its keys and no
+    trip of the loop. Returns ``(run, whether the block was ranked)``."""
+    l = run[0].shape[0]
+    i = jnp.asarray(i, jnp.int32)
+    slot = jnp.arange(l, dtype=jnp.int32)
+    at = jnp.arange(keys.size, dtype=jnp.int32).reshape(keys.shape)
+
+    def beats(c):
+        return c[3] > c[0][-1]
+
+    def enter(c):
+        vals, blk, pos, top, left = c
+        q = jnp.min(jnp.where(left == top, at, keys.size))   # its first row
+        ahead = (vals >= top).sum(dtype=jnp.int32)
+
+        def put(a, x):
+            return jnp.where(slot < ahead, a,
+                             jnp.where(slot == ahead, x, jnp.roll(a, 1)))
+        left = jnp.where(at == q, -jnp.inf, left)
+        return (put(vals, top), put(blk, i), put(pos, q),
+                jnp.max(left), left)
+
+    start = run + (jnp.max(keys), keys)
+    vals, blk, pos, _, _ = jax.lax.while_loop(beats, enter, start)
+    return (vals, blk, pos), beats(start)
+
+
+def _kmpp_pass(Xs, Ws, state, new, off, key, block0, last, cap: int, l: int):
     """One k-means|| round over a worker's shard, block by block: fold the
     candidates ``new`` (numbered from ``off``) into the per-row ``(d2,
     nearest)`` state (``state`` is ``None`` in the first round, which
-    makes it), draw each block's ``l_blk`` proposals by Gumbel-top-k over
-    p ∝ d2, and — in the ``last`` round — sum the row weights under each
-    of the ``cap`` candidates. Returns ``(d2, nearest, proposal keys
-    (blocks, l_blk), proposal rows (blocks, l_blk), rows seen, candidate
-    weights (cap,))``."""
+    makes it), draw the shard's ``l`` proposals by Gumbel-top-l over
+    p ∝ d2 — a running best ``l`` carried across the blocks
+    (``_topl_fold``), so a block is ranked only when one of its keys can
+    still win — and, in the ``last`` round, sum the row weights under
+    each of the ``cap`` candidates. Returns ``(d2, nearest, proposals
+    (keys (l,), block (l,), position (l,)), blocks ranked, rows seen,
+    candidate weights (cap,))``."""
     nbl = Xs.shape[0]
     dt = Xs.dtype
     ids = jnp.arange(cap, dtype=jnp.int32)[:, None, None]
@@ -279,7 +315,7 @@ def _kmpp_pass(Xs, Ws, state, new, off, key, block0, last, cap: int,
         state = (jnp.zeros(Ws.shape, dt), jnp.zeros(Ws.shape, jnp.int32))
 
     def body(i, c):
-        d2, nearest, topv, topi, rows, acc, comp = c
+        d2, nearest, run, ranked, rows, acc, comp = c
         xb, wb = _block_at(Xs, i), _block_at(Ws, i)
         valid = wb != 0
         with jax.named_scope("kmpp_sample"):
@@ -299,7 +335,7 @@ def _kmpp_pass(Xs, Ws, state, new, off, key, block0, last, cap: int,
             keys = jnp.where(d2b > 0,
                              jnp.log(jnp.maximum(d2b, 1e-30)) + g, -jnp.inf)
         with jax.named_scope("kmpp_topk"):
-            kv, ki = jax.lax.top_k(keys.reshape(-1), l_blk)
+            run, won = _topl_fold(run, keys, i)
         # candidate weights under the current nearest, the last round
         cnt = jax.lax.cond(
             last,
@@ -307,17 +343,18 @@ def _kmpp_pass(Xs, Ws, state, new, off, key, block0, last, cap: int,
             lambda: jnp.zeros((cap,), dt))
         acc, comp = _kahan_add(acc, comp, cnt)
         upd = jax.lax.dynamic_update_index_in_dim
-        return (upd(d2, d2b, i, 0), upd(nearest, nb_, i, 0),
-                upd(topv, kv, i, 0), upd(topi, ki.astype(jnp.int32), i, 0),
+        return (upd(d2, d2b, i, 0), upd(nearest, nb_, i, 0), run,
+                ranked + won.astype(jnp.int32),
                 rows + valid.sum(dtype=jnp.int32), acc, comp)
 
     zero = jnp.zeros((cap,), dt)
-    d2, nearest, topv, topi, rows, counts, _ = jax.lax.fori_loop(
+    none = jnp.zeros((l,), jnp.int32)
+    d2, nearest, run, ranked, rows, counts, _ = jax.lax.fori_loop(
         0, nbl, body,
-        state + (jnp.zeros((nbl, l_blk), dt),
-                 jnp.zeros((nbl, l_blk), jnp.int32),
-                 jnp.asarray(0, jnp.int32), zero, zero))
-    return d2, nearest, topv, topi, rows, counts
+        state + ((jnp.full((l,), -jnp.inf, dt), none, none),
+                 jnp.asarray(0, jnp.int32), jnp.asarray(0, jnp.int32),
+                 zero, zero))
+    return d2, nearest, run, ranked, rows, counts
 
 
 def kmeans_parallel_init(X, k: int, seed: int = 0,
@@ -332,32 +369,36 @@ def kmeans_parallel_init(X, k: int, seed: int = 0,
     Each superstep samples ``l = oversample`` new candidates with
     probability proportional to the current squared distance to the
     candidate set (the exactly-l Gumbel-top-l variant of the per-point
-    Bernoulli draw): a ``top_k`` per row block, one over the shard's
-    block winners, an ``all_gather`` and a global ``top_k``. The per-row
-    d2/nearest state updates block by block against only the l new
-    candidates, so the work is O(rounds * n * l * d / workers). The
-    Gumbel noise of a block is keyed by (seed, round, GLOBAL block
-    index), so any worker count draws the same candidates. Candidate
-    weights (summed row weights under the nearest candidate) are counted
-    in the last round; the final weighted recluster to k runs on the
-    O(rounds*l) candidate set on the host. ``info``, when given, receives
-    the candidate set, its weights and the rows each round counted.
+    Bernoulli draw). A worker keeps the best ``l`` keys of its shard as it
+    walks the blocks and ranks a block only when one of its keys is
+    STRICTLY greater than the smallest kept (``_topl_fold``; equal keys go
+    to the lower row, as a ``top_k`` over the whole shard gives them);
+    then an ``all_gather`` and a global ``top_k``. The per-row d2/nearest
+    state updates block by block against only the l new candidates, so
+    the work is O(rounds * n * l * d / workers). The Gumbel noise of a
+    block is keyed by (seed, round, GLOBAL block index), so any worker
+    count draws the same candidates. Candidate weights (summed row
+    weights under the nearest candidate) are counted in the last round;
+    the final weighted recluster to k runs on the O(rounds*l) candidate
+    set on the host. ``info``, when given, receives the candidate set,
+    its weights, the rows each round counted and the blocks each round
+    ranked (``init_blocks_ranked``, also the counter
+    ``alink_kmeans_init_blocks_ranked_total``: on rows in no particular
+    order about ``l * (1 + ln(blocks / l))`` a worker a round; near the
+    blocks walked, the table is ordered by distance and every block is
+    ranked).
     """
     env_ = env or MLEnvironmentFactory.get_default()
     nw = env_.num_workers
     col = as_block_column(X, nw)
     n, d = col.n_rows, col.dim
     dt = col.blocks.dtype
-    B = col.block_rows
-    S = B // LANES
+    S = col.block_rows // LANES
     l = int(oversample) if oversample else max(2 * k, 1)
     cap = 1 + rounds * l
     rng = np.random.RandomState(seed)
     first = take_rows(col, [rng.randint(n)])[0].astype(dt)
     nbl = -(-col.row_blocks // nw)   # blocks a worker holds (static)
-    l_blk = min(l, B)                # proposals a block makes
-    l_loc = min(l, nbl * l_blk)      # proposals a worker makes
-    l_glob = min(l, nw * l_loc)
 
     def sample(ctx):
         Xs = ctx.get_obj("X")
@@ -367,26 +408,23 @@ def kmeans_parallel_init(X, k: int, seed: int = 0,
         if init:
             cands = jnp.zeros((cap, d), dt).at[0].set(ctx.get_obj("first"))
             d2 = nearest = None
-            rows_seen = jnp.zeros((rounds,), jnp.int32)
+            rows_seen = ranked_seen = jnp.zeros((rounds,), jnp.int32)
             new, off = cands[:1], 0
         else:
             cands = ctx.get_obj("cands")
             d2 = ctx.get_obj("d2")
             nearest = ctx.get_obj("nearest")
             rows_seen = ctx.get_obj("rows")
+            ranked_seen = ctx.get_obj("ranked")
             # fold in the l candidates written by the previous superstep
             off = 1 + (step - 2) * l
             new = jax.lax.dynamic_slice_in_dim(cands, off, l, 0)  # (l, d)
         key = jax.random.fold_in(
             jax.random.wrap_key_data(ctx.get_obj("key")), step)
-        d2, nearest, topv, topi, rows, counts = _kmpp_pass(
+        d2, nearest, (kv, blk, pos), ranked, rows, counts = _kmpp_pass(
             Xs, Ws, None if init else (d2, nearest), new, off, key,
-            ctx.task_id * nbl, step == rounds, cap, l_blk)
-        with jax.named_scope("kmpp_topk"):
-            kv, which = jax.lax.top_k(topv.reshape(-1), l_loc)
-            blk = (which // l_blk).astype(jnp.int32)
-            pos = topi.reshape(-1)[which]
-            pts = _rows_at(Xs, blk, pos)                      # (l_loc, d)
+            ctx.task_id * nbl, step == rounds, cap, l)
+        pts = _rows_at(Xs, blk, pos)                          # (l, d)
         # register BOTH gathers before either is consumed: under
         # ALINK_TPU_FUSE_COLLECTIVES the pair coalesces into one
         # all-gather (the jnp.asarray coercion materializes the deferred
@@ -397,28 +435,26 @@ def kmeans_parallel_init(X, k: int, seed: int = 0,
                                  num_workers=ctx.num_task)
         gk = jnp.asarray(gk).reshape(-1)
         gp = jnp.asarray(gp).reshape(-1, d)
-        gv, gi = jax.lax.top_k(gk, l_glob)
-        sel = gp[gi]
-        valid = jnp.isfinite(gv)
-        sel = jnp.where(valid[:, None], sel, cands[0])
-        if l_glob < l:                                      # static-shape pad
-            sel = jnp.concatenate(
-                [sel, jnp.broadcast_to(cands[0], (l - l_glob, d))], 0)
+        gv, gi = jax.lax.top_k(gk, l)
+        sel = jnp.where(jnp.isfinite(gv)[:, None], gp[gi], cands[0])
         off_w = 1 + (step - 1) * l
         cands = jax.lax.dynamic_update_slice_in_dim(cands, sel, off_w, 0)
         hi, lo = _split_count(rows)
         tot = ctx.all_reduce_sum(jnp.concatenate(
-            [counts, jnp.stack([hi, lo]).astype(dt)]))
+            [counts, jnp.stack([hi, lo, ranked]).astype(dt)]))
         tot = jnp.asarray(tot)
+        upd = jax.lax.dynamic_update_index_in_dim
         ctx.put_obj("weights", tot[:cap])
-        ctx.put_obj("rows", jax.lax.dynamic_update_index_in_dim(
+        ctx.put_obj("rows", upd(
             rows_seen, _join_count(tot[cap], tot[cap + 1]), step - 1, 0))
+        ctx.put_obj("ranked", upd(
+            ranked_seen, tot[cap + 2].astype(jnp.int32), step - 1, 0))
         ctx.put_obj("cands", cands)
         ctx.put_obj("d2", d2)
         ctx.put_obj("nearest", nearest)
 
     with trace_span("kmeans.init", cat="kmeans",
-                    args={"rows": n, "rounds": rounds}):
+                    args={"rows": n, "rounds": rounds}) as span:
         res = (IterativeComQueue(env=env_, max_iter=rounds)
                .init_with_partitioned_data("X", col.blocks)
                .init_with_partitioned_data(
@@ -428,15 +464,16 @@ def kmeans_parallel_init(X, k: int, seed: int = 0,
                    "key", np.asarray(jax.random.key_data(
                        jax.random.PRNGKey(seed))))
                .add(sample)
-               .set_program_key((INIT_PROGRAM, cap, d, l, l_blk, l_loc,
-                                 l_glob, nbl, S, str(dt)))
+               .set_program_key((INIT_PROGRAM, cap, d, l, nbl, S, str(dt)))
                .exec())
-        cands, weights, rows_seen = (
-            np.array(v) for v in res.get_all(["cands", "weights", "rows"]))
-    _count(rows_seen.sum(dtype=np.int64), rounds)
+        cands, weights, rows_seen, ranked = (
+            np.array(v) for v in res.get_all(
+                ["cands", "weights", "rows", "ranked"]))
+        span.set(blocks_ranked=int(ranked.sum()))
+    _count(rows_seen.sum(dtype=np.int64), rounds, ranked.sum())
     if info is not None:
         info.update(init_candidates=cands, init_weights=weights.copy(),
-                    init_rows=rows_seen)
+                    init_rows=rows_seen, init_blocks_ranked=ranked)
     # candidates sampled in the final round carry no counted weight yet;
     # give them each weight 1 so the recluster can still use them
     weights[weights == 0] = 1.0
@@ -445,11 +482,14 @@ def kmeans_parallel_init(X, k: int, seed: int = 0,
         return _weighted_kmeans_pp(cands, weights, k, rng).astype(dt)
 
 
-def _count(rows: int, supersteps: int) -> None:
+def _count(rows: int, supersteps: int, blocks_ranked: int = 0) -> None:
     if metrics_enabled():
         reg = get_registry()
         reg.inc("alink_kmeans_rows_total", int(rows))
         reg.inc("alink_kmeans_supersteps_total", int(supersteps))
+        if blocks_ranked:
+            reg.inc("alink_kmeans_init_blocks_ranked_total",
+                    int(blocks_ranked))
 
 
 # -- Lloyd ----------------------------------------------------------------------

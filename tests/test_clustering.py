@@ -123,3 +123,174 @@ def test_kmeans_parallel_init_no_host_pass():
     pd = ((C[:, None, :] - C[None, :, :]) ** 2).sum(-1)
     np.fill_diagonal(pd, np.inf)
     assert (pd.min(1) > 1e-6).all()
+
+
+# -- k-means||: the running top-l and its threshold (PR 28) -------------------
+
+def _topl_cases():
+    rng = np.random.RandomState(7)
+    inf = np.inf
+    plain = rng.randn(6, 4, 8).astype(np.float32)
+    across = plain.copy()              # one value in four blocks, and on top
+    across[[0, 2, 3, 5], [1, 0, 3, 2], [2, 7, 0, 5]] = 9.0
+    inside = plain.copy()              # runs of equal keys inside blocks
+    inside[1, 0, :] = 5.0
+    inside[4, 2:, 3] = 5.0
+    inside[2] = -1.0
+    few = np.full((5, 4, 8), -inf, np.float32)     # fewer than l finite keys
+    few[[0, 3, 3, 4], [0, 1, 2, 3], [1, 5, 5, 7]] = [0.5, -2.0, 0.5, 3.0]
+    none = np.full((3, 4, 8), -inf, np.float32)
+    small = rng.randn(9, 3).astype(np.float32)     # blocks of 3 keys, l = 8
+    small[[1, 6], [0, 2]] = small.max() + 1
+    last = np.sort(rng.randn(6 * 32).astype(np.float32)).reshape(6, 4, 8)
+    rising = last.copy()               # every block holds winners
+    last[:5] = np.minimum(last[:5], last[5].min() - 1)
+    tops = plain.copy()                # +inf keys, tied
+    tops[[0, 4], [0, 1], [0, 1]] = inf
+    return {"plain": (plain, 5), "equal_across_blocks": (across, 5),
+            "equal_inside_blocks": (inside, 12), "few_finite": (few, 6),
+            "none_finite": (none, 4), "block_under_l": (small, 8),
+            "l_over_all_keys": (small[:2], 8),
+            "winners_in_last_block": (last, 7), "every_block_wins": (rising, 7),
+            "infinite_keys": (tops, 5), "l_is_one": (across, 1)}
+
+
+@pytest.mark.parametrize("case", sorted(_topl_cases()))
+def test_running_topl_is_top_k_of_the_shard(case):
+    """``_topl_fold`` carried over a shard's blocks gives what
+    ``lax.top_k`` over all its keys flattened gives — values, and the
+    (block, position) of every finite one, equal keys to the lower row —
+    and ranks a block only when one of its keys beats the threshold."""
+    import jax
+    import jax.numpy as jnp
+    from alink_tpu.operator.common.clustering.kmeans import _topl_fold
+
+    keys, l = _topl_cases()[case]
+    nb, per = keys.shape[0], int(np.prod(keys.shape[1:]))
+    fold = jax.jit(_topl_fold)
+    run = (jnp.full((l,), -jnp.inf, keys.dtype), jnp.zeros((l,), jnp.int32),
+           jnp.zeros((l,), jnp.int32))
+    ranked, want_ranked = [], []
+    for i in range(nb):
+        tau = float(run[0][-1])
+        run, won = fold(run, jnp.asarray(keys[i]), i)
+        ranked.append(bool(won))
+        want_ranked.append(bool(keys[i].max() > tau))
+    flat = np.concatenate([keys.reshape(-1),
+                           np.full(max(l - keys.size, 0), -np.inf, keys.dtype)])
+    wv, wi = (np.asarray(a) for a in jax.lax.top_k(jnp.asarray(flat), l))
+    vals, blk, pos = (np.asarray(a) for a in run)
+    assert vals.dtype == keys.dtype and blk.dtype == pos.dtype == np.int32
+    assert np.array_equal(vals, wv)
+    held = wv > -np.inf
+    assert np.array_equal(blk[held], wi[held] // per)
+    assert np.array_equal(pos[held], wi[held] % per)
+    assert ((blk >= 0) & (blk < nb) & (pos >= 0) & (pos < per)).all()
+    assert ranked == want_ranked
+    if case == "winners_in_last_block":
+        assert (blk == nb - 1).all() and ranked[-1]
+    if case == "every_block_wins":
+        assert all(ranked)
+    if case == "none_finite":
+        assert not any(ranked)
+
+
+def _kmpp_table(seed, blocks=5, ragged=700, d=5):
+    from alink_tpu.common.columnar import DenseBlockColumn
+    rng = np.random.RandomState(100 + seed)
+    n = (blocks - 1) * 1024 + ragged
+    centers = rng.randn(4, d) * 5
+    X = (centers[rng.randint(4, size=n)] + rng.randn(n, d)).astype(np.float32)
+    return DenseBlockColumn(DenseBlockColumn.pack(X, 1024, blocks), n)
+
+
+@pytest.mark.parametrize("nw", [1, 2, 4])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_kmeans_parallel_init_bitwise_the_per_block_top_k(seed, nw):
+    """The candidates, their weights, the rows counted and the k seeds are
+    bit for bit what the parent's hierarchy (``top_k`` of every block, then
+    of the blocks' winners) gave: ``tests/fixtures/kmpp_parent_pr27.npz``,
+    recorded at PR 27's commit on this mesh (5 blocks of 1,024 rows, the
+    last one ragged; on 4 workers the last holds padding alone)."""
+    import os
+    from alink_tpu.common.mlenv import MLEnvironment
+    from alink_tpu.operator.common.clustering.kmeans import (
+        kmeans_parallel_init)
+
+    want = np.load(os.path.join(os.path.dirname(__file__), "fixtures",
+                                "kmpp_parent_pr27.npz"))
+    info = {}
+    C = kmeans_parallel_init(_kmpp_table(seed), 4, seed=seed,
+                             env=MLEnvironment(parallelism=nw), info=info)
+    for name, got in (("cands", info["init_candidates"]),
+                      ("weights", info["init_weights"]),
+                      ("rows", info["init_rows"]), ("cents", C)):
+        ref = want[f"{name}_s{seed}_w{nw}"]
+        assert got.dtype == ref.dtype and np.array_equal(got, ref), name
+
+
+def _host_blocks_ranked(col, cands, seed, l, rounds, nw):
+    """Blocks a k-means|| run ranks, counted on the host from the same
+    keys: per round and worker, the blocks whose largest key beats the
+    smallest of the worker's running best ``l``."""
+    import jax
+    import jax.numpy as jnp
+    from alink_tpu.operator.common.clustering import kmeans as K
+
+    blocks = np.asarray(col.blocks)
+    w = np.asarray(K.block_weights(col))
+    nbl = -(-blocks.shape[0] // nw)
+    out = []
+    for r in range(1, rounds + 1):
+        key = jax.random.fold_in(jax.random.PRNGKey(seed), r)
+        C = jnp.asarray(cands[:1 + (r - 1) * l])
+        count = 0
+        for task in range(nw):
+            best = np.full(l, -np.inf, np.float32)
+            for b in range(task * nbl, min((task + 1) * nbl, blocks.shape[0])):
+                d2 = jnp.where(w[b] != 0, jnp.min(
+                    K.block_distances(jnp.asarray(blocks[b]), C), 0), 0)
+                g = jax.random.gumbel(jax.random.fold_in(key, b), d2.shape,
+                                      d2.dtype)
+                keys = np.asarray(jnp.where(
+                    d2 > 0, jnp.log(jnp.maximum(d2, 1e-30)) + g, -jnp.inf))
+                if keys.max() > best[-1]:
+                    count += 1
+                    best = np.sort(np.concatenate(
+                        [best, keys.reshape(-1)]))[::-1][:l]
+        out.append(count)
+    return np.asarray(out)
+
+
+@pytest.mark.parametrize("nw", [1, 2, 4])
+@pytest.mark.parametrize("seed", [0, 5])
+def test_init_blocks_ranked_is_the_host_count(seed, nw):
+    """``info["init_blocks_ranked"]`` (and the registry counter) is exactly
+    the number of blocks one of whose keys could still win, and well under
+    the blocks walked."""
+    from alink_tpu.common.metrics import (MetricsRegistry, get_registry,
+                                          set_registry)
+    from alink_tpu.common.mlenv import MLEnvironment
+    from alink_tpu.operator.common.clustering.kmeans import (
+        kmeans_parallel_init)
+
+    col = _kmpp_table(seed, blocks=23, ragged=300)
+    k, rounds = 2, 5
+    info = {}
+    prev = set_registry(MetricsRegistry())
+    try:
+        kmeans_parallel_init(col, k, seed=seed, rounds=rounds,
+                             env=MLEnvironment(parallelism=nw), info=info)
+        counted = get_registry().value("alink_kmeans_init_blocks_ranked_total")
+    finally:
+        set_registry(prev)
+    ranked = info["init_blocks_ranked"]
+    assert ranked.shape == (rounds,) and ranked.dtype == np.int32
+    want = _host_blocks_ranked(col, info["init_candidates"], seed, 2 * k,
+                               rounds, nw)
+    assert np.array_equal(ranked, want)
+    assert counted == ranked.sum()
+    # each worker ranks its first block that holds a row; few after it
+    assert (ranked >= nw).all() and ranked.sum() < rounds * 23
+    if nw == 1:
+        assert ranked.sum() < 0.6 * rounds * 23
