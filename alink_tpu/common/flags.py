@@ -470,13 +470,6 @@ FLAGS.register(
 
 # -- performance ------------------------------------------------------------
 FLAGS.register(
-    "ALINK_TPU_FUSE_COLLECTIVES", "bool", False,
-    "trace-time collective fusion: coalesce same-superstep, same-reduction "
-    "manifest_psum/pmax/pmin/all_gather payloads into one flattened, "
-    "offset-sliced collective per (op, dtype) lane", "performance",
-    folds_into=frozenset({PROGRAM_CACHE, CHECKPOINT_SIGNATURE}),
-    accessor="alink_tpu.engine.communication.fusion_enabled")
-FLAGS.register(
     "ALINK_TPU_MESH_DEVICES", "int", 0,
     "device count for the default session mesh (0 = all of jax.devices()); "
     "on CPU rigs, request host-platform virtual devices BEFORE the jax "

@@ -195,17 +195,15 @@ def engine_flags() -> Tuple[Tuple[str, Any], ...]:
     """The engine's key-folding flag dims, latched ONCE per exec — the
     single derivation site ENV-KEY-FOLD checks for the engine cache.
 
-    Order is load-bearing: these four occupy positions 7-10 of the
-    legacy ckey tuple (after ``criterion``), so ``engine_plan`` splices
-    them verbatim and ``legacy_key()`` stays byte-identical."""
+    Order is load-bearing: these three occupy positions 7-9 of the
+    ckey tuple (after ``criterion``); ``engine_plan`` splices them
+    verbatim."""
     from ..common.health import health_enabled
     from ..common.profiling import step_log_enabled
-    from ..engine.communication import fusion_enabled
     from ..engine.comqueue import donation_enabled
     return (("ALINK_TPU_STEP_LOG", step_log_enabled()),
             ("ALINK_TPU_HEALTH", health_enabled()),
-            ("ALINK_TPU_DONATE", donation_enabled()),
-            ("ALINK_TPU_FUSE_COLLECTIVES", fusion_enabled()))
+            ("ALINK_TPU_DONATE", donation_enabled()))
 
 
 def engine_plan(*, program_key: Any, stages_digest: Any, mesh: Any,
@@ -214,12 +212,12 @@ def engine_plan(*, program_key: Any, stages_digest: Any, mesh: Any,
                 flags: Sequence[Tuple[str, Any]],
                 part_names: Tuple[str, ...],
                 bcast_names: Tuple[str, ...]) -> ExecutionPlan:
-    """The engine program-cache plan.  ``legacy_key()`` reproduces the
-    historical 13-tuple EXACTLY (order pinned by
+    """The engine program-cache plan.  ``legacy_key()`` is the
+    12-tuple the cache is keyed on (order pinned by
     ``tests/test_plan.py``):
 
         (program_key, stages_digest, mesh, nw, max_iter, seed,
-         criterion?, step_log, probes, donate, fuse,
+         criterion?, step_log, probes, donate,
          sorted(parts), sorted(bcast))
     """
     flags = tuple(flags)
@@ -251,8 +249,7 @@ def engine_checkpoint_signature(plan: ExecutionPlan, *, part_sig: Tuple,
         max_iter=plan.get("max_iter"), seed=plan.get("seed"),
         part_sig=part_sig, bcast_names=plan.get("bcast"),
         stages_digest=plan.get("stages"), data_token=data_token,
-        probes_on=plan.get("ALINK_TPU_HEALTH"),
-        fuse_collectives=plan.get("ALINK_TPU_FUSE_COLLECTIVES"))
+        probes_on=plan.get("ALINK_TPU_HEALTH"))
 
 
 # ---------------------------------------------------------------------------
@@ -265,15 +262,14 @@ def ftrl_plan(*, mesh: Any, alpha: float, beta: float, l1: float,
               warm_fp: str) -> ExecutionPlan:
     """The FTRL drain's plan: hyperparameters + geometry + the resolved
     key-folding flags (``ALINK_TPU_FTRL_KERNEL`` mode,
-    ``ALINK_TPU_DONATE``, chained-mode ``ALINK_TPU_FUSE_COLLECTIVES``),
-    latched ONCE per drain at this single ENV-KEY-FOLD-checked site.
+    ``ALINK_TPU_DONATE``), latched ONCE per drain at this single
+    ENV-KEY-FOLD-checked site.
 
     ``kernel_resolved`` is the availability-probed tier the chained
     signature folds ("pallas" only when the triangular kernel can
     actually run at this chunk length/dtype — the probe-demoted drain
     keeps the flag-off signature, same numbers, interchangeable
     snapshots)."""
-    from ..engine.communication import fusion_enabled
     from ..engine.comqueue import donation_enabled
     from ..kernels.ftrl import chained_kernel_available, ftrl_kernel_mode
 
@@ -301,8 +297,6 @@ def ftrl_plan(*, mesh: Any, alpha: float, beta: float, l1: float,
         ("ALINK_TPU_FTRL_KERNEL", kern),
         ("kernel_resolved", resolved),
         ("ALINK_TPU_DONATE", donation_enabled()),
-        ("ALINK_TPU_FUSE_COLLECTIVES",
-         fusion_enabled() if chained else False),
     ))
 
 
@@ -310,8 +304,8 @@ def ftrl_checkpoint_signature(plan: ExecutionPlan) -> Dict[str, Any]:
     """The FTRL stream's resume signature, derived from the plan —
     content IDENTICAL to the historical hand-built ``ck_signature``
     dict, including the conditional keys (chained-only ``chunk_size`` /
-    ``ftrl_kernel`` / ``fuse_collectives``), so every pre-existing
-    snapshot keeps its exact signature and stays resumable."""
+    ``ftrl_kernel``), so every pre-existing snapshot keeps its exact
+    signature and stays resumable."""
     sig: Dict[str, Any] = {
         "kind": "ftrl_state",
         "alpha": plan.get("alpha"), "beta": plan.get("beta"),
@@ -326,8 +320,6 @@ def ftrl_checkpoint_signature(plan: ExecutionPlan) -> Dict[str, Any]:
         sig["chunk_size"] = plan.get("chunk_size")
         if plan.get("kernel_resolved") == "pallas":
             sig["ftrl_kernel"] = "pallas"
-        if plan.get("ALINK_TPU_FUSE_COLLECTIVES"):
-            sig["fuse_collectives"] = True
     return sig
 
 

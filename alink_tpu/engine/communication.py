@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import contextlib
 import threading
-from typing import Callable, List, Optional, Sequence, Tuple
+from typing import List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -66,34 +66,20 @@ def payload_nbytes(value) -> int:
 
 
 def record_collective(kind: str, name: str, per_worker_bytes: int,
-                      num_workers: int, members: Optional[Tuple[str, ...]]
-                      = None) -> None:
+                      num_workers: int) -> None:
     """Record one collective invocation. ``logical bytes moved`` is the
     payload summed over workers (every worker contributes/receives its
-    copy), not the wire traffic of a particular ring schedule.
-
-    ``members`` names the original buffers coalesced into this op when it
-    is a FUSED collective (ALINK_TPU_FUSE_COLLECTIVES): the record becomes
-    a 4-tuple carrying the fused-group membership, and the registry path
-    additionally charges ``alink_collective_fused_total`` /
-    ``alink_collective_payload_fused_bytes``."""
+    copy), not the wire traffic of a particular ring schedule."""
     logical = int(per_worker_bytes) * int(num_workers)
-    fused = members is not None and len(members) > 1
     manifest = getattr(_collector, "manifest", None)
     if manifest is not None:
-        if fused:
-            manifest.append((kind, name, logical, tuple(members)))
-        else:
-            manifest.append((kind, name, logical))
+        manifest.append((kind, name, logical))
         return
     if metrics_enabled():
         reg = get_registry()
         lbl = {"collective": kind}
         reg.inc("alink_collective_calls_total", 1, lbl)
         reg.inc("alink_collective_logical_bytes_total", logical, lbl)
-        if fused:
-            reg.inc("alink_collective_fused_total", 1, lbl)
-            reg.inc("alink_collective_payload_fused_bytes", logical, lbl)
 
 
 def record_manifest(manifest: Sequence[CollectiveRecord],
@@ -107,357 +93,22 @@ def record_manifest(manifest: Sequence[CollectiveRecord],
     outside the engine (the FTRL drain loop) capture the program's
     manifest once (:func:`collecting` around an AOT ``.lower``) and
     replay it here per invocation, so ``alink_collective_calls_total``
-    counts executed micro-batches rather than compiles.
-
-    Records are 3-tuples ``(kind, name, bytes)`` or — for fused
-    collectives — 4-tuples carrying the member-buffer names."""
+    counts executed micro-batches rather than compiles."""
     if not manifest or not metrics_enabled():
         return
     reg = get_registry()
-    for rec in manifest:
-        kind, logical = rec[0], rec[2]
+    for kind, _name, logical in manifest:
         lbl = {"collective": kind}
         reg.inc("alink_collective_calls_total", times, lbl)
         reg.inc("alink_collective_logical_bytes_total",
                 int(logical) * int(times), lbl)
-        if len(rec) > 3 and len(rec[3]) > 1:
-            reg.inc("alink_collective_fused_total", times, lbl)
-            reg.inc("alink_collective_payload_fused_bytes",
-                    int(logical) * int(times), lbl)
-
-
-# -- trace-time collective fusion (ALINK_TPU_FUSE_COLLECTIVES) --------------
-# One fused collective per superstep, where data flow allows it: inside a
-# ``fusing()`` scope (the engine arms one around every superstep trace)
-# the manifest wrappers below DEFER their reduction — the payload is
-# registered with the scope's accumulator and the caller receives a
-# :class:`_Deferred` proxy. The first *use* of any deferred value (a jnp
-# op, indexing, an attribute read) flushes the whole accumulator: all
-# same-reduction, same-dtype pending payloads are flattened, concatenated
-# into one lane buffer, reduced by ONE ``lax`` collective, and bitwise-
-# split back to the original buffers (all-reduce is elementwise, so each
-# element's result is exactly the unfused op's). ``pmin`` payloads of
-# inexact dtype ride the max lane negated (`min(x) == -max(-x)` is exact
-# for floats — the sign flip never rounds).
-#
-# Flush-on-first-use is also the dependency PROOF: a collective whose
-# input depends on an earlier collective's OUTPUT can only be registered
-# after that output was used, i.e. after the earlier flush — so what ends
-# up fused is exactly the set of independent collectives, and what stays
-# separate is separated by real data flow (L-BFGS's line-loss psum needs
-# the psummed gradient's direction; GBDT's level-L histogram needs the
-# level-L-1 split). A scope with a single pending payload lowers the
-# ORIGINAL payload through the raw op — byte-identical semantics to the
-# unfused wrapper.
-
-def fusion_enabled() -> bool:
-    """``ALINK_TPU_FUSE_COLLECTIVES`` (default OFF): trace-time collective
-    fusion. Folded into the engine program-cache key and (conditionally)
-    checkpoint signatures — the fused program is structurally different
-    HLO even though training results are bitwise-identical."""
-    from ..common.flags import flag_value
-    return bool(flag_value("ALINK_TPU_FUSE_COLLECTIVES"))
-
-
-_fusion = threading.local()
-
-
-def active_fusion_scope():
-    """The installed :class:`_FusionScope` of this thread (None outside
-    ``fusing()`` — wrappers then lower eagerly, the historical path)."""
-    return getattr(_fusion, "scope", None)
-
-
-class _Deferred:
-    """Proxy for a not-yet-materialized collective result.
-
-    Any interaction — ``__jax_array__`` (every jnp function), arithmetic,
-    indexing, or attribute access — forces the owning scope's flush and
-    then behaves as the materialized value. ``shape``/``dtype``/``ndim``/
-    ``size`` answer WITHOUT forcing (from the recorded payload aval).
-
-    Consumption contract: a deferred result must reach the compiler
-    through jnp-level operations (which convert via ``__jax_array__`` at
-    user level). Passing one RAW into ``jax.lax.*`` makes jax's
-    ``get_aval`` call ``__jax_array__`` during primitive binding, where a
-    freshly-traced flush op is an "unexpected tracer" — wrap such
-    arguments in ``jnp.asarray`` first (the kmeans|| seeding stage does
-    exactly this before ``lax.top_k``)."""
-
-    __slots__ = ("_scope", "_shape", "_dtype", "_value")
-
-    def __init__(self, scope, shape, dtype):
-        self._scope = scope
-        self._shape = tuple(shape)
-        self._dtype = dtype
-        self._value = None
-
-    # -- materialization --------------------------------------------------
-    def _set(self, value):
-        self._value = value
-
-    def _force(self):
-        if self._value is None:
-            self._scope.flush()
-        return self._value
-
-    def __jax_array__(self):
-        return self._force()
-
-    # -- aval properties (no force) ---------------------------------------
-    @property
-    def shape(self):
-        return self._shape
-
-    @property
-    def dtype(self):
-        return self._dtype
-
-    @property
-    def ndim(self):
-        return len(self._shape)
-
-    @property
-    def size(self):
-        n = 1
-        for d in self._shape:
-            n *= int(d)
-        return n
-
-    # -- everything else forces -------------------------------------------
-    def __getattr__(self, name):
-        # only reached when normal lookup fails (slots above): delegate
-        # to the materialized value (.astype, .sum, .T, .at, ...)
-        return getattr(self._force(), name)
-
-    def __getitem__(self, idx):
-        return self._force()[idx]
-
-    def __bool__(self):
-        # force, then let jax raise its TracerBoolConversionError exactly
-        # as the unfused path would — without this, Python's __len__
-        # fallback would silently truth-test a scalar as False
-        return bool(self._force())
-
-    def __len__(self):
-        if not self._shape:
-            raise TypeError("len() of a 0-d deferred collective result")
-        return self._shape[0]
-
-    def __repr__(self):
-        return (f"_Deferred(shape={self._shape}, dtype={self._dtype}, "
-                f"materialized={self._value is not None})")
-
-    def __neg__(self):
-        return -self._force()
-
-    def __pos__(self):
-        return +self._force()
-
-    def __abs__(self):
-        return abs(self._force())
-
-
-def _undefer(v):
-    return v._force() if isinstance(v, _Deferred) else v
-
-
-def _binop(opname, reflected=False):
-    import operator
-    op = getattr(operator, opname)
-
-    def fwd(self, other):
-        a, b = self._force(), _undefer(other)
-        return op(b, a) if reflected else op(a, b)
-    return fwd
-
-
-for _name, _sym in [("add", "add"), ("sub", "sub"), ("mul", "mul"),
-                    ("truediv", "truediv"), ("floordiv", "floordiv"),
-                    ("mod", "mod"), ("pow", "pow"), ("matmul", "matmul"),
-                    ("and", "and_"), ("or", "or_"), ("xor", "xor"),
-                    ("lt", "lt"), ("le", "le"), ("gt", "gt"), ("ge", "ge"),
-                    ("eq", "eq"), ("ne", "ne")]:
-    setattr(_Deferred, f"__{_name}__", _binop(_sym))
-    if _name not in ("lt", "le", "gt", "ge", "eq", "ne"):
-        setattr(_Deferred, f"__r{_name}__", _binop(_sym, reflected=True))
-del _name, _sym
-# defining __eq__ cleared the default __hash__; proxies are plain unique
-# objects (identity hash), never value-compared as dict keys
-_Deferred.__hash__ = object.__hash__
-
-
-# one pending collective: lane-grouped at flush time
-class _Pending:
-    __slots__ = ("payload", "name", "num_workers", "negate", "kind_label",
-                 "raw_op", "deferred", "gather")
-
-    def __init__(self, payload, name, num_workers, negate, kind_label,
-                 raw_op, deferred, gather=False):
-        self.payload = payload
-        self.name = name
-        self.num_workers = num_workers
-        self.negate = negate
-        self.kind_label = kind_label
-        self.raw_op = raw_op
-        self.deferred = deferred
-        self.gather = gather
-
-
-class _FusionScope:
-    """Deferred-reduction accumulator for one superstep trace.
-
-    Lanes are keyed by ``(family, axis_name, lane_op, dtype)``; each lane
-    flushes as ONE collective (flattened + offset-sliced when it holds
-    more than one payload, the raw op on the original payload when it
-    holds exactly one)."""
-
-    def __init__(self):
-        self._order: List[tuple] = []
-        self._lanes: Dict[tuple, List[_Pending]] = {}
-        # (kind, member-names, bytes) of every >1-member flush — test and
-        # observability introspection
-        self.fused_groups: List[tuple] = []
-
-    # -- registration ------------------------------------------------------
-    def _register(self, key, pending):
-        if key not in self._lanes:
-            self._lanes[key] = []
-            self._order.append(key)
-        self._lanes[key].append(pending)
-
-    def defer_reduce(self, op: str, x, axis_name, name: str,
-                     num_workers: int, kind_label: str = "AllReduce"):
-        """Defer a psum/pmax/pmin over a payload pytree; returns the
-        matching pytree of :class:`_Deferred` proxies."""
-        raw = {"sum": jax.lax.psum, "max": jax.lax.pmax,
-               "min": jax.lax.pmin}[op]
-
-        def leaf(v):
-            v = jnp.asarray(v)  # forces deferred inputs first (dependency)
-            lane_op, negate = op, False
-            if op == "min" and jnp.issubdtype(v.dtype, jnp.inexact):
-                # min(x) == -max(-x), exact for floats: the min payload
-                # rides the max lane so pmax+pmin pairs fuse to one op
-                lane_op, negate = "max", True
-            d = _Deferred(self, v.shape, v.dtype)
-            self._register(("red", axis_name, lane_op, str(v.dtype)),
-                           _Pending(v, name, num_workers, negate,
-                                    kind_label, raw, d))
-            return d
-        return jax.tree_util.tree_map(leaf, x)
-
-    def defer_gather(self, x, axis_name, axis: int, tiled: bool,
-                     name: str, num_workers: int):
-        """Defer an all_gather (axis-0, untiled form only — the fusable
-        layout); other forms lower eagerly with a manifest record."""
-        if axis != 0 or tiled:
-            record_collective("AllGather", name, payload_nbytes(x),
-                              num_workers)
-            return jax.lax.all_gather(x, axis_name, axis=axis, tiled=tiled)
-
-        def leaf(v):
-            v = jnp.asarray(v)
-            d = _Deferred(self, (num_workers,) + tuple(v.shape), v.dtype)
-            self._register(("gather", axis_name, str(v.dtype)),
-                           _Pending(v, name, num_workers, False,
-                                    "AllGather", None, d, gather=True))
-            return d
-        return jax.tree_util.tree_map(leaf, x)
-
-    # -- flush -------------------------------------------------------------
-    def flush(self):
-        """Materialize every pending collective: one lax op per lane."""
-        if not self._order:
-            return
-        order, lanes = self._order, self._lanes
-        self._order, self._lanes = [], {}
-        for key in order:
-            entries = lanes[key]
-            if len(entries) == 1:
-                e = entries[0]
-                # single payload: the raw op on the ORIGINAL payload —
-                # byte-identical lowering to the unfused wrapper
-                record_collective(e.kind_label, e.name,
-                                  payload_nbytes(e.payload), e.num_workers)
-                if e.gather:
-                    e.deferred._set(jax.lax.all_gather(e.payload, key[1]))
-                else:
-                    e.deferred._set(e.raw_op(e.payload, key[1]))
-                continue
-            axis_name = key[1]
-            flats = [(-jnp.ravel(e.payload) if e.negate
-                      else jnp.ravel(e.payload)) for e in entries]
-            sizes = [f.size for f in flats]
-            buf = jnp.concatenate(flats)
-            names = tuple(e.name for e in entries)
-            per_worker = sum(payload_nbytes(e.payload) for e in entries)
-            # keep the members' kind label when they agree (a pure
-            # ctx.all_reduce_sum group stays "InlineAllReduce" fused or
-            # not); mixed groups fall back to the generic kind
-            kinds = {e.kind_label for e in entries}
-            if len(kinds) == 1:
-                kind = entries[0].kind_label
-            else:
-                kind = "AllGather" if key[0] == "gather" else "AllReduce"
-            record_collective(kind, "fused(" + "+".join(names) + ")",
-                              per_worker, entries[0].num_workers,
-                              members=names)
-            self.fused_groups.append((kind, names, per_worker))
-            if key[0] == "gather":
-                out = jax.lax.all_gather(buf, axis_name)   # (nw, total)
-                off = 0
-                for e, sz in zip(entries, sizes):
-                    piece = out[:, off:off + sz]
-                    e.deferred._set(piece.reshape(
-                        (out.shape[0],) + tuple(e.payload.shape)))
-                    off += sz
-            else:
-                lane_op = key[2]
-                raw = {"sum": jax.lax.psum, "max": jax.lax.pmax,
-                       "min": jax.lax.pmin}[lane_op]
-                out = raw(buf, axis_name)
-                off = 0
-                for e, sz in zip(entries, sizes):
-                    piece = out[off:off + sz]
-                    if e.negate:
-                        piece = -piece
-                    e.deferred._set(piece.reshape(e.payload.shape))
-                    off += sz
-
-
-@contextlib.contextmanager
-def fusing(enabled: bool = True):
-    """Install a :class:`_FusionScope` on this thread (the engine arms one
-    per superstep trace). Pending collectives flush on first use and, as
-    a backstop, when the scope exits cleanly."""
-    if not enabled:
-        yield None
-        return
-    prev = getattr(_fusion, "scope", None)
-    scope = _FusionScope()
-    _fusion.scope = scope
-    try:
-        yield scope
-        scope.flush()
-    finally:
-        _fusion.scope = prev
-
-
-def resolve_deferred(tree):
-    """Replace every :class:`_Deferred` leaf with its materialized value
-    (the engine runs this over the carry before it leaves the superstep —
-    deferred proxies must never reach ``lax.while_loop``)."""
-    return jax.tree_util.tree_map(
-        lambda v: v._force() if isinstance(v, _Deferred) else v, tree,
-        is_leaf=lambda v: isinstance(v, _Deferred))
 
 
 # -- manifest-recording raw-collective wrappers -----------------------------
 # The collective manifest only saw traffic routed through the stage
-# classes above (and ctx.all_reduce_sum); raw ``lax.psum``/... calls in
-# operator code ran real inter-chip traffic the accounting, the scaling
-# evidence, and the planned ROADMAP-item-1 psum fusion could not see.
+# classes below (and ctx.all_reduce_sum); raw ``lax.psum``/... calls in
+# operator code ran real inter-chip traffic the accounting and the scaling
+# evidence could not see.
 # These wrappers are the sanctioned call form outside this module — the
 # alink-lint COLLECTIVE-SITE rule rejects raw ``lax`` collectives
 # anywhere else. Each wrapper records at TRACE time (once per traced
@@ -465,15 +116,13 @@ def resolve_deferred(tree):
 # engine multiplies per-superstep manifests by the executed superstep
 # count; loops that drive jit-cached programs outside the engine replay
 # the captured manifest per invocation via record_manifest) and lowers
-# to exactly the raw ``lax`` op: zero HLO change. Inside an armed
-# ``fusing()`` scope they DEFER instead (see the fusion block above).
+# to exactly the raw ``lax`` op: zero HLO change. Independent collectives
+# of one superstep are left to XLA's combiner, which merges them (a
+# compiled Newton superstep holds one all-reduce for its two psums).
 
 def manifest_psum(x, axis_name, *, name: str = "<psum>",
                   num_workers: int = 1):
     """``lax.psum`` + manifest record (kind AllReduce)."""
-    scope = active_fusion_scope()
-    if scope is not None:
-        return scope.defer_reduce("sum", x, axis_name, name, num_workers)
     record_collective("AllReduce", name, payload_nbytes(x), num_workers)
     return jax.lax.psum(x, axis_name)
 
@@ -481,9 +130,6 @@ def manifest_psum(x, axis_name, *, name: str = "<psum>",
 def manifest_pmax(x, axis_name, *, name: str = "<pmax>",
                   num_workers: int = 1):
     """``lax.pmax`` + manifest record (kind AllReduce)."""
-    scope = active_fusion_scope()
-    if scope is not None:
-        return scope.defer_reduce("max", x, axis_name, name, num_workers)
     record_collective("AllReduce", name, payload_nbytes(x), num_workers)
     return jax.lax.pmax(x, axis_name)
 
@@ -491,9 +137,6 @@ def manifest_pmax(x, axis_name, *, name: str = "<pmax>",
 def manifest_pmin(x, axis_name, *, name: str = "<pmin>",
                   num_workers: int = 1):
     """``lax.pmin`` + manifest record (kind AllReduce)."""
-    scope = active_fusion_scope()
-    if scope is not None:
-        return scope.defer_reduce("min", x, axis_name, name, num_workers)
     record_collective("AllReduce", name, payload_nbytes(x), num_workers)
     return jax.lax.pmin(x, axis_name)
 
@@ -502,10 +145,6 @@ def manifest_all_gather(x, axis_name, *, axis: int = 0, tiled: bool = False,
                         name: str = "<all_gather>", num_workers: int = 1):
     """``lax.all_gather`` + manifest record (kind AllGather; bytes are
     the pre-gather shard payload × workers, like the AllGather stage)."""
-    scope = active_fusion_scope()
-    if scope is not None:
-        return scope.defer_gather(x, axis_name, axis, tiled, name,
-                                  num_workers)
     record_collective("AllGather", name, payload_nbytes(x), num_workers)
     return jax.lax.all_gather(x, axis_name, axis=axis, tiled=tiled)
 
@@ -555,18 +194,11 @@ class AllReduce(CommunicateFunction):
                 "min": manifest_pmin}[self.op]
         for name in self.buffer_names:
             v = context.get_obj(name)
-            # route through the manifest wrapper: eagerly it records +
-            # lowers the identical raw op; inside the engine's fusing()
-            # scope the reduction DEFERS, so adjacent AllReduce stages
-            # (Newton's H + glw, FM's avg + lw) coalesce into one psum
             out = wrap(v, ComContext.AXIS, name=name,
                        num_workers=context.num_task)
             if self.mean:
-                # dividing forces a deferred result — mean reductions
-                # materialize eagerly (word2vec's one psum loses nothing)
                 out = jax.tree_util.tree_map(
-                    lambda x: x / context.num_task, out,
-                    is_leaf=lambda x: isinstance(x, _Deferred))
+                    lambda x: x / context.num_task, out)
             context.put_obj(name, out)
 
 
@@ -588,8 +220,6 @@ class AllGather(CommunicateFunction):
     def calc(self, context: ComContext):
         for name in self.buffer_names:
             v = context.get_obj(name)
-            # manifest wrapper: identical eager lowering; defers (and can
-            # fuse adjacent gathers) inside the engine's fusing() scope
             out = manifest_all_gather(v, ComContext.AXIS, axis=self.axis,
                                       tiled=self.tiled, name=name,
                                       num_workers=context.num_task)

@@ -499,6 +499,279 @@ def _first_shards(tree):
     return jax.tree_util.tree_map(lambda x: x[0], tree)
 
 
+def _lookup_programs(cache: str, key: Optional[tuple], plan, build: Callable,
+                     manifest: dict, *, site: Optional[str], mesh, mx: bool,
+                     aot: Sequence[tuple] = (),
+                     fresh_jaxpr: Optional[Callable[[], str]] = None):
+    """The program(s) for one plan: from the in-memory LRU, else from
+    the AOT store, else built — the one place that decides, and that
+    keeps each outcome's books (LRU order, eviction, the compile ledger,
+    the hit/miss counter, the metric, the trace instant).
+
+    ``cache`` names the ledger's cache (``engine.program``: the single
+    whole-loop program; ``engine.chunked``: the ``first`` + ``cont``
+    pair); ``key`` is the LRU key (None: an uncached queue, built and
+    nothing else); ``plan`` is what the ledger records; ``build`` returns
+    the tuple of jitted programs. ``aot`` holds one ``(artifact plan,
+    placement specs)`` a program where the AOT store may be read
+    (load-before-compile, ISSUE 20): every artifact loads or none
+    installs — half a pair would force a recompile anyway — so the
+    ledger's disk-hit is written only on full success. ``fresh_jaxpr``
+    is given under ``ALINK_VERIFY_PROGRAM_CACHE`` alone.
+
+    Returns ``(programs, status, manifest)``; ``manifest`` is the dict
+    the programs' superstep closures write into — on a hit the one
+    stored at miss time, not this exec's."""
+    if key is None:
+        return build(), "uncached", manifest
+    from ..common import aotcache, compileledger
+    compileledger.register_cache(cache, "engine", _PROGRAM_CACHE_MAX)
+    programs = _PROGRAM_CACHE.get(key)
+    if programs is not None:
+        status = "hit"
+        _PROGRAM_CACHE_STATS["hits"] += 1
+        _PROGRAM_CACHE.move_to_end(key)
+        compileledger.record_hit(cache)
+        manifest = _PROGRAM_CACHE_MANIFESTS.setdefault(key, manifest)
+    else:
+        loaded = []
+        for aot_plan, _specs in aot:
+            got = aotcache.load(aot_plan, cache=cache, site=site,
+                                subsystem="engine", record=False)
+            if got is None:
+                break
+            loaded.append(got)
+        if aot and len(loaded) == len(aot):
+            status = "disk-hit"
+            _PROGRAM_CACHE_STATS["hits"] += 1
+            programs = tuple(_AotMeshCall(got.fn, mesh, specs)
+                             for got, (_plan, specs) in zip(loaded, aot))
+            # deserialized programs never trace, so the per-superstep
+            # collective manifest rides the artifact header instead of
+            # the closure
+            header = loaded[0].manifest(None)
+            if isinstance(header, dict) and header:
+                manifest.update(header)
+            for got in loaded:
+                compileledger.record_disk_hit(cache, plan, wall_s=got.wall_s,
+                                              site=site, subsystem="engine")
+        else:
+            status = "miss"
+            _PROGRAM_CACHE_STATS["misses"] += 1
+            programs = build()
+            # ledger event at insert time; jit is lazy, so the
+            # trace+compile wall is only observable around the first
+            # dispatch (the plain path's note_wall attaches it)
+            compileledger.record_event(cache, plan, site=site,
+                                       subsystem="engine")
+        _PROGRAM_CACHE[key] = programs
+        _PROGRAM_CACHE_MANIFESTS[key] = manifest
+        # an evicted program takes its side tables with it
+        while len(_PROGRAM_CACHE) > _PROGRAM_CACHE_MAX:
+            old_key, _ = _PROGRAM_CACHE.popitem(last=False)
+            _PROGRAM_CACHE_JAXPRS.pop(old_key, None)
+            _PROGRAM_CACHE_MANIFESTS.pop(old_key, None)
+            _PROGRAM_CACHE_COSTS.pop(old_key, None)
+            compileledger.record_eviction(
+                "engine.chunked" if old_key and old_key[0] == "__ckpt__"
+                else "engine.program")
+    if fresh_jaxpr is not None:
+        # debug mode: the baseline jaxpr is recorded AT COMPILE TIME, so
+        # the very first post-compile drift is caught on the next hit;
+        # every hit re-traces and compares — catches any constant the
+        # structural guard cannot see
+        fresh = fresh_jaxpr()
+        if status == "miss":
+            _PROGRAM_CACHE_JAXPRS[key] = fresh
+        elif fresh != _PROGRAM_CACHE_JAXPRS.setdefault(key, fresh):
+            raise RuntimeError(
+                "ALINK_VERIFY_PROGRAM_CACHE: cached program for key "
+                f"{key[0]!r} no longer matches a fresh trace — a stage "
+                "closure baked state the program_key does not cover")
+    if mx:
+        from ..common.metrics import get_registry
+        get_registry().inc("alink_comqueue_program_cache_total", 1,
+                           {"result": status})
+    trace_instant("comqueue.program_cache", cat="engine",
+                  args={"result": status})
+    return programs, status, manifest
+
+
+class _StepBodies:
+    """The traced bodies of one exec: the superstep over the queue's
+    stages and the three ``shard_map`` programs built on it — the whole
+    loop (``mapped``), and the checkpoint-mode pair whose loop bound is
+    a TRACED scalar, so one compiled pair serves every chunk while the
+    host persists the carry between the calls (engine/recovery.py):
+    ``first_chunk`` runs the init pass, ``cont_chunk`` re-enters with a
+    (possibly disk-round-tripped) stacked carry.
+
+    ``manifest`` takes every traced pass's collectives (trace-time; see
+    communication.collecting), keyed by the traced input signature:
+    jax.jit keeps a shape-keyed trace cache underneath each compiled
+    entry, so one cached program can hold several traces with different
+    payload sizes. A dict, so that the superstep closure — which may be
+    retraced later through a CACHED program — always writes into the
+    object stored with that program."""
+
+    def __init__(self, queue: "IterativeComQueue", env: MLEnvironment,
+                 probes_on: bool, donate: bool):
+        self.stages = list(queue._stages)
+        self.criterion = queue._criterion
+        self.program_key = queue._program_key
+        self.max_iter = int(queue.max_iter)
+        self.seed = int(queue.seed)
+        self.nw = env.num_workers
+        self.mesh = env.mesh
+        self.probes_on = probes_on
+        self.donate = donate
+        self.manifest: Dict[tuple, Dict[str, list]] = {}
+
+    @staticmethod
+    def _static_sig(static) -> tuple:
+        """Trace signature: per-worker shapes/dtypes of every input
+        leaf, computed identically on host inputs (given the P('d')
+        leading-axis split) and on the tracers inside superstep."""
+        import jax
+        items = []
+        for k in sorted(static):
+            for leaf in jax.tree_util.tree_leaves(static[k]):
+                items.append((k, tuple(map(int, leaf.shape)),
+                              str(leaf.dtype)))
+        return tuple(items)
+
+    def superstep(self, carry, static, init_pass):
+        import jax.numpy as jnp
+
+        from ..common.profiling import log_superstep, named_stage
+        from .communication import collecting
+        ctx = ComContext(carry, static, self.nw, init_pass,
+                         max_iter=self.max_iter, probes_on=self.probes_on)
+        # capture this pass's collectives at TRACE time (shapes are on
+        # the tracers; nothing is added to the compiled program).
+        # clear() first: a retrace through a cached program must
+        # OVERWRITE the stored per-pass manifest, not append to it.
+        per = self.manifest.setdefault(self._static_sig(static),
+                                       {"init": [], "body": []})
+        entries = per["init" if init_pass else "body"]
+        entries.clear()
+        with collecting(entries):
+            for s in self.stages:
+                # name each compiled stage (the reference .name()s every
+                # dataflow stage for the Flink UI,
+                # BaseComQueue.java:172-195)
+                with named_stage(getattr(s, "__name__", type(s).__name__)):
+                    s.calc(ctx)
+            if self.criterion is not None:
+                stop = self.criterion(ctx)
+                ctx.put_obj("__stop", jnp.asarray(stop, bool).reshape(()))
+            else:
+                ctx.put_obj("__stop", jnp.asarray(False))
+        log_superstep(ctx.step_no, task=ctx.task_id,
+                      stop=ctx.get_obj("__stop"))
+        return ctx.carry
+
+    def _loop(self, static, limit=None):
+        """``(body, cond)`` of the superstep loop; ``limit`` is a chunk's
+        traced upper bound."""
+        import jax.numpy as jnp
+        max_iter = self.max_iter
+
+        def body(c):
+            c = dict(c)
+            c["__step"] = c["__step"] + 1
+            return self.superstep(c, static, init_pass=False)
+
+        if limit is None:
+            def cond(c):
+                return (c["__step"] < max_iter) & jnp.logical_not(c["__stop"])
+        else:
+            def cond(c):
+                return ((c["__step"] < limit) & (c["__step"] < max_iter)
+                        & jnp.logical_not(c["__stop"]))
+        return body, cond
+
+    def _from_init(self, static, limit=None):
+        """The init pass, then the loop (to ``limit``, in a chunk)."""
+        import jax
+        import jax.numpy as jnp
+        carry = {"__step": jnp.asarray(1, jnp.int32),
+                 "__key": jax.random.PRNGKey(self.seed)}
+        carry = self.superstep(carry, static, init_pass=True)
+        body, cond = self._loop(static, limit)
+        final = jax.lax.while_loop(cond, body, carry) \
+            if self.max_iter > 1 else carry
+        return _stack_worker_axis(final)
+
+    def _shard_map(self, fn, role, in_specs):
+        from jax.sharding import PartitionSpec as P
+
+        from ..common.compat import shard_map
+        # uniform out_spec: every leaf gains a leading worker axis
+        return shard_map(_name_program(fn, self.program_key, role),
+                         mesh=self.mesh, in_specs=in_specs,
+                         out_specs=P("d"), check_vma=False)
+
+    def mapped(self):
+        # ONE construction shared by lowered() and exec(): the HLO audit
+        # must inspect exactly the program exec runs
+        from jax.sharding import PartitionSpec as P
+
+        def run(parts_shard, bcast_rep):
+            return self._from_init({**parts_shard, **bcast_rep})
+        return self._shard_map(run, "", (P("d"), P()))
+
+    def first_chunk(self):
+        from jax.sharding import PartitionSpec as P
+
+        def run_first(parts_shard, bcast_rep, limit):
+            return self._from_init({**parts_shard, **bcast_rep}, limit)
+        return self._shard_map(run_first, "_first", (P("d"), P(), P()))
+
+    def cont_chunk(self):
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import PartitionSpec as P
+
+        def run_cont(parts_shard, bcast_rep, carry_stacked, limit):
+            static = {**parts_shard, **bcast_rep}
+            carry = jax.tree_util.tree_map(
+                lambda x: jnp.squeeze(x, 0), dict(carry_stacked))
+            body, cond = self._loop(static, limit)
+            return _stack_worker_axis(jax.lax.while_loop(cond, body, carry))
+        return self._shard_map(run_cont, "_cont",
+                               (P("d"), P(), P("d"), P()))
+
+    def jit_chunks(self):
+        """``(first, cont)``, jitted. Carry donation (ALINK_TPU_DONATE):
+        argnum 2 of ``cont`` is the stacked chunk carry — the ONLY input
+        a chunk pass consumes. parts/bcast are never donatable (every
+        later chunk re-reads them)."""
+        import jax
+        return (jax.jit(self.first_chunk()),
+                jax.jit(self.cont_chunk(),
+                        donate_argnums=(2,) if self.donate else ()))
+
+    def lower(self, parts, bcast, chunked: bool):
+        import jax
+        import jax.numpy as jnp
+        if not chunked:
+            return jax.jit(self.mapped()).lower(parts, bcast)
+        lim = jnp.asarray(self.max_iter, jnp.int32)
+        first, cont = self.jit_chunks()
+        # the cont program's carry geometry comes from the first
+        # program's abstract output — no execution, no compile
+        carry_shape = jax.eval_shape(first, parts, bcast, lim)
+        return (first.lower(parts, bcast, lim),
+                cont.lower(parts, bcast, carry_shape, lim))
+
+
+def _stack_worker_axis(carry):
+    import jax
+    import jax.numpy as jnp
+    return jax.tree_util.tree_map(lambda x: jnp.expand_dims(x, 0), carry)
+
+
 class ComputeFunction:
     """One per-worker compute stage (reference comqueue/ComputeFunction.java)."""
 
@@ -839,47 +1112,44 @@ class IterativeComQueue:
             return self._run(lower_only=False)
 
     def _run(self, lower_only: bool = False, lower_chunked: bool = False):
-        import jax
-        import jax.numpy as jnp
-        from jax.sharding import PartitionSpec as P
-
-        from ..common.compat import shard_map
-
-        from ..common.metrics import get_registry, metrics_enabled
+        """One pass over the queue: prepare the inputs, then either lower
+        the program(s) (``lowered`` / ``lowered_chunked``) or plan, look
+        the program(s) up and execute — chunked with host boundaries
+        under ``set_checkpoint`` / ``set_boundary``, else as ONE
+        program."""
+        from ..common import compileledger
+        from ..common import plan as planlib
+        from ..common.metrics import metrics_enabled
 
         env = self.env or MLEnvironmentFactory.get_default()
-        nw = env.num_workers
-        mesh = env.mesh
-        stages = list(self._stages)
-        criterion = self._criterion
-        max_iter = int(self.max_iter)
-        seed = int(self.seed)
-        mx = metrics_enabled() and not lower_only
         # key-folding flag dims, latched ONCE per run at the plan
         # derivation site (common/plan.engine_flags — the ENV-KEY-FOLD
         # checked site).  probes: stacked (max_iter,) carry entries make
         # a toggled flag a structurally different program.  donate: the
         # buffer-aliasing contract differs even though the HLO ops are
-        # identical.  fuse: the fused program's collective set is
-        # structurally different HLO.  All three (plus step_log) ride
-        # the program-cache key via the ExecutionPlan below.
-        from ..common import aotcache, compileledger
-        from ..common import plan as planlib
+        # identical.  Both (plus step_log) ride the program-cache key
+        # via the ExecutionPlan below.
         plan_flags = planlib.engine_flags()
         probes_on = plan_flags[1][1]
         donate = plan_flags[2][1]
-        fuse = plan_flags[3][1]
-        from .communication import fusing, resolve_deferred
-        # per-superstep collective capture (trace-time; see communication
-        # .collecting), keyed by the traced input signature: jax.jit keeps
-        # a shape-keyed trace cache underneath each compiled entry, so one
-        # cached program can hold several traces with different payload
-        # sizes — each signature gets its own init/body manifest. A dict
-        # so the superstep closure — which may be retraced later through a
-        # CACHED program — always writes into the manifest object stored
-        # with that program.
-        manifest: Dict[tuple, Dict[str, list]] = {}
+        parts, totals, bcast = self._prepare(env.num_workers)
+        bodies = _StepBodies(self, env, probes_on, donate)
+        if lower_only:
+            return bodies.lower(parts, bcast, chunked=lower_chunked)
+        splan, ckey = self._plan(bodies, plan_flags, parts, bcast)
+        compileledger.subsystem_start("engine")
+        execute = self._exec_chunked \
+            if self._ckpt is not None or self._boundary is not None \
+            else self._exec_plain
+        return execute(bodies, parts, totals, bcast, splan, ckey,
+                       metrics_enabled())
 
+    def _prepare(self, nw: int):
+        """``(parts, totals, bcast)`` on the device: the partitioned
+        inputs padded to whole shards, their true row counts, and the
+        broadcast inputs with a ``__total_<name>`` scalar an input."""
+        import jax
+        import jax.numpy as jnp
         parts: Dict[str, Any] = {}
         totals: Dict[str, int] = {}
         # the prepare phase is host padding + the H2D input ship. ONE span:
@@ -919,441 +1189,167 @@ class IterativeComQueue:
                      for k, v in self._broadcast.items()}
             for k, n in totals.items():
                 bcast[f"__total_{k}"] = jnp.asarray(n, jnp.int32)
-        from ..common.profiling import log_superstep, named_stage
-        from .communication import collecting
+        return parts, totals, bcast
 
-        def static_sig(static):
-            """Trace signature: per-worker shapes/dtypes of every input
-            leaf, computed identically on host inputs (given the P('d')
-            leading-axis split) and on the tracers inside superstep."""
-            items = []
-            for k in sorted(static):
-                for leaf in jax.tree_util.tree_leaves(static[k]):
-                    items.append((k, tuple(map(int, leaf.shape)),
-                                  str(leaf.dtype)))
-            return tuple(items)
-
-        def superstep(carry, static, init_pass):
-            ctx = ComContext(carry, static, nw, init_pass,
-                             max_iter=max_iter, probes_on=probes_on)
-            # capture this pass's collectives at TRACE time (shapes are on
-            # the tracers; nothing is added to the compiled program).
-            # clear() first: a retrace through a cached program must
-            # OVERWRITE the stored per-pass manifest, not append to it.
-            per = manifest.setdefault(static_sig(static),
-                                      {"init": [], "body": []})
-            entries = per["init" if init_pass else "body"]
-            entries.clear()
-            with collecting(entries):
-                # fusion scope (no-op when the flag is off): manifest
-                # wrappers defer their reductions; the first USE of any
-                # deferred value flushes all independent pending payloads
-                # as one flattened collective, and the scope exit flushes
-                # whatever was never read inside this superstep
-                with fusing(enabled=fuse):
-                    for s in stages:
-                        # name each compiled stage (the reference .name()s
-                        # every dataflow stage for the Flink UI,
-                        # BaseComQueue.java:172-195)
-                        with named_stage(getattr(s, "__name__",
-                                                 type(s).__name__)):
-                            s.calc(ctx)
-                    if criterion is not None:
-                        stop = criterion(ctx)
-                        ctx.put_obj("__stop",
-                                    jnp.asarray(stop, bool).reshape(()))
-                    else:
-                        ctx.put_obj("__stop", jnp.asarray(False))
-            if fuse:
-                # deferred proxies must never reach the while_loop carry
-                for k in list(ctx.carry):
-                    ctx.carry[k] = resolve_deferred(ctx.carry[k])
-            log_superstep(ctx.step_no, task=ctx.task_id,
-                          stop=ctx.get_obj("__stop"))
-            return ctx.carry
-
-        def run(parts_shard, bcast_rep):
-            static = {**parts_shard, **bcast_rep}
-            carry = {"__step": jnp.asarray(1, jnp.int32),
-                     "__key": jax.random.PRNGKey(seed)}
-            carry = superstep(carry, static, init_pass=True)
-
-            def body(c):
-                c = dict(c)
-                c["__step"] = c["__step"] + 1
-                return superstep(c, static, init_pass=False)
-
-            def cond(c):
-                return (c["__step"] < max_iter) & jnp.logical_not(c["__stop"])
-
-            final = jax.lax.while_loop(cond, body, carry) if max_iter > 1 else carry
-            # uniform out_spec: every leaf gains a leading worker axis
-            return jax.tree_util.tree_map(lambda x: jnp.expand_dims(x, 0), final)
-
-        def build_mapped():
-            # ONE construction shared by lowered() and exec(): the HLO
-            # audit must inspect exactly the program exec runs
-            return shard_map(_name_program(run, self._program_key),
-                             mesh=mesh, in_specs=(P("d"), P()),
-                             out_specs=P("d"), check_vma=False)
-
-        # -- checkpoint-mode chunk programs -------------------------------
-        # The SAME superstep body, but the loop's upper bound is a TRACED
-        # scalar: one compiled program serves every chunk between
-        # checkpoint boundaries, and the host persists the carry between
-        # chunk calls (engine/recovery.py). ``first`` runs the init pass;
-        # ``cont`` re-enters with a (possibly disk-round-tripped) stacked
-        # carry.
-        def chunk_body_cond(static, limit):
-            def body(c):
-                c = dict(c)
-                c["__step"] = c["__step"] + 1
-                return superstep(c, static, init_pass=False)
-
-            def cond(c):
-                return ((c["__step"] < limit) & (c["__step"] < max_iter)
-                        & jnp.logical_not(c["__stop"]))
-            return body, cond
-
-        def build_first_chunk():
-            def run_first(parts_shard, bcast_rep, limit):
-                static = {**parts_shard, **bcast_rep}
-                carry = {"__step": jnp.asarray(1, jnp.int32),
-                         "__key": jax.random.PRNGKey(seed)}
-                carry = superstep(carry, static, init_pass=True)
-                body, cond = chunk_body_cond(static, limit)
-                final = jax.lax.while_loop(cond, body, carry) \
-                    if max_iter > 1 else carry
-                return jax.tree_util.tree_map(
-                    lambda x: jnp.expand_dims(x, 0), final)
-            return shard_map(_name_program(run_first, self._program_key,
-                                           "_first"), mesh=mesh,
-                             in_specs=(P("d"), P(), P()),
-                             out_specs=P("d"), check_vma=False)
-
-        def build_cont_chunk():
-            def run_cont(parts_shard, bcast_rep, carry_stacked, limit):
-                static = {**parts_shard, **bcast_rep}
-                carry = jax.tree_util.tree_map(
-                    lambda x: jnp.squeeze(x, 0), dict(carry_stacked))
-                body, cond = chunk_body_cond(static, limit)
-                final = jax.lax.while_loop(cond, body, carry)
-                return jax.tree_util.tree_map(
-                    lambda x: jnp.expand_dims(x, 0), final)
-            return shard_map(_name_program(run_cont, self._program_key,
-                                           "_cont"), mesh=mesh,
-                             in_specs=(P("d"), P(), P("d"), P()),
-                             out_specs=P("d"), check_vma=False)
-
-        def jit_cont():
-            # carry donation (ALINK_TPU_DONATE): argnum 2 is the stacked
-            # chunk carry — the ONLY input a chunk pass consumes. parts/
-            # bcast are never donatable (every later chunk re-reads them)
-            return jax.jit(build_cont_chunk(),
-                           donate_argnums=(2,) if donate else ())
-
-        if lower_only:
-            if not lower_chunked:
-                return jax.jit(build_mapped()).lower(parts, bcast)
-            lim = jnp.asarray(max_iter, jnp.int32)
-            first_fn = jax.jit(build_first_chunk())
-            first_low = first_fn.lower(parts, bcast, lim)
-            # the cont program's carry geometry comes from the first
-            # program's abstract output — no execution, no compile
-            carry_shape = jax.eval_shape(first_fn, parts, bcast, lim)
-            cont_low = jit_cont().lower(parts, bcast, carry_shape, lim)
-            return first_low, cont_low
-        compiled = None
-        ckey = None
-        cache_status = "uncached"
+    def _plan(self, bodies: _StepBodies, plan_flags, parts, bcast):
+        """ONE ExecutionPlan per exec (ROADMAP item 1): the program-cache
+        key and the recovery signature both derive from it.  The
+        structural guard stays (advisor r4): the stage bytecode + frozen
+        closure cells ride in the "stages" dim, so a program_key that
+        under-specifies a baked constant misses instead of silently
+        re-running a stale program.  Returns ``(plan, cache key)``; the
+        key is None for a queue without a ``program_key``."""
+        from ..common import plan as planlib
         stages_dig = None
         if self._program_key is not None or self._ckpt is not None:
-            stages_dig = _stages_digest(stages, criterion)
-        # ONE ExecutionPlan per exec (ROADMAP item 1): the program-cache
-        # key and the recovery signature both derive from it.  The
-        # structural guard stays (advisor r4): the stage bytecode +
-        # frozen closure cells ride in the "stages" dim, so a
-        # program_key that under-specifies a baked constant misses
-        # instead of silently re-running a stale program.
+            stages_dig = _stages_digest(bodies.stages, bodies.criterion)
         splan = planlib.engine_plan(
             program_key=self._program_key, stages_digest=stages_dig,
-            mesh=mesh, num_workers=nw, max_iter=max_iter, seed=seed,
-            has_criterion=criterion is not None, flags=plan_flags,
+            mesh=bodies.mesh, num_workers=bodies.nw,
+            max_iter=bodies.max_iter, seed=bodies.seed,
+            has_criterion=bodies.criterion is not None, flags=plan_flags,
             part_names=tuple(sorted(parts)),
             bcast_names=tuple(sorted(bcast)))
-        if self._program_key is not None:
-            ckey = splan.legacy_key()
-        if not lower_only:
-            compileledger.subsystem_start("engine")
+        ckey = splan.legacy_key() if self._program_key is not None else None
+        return splan, ckey
 
-        if self._ckpt is not None or self._boundary is not None:
-            # -- durable chunked execution (engine/recovery.py) -----------
-            from . import recovery
-            if jax.process_count() > 1:
-                raise NotImplementedError(
-                    "comqueue checkpointing is single-process for now: the "
-                    "per-boundary carry fetch would need a multihost "
-                    "allgather + single-writer election")
-            ck = self._ckpt
-            on_boundary = None
-            if self._boundary is not None:
-                # boundary-driven chunking (tuning sweep rungs): the hook
-                # cadence overrides the snapshot cadence — the sweep
-                # aligns both, and a hook without set_checkpoint runs the
-                # chunk programs with persistence off (directory=None)
-                b_every, on_boundary = self._boundary
-                if ck is None:
-                    ck = recovery.CheckpointConfig(directory=None,
-                                                   every=b_every)
-                elif int(ck.every) != b_every:
-                    import dataclasses
-                    ck = dataclasses.replace(ck, every=b_every)
-            first = cont = None
-            ckkey = ("__ckpt__", ckey) if ckey is not None else None
-            aot_first_plan = aot_cont_plan = None
-            if ckkey is not None:
-                compileledger.register_cache("engine.chunked", "engine",
-                                             _PROGRAM_CACHE_MAX)
-                cached = _PROGRAM_CACHE.get(ckkey)
-                if cached is not None:
-                    cache_status = "hit"
-                    _PROGRAM_CACHE_STATS["hits"] += 1
-                    _PROGRAM_CACHE.move_to_end(ckkey)
-                    first, cont = cached
-                    manifest = _PROGRAM_CACHE_MANIFESTS.setdefault(ckkey,
-                                                                   manifest)
-                    compileledger.record_hit("engine.chunked")
-            if (first is None and ckkey is not None and aotcache.active()
-                    and jax.process_count() == 1):
-                # load-before-compile (ISSUE 20): the chunked pair ships
-                # as two artifacts keyed off the same plan with a role
-                # dim.  Both must load or neither installs (a half pair
-                # would force a recompile anyway), so record=False here
-                # and the ledger disk-hit is written only on full success
-                _base = splan.extend(("checkpoint_chunked", True))
-                aot_first_plan = _base.extend(("role", "first"))
-                aot_cont_plan = _base.extend(("role", "cont"))
-                _site = _program_label(self._program_key)
-                lf = aotcache.load(aot_first_plan, cache="engine.chunked",
-                                   site=_site, subsystem="engine",
-                                   record=False)
-                lc = aotcache.load(aot_cont_plan, cache="engine.chunked",
-                                   site=_site, subsystem="engine",
-                                   record=False) if lf is not None else None
-                if lf is not None and lc is not None:
-                    first = _AotMeshCall(lf.fn, mesh,
-                                         ("shard", "repl", "repl"))
-                    cont = _AotMeshCall(lc.fn, mesh,
-                                        ("shard", "repl", "shard", "repl"))
-                    cache_status = "disk-hit"
-                    _PROGRAM_CACHE_STATS["hits"] += 1
-                    _PROGRAM_CACHE[ckkey] = (first, cont)
-                    # the deserialized programs never trace, so the
-                    # per-superstep collective manifest rides the artifact
-                    # header instead of the closure
-                    _m = lf.manifest(None)
-                    if isinstance(_m, dict) and _m:
-                        manifest.update(_m)
-                    _PROGRAM_CACHE_MANIFESTS[ckkey] = manifest
-                    for _lp in (lf, lc):
-                        compileledger.record_disk_hit(
-                            "engine.chunked", _base, wall_s=_lp.wall_s,
-                            site=_site, subsystem="engine")
-            if first is None:
-                first = jax.jit(build_first_chunk())
-                cont = jit_cont()
-                if ckkey is not None:
-                    cache_status = "miss"
-                    _PROGRAM_CACHE_STATS["misses"] += 1
-                    _PROGRAM_CACHE[ckkey] = (first, cont)
-                    _PROGRAM_CACHE_MANIFESTS[ckkey] = manifest
-                    compileledger.record_event(
-                        "engine.chunked",
-                        splan.extend(("checkpoint_chunked", True)),
-                        site=_program_label(self._program_key),
-                        subsystem="engine")
-                    while len(_PROGRAM_CACHE) > _PROGRAM_CACHE_MAX:
-                        old_key, _ = _PROGRAM_CACHE.popitem(last=False)
-                        _PROGRAM_CACHE_JAXPRS.pop(old_key, None)
-                        _PROGRAM_CACHE_MANIFESTS.pop(old_key, None)
-                        _PROGRAM_CACHE_COSTS.pop(old_key, None)
-                        compileledger.record_eviction(
-                            "engine.chunked"
-                            if old_key and old_key[0] == "__ckpt__"
-                            else "engine.program")
-                    if aot_first_plan is not None:
-                        # export BEFORE recovery.drive: export's trace runs
-                        # the superstep closures, so the collective
-                        # manifest is populated by the time the header
-                        # snapshots it.  Gate the cont store on the first:
-                        # a half pair on disk would never install
-                        _site = _program_label(self._program_key)
-                        _lim0 = jnp.asarray(int(max_iter), jnp.int32)
-                        if aotcache.store(aot_first_plan, first,
-                                          (parts, bcast, _lim0),
-                                          cache="engine.chunked",
-                                          site=_site, manifest=manifest):
-                            _carry_av = jax.eval_shape(first, parts, bcast,
-                                                       _lim0)
-                            aotcache.store(aot_cont_plan, cont,
-                                           (parts, bcast, _carry_av, _lim0),
-                                           cache="engine.chunked",
-                                           site=_site, manifest=manifest)
-            if mx and ckkey is not None:
-                get_registry().inc("alink_comqueue_program_cache_total", 1,
-                                   {"result": cache_status})
-            if ckkey is not None:
-                trace_instant("comqueue.program_cache", cat="engine",
-                              args={"result": cache_status})
-            cost = _maybe_cost(ckkey, lambda: first.lower(
-                parts, bcast, jnp.asarray(max_iter, jnp.int32)))
-            if ck.directory or ck.resume_from:
-                part_sig = tuple(
-                    (k, tuple(map(int, np.shape(parts[k]))),
-                     str(getattr(parts[k], "dtype", "?")))
-                    for k in sorted(parts))
-                # fingerprint the ORIGINAL (pre-padding, host-side)
-                # inputs: np arrays hash by content, device-resident
-                # arrays degrade to shape/dtype tokens (no forced
-                # device->host round trip). Memoized per queue instance
-                # (invalidated by init_with_*): repeated exec() on the
-                # same queue must not re-hash the whole dataset per
-                # program-cache hit
-                data_token = self._data_token
-                if data_token is None:
-                    data_token = self._data_token = _freeze_closure_value(
-                        {"parts": dict(self._partitioned),
-                         "bcast": dict(self._broadcast)}, 3)
-                # the durable-run signature derives from the SAME plan
-                # as the program-cache key (content identical to the
-                # historical direct program_signature call — old
-                # snapshots stay resumable)
-                signature = planlib.engine_checkpoint_signature(
-                    splan, part_sig=part_sig, data_token=data_token)
-                resumed = recovery.resume_state(ck, signature)
-            else:
-                # boundary-only chunking (set_boundary without a
-                # checkpoint dir): nothing persists and nothing resumes,
-                # so content-hashing the whole dataset for a signature
-                # no snapshot will ever carry is pure waste
-                signature, resumed = None, None
-            on_snapshot = None
-            if self._health is not None and probes_on:
-                # mid-run watchdog: evaluate on the carry the boundary
-                # save just fetched — zero extra device->host traffic.
-                # evaluate() may raise HealthAlertError (raise_on=...),
-                # aborting AFTER the snapshot published, so the run stays
-                # resumable/inspectable
-                def on_snapshot(host, step, _m=self._health):
-                    self._ingest_probes(_m, host, step)
-            with _ENGINE_TIMER.span("comqueue.execute",
-                                    labels={"program": cache_status}):
-                stacked, ck_info = recovery.drive(
-                    ck, first=first, cont=cont, parts=parts, bcast=bcast,
-                    max_iter=max_iter, signature=signature, resumed=resumed,
-                    on_snapshot=on_snapshot, donate=donate,
-                    on_boundary=on_boundary)
-            # chunked path: the program runs once per chunk, so only the
-            # STATIC cost gauges are meaningful (no exec_t0 -> no achieved
-            # rates; see _finish)
-            return self._finish(stacked, nw, totals, manifest, parts, bcast,
-                                mx, ck_info, cost=cost,
-                                prog_label=_program_label(self._program_key)
-                                if self._program_key is not None else None,
-                                probes_on=probes_on)
+    def _exec_chunked(self, bodies: _StepBodies, parts, totals, bcast,
+                      splan, ckey, mx: bool):
+        """Durable chunked execution (engine/recovery.py): the ``first``
+        + ``cont`` pair, a host boundary every ``every`` supersteps."""
+        import jax
+        import jax.numpy as jnp
+
+        from ..common import aotcache
+        from ..common import plan as planlib
+        from . import recovery
+        if jax.process_count() > 1:
+            raise NotImplementedError(
+                "comqueue checkpointing is single-process for now: the "
+                "per-boundary carry fetch would need a multihost "
+                "allgather + single-writer election")
+        ck = self._ckpt
+        on_boundary = None
+        if self._boundary is not None:
+            # boundary-driven chunking (tuning sweep rungs): the hook
+            # cadence overrides the snapshot cadence — the sweep
+            # aligns both, and a hook without set_checkpoint runs the
+            # chunk programs with persistence off (directory=None)
+            b_every, on_boundary = self._boundary
+            if ck is None:
+                ck = recovery.CheckpointConfig(directory=None,
+                                               every=b_every)
+            elif int(ck.every) != b_every:
+                import dataclasses
+                ck = dataclasses.replace(ck, every=b_every)
+        ckkey = ("__ckpt__", ckey) if ckey is not None else None
+        site = _program_label(self._program_key) \
+            if self._program_key is not None else None
+        cplan = splan.extend(("checkpoint_chunked", True))
+        aot = ()
+        if ckkey is not None and aotcache.active():
+            # the pair ships as two artifacts keyed off the same plan
+            # with a role dim
+            aot = ((cplan.extend(("role", "first")),
+                    ("shard", "repl", "repl")),
+                   (cplan.extend(("role", "cont")),
+                    ("shard", "repl", "shard", "repl")))
+        (first, cont), cache_status, manifest = _lookup_programs(
+            "engine.chunked", ckkey, cplan, bodies.jit_chunks,
+            bodies.manifest, site=site, mesh=bodies.mesh, mx=mx, aot=aot)
+        lim0 = jnp.asarray(bodies.max_iter, jnp.int32)
+        if cache_status == "miss" and aot:
+            # export BEFORE recovery.drive: export's trace runs the
+            # superstep closures, so the collective manifest is
+            # populated by the time the header snapshots it.  Gate the
+            # cont store on the first: a half pair on disk would never
+            # install
+            if aotcache.store(aot[0][0], first, (parts, bcast, lim0),
+                              cache="engine.chunked", site=site,
+                              manifest=manifest):
+                carry_av = jax.eval_shape(first, parts, bcast, lim0)
+                aotcache.store(aot[1][0], cont,
+                               (parts, bcast, carry_av, lim0),
+                               cache="engine.chunked", site=site,
+                               manifest=manifest)
+        cost = _maybe_cost(ckkey, lambda: first.lower(parts, bcast, lim0))
+        if ck.directory or ck.resume_from:
+            part_sig = tuple(
+                (k, tuple(map(int, np.shape(parts[k]))),
+                 str(getattr(parts[k], "dtype", "?")))
+                for k in sorted(parts))
+            # fingerprint the ORIGINAL (pre-padding, host-side)
+            # inputs: np arrays hash by content, device-resident
+            # arrays degrade to shape/dtype tokens (no forced
+            # device->host round trip). Memoized per queue instance
+            # (invalidated by init_with_*): repeated exec() on the
+            # same queue must not re-hash the whole dataset per
+            # program-cache hit
+            data_token = self._data_token
+            if data_token is None:
+                data_token = self._data_token = _freeze_closure_value(
+                    {"parts": dict(self._partitioned),
+                     "bcast": dict(self._broadcast)}, 3)
+            # the durable-run signature derives from the SAME plan
+            # as the program-cache key (content identical to the
+            # historical direct program_signature call — old
+            # snapshots stay resumable)
+            signature = planlib.engine_checkpoint_signature(
+                splan, part_sig=part_sig, data_token=data_token)
+            resumed = recovery.resume_state(ck, signature)
+        else:
+            # boundary-only chunking (set_boundary without a
+            # checkpoint dir): nothing persists and nothing resumes,
+            # so content-hashing the whole dataset for a signature
+            # no snapshot will ever carry is pure waste
+            signature, resumed = None, None
+        on_snapshot = None
+        if self._health is not None and bodies.probes_on:
+            # mid-run watchdog: evaluate on the carry the boundary
+            # save just fetched — zero extra device->host traffic.
+            # evaluate() may raise HealthAlertError (raise_on=...),
+            # aborting AFTER the snapshot published, so the run stays
+            # resumable/inspectable
+            def on_snapshot(host, step, _m=self._health):
+                self._ingest_probes(_m, host, step)
+        with _ENGINE_TIMER.span("comqueue.execute",
+                                labels={"program": cache_status}):
+            stacked, ck_info = recovery.drive(
+                ck, first=first, cont=cont, parts=parts, bcast=bcast,
+                max_iter=bodies.max_iter, signature=signature,
+                resumed=resumed, on_snapshot=on_snapshot,
+                donate=bodies.donate, on_boundary=on_boundary)
+        # chunked path: the program runs once per chunk, so only the
+        # STATIC cost gauges are meaningful (no exec_t0 -> no achieved
+        # rates; see _finish)
+        return self._finish(stacked, bodies.nw, totals, manifest, parts,
+                            bcast, mx, ck_info, cost=cost,
+                            prog_label=site,
+                            probes_on=bodies.probes_on)
+
+    def _exec_plain(self, bodies: _StepBodies, parts, totals, bcast,
+                    splan, ckey, mx: bool):
+        """The whole superstep loop as ONE program, dispatched once."""
+        import jax
+
+        from ..common import aotcache, compileledger
         from ..common.metrics import env_flag
         verify = env_flag("ALINK_VERIFY_PROGRAM_CACHE", default=False)
-        if ckey is not None:
-            compileledger.register_cache("engine.program", "engine",
-                                         _PROGRAM_CACHE_MAX)
-            compiled = _PROGRAM_CACHE.get(ckey)
-        # verify mode is excluded: it compares fresh jaxprs against the
-        # trace recorded at compile time, and a deserialized program has
-        # no trace to baseline against
-        aot_plain = (ckey is not None and not verify
-                     and jax.process_count() == 1 and aotcache.active())
-        disk_hit = False
-        if compiled is None and aot_plain:
-            loaded = aotcache.load(splan, cache="engine.program",
-                                   site=_program_label(self._program_key),
-                                   subsystem="engine")
-            if loaded is not None:
-                compiled = _AotMeshCall(loaded.fn, mesh, ("shard", "repl"))
-                disk_hit = True
-                cache_status = "disk-hit"
-                _PROGRAM_CACHE_STATS["hits"] += 1
-                _PROGRAM_CACHE[ckey] = compiled
-                # deserialized programs never trace, so the collective
-                # manifest comes from the artifact header
-                _m = loaded.manifest(None)
-                if isinstance(_m, dict) and _m:
-                    manifest.update(_m)
-                _PROGRAM_CACHE_MANIFESTS[ckey] = manifest
-                while len(_PROGRAM_CACHE) > _PROGRAM_CACHE_MAX:
-                    old_key, _ = _PROGRAM_CACHE.popitem(last=False)
-                    _PROGRAM_CACHE_JAXPRS.pop(old_key, None)
-                    _PROGRAM_CACHE_MANIFESTS.pop(old_key, None)
-                    _PROGRAM_CACHE_COSTS.pop(old_key, None)
-                    compileledger.record_eviction(
-                        "engine.chunked"
-                        if old_key and old_key[0] == "__ckpt__"
-                        else "engine.program")
-        if compiled is None:
-            compiled = jax.jit(build_mapped())
-            if ckey is not None:
-                cache_status = "miss"
-                _PROGRAM_CACHE_STATS["misses"] += 1
-                _PROGRAM_CACHE[ckey] = compiled
-                # ledger event at insert time; the trace+compile wall is
-                # only observable around the first dispatch (jit is
-                # lazy) — note_wall below attaches it
-                compileledger.record_event(
-                    "engine.program", splan,
-                    site=_program_label(self._program_key),
-                    subsystem="engine")
-                # the cached program's superstep closure writes into THIS
-                # manifest dict; store it so later cache-hit execs can
-                # read the per-superstep collective capture
-                _PROGRAM_CACHE_MANIFESTS[ckey] = manifest
-                if verify:
-                    # baseline jaxpr recorded AT COMPILE TIME, so the very
-                    # first post-compile drift is caught on the next hit
-                    _PROGRAM_CACHE_JAXPRS[ckey] = str(
-                        jax.make_jaxpr(build_mapped())(parts, bcast))
-                while len(_PROGRAM_CACHE) > _PROGRAM_CACHE_MAX:
-                    old_key, _ = _PROGRAM_CACHE.popitem(last=False)
-                    _PROGRAM_CACHE_JAXPRS.pop(old_key, None)
-                    _PROGRAM_CACHE_MANIFESTS.pop(old_key, None)
-                    _PROGRAM_CACHE_COSTS.pop(old_key, None)
-                    compileledger.record_eviction(
-                        "engine.chunked"
-                        if old_key and old_key[0] == "__ckpt__"
-                        else "engine.program")
-        elif ckey is not None and not disk_hit:
-            cache_status = "hit"
-            _PROGRAM_CACHE_STATS["hits"] += 1
-            _PROGRAM_CACHE.move_to_end(ckey)
-            compileledger.record_hit("engine.program")
-            # the cached closure traces into the manifest stored at miss
-            # time, not this exec's local dict — read from the stored one
-            manifest = _PROGRAM_CACHE_MANIFESTS.setdefault(ckey, manifest)
-            if verify:
-                # debug mode: re-trace on every hit and compare jaxprs —
-                # catches any constant the structural guard cannot see
-                fresh = str(jax.make_jaxpr(build_mapped())(parts, bcast))
-                seen = _PROGRAM_CACHE_JAXPRS.setdefault(ckey, fresh)
-                if fresh != seen:
-                    raise RuntimeError(
-                        "ALINK_VERIFY_PROGRAM_CACHE: cached program for key "
-                        f"{self._program_key!r} no longer matches a fresh "
-                        "trace — a stage closure baked state the program_key "
-                        "does not cover")
-        if mx and ckey is not None:
-            get_registry().inc("alink_comqueue_program_cache_total", 1,
-                               {"result": cache_status})
-        if ckey is not None:
-            trace_instant("comqueue.program_cache", cat="engine",
-                          args={"result": cache_status})
+        site = _program_label(self._program_key) \
+            if self._program_key is not None else None
+        # verify mode is excluded from the AOT store: it compares fresh
+        # jaxprs against the trace recorded at compile time, and a
+        # deserialized program has no trace to baseline against
+        aot = ()
+        if (ckey is not None and not verify and jax.process_count() == 1
+                and aotcache.active()):
+            aot = ((splan, ("shard", "repl")),)
+        (compiled,), cache_status, manifest = _lookup_programs(
+            "engine.program", ckey, splan,
+            lambda: (jax.jit(bodies.mapped()),), bodies.manifest,
+            site=site, mesh=bodies.mesh, mx=mx, aot=aot,
+            fresh_jaxpr=(lambda: str(jax.make_jaxpr(bodies.mapped())(
+                parts, bcast))) if verify else None)
         cost = _maybe_cost(ckey, lambda: compiled.lower(parts, bcast))
         exec_t0 = time.perf_counter()
         with _ENGINE_TIMER.span("comqueue.execute",
@@ -1379,15 +1375,13 @@ class IterativeComQueue:
                     jax.block_until_ready(stacked)
                     pw.device(time.perf_counter() - _pt1)
         hbm_snapshot("comqueue.exec")
-        if cache_status == "miss" and aot_plain:
+        if cache_status == "miss" and aot:
             # persist off the hot path, after the first dispatch: the
             # export re-trace refreshes the same manifest dict the miss
             # installed (superstep capture is overwrite-safe)
             aotcache.store(splan, compiled, (parts, bcast),
-                           cache="engine.program",
-                           site=_program_label(self._program_key),
-                           manifest=_PROGRAM_CACHE_MANIFESTS.get(
-                               ckey, manifest))
+                           cache="engine.program", site=site,
+                           manifest=manifest)
         if jax.process_count() > 1:
             # multi-host session: leaves span non-addressable devices —
             # gather every worker's shard to every host before fetching
@@ -1397,11 +1391,10 @@ class IterativeComQueue:
                 lambda x: np.asarray(
                     multihost_utils.process_allgather(x, tiled=True)),
                 stacked)
-        return self._finish(stacked, nw, totals, manifest, parts, bcast,
-                            mx, None, cost=cost, exec_t0=exec_t0,
-                            prog_label=_program_label(self._program_key)
-                            if self._program_key is not None else None,
-                            probes_on=probes_on)
+        return self._finish(stacked, bodies.nw, totals, manifest, parts,
+                            bcast, mx, None, cost=cost, exec_t0=exec_t0,
+                            prog_label=site,
+                            probes_on=bodies.probes_on)
 
     @staticmethod
     def _ingest_probes(monitor, host, step):
@@ -1473,9 +1466,6 @@ class IterativeComQueue:
             # supersteps (the body is TRACED even for runs whose criterion
             # stops at step 1, so it must not be charged for supersteps it
             # never ran)
-            # charge the captured manifests through the ONE fused-aware
-            # replay helper (records are 3-tuples, or 4-tuples carrying
-            # fused-group membership — communication.record_manifest)
             if per is not None:
                 from .communication import record_manifest
                 if init_runs > 0:

@@ -65,21 +65,7 @@ class ComContext:
     # -- state -----------------------------------------------------------
     def get_obj(self, name: str):
         if name in self._carry:
-            v = self._carry[name]
-            # collective fusion (ALINK_TPU_FUSE_COLLECTIVES): a deferred
-            # reduction stored by a communicate stage materializes on
-            # first READ — flushing every independent pending collective
-            # as one fused op — so trainer code always receives real
-            # traced values, never proxies (jnp coverage of foreign
-            # array-likes is partial; see communication._Deferred)
-            from .communication import (_Deferred, active_fusion_scope,
-                                        resolve_deferred)
-            if active_fusion_scope() is not None and any(
-                    isinstance(leaf, _Deferred)
-                    for leaf in jax.tree_util.tree_leaves(
-                        v, is_leaf=lambda x: isinstance(x, _Deferred))):
-                self._carry[name] = v = resolve_deferred(v)
-            return v
+            return self._carry[name]
         if name in self._static:
             return self._static[name]
         raise KeyError(f"ComContext: no object '{name}' "
@@ -154,15 +140,7 @@ class ComContext:
         for the common in-stage case; the stage-based ``AllReduce`` class
         remains for queue-structured use)."""
         # late import: communication imports this module at load time
-        from .communication import (active_fusion_scope, payload_nbytes,
-                                    record_collective)
-        scope = active_fusion_scope()
-        if scope is not None:
-            # deferred (ALINK_TPU_FUSE_COLLECTIVES): back-to-back inline
-            # psums (LDA's sstats pairs) coalesce into one collective
-            return scope.defer_reduce("sum", value, self.AXIS, "<inline>",
-                                      self._num_workers,
-                                      kind_label="InlineAllReduce")
+        from .communication import payload_nbytes, record_collective
         record_collective("InlineAllReduce", "<inline>",
                           payload_nbytes(value), self._num_workers)
         return jax.tree_util.tree_map(

@@ -99,8 +99,7 @@ def program_signature(*, num_workers: int, max_iter: int, seed: int,
                       part_sig: Tuple, bcast_names: Tuple,
                       stages_digest: Any,
                       data_token: Any = None,
-                      probes_on: bool = False,
-                      fuse_collectives: bool = False) -> Dict[str, Any]:
+                      probes_on: bool = False) -> Dict[str, Any]:
     """JSON identity of the compiled superstep program a snapshot belongs
     to. A resume target must match exactly: same worker count, same input
     geometry, same stage structure — otherwise the carry pytree would be
@@ -125,12 +124,6 @@ def program_signature(*, num_workers: int, max_iter: int, seed: int,
         # must not resume a probed program (and vice versa). Emitted only
         # when on, so pre-health snapshots stay resumable unchanged.
         sig["health_probes"] = True
-    if fuse_collectives:
-        # fused programs produce bitwise-identical carries, but the
-        # compiled program a resume re-enters is structurally different
-        # (flattened psum lanes); refuse cross-flag resumes conservatively.
-        # Emitted only when on, so pre-fusion snapshots stay resumable.
-        sig["fuse_collectives"] = True
     if data_token is not None:
         sig["data_blake2b"] = hashlib.blake2b(
             repr(data_token).encode(), digest_size=12).hexdigest()

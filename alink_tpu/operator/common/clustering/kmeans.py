@@ -425,16 +425,12 @@ def kmeans_parallel_init(X, k: int, seed: int = 0,
             Xs, Ws, None if init else (d2, nearest), new, off, key,
             ctx.task_id * nbl, step == rounds, cap, l)
         pts = _rows_at(Xs, blk, pos)                          # (l, d)
-        # register BOTH gathers before either is consumed: under
-        # ALINK_TPU_FUSE_COLLECTIVES the pair coalesces into one
-        # all-gather (the jnp.asarray coercion materializes the deferred
-        # results at user level — lax.top_k must never see a raw proxy)
         gk = manifest_all_gather(kv, ctx.AXIS, name="kmpp_keys",
                                  num_workers=ctx.num_task)
         gp = manifest_all_gather(pts, ctx.AXIS, name="kmpp_cands",
                                  num_workers=ctx.num_task)
-        gk = jnp.asarray(gk).reshape(-1)
-        gp = jnp.asarray(gp).reshape(-1, d)
+        gk = gk.reshape(-1)
+        gp = gp.reshape(-1, d)
         gv, gi = jax.lax.top_k(gk, l)
         sel = jnp.where(jnp.isfinite(gv)[:, None], gp[gi], cands[0])
         off_w = 1 + (step - 1) * l
@@ -442,7 +438,6 @@ def kmeans_parallel_init(X, k: int, seed: int = 0,
         hi, lo = _split_count(rows)
         tot = ctx.all_reduce_sum(jnp.concatenate(
             [counts, jnp.stack([hi, lo, ranked]).astype(dt)]))
-        tot = jnp.asarray(tot)
         upd = jax.lax.dynamic_update_index_in_dim
         ctx.put_obj("weights", tot[:cap])
         ctx.put_obj("rows", upd(

@@ -156,10 +156,6 @@ def online_lda_train(ids: np.ndarray, cnts: np.ndarray, k: int, V: int,
         gamma, sstats = _e_step(ids_b, cnt_mb, eEb, avec[None, :], kgam, n_inner)
         mb_words = ctx.all_reduce_sum(cnt_mb.sum())
         sstats = ctx.all_reduce_sum(sstats)
-        # materialize after BOTH registered: under fusion the word-count
-        # scalar and the sufficient-statistics matrix ride ONE flattened
-        # psum (2 -> 1); eagerly the asarray is a no-op
-        mb_words, sstats = jnp.asarray(mb_words), jnp.asarray(sstats)
         # natural-gradient step, rescaled minibatch -> corpus
         rho = (tau0 + step) ** (-kappa)
         scale = total_words / jnp.maximum(mb_words, 1.0)
@@ -176,9 +172,7 @@ def online_lda_train(ids: np.ndarray, cnts: np.ndarray, k: int, V: int,
             elt = jax.scipy.special.digamma(gamma) - \
                 jax.scipy.special.digamma(gamma.sum(1, keepdims=True))
             logphat_sum = ctx.all_reduce_sum((elt * valid[:, None]).sum(0))
-            # both registered -> one fused psum under the flag
-            n_sel = jnp.asarray(n_sel)
-            logphat = jnp.asarray(logphat_sum) / jnp.maximum(n_sel, 1.0)
+            logphat = logphat_sum / jnp.maximum(n_sel, 1.0)
             grad = n_sel * (jax.scipy.special.digamma(avec.sum())
                             - jax.scipy.special.digamma(avec) + logphat)
             q = -n_sel * jax.scipy.special.polygamma(1, avec)

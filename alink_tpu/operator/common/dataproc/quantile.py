@@ -60,10 +60,6 @@ def distributed_quantiles(X: np.ndarray, probs: np.ndarray,
                            num_workers=ctx.num_task)
         mn = manifest_pmin(small, ctx.AXIS, name="quantile_min",
                            num_workers=ctx.num_task)
-        # materialize after BOTH registered: under fusion the pmin rides
-        # the pmax lane negated (min(x) == -max(-x), exact for floats),
-        # so the pair lowers as ONE all-reduce (2 -> 1)
-        mx, mn = jnp.asarray(mx), jnp.asarray(mn)
         span = jnp.maximum(mx - mn, 1e-300)
         b = jnp.clip(((Xb - mn) / span * fine_bins).astype(jnp.int32),
                      0, fine_bins - 1)

@@ -249,11 +249,6 @@ def als_train(users: np.ndarray, items: np.ndarray, ratings: np.ndarray,
             A = manifest_psum(A, "d", name="als_eq_A", num_workers=nw)
             b = manifest_psum(b, "d", name="als_eq_b", num_workers=nw)
             cnt = manifest_psum(cnt, "d", name="als_eq_cnt", num_workers=nw)
-        # materialize AFTER all three registered: under
-        # ALINK_TPU_FUSE_COLLECTIVES the asarray flush coalesces the three
-        # normal-equation psums into ONE flattened all-reduce (3 -> 1);
-        # eagerly (and on the psum_scatter branch) it is a no-op
-        A, b, cnt = jnp.asarray(A), jnp.asarray(b), jnp.asarray(cnt)
         A = A[:, unpack].reshape(A.shape[0], rank, rank)      # symmetrize
         A = A + lam * jnp.maximum(cnt, 1.0)[:, None, None] * eye
         # batched unrolled Gauss-Jordan: jnp.linalg.solve's batched LU

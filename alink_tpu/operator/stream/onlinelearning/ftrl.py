@@ -1046,10 +1046,9 @@ class FtrlTrainStreamOp(StreamOperator, HasVectorCol, HasFeatureCols, HasLabelCo
         # keys, so toggling never serves a stale step program; the
         # chained signature folds the availability-PROBED tier, so a
         # probe-demoted drain keeps the flag-off signature and its
-        # snapshots stay interchangeable), ALINK_TPU_DONATE (the (z, n)
-        # buffer-aliasing contract rides every lru key) and the
-        # chained-only ALINK_TPU_FUSE_COLLECTIVES fold — all latched
-        # ONCE at the plan derivation site (common/plan.ftrl_plan, the
+        # snapshots stay interchangeable) and ALINK_TPU_DONATE (the
+        # (z, n) buffer-aliasing contract rides every lru key) — both
+        # latched ONCE at the plan derivation site (common/plan.ftrl_plan, the
         # ENV-KEY-FOLD checked site).  The resume signature derives from
         # the same plan, content-identical to the historical dict
         # (conditional chained-mode keys included), so every

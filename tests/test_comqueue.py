@@ -337,3 +337,212 @@ def test_result_memoize_and_release():
     np.testing.assert_array_equal(res.shards("big"), sh)
     with pytest.raises(KeyError):
         res.shards("x")                 # never fetched -> dropped
+
+
+# -- collectives: one path, what each trainer asks of it --------------------
+
+_COLLECTIVE_OP = r"\b(?:all-reduce|all-gather|reduce-scatter|" \
+                 r"collective-permute|all-to-all)(?:-start)?\("
+
+
+def _superstep_requests(fit):
+    """Run ``fit`` and return ``{program label: (asked, compiled)}`` for
+    every engine program it built: ``asked`` is the ``(kind, name)`` list
+    ONE superstep's trace wrote into the manifest (the loop body's; the
+    init pass's for a queue with no loop), ``compiled`` the number of
+    collective ops in the compiled module against the number the module's
+    traced passes asked for."""
+    import re
+    import alink_tpu.engine.comqueue as cq
+    out = {}
+    orig = cq.IterativeComQueue.exec
+
+    def spy(q):
+        before = set(cq._PROGRAM_CACHE_MANIFESTS)
+        res = orig(q)
+        for key in set(cq._PROGRAM_CACHE_MANIFESTS) - before:
+            (per,) = cq._PROGRAM_CACHE_MANIFESTS[key].values()
+            hlo = q.lowered().compile().as_text()
+            step = per["body"] if q.max_iter > 1 else per["init"]
+            out[cq._program_label(q._program_key)] = (
+                [(kind, name) for kind, name, _ in step],
+                (len(re.findall(_COLLECTIVE_OP, hlo)),
+                 len(per["init"]) + len(per["body"])))
+        return res
+
+    cq.clear_program_cache()
+    cq.IterativeComQueue.exec = spy
+    try:
+        fit()
+    finally:
+        cq.IterativeComQueue.exec = orig
+        cq.clear_program_cache()
+    return out
+
+
+def _optimizer_fit(method, l1=0.0):
+    def fit(env, r):
+        import alink_tpu.operator.common.optim.optimizers as O
+        from alink_tpu.operator.common.optim.objfunc import (
+            LogLossFunc, UnaryLossObjFunc)
+        X = r.randn(48, 5)
+        data = {"X": X, "y": np.where(X[:, 0] > 0, 1.0, -1.0),
+                "w": np.ones(48)}
+        O.optimize(UnaryLossObjFunc(LogLossFunc(), 5, l1=l1, l2=1e-3), data,
+                   O.OptimParams(method=method, max_iter=3, epsilon=0.0), env)
+    return fit
+
+
+def _kmeans_fit(env, r):
+    from alink_tpu.operator.common.clustering.kmeans import kmeans_train
+    kmeans_train(r.randn(64, 3).astype(np.float32), k=3, max_iter=4,
+                 env=env, init="K_MEANS_PARALLEL")
+
+
+def _als_fit(shard_solve):
+    def fit(env, r):
+        from alink_tpu.operator.common.recommendation import als as A
+        A.als_train(r.randint(0, 24, 300), r.randint(0, 16, 300),
+                    (r.rand(300) * 5).astype(np.float32),
+                    A.AlsTrainParams(rank=3, num_iter=3, lambda_reg=0.1,
+                                     shard_solve=shard_solve), env=env)
+    return fit
+
+
+def _fm_fit(env, r):
+    from alink_tpu.operator.common.fm.fm import FmTrainParams, fm_train
+    X = r.randn(64, 8).astype(np.float32)
+    fm_train({"X": X, "y": np.where(X[:, 0] > 0, 1.0, -1.0).astype(
+        np.float32), "w": np.ones(64, np.float32)}, 8,
+        FmTrainParams(num_factors=2, num_epochs=2), env=env)
+
+
+def _word2vec_fit(env, r):
+    from alink_tpu.common.mtable import MTable
+    from alink_tpu.operator.common.nlp.word2vec import (Word2VecParams,
+                                                        word2vec_train)
+    words = [f"w{i}" for i in range(16)]
+    rows = [(" ".join(r.choice(words, 8)),) for _ in range(16)]
+    word2vec_train(MTable(rows, "doc STRING"), "doc",
+                   Word2VecParams(vector_size=4, min_count=1, num_iter=2,
+                                  window=2, batch_size=16), env=env)
+
+
+def _lda_fit(train):
+    def fit(env, r):
+        from alink_tpu.operator.common.clustering import lda
+        getattr(lda, train)(r.randint(0, 12, (8, 6)).astype(np.int32),
+                            np.ones((8, 6), np.float64), k=2, V=12,
+                            num_iter=2, env=env)
+    return fit
+
+
+def _quantile_fit(env, r):
+    from alink_tpu.operator.common.dataproc.quantile import (
+        distributed_quantiles)
+    distributed_quantiles(r.randn(128, 3), np.array([0.25, 0.5, 0.75]),
+                          env=env)
+
+
+def _gbdt_fit(env, r):
+    from alink_tpu.operator.common.tree.trainers import (TreeTrainParams,
+                                                         gbdt_train)
+    X = r.randn(64, 4).astype(np.float32)
+    gbdt_train(X, (X[:, 0] > 0).astype(np.float32),
+               TreeTrainParams(num_trees=2, max_depth=3, n_bins=8), False, env)
+
+
+_QN = [("AllReduce", "glw"), ("AllReduce", "line_losses")]
+_ALS_EQ = [("AllReduce", "als_eq_A"), ("AllReduce", "als_eq_b"),
+           ("AllReduce", "als_eq_cnt")]
+_ALS_EQ_SHARDED = [("ReduceScatter", "als_eq_A"),
+                   ("ReduceScatter", "als_eq_b"),
+                   ("ReduceScatter", "als_eq_cnt"),
+                   ("AllGather", "als_factors")]
+_INLINE = ("InlineAllReduce", "<inline>")
+
+# trainer -> (fit, {program label: the (kind, name) list of ONE superstep})
+_SUPERSTEP_COLLECTIVES = {
+    # the line-search loss needs the direction built from the psummed
+    # gradient: dependency-forced, 2 a superstep whatever the compiler does
+    "lbfgs": (_optimizer_fit("LBFGS"), {"qn": _QN}),
+    "owlqn": (_optimizer_fit("OWLQN", l1=1e-3), {"qn": _QN}),
+    "newton": (_optimizer_fit("Newton"),
+               {"newton": [("AllReduce", "H"), ("AllReduce", "glw")]}),
+    "kmeans": (_kmeans_fit, {
+        "kmeans_init": [("AllGather", "kmpp_keys"),
+                        ("AllGather", "kmpp_cands"), _INLINE],
+        "kmeans_lloyd": [("AllReduce", "buf")]}),
+    # two half-sweeps of normal equations, then the rmse
+    "als": (_als_fit(False),
+            {"als": _ALS_EQ + _ALS_EQ + [("AllReduce", "als_rmse")]}),
+    "als_shard_solve": (_als_fit(True), {
+        "als": _ALS_EQ_SHARDED + _ALS_EQ_SHARDED
+        + [("AllReduce", "als_rmse")]}),
+    "fm": (_fm_fit, {"fm": [("AllReduce", "avg"), ("AllReduce", "lw")]}),
+    "word2vec": (_word2vec_fit, {"w2v": [("AllReduce", "emb")]}),
+    "lda_online": (_lda_fit("online_lda_train"),
+                   {"lda_online": [_INLINE] * 5}),
+    "lda_gibbs": (_lda_fit("gibbs_lda_train"), {"lda_gibbs": [_INLINE]}),
+    "quantile": (_quantile_fit, {"quantile_hist": [
+        ("AllReduce", "quantile_max"), ("AllReduce", "quantile_min"),
+        _INLINE]}),
+    # a histogram a level (max_depth 3), the leaves, the loss
+    "gbdt": (_gbdt_fit, {"gbdt": [("AllReduce", "tree_hist")] * 3 + [
+        ("AllReduce", "tree_leaf_hist"), ("AllReduce", "gbdt_loss")]}),
+}
+
+
+@pytest.mark.parametrize("trainer", sorted(_SUPERSTEP_COLLECTIVES))
+def test_superstep_collectives_requested(trainer):
+    """What each iterative trainer ASKS of the interconnect in one
+    superstep on a 4-device mesh: the record a multi-chip cell is planned
+    from, and one no compiler upgrade moves. The compiled module may hold
+    fewer collectives than asked (XLA's combiner merges independent
+    ones), never more."""
+    from alink_tpu.common.mlenv import MLEnvironment
+    fit, want = _SUPERSTEP_COLLECTIVES[trainer]
+    env = MLEnvironment(parallelism=4, devices=jax.devices()[:4])
+    got = _superstep_requests(lambda: fit(env, np.random.RandomState(0)))
+    assert {k: asked for k, (asked, _) in got.items()} == want
+    for label, (_, (compiled, asked_in_module)) in got.items():
+        assert 0 < compiled <= asked_in_module, (label, compiled)
+
+
+_RAW_COLLECTIVES = {
+    "psum": (lambda x: jax.lax.psum(x, "d"), "AllReduce", {}),
+    "pmax": (lambda x: jax.lax.pmax(x, "d"), "AllReduce", {}),
+    "pmin": (lambda x: jax.lax.pmin(x, "d"), "AllReduce", {}),
+    "all_gather": (lambda x: jax.lax.all_gather(x, "d", axis=0, tiled=True),
+                   "AllGather", {"axis": 0, "tiled": True}),
+    "psum_scatter": (lambda x: jax.lax.psum_scatter(
+        x, "d", scatter_dimension=0, tiled=True),
+        "ReduceScatter", {"scatter_dimension": 0, "tiled": True}),
+}
+
+
+@pytest.mark.parametrize("op", sorted(_RAW_COLLECTIVES))
+def test_manifest_wrapper_lowers_to_raw_op(op):
+    """Each ``manifest_*`` wrapper is the raw ``lax`` op plus exactly one
+    ``(kind, name, bytes)`` record: the lowered text is the same."""
+    from jax.sharding import Mesh, PartitionSpec as P
+    from alink_tpu.common.compat import shard_map
+    from alink_tpu.engine import communication as comm
+    raw, kind, kwargs = _RAW_COLLECTIVES[op]
+    wrapper = getattr(comm, "manifest_" + op)
+    mesh = Mesh(np.array(jax.devices()[:4]), ("d",))
+    x = np.ones((16, 3), np.float32)
+
+    def lowered(body):
+        def f(x):
+            return body(x)
+        return jax.jit(shard_map(f, mesh=mesh, in_specs=(P("d"),),
+                                 out_specs=P("d"), check_vma=False)
+                       ).lower(x).as_text()
+
+    manifest = []
+    with comm.collecting(manifest):
+        wrapped = lowered(lambda x: wrapper(x, "d", name="v", num_workers=4,
+                                            **kwargs))
+    assert wrapped == lowered(raw)
+    assert manifest == [(kind, "v", 4 * 3 * 4 * 4)]  # a (4, 3) f32 shard x 4

@@ -114,16 +114,16 @@ class TestDigest:
 
 class TestEnginePlan:
     def test_legacy_key_reproduces_historical_13_tuple(self):
-        """The exact pre-ISSUE-19 ckey shape, order and values:
+        """The pre-ISSUE-19 ckey shape, order and values, less the
+        collective-fusion slot ISSUE 29 took out (12 positions now):
 
             (program_key, stages_dig, mesh, nw, max_iter, seed,
-             criterion?, step_log, probes, donate, fuse,
+             criterion?, step_log, probes, donate,
              sorted(parts), sorted(bcast))
         """
         flags = (("ALINK_TPU_STEP_LOG", False),
                  ("ALINK_TPU_HEALTH", True),
-                 ("ALINK_TPU_DONATE", True),
-                 ("ALINK_TPU_FUSE_COLLECTIVES", False))
+                 ("ALINK_TPU_DONATE", True))
         mesh = object()   # identity-keyed, exactly like the legacy tuple
         p = planlib.engine_plan(
             program_key=("lr", 5), stages_digest="digest123", mesh=mesh,
@@ -131,27 +131,24 @@ class TestEnginePlan:
             flags=flags, part_names=("a", "train"), bcast_names=("b0",))
         assert p.legacy_key() == (
             ("lr", 5), "digest123", mesh, 4, 10, 7,
-            True, False, True, True, False, ("a", "train"), ("b0",))
+            True, False, True, True, ("a", "train"), ("b0",))
 
     def test_live_flags_match_accessors(self):
         from alink_tpu.common.health import health_enabled
         from alink_tpu.common.profiling import step_log_enabled
-        from alink_tpu.engine.communication import fusion_enabled
         from alink_tpu.engine.comqueue import donation_enabled
         flags = dict(planlib.engine_flags())
         assert flags == {
             "ALINK_TPU_STEP_LOG": step_log_enabled(),
             "ALINK_TPU_HEALTH": health_enabled(),
             "ALINK_TPU_DONATE": donation_enabled(),
-            "ALINK_TPU_FUSE_COLLECTIVES": fusion_enabled(),
         }
 
     def test_checkpoint_signature_content_identical(self):
         from alink_tpu.engine import recovery
         flags = (("ALINK_TPU_STEP_LOG", False),
                  ("ALINK_TPU_HEALTH", True),
-                 ("ALINK_TPU_DONATE", False),
-                 ("ALINK_TPU_FUSE_COLLECTIVES", True))
+                 ("ALINK_TPU_DONATE", False))
         p = planlib.engine_plan(
             program_key=None, stages_digest="sd", mesh=None,
             num_workers=2, max_iter=3, seed=9, has_criterion=False,
@@ -162,8 +159,16 @@ class TestEnginePlan:
             num_workers=2, max_iter=3, seed=9,
             part_sig=(("train", (8, 2)),), bcast_names=("w",),
             stages_digest="sd", data_token="tok",
-            probes_on=True, fuse_collectives=True)
+            probes_on=True)
         assert got == want
+        # what the commit before ISSUE 29 wrote into a snapshot for this
+        # plan (recorded there): a run checkpointed then resumes now
+        assert got == {
+            "kind": "comqueue_carry", "num_workers": 2, "max_iter": 3,
+            "seed": 9, "parts": [["train", "(8, 2)"]], "bcast": ["w"],
+            "stages_blake2b": "0d981ed3936c5a4633a22848",
+            "health_probes": True,
+            "data_blake2b": "62d0ff5ca76d04a362551c5e"}
 
 
 # ---------------------------------------------------------------------------
@@ -172,8 +177,7 @@ class TestEnginePlan:
 
 def _legacy_ftrl_signature(*, alpha, beta, l1, l2, dim, dim_pad,
                            update_mode, staleness, chunk_size,
-                           has_icpt, warm_fp, kern_resolved_pallas,
-                           fuse):
+                           has_icpt, warm_fp, kern_resolved_pallas):
     """The pre-ISSUE-19 hand-built ck_signature, verbatim."""
     sig = {"kind": "ftrl_state", "alpha": alpha, "beta": beta,
            "l1": l1, "l2": l2, "dim": dim, "dim_pad": dim_pad,
@@ -186,8 +190,6 @@ def _legacy_ftrl_signature(*, alpha, beta, l1, l2, dim, dim_pad,
         sig["chunk_size"] = chunk_size
         if kern_resolved_pallas:
             sig["ftrl_kernel"] = "pallas"
-        if fuse:
-            sig["fuse_collectives"] = True
     return sig
 
 
@@ -195,7 +197,6 @@ class TestFtrlPlan:
     @pytest.mark.parametrize("mode", ["dense", "staleness", "chained"])
     def test_signature_content_identical(self, mode, monkeypatch):
         monkeypatch.delenv("ALINK_TPU_FTRL_KERNEL", raising=False)
-        monkeypatch.delenv("ALINK_TPU_FUSE_COLLECTIVES", raising=False)
         kw = dict(alpha=0.1, beta=1.0, l1=0.01, l2=0.05, dim=33,
                   dim_pad=64, update_mode=mode, staleness=4,
                   chunk_size=128)
@@ -203,23 +204,8 @@ class TestFtrlPlan:
                               warm_fp="abc123", **kw)
         want = _legacy_ftrl_signature(
             has_icpt=True, warm_fp="abc123",
-            kern_resolved_pallas=False, fuse=False, **kw)
+            kern_resolved_pallas=False, **kw)
         assert planlib.ftrl_checkpoint_signature(p) == want
-
-    def test_chained_fuse_folds_conditionally(self, monkeypatch):
-        monkeypatch.delenv("ALINK_TPU_FTRL_KERNEL", raising=False)
-        monkeypatch.setenv("ALINK_TPU_FUSE_COLLECTIVES", "1")
-        kw = dict(mesh=None, alpha=0.1, beta=1.0, l1=0.0, l2=0.0,
-                  dim=8, dim_pad=8, staleness=0, chunk_size=64,
-                  has_intercept=False, warm_fp="x")
-        chained = planlib.ftrl_plan(update_mode="chained", **kw)
-        assert planlib.ftrl_checkpoint_signature(
-            chained).get("fuse_collectives") is True
-        dense = planlib.ftrl_plan(update_mode="dense", **kw)
-        assert "fuse_collectives" not in \
-            planlib.ftrl_checkpoint_signature(dense)
-        assert "chunk_size" not in \
-            planlib.ftrl_checkpoint_signature(dense)
 
 
 # ---------------------------------------------------------------------------

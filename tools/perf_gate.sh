@@ -27,13 +27,6 @@ cd "$(dirname "$0")/.."
 # tools/lint_baseline.json with written justifications.
 python -m tools.lint --strict
 
-# >=4-device fusion smoke (ISSUE 9): one fresh 4-virtual-device child
-# runs kmeans + Newton fused (ALINK_TPU_FUSE_COLLECTIVES=1) and unfused,
-# asserting bitwise-identical results and the compiled all-reduce count
-# drop (2 -> 1 per superstep) — the sharded/fused path cannot rot on
-# CPU-only rigs even though the default bench leg runs 1-device.
-python tools/scaling_evidence.py --smoke
-
 # 4-device sharded-serve smoke (ISSUE 11): fresh 1- and 4-device
 # children serve the SAME feature-sharded model through mesh-sharded
 # bucket programs — probe digests must match BITWISE across mesh sizes

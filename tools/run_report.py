@@ -99,27 +99,15 @@ def render(reg: MetricsRegistry, show_all: bool = False) -> str:
              for r in by_name.get("alink_collective_calls_total", [])}
     byts = {r["labels"].get("collective", "?"): r["value"]
             for r in by_name.get("alink_collective_logical_bytes_total", [])}
-    fused = {r["labels"].get("collective", "?"): r["value"]
-             for r in by_name.get("alink_collective_fused_total", [])}
-    fbyts = {r["labels"].get("collective", "?"): r["value"]
-             for r in by_name.get("alink_collective_payload_fused_bytes", [])}
     claimed |= {"alink_collective_calls_total",
-                "alink_collective_logical_bytes_total",
-                "alink_collective_fused_total",
-                "alink_collective_payload_fused_bytes"}
+                "alink_collective_logical_bytes_total"}
     for kind in sorted(set(calls) | set(byts)):
         c = calls.get(kind, 0.0)
         b = byts.get(kind, 0.0)
         crows.append([kind, f"{int(c):,}", _fmt_bytes(b),
-                      _fmt_bytes(b / c) if c else "-",
-                      f"{int(fused.get(kind, 0)):,}",
-                      _fmt_bytes(fbyts.get(kind, 0.0))])
-    out.append(_table(["collective", "calls", "logical bytes", "bytes/call",
-                       "fused calls", "fused bytes"], crows))
-    total_fused = sum(fused.values())
-    if total_fused:
-        out.append(f"  ({int(total_fused):,} collectives were FUSED "
-                   f"multi-buffer payloads — ALINK_TPU_FUSE_COLLECTIVES)")
+                      _fmt_bytes(b / c) if c else "-"])
+    out.append(_table(["collective", "calls", "logical bytes", "bytes/call"],
+                      crows))
 
     # -- host spans (StepTimer mirror) ------------------------------------
     out.append("\n== Host spans (StepTimer) ==")

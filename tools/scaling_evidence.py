@@ -8,20 +8,14 @@ Modes:
                         device meshes (bootenv.cpu_mesh_env — XLA flags
                         latch at backend init, so each device count needs
                         its own process) at 1/4/8 devices, execute the
-                        compiled BSP programs fused
-                        (ALINK_TPU_FUSE_COLLECTIVES=1) and unfused, and
-                        write SCALING_r06.json with measured per-superstep
-                        walltimes, measured superstep efficiency
-                        t(1 dev)/t(p dev) at constant per-device rows,
-                        and the fused-vs-unfused compiled all-reduce
+                        compiled BSP programs, and write SCALING_r06.json
+                        with measured per-superstep walltimes, measured
+                        superstep efficiency t(1 dev)/t(p dev) at constant
+                        per-device rows, and the compiled all-reduce
                         counts for every iterative trainer (logreg,
                         kmeans, ALS, GBDT, FTRL, Word2Vec, FM).
   --projected           the legacy r05 artifact (virtual-mesh audit +
                         ring-model projections), kept for comparison.
-  --smoke               quick ≥4-device fusion gate for tools/perf_gate.sh:
-                        one 4-device child runs kmeans + Newton fused and
-                        unfused, asserts bitwise-identical results AND the
-                        fused all-reduce count drop; exit != 0 on failure.
 
 Legacy r05 evidence (kept under --projected), written to SCALING_r05.json
 and summarized in docs/parallelism.md:
@@ -227,7 +221,7 @@ def build_workloads(env):
     def word2vec_queue():
         # periodic psum of the input/output embedding matrices
         # (Word2VecTrainBatchOp.java:329-342) — the AllReduce(mean) stage
-        # reduces a TWO-leaf pytree, so fusion coalesces 2 -> 1
+        # reduces a TWO-leaf pytree
         from alink_tpu.common.mtable import MTable
         from alink_tpu.operator.common.nlp.word2vec import (Word2VecParams,
                                                             word2vec_train)
@@ -246,7 +240,7 @@ def build_workloads(env):
 
     def fm_queue():
         # FmOptimizer.java:273-295 weighted model average: AllReduce(avg)
-        # + AllReduce(lw) adjacent stages — fused 2 -> 1
+        # + AllReduce(lw) adjacent stages
         from alink_tpu.operator.common.fm.fm import FmTrainParams, fm_train
         n = per_dev * nw
         rr = np.random.RandomState(0)
@@ -472,7 +466,7 @@ def weak_scaling(env_sizes):
 MEASURED_DEVICE_COUNTS = (1, 4, 8)
 
 
-def _measure_child(n_devices: int, fused: bool, with_audit: bool) -> dict:
+def _measure_child(n_devices: int, with_audit: bool) -> dict:
     """Runs INSIDE a child interpreter whose backend was launched with
     ``--xla_force_host_platform_device_count=n_devices``: executes the
     real compiled BSP programs over the n-device mesh and returns
@@ -486,8 +480,7 @@ def _measure_child(n_devices: int, fused: bool, with_audit: bool) -> dict:
     env = MLEnvironment(parallelism=n_devices,
                         devices=jax.devices()[:n_devices])
     per_dev = 256
-    out = {"n_devices": n_devices,
-           "fused": bool(fused), "workloads": {}}
+    out = {"n_devices": n_devices, "workloads": {}}
 
     def timed_queue(name, build_exec, steps_of):
         """exec twice (compile, then cached) and record the cached run's
@@ -524,7 +517,7 @@ def _measure_child(n_devices: int, fused: bool, with_audit: bool) -> dict:
         return steps
     timed_queue("logreg_criteo", logreg_exec, lambda s: s)
 
-    # kmeans (the r05 weak-scaling workload, now measured fused/unfused)
+    # kmeans (the r05 weak-scaling workload)
     def kmeans_exec():
         build = build_workloads(env)["kmeans"]
         res = build().exec()
@@ -582,8 +575,7 @@ def _measure_child(n_devices: int, fused: bool, with_audit: bool) -> dict:
     return out
 
 
-def _spawn_child(n_devices: int, args: list, fused: bool,
-                 timeout: int = 1800) -> dict:
+def _spawn_child(n_devices: int, args: list, timeout: int = 1800) -> dict:
     """Re-invoke this tool in a fresh interpreter on an n-device
     host-platform CPU mesh (XLA flags latch at backend init — bootenv)."""
     import subprocess
@@ -593,14 +585,13 @@ def _spawn_child(n_devices: int, args: list, fused: bool,
     from bootenv import cpu_mesh_env
     envv = cpu_mesh_env(n_devices)
     envv["PYTHONPATH"] = repo_root + os.pathsep + envv.get("PYTHONPATH", "")
-    envv["ALINK_TPU_FUSE_COLLECTIVES"] = "1" if fused else "0"
     envv["ALINK_TPU_METRICS"] = "0"       # timing children: no registry noise
     p = subprocess.run(
         [sys.executable, os.path.abspath(__file__)] + args,
         env=envv, cwd=repo_root, capture_output=True, timeout=timeout)
     if p.returncode != 0:
         raise RuntimeError(
-            f"scaling child (n={n_devices}, fused={fused}, {args}) failed "
+            f"scaling child (n={n_devices}, {args}) failed "
             f"rc={p.returncode}:\n{p.stdout.decode(errors='replace')[-4000:]}"
             f"\n{p.stderr.decode(errors='replace')[-4000:]}")
     # the child prints exactly one JSON document on its last line
@@ -622,45 +613,31 @@ def _audit_per_superstep(audit_rows: dict) -> dict:
 def measured_main(out_path: str) -> dict:
     """Orchestrate the measured-scaling capture -> SCALING_r06.json."""
     runs = {}
-    for n in MEASURED_DEVICE_COUNTS:
-        for fused in (False, True):
-            with_audit = n == max(MEASURED_DEVICE_COUNTS)
-            child_args = ["--child-measure", str(n)]
-            if with_audit:
-                child_args.append("--with-audit")
-            print(f"[scaling_evidence] measuring n={n} fused={fused} ...",
-                  file=sys.stderr)
-            runs[(n, fused)] = _spawn_child(n, child_args, fused)
-
     nmax = max(MEASURED_DEVICE_COUNTS)
-    audit_unfused = runs[(nmax, False)]["audit"]
-    audit_fused = runs[(nmax, True)]["audit"]
-    per_uf = _audit_per_superstep(audit_unfused)
-    per_f = _audit_per_superstep(audit_fused)
+    for n in MEASURED_DEVICE_COUNTS:
+        child_args = ["--child-measure", str(n)]
+        if n == nmax:
+            child_args.append("--with-audit")
+        print(f"[scaling_evidence] measuring n={n} ...", file=sys.stderr)
+        runs[n] = _spawn_child(n, child_args)
+
+    audit_rows = runs[nmax]["audit"]
 
     workloads = {}
-    names = runs[(MEASURED_DEVICE_COUNTS[0], False)]["workloads"].keys()
-    for name in names:
-        row = {}
-        for n in MEASURED_DEVICE_COUNTS:
-            for fused in (False, True):
-                w = runs[(n, fused)]["workloads"][name]
-                key = f"{n}dev_" + ("fused" if fused else "unfused")
-                row[key] = w
+    for name in runs[MEASURED_DEVICE_COUNTS[0]]["workloads"]:
+        row = {f"{n}dev": runs[n]["workloads"][name]
+               for n in MEASURED_DEVICE_COUNTS}
         # measured superstep efficiency: t(1 dev) / t(p dev) at constant
         # per-device rows — compute/(compute + comm + launch overhead).
         # NOTE the honest caveat: the virtual devices share host cores,
         # so this is a lower bound on real-ICI efficiency for the compute
         # term but a truthful measurement of the collective/launch path.
-        base_key = "superstep_ms" if "superstep_ms" in \
-            row["1dev_unfused"] else "per_micro_batch_ms"
-        for fused in (False, True):
-            lbl = "fused" if fused else "unfused"
-            t1 = row[f"1dev_{lbl}"][base_key]
-            row[f"measured_efficiency_{lbl}"] = {
-                str(n): round(t1 / max(row[f"{n}dev_{lbl}"][base_key],
-                                       1e-9), 4)
-                for n in MEASURED_DEVICE_COUNTS if n > 1}
+        base_key = "superstep_ms" if "superstep_ms" in row["1dev"] \
+            else "per_micro_batch_ms"
+        t1 = row["1dev"][base_key]
+        row["measured_efficiency"] = {
+            str(n): round(t1 / max(row[f"{n}dev"][base_key], 1e-9), 4)
+            for n in MEASURED_DEVICE_COUNTS if n > 1}
         workloads[name] = row
 
     artifact = {
@@ -668,30 +645,25 @@ def measured_main(out_path: str) -> dict:
         "method": "MEASURED multi-device execution: real host-platform "
                   "device meshes (1/4/8 devices, one fresh interpreter "
                   "per count — XLA flags latch at backend init), compiled "
-                  "BSP programs executed fused "
-                  "(ALINK_TPU_FUSE_COLLECTIVES=1) and unfused, walltimes "
-                  "from cached-program runs; collective counts from the "
-                  "compiled HLO of the SAME programs "
-                  "(tools/scaling_evidence.py --measured)",
+                  "BSP programs executed, walltimes from cached-program "
+                  "runs; collective counts from the compiled HLO of the "
+                  "SAME programs (tools/scaling_evidence.py --measured)",
         "supersedes": "SCALING_r05.json — its efficiency numbers were "
                       "PROJECTED from a ring-allreduce model; every "
                       "number here is measured from executing programs",
         "mesh_note": "host-platform virtual devices share the rig's CPU "
                      "cores, so absolute walltimes are not chip times; "
-                     "the fused-vs-unfused deltas and the per-superstep "
-                     "collective counts are the transferable facts (on "
-                     "TPU the same programs run unchanged over ICI)",
+                     "the per-superstep collective counts are the "
+                     "transferable facts (on TPU the same programs run "
+                     "unchanged over ICI)",
         "measured_workloads": workloads,
-        "allreduce_per_superstep": {
-            name: {"unfused": per_uf.get(name), "fused": per_f.get(name)}
-            for name in sorted(set(per_uf) | set(per_f))},
-        "collective_audit_fused": audit_fused,
-        "collective_audit_unfused": audit_unfused,
-        "fusion_dependency_notes": {
-            "logreg_criteo": "stays at 2/superstep: the line-search loss "
-                             "psum consumes the direction built from the "
-                             "psummed gradient — dependency-forced, the "
-                             "accumulator proves it by flushing on read",
+        "allreduce_per_superstep": _audit_per_superstep(audit_rows),
+        "collective_audit": audit_rows,
+        "dependency_notes": {
+            "logreg_criteo": "2/superstep: the line-search loss psum "
+                             "consumes the direction built from the "
+                             "psummed gradient — no combiner can merge "
+                             "them",
             "gbdt_adult_shape": "level-L histogram psum needs level-L-1's "
                                 "split: per-level psums are sequential by "
                                 "construction",
@@ -707,99 +679,6 @@ def measured_main(out_path: str) -> dict:
                       "allreduce_per_superstep":
                           artifact["allreduce_per_superstep"]}, indent=1))
     return artifact
-
-
-# ---------------------------------------------------------------------------
-# ≥4-device fusion smoke (tools/perf_gate.sh leg)
-# ---------------------------------------------------------------------------
-
-def _smoke_child(n_devices: int) -> dict:
-    """Runs inside one n-device child: kmeans + Newton, fused vs unfused
-    — asserts bitwise-identical results and the fused count drop."""
-    import jax
-    from alink_tpu.common.mlenv import MLEnvironment
-    from alink_tpu.engine.comqueue import clear_program_cache
-    env = MLEnvironment(parallelism=n_devices,
-                        devices=jax.devices()[:n_devices])
-    r = np.random.RandomState(0)
-
-    def with_flag(fused, fn):
-        prev = os.environ.get("ALINK_TPU_FUSE_COLLECTIVES")
-        os.environ["ALINK_TPU_FUSE_COLLECTIVES"] = "1" if fused else "0"
-        clear_program_cache()
-        try:
-            return fn()
-        finally:
-            if prev is None:
-                os.environ.pop("ALINK_TPU_FUSE_COLLECTIVES", None)
-            else:
-                os.environ["ALINK_TPU_FUSE_COLLECTIVES"] = prev
-
-    # kmeans: bitwise parity
-    from alink_tpu.operator.common.clustering.kmeans import kmeans_train
-    Xk = r.randn(40 * n_devices, 3).astype(np.float32)
-    c0 = np.asarray(with_flag(False, lambda: kmeans_train(
-        Xk, k=3, max_iter=4, env=env)[0]))
-    c1 = np.asarray(with_flag(True, lambda: kmeans_train(
-        Xk, k=3, max_iter=4, env=env)[0]))
-    assert (c0 == c1).all(), "kmeans fused-vs-unfused results differ"
-
-    # Newton: bitwise parity + compiled all-reduce count 2/superstep -> 1
-    import alink_tpu.operator.common.optim.optimizers as O
-    from alink_tpu.operator.common.optim.objfunc import (LogLossFunc,
-                                                         UnaryLossObjFunc)
-    n = 16 * n_devices
-    X = r.randn(n, 5).astype(np.float64)
-    y = np.where(X[:, 0] > 0, 1.0, -1.0)
-    d = {"X": X, "y": y, "w": np.ones(n)}
-
-    def newton():
-        obj = UnaryLossObjFunc(LogLossFunc(), 5, l2=1e-3)
-        return O.optimize(obj, d,
-                          O.OptimParams(method="Newton", max_iter=3,
-                                        epsilon=0.0), env)[0]
-
-    def newton_hlo():
-        cap = {}
-        import alink_tpu.engine.comqueue as cq
-        orig = cq.IterativeComQueue.exec
-
-        def spy(q):
-            cap["hlo"] = q.lowered().compile().as_text()
-            raise _Captured()
-        cq.IterativeComQueue.exec = spy
-        try:
-            newton()
-        except _Captured:
-            pass
-        finally:
-            cq.IterativeComQueue.exec = orig
-        return cap["hlo"]
-
-    def count_ar(h):
-        return h.count("all-reduce(") + h.count("all-reduce-start(")
-
-    w0 = np.asarray(with_flag(False, newton))
-    w1 = np.asarray(with_flag(True, newton))
-    assert (w0 == w1).all(), "Newton fused-vs-unfused results differ"
-    a0 = with_flag(False, lambda: count_ar(newton_hlo()))
-    a1 = with_flag(True, lambda: count_ar(newton_hlo()))
-    assert a0 == 4 and a1 == 2, (
-        f"Newton compiled all-reduce count expected 4 -> 2 "
-        f"(init+body copies), got {a0} -> {a1}")
-    return {"ok": True, "n_devices": n_devices,
-            "newton_allreduce_unfused": a0, "newton_allreduce_fused": a1}
-
-
-def smoke_main(n_devices: int = 4) -> int:
-    try:
-        res = _spawn_child(n_devices, ["--child-smoke", str(n_devices)],
-                           fused=False, timeout=600)
-    except RuntimeError as e:
-        print(f"scaling_evidence --smoke FAILED:\n{e}", file=sys.stderr)
-        return 1
-    print(f"scaling_evidence --smoke OK: {res}")
-    return 0
 
 
 def projected_main():
@@ -886,37 +765,24 @@ def main(argv=None):
     import argparse
     ap = argparse.ArgumentParser(
         description="Scaling evidence: measured multi-device execution "
-                    "(SCALING_r06) / legacy projections / fusion smoke")
+                    "(SCALING_r06) / legacy projections")
     ap.add_argument("--measured", action="store_true",
                     help="measured capture -> SCALING_r06.json (default)")
     ap.add_argument("--projected", action="store_true",
                     help="legacy r05 projection artifact")
-    ap.add_argument("--smoke", action="store_true",
-                    help="quick >=4-device fusion gate (perf_gate.sh leg)")
     ap.add_argument("--out", default=None, help="artifact path override")
-    ap.add_argument("--smoke-devices", type=int, default=4)
     # internal child entry points (spawned by the orchestrator with an
     # n-device host-platform backend already in XLA_FLAGS)
     ap.add_argument("--child-measure", type=int, default=None,
                     help=argparse.SUPPRESS)
     ap.add_argument("--with-audit", action="store_true",
                     help=argparse.SUPPRESS)
-    ap.add_argument("--child-smoke", type=int, default=None,
-                    help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
 
     if args.child_measure is not None:
-        from alink_tpu.common.flags import env_flag
-        res = _measure_child(args.child_measure,
-                             env_flag("ALINK_TPU_FUSE_COLLECTIVES"),
-                             args.with_audit)
-        print(json.dumps(res))
+        print(json.dumps(_measure_child(args.child_measure,
+                                        args.with_audit)))
         return 0
-    if args.child_smoke is not None:
-        print(json.dumps(_smoke_child(args.child_smoke)))
-        return 0
-    if args.smoke:
-        return smoke_main(args.smoke_devices)
     if args.projected:
         projected_main()
         return 0
