@@ -11,7 +11,11 @@ ONE availability/demotion contract they all ride (``runtime``):
   chained-correction triangular matvec (``ALINK_TPU_FTRL_KERNEL``);
 * ``serve``    — the fused encode-gather -> dot -> link serving score
   kernel (``ALINK_TPU_SERVE_FUSED``) and the opt-in bf16/int8
-  low-precision score path (``ALINK_TPU_SERVE_DTYPE``).
+  low-precision score path (``ALINK_TPU_SERVE_DTYPE``);
+* ``kmeans``   — the k-means|| candidate fold (ISSUE 30): distances,
+  min, argmin and the ``(d2, nearest)`` update of a table block in one
+  streamed pass. It has no flag: a fit takes it where its input allows
+  (``kmeans.fold_path``) and says so (``init_fold``).
 
 Every kernel is parity-pinned against its XLA path (bitwise where the
 contract demands it, pinned tolerance where association differs) and

@@ -40,6 +40,7 @@ from ....common.mlenv import MLEnvironment, MLEnvironmentFactory
 from ....common.tracing import trace_span
 from ....engine import AllReduce, IterativeComQueue
 from ....engine.communication import manifest_all_gather
+from ....kernels.kmeans import fold_candidates, fold_path
 
 #: the engine names each compiled program ``jit_<first word of its key>``
 LLOYD_PROGRAM = "kmeans_lloyd"
@@ -296,39 +297,52 @@ def _topl_fold(run, keys, i):
     return (vals, blk, pos), beats(start)
 
 
-def _kmpp_pass(Xs, Ws, state, new, off, key, block0, last, cap: int, l: int):
-    """One k-means|| round over a worker's shard, block by block: fold the
-    candidates ``new`` (numbered from ``off``) into the per-row ``(d2,
-    nearest)`` state (``state`` is ``None`` in the first round, which
-    makes it), draw the shard's ``l`` proposals by Gumbel-top-l over
-    p ∝ d2 — a running best ``l`` carried across the blocks
-    (``_topl_fold``), so a block is ranked only when one of its keys can
-    still win — and, in the ``last`` round, sum the row weights under
-    each of the ``cap`` candidates. Returns ``(d2, nearest, proposals
-    (keys (l,), block (l,), position (l,)), blocks ranked, rows seen,
-    candidate weights (cap,))``."""
-    nbl = Xs.shape[0]
-    dt = Xs.dtype
-    ids = jnp.arange(cap, dtype=jnp.int32)[:, None, None]
-    fresh = state is None
-    if fresh:
-        state = (jnp.zeros(Ws.shape, dt), jnp.zeros(Ws.shape, jnp.int32))
+def _kmpp_fold(Xs, Ws, d2, nearest, new, off, path: str):
+    """Fold a round's candidates ``new`` ``(l or 1, d)``, numbered from
+    ``off``, into a shard's per-row ``(d2, nearest)`` state: a row whose
+    distance to the nearest of them is under its ``d2`` takes that
+    distance and that candidate; a row of weight 0 reads distance 0. The
+    first round is the same fold on ``d2 = +inf``, ``nearest = 0``.
+    ``path`` (``kernels.kmeans.fold_path``) names who does it: the one
+    streamed ``"kernel"``, or ``"xla"``, ``block_distances`` block by
+    block."""
+    if path == "kernel":
+        return fold_candidates(Xs, Ws, d2, nearest, new, off)
 
     def body(i, c):
-        d2, nearest, run, ranked, rows, acc, comp = c
-        xb, wb = _block_at(Xs, i), _block_at(Ws, i)
-        valid = wb != 0
+        d2, nearest = c
+        Dn = block_distances(_block_at(Xs, i), new)      # (l or 1, S, 128)
+        dn = jnp.where(_block_at(Ws, i) != 0, jnp.min(Dn, 0), 0)
+        j = off + jnp.argmin(Dn, 0).astype(jnp.int32)
+        d2b, nb_ = _block_at(d2, i), _block_at(nearest, i)
+        closer = dn < d2b
+        upd = jax.lax.dynamic_update_index_in_dim
+        return (upd(d2, jnp.where(closer, dn, d2b), i, 0),
+                upd(nearest, jnp.where(closer, j, nb_), i, 0))
+
+    with jax.named_scope("kmpp_fold"):
+        return jax.lax.fori_loop(0, Xs.shape[0], body, (d2, nearest))
+
+
+# jitted: the init pass and the loop body of the program call it on the same
+# shapes, so it is traced and lowered once (0.8 s of a process's first fit)
+@functools.partial(jax.jit, static_argnames=("cap", "l"))
+def _kmpp_draw(Ws, d2, nearest, key, block0, last, cap: int, l: int):
+    """One k-means|| round's draw over a worker's shard, block by block,
+    from the folded state: the shard's ``l`` proposals by Gumbel-top-l
+    over p ∝ d2 — a running best ``l`` carried across the blocks
+    (``_topl_fold``), so a block is ranked only when one of its keys can
+    still win — the rows seen and, in the ``last`` round, the row weights
+    summed under each of the ``cap`` candidates. Returns ``(proposals
+    (keys (l,), block (l,), position (l,)), blocks ranked, rows seen,
+    candidate weights (cap,))``."""
+    dt = d2.dtype
+    ids = jnp.arange(cap, dtype=jnp.int32)[:, None, None]
+
+    def body(i, c):
+        run, ranked, rows, acc, comp = c
+        wb, d2b = _block_at(Ws, i), _block_at(d2, i)
         with jax.named_scope("kmpp_sample"):
-            Dn = block_distances(xb, new)                # (l or 1, S, 128)
-            dn = jnp.where(valid, jnp.min(Dn, 0), 0)
-            j = off + jnp.argmin(Dn, 0).astype(jnp.int32)
-            if fresh:
-                d2b, nb_ = dn, j
-            else:
-                d2b, nb_ = _block_at(d2, i), _block_at(nearest, i)
-                closer = dn < d2b
-                nb_ = jnp.where(closer, j, nb_)
-                d2b = jnp.where(closer, dn, d2b)
             # this round's draw: Gumbel-top-l over p_i ∝ d2_i
             g = jax.random.gumbel(
                 jax.random.fold_in(key, block0 + i), d2b.shape, dt)
@@ -339,22 +353,20 @@ def _kmpp_pass(Xs, Ws, state, new, off, key, block0, last, cap: int, l: int):
         # candidate weights under the current nearest, the last round
         cnt = jax.lax.cond(
             last,
-            lambda: jnp.where(nb_[None] == ids, wb[None], 0).sum((1, 2)),
+            lambda: jnp.where(_block_at(nearest, i)[None] == ids,
+                              wb[None], 0).sum((1, 2)),
             lambda: jnp.zeros((cap,), dt))
         acc, comp = _kahan_add(acc, comp, cnt)
-        upd = jax.lax.dynamic_update_index_in_dim
-        return (upd(d2, d2b, i, 0), upd(nearest, nb_, i, 0), run,
-                ranked + won.astype(jnp.int32),
-                rows + valid.sum(dtype=jnp.int32), acc, comp)
+        return (run, ranked + won.astype(jnp.int32),
+                rows + (wb != 0).sum(dtype=jnp.int32), acc, comp)
 
     zero = jnp.zeros((cap,), dt)
     none = jnp.zeros((l,), jnp.int32)
-    d2, nearest, run, ranked, rows, counts, _ = jax.lax.fori_loop(
-        0, nbl, body,
-        state + ((jnp.full((l,), -jnp.inf, dt), none, none),
-                 jnp.asarray(0, jnp.int32), jnp.asarray(0, jnp.int32),
-                 zero, zero))
-    return d2, nearest, run, ranked, rows, counts
+    run, ranked, rows, counts, _ = jax.lax.fori_loop(
+        0, Ws.shape[0], body,
+        ((jnp.full((l,), -jnp.inf, dt), none, none),
+         jnp.asarray(0, jnp.int32), jnp.asarray(0, jnp.int32), zero, zero))
+    return run, ranked, rows, counts
 
 
 def kmeans_parallel_init(X, k: int, seed: int = 0,
@@ -399,15 +411,16 @@ def kmeans_parallel_init(X, k: int, seed: int = 0,
     rng = np.random.RandomState(seed)
     first = take_rows(col, [rng.randint(n)])[0].astype(dt)
     nbl = -(-col.row_blocks // nw)   # blocks a worker holds (static)
+    path = fold_path(dt, S, l, d)
 
     def sample(ctx):
         Xs = ctx.get_obj("X")
         Ws = ctx.get_obj("w")
         step = ctx.step_no
-        init = ctx.is_init_step
-        if init:
+        if ctx.is_init_step:
             cands = jnp.zeros((cap, d), dt).at[0].set(ctx.get_obj("first"))
-            d2 = nearest = None
+            d2 = jnp.full(Ws.shape, jnp.inf, dt)
+            nearest = jnp.zeros(Ws.shape, jnp.int32)
             rows_seen = ranked_seen = jnp.zeros((rounds,), jnp.int32)
             new, off = cands[:1], 0
         else:
@@ -421,9 +434,9 @@ def kmeans_parallel_init(X, k: int, seed: int = 0,
             new = jax.lax.dynamic_slice_in_dim(cands, off, l, 0)  # (l, d)
         key = jax.random.fold_in(
             jax.random.wrap_key_data(ctx.get_obj("key")), step)
-        d2, nearest, (kv, blk, pos), ranked, rows, counts = _kmpp_pass(
-            Xs, Ws, None if init else (d2, nearest), new, off, key,
-            ctx.task_id * nbl, step == rounds, cap, l)
+        d2, nearest = _kmpp_fold(Xs, Ws, d2, nearest, new, off, path)
+        (kv, blk, pos), ranked, rows, counts = _kmpp_draw(
+            Ws, d2, nearest, key, ctx.task_id * nbl, step == rounds, cap, l)
         pts = _rows_at(Xs, blk, pos)                          # (l, d)
         gk = manifest_all_gather(kv, ctx.AXIS, name="kmpp_keys",
                                  num_workers=ctx.num_task)
@@ -449,7 +462,7 @@ def kmeans_parallel_init(X, k: int, seed: int = 0,
         ctx.put_obj("nearest", nearest)
 
     with trace_span("kmeans.init", cat="kmeans",
-                    args={"rows": n, "rounds": rounds}) as span:
+                    args={"rows": n, "rounds": rounds, "fold": path}) as span:
         res = (IterativeComQueue(env=env_, max_iter=rounds)
                .init_with_partitioned_data("X", col.blocks)
                .init_with_partitioned_data(
@@ -459,16 +472,21 @@ def kmeans_parallel_init(X, k: int, seed: int = 0,
                    "key", np.asarray(jax.random.key_data(
                        jax.random.PRNGKey(seed))))
                .add(sample)
-               .set_program_key((INIT_PROGRAM, cap, d, l, nbl, S, str(dt)))
+               .set_program_key((INIT_PROGRAM, cap, d, l, nbl, S, str(dt),
+                                 path))
                .exec())
         cands, weights, rows_seen, ranked = (
             np.array(v) for v in res.get_all(
                 ["cands", "weights", "rows", "ranked"]))
         span.set(blocks_ranked=int(ranked.sum()))
     _count(rows_seen.sum(dtype=np.int64), rounds, ranked.sum())
+    if metrics_enabled():
+        get_registry().inc("alink_kmeans_init_fold_blocks_total",
+                           rounds * col.row_blocks, {"path": path})
     if info is not None:
         info.update(init_candidates=cands, init_weights=weights.copy(),
-                    init_rows=rows_seen, init_blocks_ranked=ranked)
+                    init_rows=rows_seen, init_blocks_ranked=ranked,
+                    init_fold=path)
     # candidates sampled in the final round carry no counted weight yet;
     # give them each weight 1 so the recluster can still use them
     weights[weights == 0] = 1.0

@@ -294,3 +294,113 @@ def test_init_blocks_ranked_is_the_host_count(seed, nw):
     assert (ranked >= nw).all() and ranked.sum() < rounds * 23
     if nw == 1:
         assert ranked.sum() < 0.6 * rounds * 23
+
+
+def _ulps(a, b):
+    """|a - b| in units of b's last place (float32)."""
+    return np.abs(a - b).astype(np.float64) / np.spacing(
+        np.maximum(np.abs(b), np.float32(1e-30)))
+
+
+@pytest.mark.parametrize("S, cut", [(8, False), (512, False), (512, True)],
+                         ids=["S8", "S512", "S512_cut"])
+@pytest.mark.parametrize("later", [False, True], ids=["first", "later"])
+@pytest.mark.parametrize("l", [1, 20])
+def test_kmpp_fold_kernel_is_the_xla_fold(monkeypatch, l, later, S, cut):
+    """The streamed kernel (interpreted here) against the ``xla`` fold,
+    block by block with ``block_distances``: a first round (``d2 =
+    +inf``) and a later one, a last block that is part padding, and a
+    block ``cut`` over its sublanes as one too wide for VMEM is. ``d2``
+    within 2 ulp; ``nearest`` equal wherever the two smallest distances
+    differ by more than 2 ulp; padding rows read 0 and, once 0, are
+    never ``closer``; equal distances go to the lowest candidate."""
+    import jax
+    import jax.numpy as jnp
+    from alink_tpu.kernels import kmeans as kernel
+    from alink_tpu.operator.common.clustering import kmeans as K
+
+    fold_path = kernel.fold_path
+    if cut:                      # two table blocks of 64 sublanes fit
+        monkeypatch.setattr(kernel, "_TABLE_VMEM", 2 * 3 * 64 * 128 * 4)
+        assert kernel._sublanes_per_step(3, S) == 64
+    else:
+        assert kernel._sublanes_per_step(3, S) == S
+
+    assert fold_path(np.float32, S, l, 3) == "xla"  # the rig as it stands
+    monkeypatch.setenv("ALINK_TPU_PALLAS_INTERPRET", "1")
+    assert fold_path(np.float32, S, l, 3) == "kernel"
+    assert fold_path(np.float64, S, l, 3) == "xla"  # a float64 table,
+    assert fold_path(np.float32, 12, l, 3) == "xla"  # ragged register tiles,
+    assert fold_path(np.float32, S, 200, 50) == "xla"    # too much to unroll
+
+    rng = np.random.RandomState(l + 2 * later + S)
+    nbl, d, off = 2, 3, 1 + 20 * later
+    X = rng.randn(nbl, d, S, 128).astype(np.float32)
+    W = np.ones((nbl, S, 128), np.float32)
+    W[-1, S // 2:] = 0                              # the ragged tail
+    pad = W == 0
+    new = rng.randn(l, d).astype(np.float32)
+    if l > 1:
+        new[7] = new[2]                             # equal distances
+        X[0, :, 0, :5] = new[2][:, None]            # rows ON a candidate
+    if later:
+        d2 = (rng.rand(nbl, S, 128) * 3).astype(np.float32) * ~pad
+        near = rng.randint(0, off, (nbl, S, 128)).astype(np.int32)
+    else:
+        d2 = np.full((nbl, S, 128), np.inf, np.float32)
+        near = np.zeros((nbl, S, 128), np.int32)
+
+    got, want = (
+        [np.asarray(a) for a in jax.jit(
+            lambda *a, path=path: K._kmpp_fold(*a, path))(
+                X, W, d2, near, new, jnp.int32(off))]
+        for path in ("kernel", "xla"))
+    assert got[0].dtype == np.float32 and got[1].dtype == np.int32
+    assert _ulps(got[0], want[0]).max() <= 2
+    D = np.sort(np.stack([np.asarray(K.block_distances(jnp.asarray(xb), new))
+                          for xb in X], 1), 0)      # (l, nbl, S, 128)
+    clear = np.ones(pad.shape, bool) if l == 1 else \
+        (D[1] - D[0]) > 2 * np.spacing(D[1])
+    clear &= _ulps(D[0], d2) > 2 if later else True
+    assert clear.mean() > 0.9
+    assert np.array_equal(got[1][clear], want[1][clear])
+    assert (got[0][pad] == 0).all()
+    if later:
+        assert np.array_equal(got[1][pad], near[pad])   # never closer
+    if l > 1:
+        assert not (got[1] == off + 7).any() and not (want[1] == off + 7).any()
+        assert (got[0][0, 0, :5] == 0).all()
+        assert (got[1][0, 0, :5] == off + 2).all()
+
+
+@pytest.mark.parametrize("nw", [1, 4])
+def test_kmeans_parallel_init_same_candidates_by_either_fold(monkeypatch, nw):
+    """A whole k-means|| by the ``xla`` fold (the rig's own) and by the
+    kernel (interpreted) draws the same candidates; the path is read off
+    ``info["init_fold"]`` and the counter of blocks folded."""
+    from alink_tpu.common.metrics import (MetricsRegistry, get_registry,
+                                          set_registry)
+    from alink_tpu.common.mlenv import MLEnvironment
+    from alink_tpu.operator.common.clustering.kmeans import (
+        kmeans_parallel_init)
+
+    col = _kmpp_table(3, blocks=8)
+    out = {}
+    prev = set_registry(MetricsRegistry())
+    try:
+        for path in ("xla", "kernel"):
+            if path == "kernel":
+                monkeypatch.setenv("ALINK_TPU_PALLAS_INTERPRET", "1")
+            out[path] = info = {}
+            kmeans_parallel_init(col, 4, seed=3,
+                                 env=MLEnvironment(parallelism=nw), info=info)
+            assert info["init_fold"] == path
+            assert get_registry().value(
+                "alink_kmeans_init_fold_blocks_total",
+                {"path": path}) == 5 * 8
+    finally:
+        set_registry(prev)
+    for name in ("init_candidates", "init_rows", "init_blocks_ranked"):
+        assert np.array_equal(out["kernel"][name], out["xla"][name]), name
+    assert np.abs(out["kernel"]["init_weights"]
+                  - out["xla"]["init_weights"]).sum() <= 2

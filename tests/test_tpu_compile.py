@@ -1,0 +1,133 @@
+"""Kernels of the main path compiled at their real shapes for a DESCRIBED
+TPU v5e (``jax.experimental.topologies``: the chip's compiler is
+installed here, the chip is not). Nothing runs: these tests say what the
+compiler makes of a program, never how fast it is. They skip where no
+TPU compiler can describe the topology. Keep every such test in THIS
+file: the process that describes the topology holds the TPU library."""
+
+import re
+
+import numpy as np
+import pytest
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _kernel_calls(text):
+    return [ln for ln in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in ln]
+
+
+@pytest.mark.parametrize("l", [20, 1], ids=["later_round", "first_round"])
+def test_kmpp_round_compiles_to_one_streamed_kernel(monkeypatch, one_chip, l):
+    """A k-means|| round of ``kmeans-fit`` (1,526 blocks of 20 × 512 ×
+    128 float32; 20 new candidates, or the first round's one): the fold
+    is ONE ``tpu_custom_call``, the state aliased through it; no array of
+    a block's distances ``(l, S, 128)`` or of a block's copy ``(d, S,
+    128)`` exists; the table reaches the kernel as the program's own
+    parameter, uncopied."""
+    import jax
+    import jax.numpy as jnp
+    from alink_tpu.kernels import kmeans as kernel
+    from alink_tpu.operator.common.clustering import kmeans as K
+
+    # the rig's backend is the CPU: compile the kernel, not its interpreter
+    monkeypatch.setattr(kernel, "interpret_mode", lambda: False)
+    nbl, d, S, cap = 1526, 20, 512, 101
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def round_(Xs, Ws, d2, nearest, new, off, key, last):
+        d2, nearest = K._kmpp_fold(Xs, Ws, d2, nearest, new, off, "kernel")
+        return d2, nearest, K._kmpp_draw(
+            Ws, d2, nearest, jax.random.wrap_key_data(key), 0, last, cap, 20)
+
+    with jax.enable_x64(False):                      # as on the chip
+        text = jax.jit(round_, donate_argnums=(2, 3)).lower(
+            sd((nbl, d, S, 128), jnp.float32), sd((nbl, S, 128), jnp.float32),
+            sd((nbl, S, 128), jnp.float32), sd((nbl, S, 128), jnp.int32),
+            sd((l, d), jnp.float32), sd((), jnp.int32), sd((2,), jnp.uint32),
+            sd((), jnp.bool_)).compile().as_text()
+
+    calls = _kernel_calls(text)
+    assert len(calls) == 1
+    assert "kmpp_fold" in calls[0]
+    assert "output_to_operand_aliasing" in calls[0]
+    for shape in {f"[{l},{S},128]", f"[{d},{S},128]"} - {f"[1,{S},128]"}:
+        assert shape not in text, shape
+    # the table: a parameter of the entry computation that reaches the
+    # kernel through a bitcast (its rows seen as register tiles, the same
+    # bytes in place); never copied or laid out anew
+    table = f"f32[{nbl},{d},{S},128]"
+    made = [ln.strip() for ln in text.splitlines()
+            if re.search(rf"= {re.escape(table)}\S* (?!parameter)", ln)]
+    assert made == []
+    param = re.search(rf"(\S+) = {re.escape(table)}\S* parameter\(0\)", text)
+    tiled = re.escape(f"f32[{nbl},{d},{S // 8},8,128]")
+    made = re.findall(rf"(\S+) = {tiled}\S* (\w+)\((\S+?)\)", text)
+    assert made and all(op == "bitcast" and src == param.group(1)
+                        for _, op, src in made)
+    assert any(name in calls[0] for name, _, _ in made)
+
+
+def test_kmpp_round_compiles_a_worker_on_four_chips(monkeypatch, topo):
+    """The same round inside a ``shard_map`` as the engine makes it
+    (``check_vma=False``), the table's blocks split over a 2 x 2 v5e: one
+    kernel a worker over its own 382 blocks, the state aliased, and a
+    quarter of the table a chip."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from alink_tpu.common.compat import shard_map
+    from alink_tpu.kernels import kmeans as kernel
+    from alink_tpu.operator.common.clustering import kmeans as K
+
+    monkeypatch.setattr(kernel, "interpret_mode", lambda: False)
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("d",))
+    nbl, d, S, l, cap = 382, 20, 512, 20, 101
+
+    def sd(shape, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    def round_(Xs, Ws, d2, nearest, new, off, key, last):
+        d2, nearest = K._kmpp_fold(Xs, Ws, d2, nearest, new, off, "kernel")
+        (kv, _, _), _, _, counts = K._kmpp_draw(
+            Ws, d2, nearest, jax.random.wrap_key_data(key),
+            jax.lax.axis_index("d") * nbl, last, cap, l)
+        return d2, nearest, jax.lax.all_gather(kv, "d"), \
+            jax.lax.psum(counts, "d")
+
+    rows = (4 * nbl, S, 128)
+    with jax.enable_x64(False):
+        compiled = jax.jit(shard_map(
+            round_, mesh=mesh, in_specs=(P("d"),) * 4 + (P(),) * 4,
+            out_specs=(P("d"), P("d"), P(), P()), check_vma=False),
+            donate_argnums=(2, 3)).lower(
+                sd((4 * nbl, d, S, 128), jnp.float32, P("d")),
+                sd(rows, jnp.float32, P("d")), sd(rows, jnp.float32, P("d")),
+                sd(rows, jnp.int32, P("d")), sd((l, d), jnp.float32),
+                sd((), jnp.int32), sd((2,), jnp.uint32),
+                sd((), jnp.bool_)).compile()
+    calls = _kernel_calls(compiled.as_text())
+    assert len(calls) == 1 and f"f32[{nbl},{d},{S // 8},8,128]" in calls[0]
+    assert "output_to_operand_aliasing" in calls[0]
+    table = 4 * nbl * d * S * 128 * 4
+    assert compiled.memory_analysis().argument_size_in_bytes < 0.3 * table
