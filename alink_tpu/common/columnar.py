@@ -11,6 +11,8 @@ selection -> same column type), ``__len__``, ``copy`` and optionally
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
 
@@ -54,6 +56,9 @@ LANES = 128
 #: rows of a block are a multiple of this (8 sublanes x 128 lanes), so a
 #: block's (S, 128) slabs tile the device's registers with no padding
 BLOCK_QUANTUM = 8 * LANES
+#: the same for a one-byte table (the trees' uint8 bins): a register tile
+#: of bytes is 32 sublanes deep, so S must be a multiple of 32
+BYTE_BLOCK_QUANTUM = 32 * LANES
 DEFAULT_BLOCK_ROWS = 1 << 16
 
 
@@ -102,12 +107,13 @@ class DenseBlockColumn(ColumnarColumn):
         return not isinstance(self.blocks, np.ndarray)
 
     @staticmethod
-    def block_rows_for(n_rows: int, block_rows: int = 0) -> int:
+    def block_rows_for(n_rows: int, block_rows: int = 0,
+                       quantum: int = BLOCK_QUANTUM) -> int:
         """Rows a block holds: ``block_rows`` (default 65,536) rounded up
         to the quantum, and no more than ``n_rows`` needs."""
         want = int(block_rows) or DEFAULT_BLOCK_ROWS
-        need = -(-max(int(n_rows), 1) // BLOCK_QUANTUM) * BLOCK_QUANTUM
-        return min(-(-want // BLOCK_QUANTUM) * BLOCK_QUANTUM, need)
+        need = -(-max(int(n_rows), 1) // quantum) * quantum
+        return min(-(-want // quantum) * quantum, need)
 
     @staticmethod
     def pack(X: np.ndarray, block_rows: int, row_blocks: int = 0
@@ -165,3 +171,145 @@ class DenseBlockColumn(ColumnarColumn):
         out = np.empty(self.n_rows, object)
         out[:] = [DenseVector(r.astype(np.float64)) for r in self.to_rows()]
         return out
+
+
+class RowBlockColumn(ColumnarColumn):
+    """A numeric column of ``n_rows`` values (labels, weights) laid out as
+    the rows of a :class:`DenseBlockColumn` are: ``blocks`` is
+    ``(row_blocks, S, 128)``, row ``r`` of block ``b`` at ``blocks[b, r //
+    128, r % 128]``, zero past ``n_rows``. Host ``numpy`` or
+    device-resident; a trainer that walks the table block by block reads
+    the label of a row where it reads the row. Per-row access fetches and
+    is for small tables and tests."""
+
+    __slots__ = ("blocks", "n_rows")
+
+    def __init__(self, blocks, n_rows: int):
+        shape = tuple(blocks.shape)
+        if len(shape) != 3 or shape[2] != LANES or shape[1] % 8:
+            raise ValueError(
+                f"RowBlockColumn: blocks must be (row_blocks, S, {LANES}) "
+                f"with S a multiple of 8, got {shape}")
+        if not 0 <= int(n_rows) <= shape[0] * shape[1] * LANES:
+            raise ValueError(f"RowBlockColumn: {n_rows} rows do not fit "
+                             f"blocks of shape {shape}")
+        self.blocks = blocks
+        self.n_rows = int(n_rows)
+
+    @property
+    def dtype(self):
+        return np.dtype(self.blocks.dtype)
+
+    @property
+    def block_rows(self) -> int:
+        return int(self.blocks.shape[1]) * LANES
+
+    @property
+    def on_device(self) -> bool:
+        return not isinstance(self.blocks, np.ndarray)
+
+    @classmethod
+    def from_values(cls, v: np.ndarray, block_rows: int = 0
+                    ) -> "RowBlockColumn":
+        v = np.asarray(v)
+        if v.ndim != 1:
+            raise ValueError("RowBlockColumn.from_values: v must be (n,)")
+        B = DenseBlockColumn.block_rows_for(v.shape[0], block_rows)
+        return cls(DenseBlockColumn.pack(v, B), v.shape[0])
+
+    def to_values(self) -> np.ndarray:
+        """Host values ``(n_rows,)`` (fetches a device-resident column)."""
+        return np.asarray(self.blocks).reshape(-1)[:self.n_rows]
+
+    def __len__(self):
+        return self.n_rows
+
+    def _render_row(self, i: int):
+        if not -self.n_rows <= i < self.n_rows:
+            raise IndexError(i)
+        b, r = divmod(i % self.n_rows, self.block_rows)
+        return np.asarray(self.blocks[b, r // LANES, r % LANES]).item()
+
+    def _subset(self, sel):
+        return RowBlockColumn.from_values(self.to_values()[sel],
+                                          self.block_rows)
+
+    def copy(self) -> "RowBlockColumn":
+        return RowBlockColumn(self.blocks.copy(), self.n_rows)
+
+    def materialize(self) -> np.ndarray:
+        return self.to_values()
+
+
+def as_block_column(X, num_workers: int = 1,
+                    quantum: int = BLOCK_QUANTUM) -> DenseBlockColumn:
+    """The blocked trainers' one input form. A ``DenseBlockColumn`` passes
+    through untouched (device-resident or not); host rows ``(n, d)`` are
+    packed once into blocks of a multiple of ``quantum`` rows, their count
+    a multiple of the workers so the engine has nothing to pad."""
+    if isinstance(X, DenseBlockColumn):
+        return X
+    X = np.asarray(X)
+    if X.ndim != 2:
+        raise ValueError("X must be (n, d) rows or a DenseBlockColumn")
+    B = DenseBlockColumn.block_rows_for(X.shape[0], quantum=quantum)
+    nb = -(-max(X.shape[0], 1) // B)
+    nb = -(-nb // num_workers) * num_workers
+    return DenseBlockColumn(DenseBlockColumn.pack(X, B, nb), X.shape[0])
+
+
+def block_values(col: DenseBlockColumn, values):
+    """Per-row ``values`` laid out like the table's rows, ``(row_blocks,
+    S, 128)``: a :class:`RowBlockColumn` or an array already so laid out
+    passes through where it lies, host values ``(n,)`` are packed,
+    ``None`` gives ``None``."""
+    nb, _, S, _ = col.blocks.shape
+    if values is None:
+        return None
+    if isinstance(values, RowBlockColumn):
+        if values.n_rows != col.n_rows:
+            raise ValueError(f"{values.n_rows} values for {col.n_rows} rows")
+        values = values.blocks
+    if getattr(values, "shape", None) == (nb, S, LANES):
+        return values
+    v = np.asarray(values)
+    if v.shape != (col.n_rows,):
+        raise ValueError(f"per-row values must be ({col.n_rows},), "
+                         f"got {v.shape}")
+    return DenseBlockColumn.pack(v, col.block_rows, nb)
+
+
+@functools.lru_cache(maxsize=None)
+def _unit_weights_fn():
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def unit_weights_like(blocks, n_rows):
+        nb, _, S, L = blocks.shape
+        at = jax.lax.broadcasted_iota(jnp.int32, (nb, S, L), 0) * (S * L) \
+            + jax.lax.broadcasted_iota(jnp.int32, (nb, S, L), 1) * L \
+            + jax.lax.broadcasted_iota(jnp.int32, (nb, S, L), 2)
+        return (at < n_rows).astype(blocks.dtype)
+    return unit_weights_like
+
+
+def block_weights(col: DenseBlockColumn, sample_weight=None):
+    """Per-row weights laid out like the table's rows, ``(row_blocks, S,
+    128)``, zero on the padding past ``n_rows`` — the mask every pass of a
+    blocked trainer carries. Without ``sample_weight`` they are made where
+    the table lives (no ``(n,)`` host array); weights already so laid out
+    pass through."""
+    nb, _, S, _ = col.blocks.shape
+    if getattr(sample_weight, "shape", None) == (nb, S, LANES):
+        return sample_weight              # already laid out (one fit, twice)
+    if sample_weight is None:
+        if col.on_device:
+            return _unit_weights_fn()(col.blocks, col.n_rows)
+        w = np.zeros(nb * S * LANES, col.blocks.dtype)
+        w[:col.n_rows] = 1
+        return w.reshape(nb, S, LANES)
+    w = np.asarray(sample_weight, col.blocks.dtype)
+    if w.shape != (col.n_rows,):
+        raise ValueError("sample_weight must be (n,)")
+    return DenseBlockColumn.pack(w, col.block_rows, nb)

@@ -843,6 +843,15 @@ class ComQueueResult:
                 self._stacked[name])
         return got
 
+    def device(self, name: str):
+        """The per-worker values of a carry stacked ``(num_workers, ...)``
+        WHERE THEY LIE: no fetch. For a result the next program reads on
+        the device (a binned table): sharded over the workers on its
+        leading axis as the engine partitions an input."""
+        if name not in self._stacked:
+            raise KeyError(f"no carry object '{name}'; have {sorted(self._stacked)}")
+        return self._stacked[name]
+
     def get(self, name: str):
         """Worker 0's copy (read-only) — use for replicated
         (post-allreduce) state. See :meth:`get_all`."""

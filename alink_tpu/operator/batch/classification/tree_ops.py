@@ -8,11 +8,14 @@ histogram-parallel device builder (common/tree/).
 
 from __future__ import annotations
 
+import functools
 import json
 from typing import List, Optional
 
 import numpy as np
 
+from ....common.columnar import DenseBlockColumn, RowBlockColumn
+from ....common.metrics import get_registry, metrics_enabled
 from ....common.mtable import MTable
 from ....common.params import ParamInfo, Params, RangeValidator
 from ....common.types import AlinkTypes, TableSchema
@@ -22,9 +25,11 @@ from ....model.converters import (SimpleModelDataConverter, decode_array,
 from ....params.shared import (HasFeatureCols, HasLabelCol, HasPredictionCol,
                                HasPredictionDetailCol, HasReservedCols, HasSeed,
                                HasVectorCol, HasWeightCol)
+from ....common.tracing import trace_span
 from ...base import BatchOperator
 from ...common.dataproc.feature_extract import extract_design, resolve_feature_cols
-from ...common.tree.hist import bins_to_thresholds, tree_apply_values
+from ...common.tree.hist import (bins_to_thresholds, thresholds_as,
+                                 tree_apply_values)
 from ...common.tree.trainers import TreeTrainParams, forest_train, gbdt_train
 from ..utils.model_map import ModelMapBatchOp
 
@@ -161,6 +166,8 @@ def _extract_xy(op, t: MTable, regression: bool):
             raise ValueError("categorical_cols requires feature_cols input "
                              "(vector input has no column identity)")
         design = extract_design(t, feature_cols, vector_col, np.float64)
+        # a DenseBlockColumn comes back as itself: the trainer reads it
+        # where it lies
         X = design["X"] if design["kind"] == "dense" else None
         if X is None:
             from ....common.vector import SparseBatch
@@ -169,18 +176,70 @@ def _extract_xy(op, t: MTable, regression: bool):
     raw = t.col(label_col)
     label_type = t.schema.type_of(label_col)
     if regression:
-        labels, y = [], np.asarray(raw, np.float64)
+        labels = []
+        y = raw if isinstance(raw, RowBlockColumn) else np.asarray(
+            raw, np.float64)
     else:
-        labels = sorted({str(v) for v in raw})
-        y = np.asarray([labels.index(str(v)) for v in raw], np.float64)
+        labels, y = _encode_labels(raw)
         if label_type in (AlinkTypes.LONG, AlinkTypes.INT):
             labels = [int(float(v)) for v in labels]
         elif label_type in (AlinkTypes.DOUBLE, AlinkTypes.FLOAT):
             labels = [float(v) for v in labels]
-    w = (np.asarray(t.col(weight_col), np.float64) if weight_col
-         else np.ones(len(y)))
+    w = t.col(weight_col) if weight_col else None
+    if w is not None and not isinstance(w, RowBlockColumn):
+        w = np.asarray(w, np.float64)
     return (X, y, w, labels, feature_cols, vector_col, label_type,
             cat_mask if not vector_col else None, cat_cols, vocabs)
+
+
+def _encode_labels(raw):
+    """``(labels, y)``: the distinct label values as strings, sorted, and
+    each row's index among them, with no Python a row. A device-resident
+    ``RowBlockColumn`` is read where it lies (its distinct values by one
+    reduction, at most two of them) and ``y`` comes back laid out as it
+    is."""
+    if isinstance(raw, RowBlockColumn):
+        lo, hi, others = (float(v) for v in _label_pair(raw.blocks,
+                                                        raw.n_rows))
+        if others:
+            raise ValueError("a blocked label column must hold at most two "
+                             f"distinct values; found one besides {lo} and "
+                             f"{hi}")
+        vals = [lo] if lo == hi else sorted([lo, hi], key=str)
+        y = (raw.blocks == vals[-1]).astype(raw.blocks.dtype)
+        return [str(v) for v in vals], y
+    arr = np.asarray(raw)
+    try:
+        uniq, inv = np.unique(arr, return_inverse=True)
+    except TypeError:                       # mixed objects: row by row
+        labels = sorted({str(v) for v in raw})
+        return labels, np.asarray([labels.index(str(v)) for v in raw],
+                                  np.float64)
+    strs = [str(v) for v in uniq]
+    labels = sorted(set(strs))
+    rank = np.asarray([labels.index(s) for s in strs], np.float64)
+    return labels, rank[inv.reshape(-1)]
+
+
+@functools.lru_cache(maxsize=None)
+def _label_pair_fn():
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def pair(b, n_rows):
+        here = jnp.arange(b.size).reshape(b.shape) < n_rows
+        lo = jnp.where(here, b, jnp.inf).min()
+        hi = jnp.where(here, b, -jnp.inf).max()
+        return lo, hi, (here & (b != lo) & (b != hi)).sum()
+    return pair
+
+
+def _label_pair(blocks, n_rows: int):
+    """(least, greatest, rows that are neither) of a blocked per-row
+    column's first ``n_rows`` values, in one fused reduction where it
+    lies (no per-row temporary)."""
+    return _label_pair_fn()(blocks, n_rows)
 
 
 def _model_info_table(m: "TreeModelData") -> MTable:
@@ -227,27 +286,49 @@ class GbdtTrainBatchOp(BatchOperator, _TreeTrainParamsMixin):
     IS_REGRESSION = False
 
     def link_from(self, in_op: BatchOperator):
-        t = in_op.get_output_table()
-        (X, y, w, labels, fc, vc, lt, cat_mask, cat_cols,
-         vocabs) = _extract_xy(t=t, op=self, regression=self.IS_REGRESSION)
-        if not self.IS_REGRESSION and len(labels) != 2:
-            raise ValueError(f"GBDT classifier is binary; got labels {labels}")
-        p = _tree_params(self)
-        tf, tb, tm, tv, edges, base, curve, imp = gbdt_train(
-            X, y, p, self.IS_REGRESSION, sample_weight=w, cat_mask=cat_mask)
-        thr = np.stack([bins_to_thresholds(np.asarray(tf[i]), np.asarray(tb[i]),
-                                           edges) for i in range(p.num_trees)])
-        model = TreeModelData(
-            "gbdt", self.IS_REGRESSION, p.max_depth, np.asarray(tf), thr,
-            np.asarray(tv), base, p.learning_rate, labels, fc, vc, lt,
-            split_masks=np.asarray(tm), cat_cols=cat_cols, cat_vocabs=vocabs,
-            importances=np.asarray(imp))
-        self._output = TreeModelDataConverter().save_model(model)
-        self._side_outputs = [MTable({"tree": np.arange(1, len(curve) + 1),
-                                      "loss": curve.astype(np.float64)}),
-                              _importance_table(fc, imp)]
+        with trace_span("gbdt.fit", cat="gbdt") as fit:
+            t = in_op.get_output_table()
+            with trace_span("gbdt.extract", cat="gbdt"):
+                (X, y, w, labels, fc, vc, lt, cat_mask, cat_cols,
+                 vocabs) = _extract_xy(t=t, op=self,
+                                       regression=self.IS_REGRESSION)
+            if not self.IS_REGRESSION and len(labels) != 2:
+                raise ValueError(
+                    f"GBDT classifier is binary; got labels {labels}")
+            p = _tree_params(self)
+            info: dict = {}
+            tf, tb, tm, tv, edges, base, curve, imp = gbdt_train(
+                X, y, p, self.IS_REGRESSION, sample_weight=w,
+                cat_mask=cat_mask, info=info)
+            with trace_span("gbdt.model", cat="gbdt"):
+                tf, tb, tv = np.asarray(tf), np.asarray(tb), np.asarray(tv)
+                thr = np.stack([bins_to_thresholds(tf[i], tb[i], edges)
+                                for i in range(p.num_trees)])
+                model = TreeModelData(
+                    "gbdt", self.IS_REGRESSION, p.max_depth, tf, thr, tv,
+                    base, p.learning_rate, labels, fc, vc, lt,
+                    split_masks=np.asarray(tm), cat_cols=cat_cols,
+                    cat_vocabs=vocabs, importances=np.asarray(imp))
+                self._output = TreeModelDataConverter().save_model(model)
+                self._side_outputs = [
+                    MTable({"tree": np.arange(1, len(curve) + 1),
+                            "loss": curve.astype(np.float64)}),
+                    _importance_table(fc, imp)]
+            info.update(features=tf, split_bins=tb, leaf_values=tv,
+                        loss_curve=np.asarray(curve), base_score=base)
+            self._train_info = info
+            fit.set(rows=int(t.num_rows), trees=int(p.num_trees))
+        if metrics_enabled():
+            get_registry().inc("alink_gbdt_fits_total", 1)
         return self
 
+    def get_train_info(self) -> dict:
+        """What the last fit went through: which histogram ran
+        (``hist``: ``"onehot"`` on the MXU or ``"scatter"``), the bin
+        edges, the trees as the device grew them (``features``,
+        ``split_bins``, ``leaf_values``, every node's ``counts``), the
+        loss curve and the rows counted."""
+        return self._train_info
 
     def get_model_info(self) -> MTable:
         m = TreeModelDataConverter().load_model(self.get_output_table())
@@ -275,6 +356,12 @@ class RandomForestTrainBatchOp(BatchOperator, _TreeTrainParamsMixin):
         t = in_op.get_output_table()
         (X, y, w, labels, fc, vc, lt, cat_mask, cat_cols,
          vocabs) = _extract_xy(t=t, op=self, regression=self.IS_REGRESSION)
+        # the forests still grow on host rows (hist.build_tree)
+        if isinstance(X, DenseBlockColumn):
+            X = X.to_rows().astype(np.float64)
+        y = y.to_values() if isinstance(y, RowBlockColumn) else np.asarray(y)
+        w = (np.ones(len(y)) if w is None else
+             w.to_values() if isinstance(w, RowBlockColumn) else w)
         p = _tree_params(self)
         if self.IS_REGRESSION:
             stats = np.stack([y * w, y * y * w, w], axis=1)
@@ -463,8 +550,10 @@ class TreeModelMapper(ModelMapper):
         n_feat = int(len(m.feature_cols)) if m.feature_cols else None
         gbdt = m.algo == "gbdt"
 
+        # thresholds rounded DOWN into the shipping dtype: a row that ties
+        # a cut point goes the host mapper's way in float32 too
         model_arrays = [np.asarray(m.features, np.int32),
-                        np.asarray(m.thresholds, ship_dt),
+                        thresholds_as(m.thresholds, ship_dt),
                         np.asarray(m.leaf_values, ship_dt),
                         np.asarray(m.base_score, ship_dt),
                         np.asarray(m.learning_rate, ship_dt)]

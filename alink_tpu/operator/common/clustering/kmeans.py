@@ -34,13 +34,16 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ....common.columnar import LANES, DenseBlockColumn
+from ....common.columnar import (LANES, DenseBlockColumn,
+                                  as_block_column, block_weights)
 from ....common.metrics import get_registry, metrics_enabled
 from ....common.mlenv import MLEnvironment, MLEnvironmentFactory
 from ....common.tracing import trace_span
 from ....engine import AllReduce, IterativeComQueue
 from ....engine.communication import manifest_all_gather
 from ....kernels.kmeans import fold_candidates, fold_path
+from ..blocked import (block_at as _block_at, join_count as _join_count,
+                       kahan_add as _kahan_add, split_count as _split_count)
 
 #: the engine names each compiled program ``jit_<first word of its key>``
 LLOYD_PROGRAM = "kmeans_lloyd"
@@ -118,22 +121,6 @@ def _weighted_kmeans_pp(C: np.ndarray, w: np.ndarray, k: int,
 
 # -- the blocked table ---------------------------------------------------------
 
-def as_block_column(X, num_workers: int = 1) -> DenseBlockColumn:
-    """The trainer's one input form. A ``DenseBlockColumn`` passes through
-    untouched (device-resident or not); host rows ``(n, d)`` are packed
-    once into blocks, their count a multiple of the workers so the engine
-    has nothing to pad."""
-    if isinstance(X, DenseBlockColumn):
-        return X
-    X = np.asarray(X)
-    if X.ndim != 2:
-        raise ValueError("kmeans: X must be (n, d) rows or a DenseBlockColumn")
-    B = DenseBlockColumn.block_rows_for(X.shape[0])
-    nb = -(-max(X.shape[0], 1) // B)
-    nb = -(-nb // num_workers) * num_workers
-    return DenseBlockColumn(DenseBlockColumn.pack(X, B, nb), X.shape[0])
-
-
 def take_rows(col: DenseBlockColumn, idx) -> np.ndarray:
     """Host copies ``(len(idx), d)`` of a few rows of the table (read
     tile by tile where the table lives on the device: ``_rows_at``)."""
@@ -143,36 +130,6 @@ def take_rows(col: DenseBlockColumn, idx) -> np.ndarray:
         return np.asarray(_device_rows(col.blocks, b.astype(np.int32),
                                        r.astype(np.int32)))
     return col.blocks[b, :, r // LANES, r % LANES]
-
-
-@jax.jit
-def _unit_weights_like(blocks, n_rows):
-    nb, _, S, L = blocks.shape
-    at = jax.lax.broadcasted_iota(jnp.int32, (nb, S, L), 0) * (S * L) \
-        + jax.lax.broadcasted_iota(jnp.int32, (nb, S, L), 1) * L \
-        + jax.lax.broadcasted_iota(jnp.int32, (nb, S, L), 2)
-    return (at < n_rows).astype(blocks.dtype)
-
-
-def block_weights(col: DenseBlockColumn, sample_weight=None):
-    """Per-row weights laid out like the table's rows, ``(row_blocks, S,
-    128)``, zero on the padding past ``n_rows`` — the mask every pass
-    carries. Without ``sample_weight`` they are made where the table
-    lives (no ``(n,)`` host array); weights already so laid out pass
-    through."""
-    nb, _, S, _ = col.blocks.shape
-    if getattr(sample_weight, "shape", None) == (nb, S, LANES):
-        return sample_weight              # already laid out (one fit, twice)
-    if sample_weight is None:
-        if col.on_device:
-            return _unit_weights_like(col.blocks, col.n_rows)
-        w = np.zeros(nb * S * LANES, col.blocks.dtype)
-        w[:col.n_rows] = 1
-        return w.reshape(nb, S, LANES)
-    w = np.asarray(sample_weight, col.blocks.dtype)
-    if w.shape != (col.n_rows,):
-        raise ValueError("kmeans: sample_weight must be (n,)")
-    return DenseBlockColumn.pack(w, col.block_rows, nb)
 
 
 def block_distances(xb, C, distance_type: str = "EUCLIDEAN"):
@@ -213,27 +170,6 @@ def assign_table(col: DenseBlockColumn, C, distance_type: str = "EUCLIDEAN"
                                distance_type)
     n = col.n_rows
     return (np.asarray(ids).reshape(-1)[:n], np.asarray(dist).reshape(-1)[:n])
-
-
-def _kahan_add(acc, comp, x):
-    """One compensated addition: the running sum and what it lost."""
-    y = x - comp
-    t = acc + y
-    return t, (t - acc) - y
-
-
-def _split_count(rows):
-    """An int32 row count as two halves that a float32 psum keeps exact
-    (each under 2^16 a worker); ``_join_count`` puts them back."""
-    return rows // 65536, rows % 65536
-
-
-def _join_count(hi, lo):
-    return hi.astype(jnp.int32) * 65536 + lo.astype(jnp.int32)
-
-
-def _block_at(arr, i):
-    return jax.lax.dynamic_index_in_dim(arr, i, 0, keepdims=False)
 
 
 def _rows_at(Xs, blk, pos):

@@ -34,14 +34,19 @@ import jax.numpy as jnp
 # host-side quantile binning
 # ---------------------------------------------------------------------------
 
+from ....common.columnar import LANES, DenseBlockColumn
 from ....engine.communication import manifest_psum
+from ..blocked import block_at, join_count, kahan_add, split_count
 from ..dataproc.quantile import DEVICE_BINNING_MIN_CELLS as _DEVICE_BINNING_MIN_CELLS
 
 
-def make_bin_edges(X: np.ndarray, n_bins: int,
+def make_bin_edges(X, n_bins: int,
                    cat_mask: Optional[np.ndarray] = None,
-                   device: Optional[bool] = None, env=None) -> np.ndarray:
+                   device: Optional[bool] = None, env=None,
+                   program: str = "quantile_hist") -> np.ndarray:
     """(F, n_bins-1) per-feature quantile cut points (padded with +inf).
+    ``X`` is host rows ``(n, F)`` or a ``DenseBlockColumn`` (read where it
+    lies, on the device if it is there).
 
     Categorical features (``cat_mask[f]`` True; values must be integer
     category codes) get identity edges 0.5, 1.5, ... so every category is
@@ -49,21 +54,29 @@ def make_bin_edges(X: np.ndarray, n_bins: int,
     seriestree/CategoricalSplitter.java treats categories as unordered).
 
     ``device=None`` auto-selects the distributed histogram-quantile pass
-    (dataproc/quantile.py, the SortUtils.pSort analogue) once n*F is large
-    enough that per-column host ``np.quantile`` would dominate; True/False
-    force it.
+    (dataproc/quantile.py, the SortUtils.pSort analogue) for a blocked
+    table and once n*F is large enough that per-column host
+    ``np.quantile`` would dominate; True/False force it. ``program``
+    names that pass's engine program.
     """
-    n, F = X.shape
+    blocked = isinstance(X, DenseBlockColumn)
+    n, F = (X.n_rows, X.dim) if blocked else X.shape
+    if blocked and cat_mask is not None and np.any(cat_mask):
+        raise ValueError("categorical columns need host rows (a blocked "
+                         "vector column has no column identity)")
     edges = np.full((F, n_bins - 1), np.inf)
     if device is None:
-        device = n * F >= _DEVICE_BINNING_MIN_CELLS
+        device = blocked or n * F >= _DEVICE_BINNING_MIN_CELLS
+    if blocked and not device:
+        X, blocked = X.to_rows(), False
     cont = ([f for f in range(F) if not cat_mask[f]]
             if cat_mask is not None else list(range(F)))
     probs = np.linspace(0, 1, n_bins + 1)[1:-1]
     if device and cont:
         from ..dataproc.quantile import distributed_quantiles
         qs_all = distributed_quantiles(
-            np.ascontiguousarray(X[:, cont]), probs, env=env)
+            X if blocked else np.ascontiguousarray(X[:, cont]), probs,
+            env=env, program=program)
     for pos, f in enumerate(cont):
         if device:
             qs = qs_all[pos]
@@ -84,13 +97,37 @@ def make_bin_edges(X: np.ndarray, n_bins: int,
 
 
 def bin_data(X: np.ndarray, edges: np.ndarray) -> np.ndarray:
-    """(n, F) int32 bin ids in [0, n_bins)."""
+    """(n, F) int32 bin ids in [0, n_bins): the number of a feature's
+    finite edges at or below the value (host rows; the blocked trainers'
+    :func:`bin_blocks` gives the same ids as uint8, on the device)."""
     n, F = X.shape
     out = np.empty((n, F), np.int32)
     for f in range(F):
         e = edges[f]
         out[:, f] = np.searchsorted(e[np.isfinite(e)], X[:, f], side="right")
     return out
+
+
+#: the dtype of the blocked trainers' bins: ``max_bins`` is at most 256
+BIN_DTYPE = jnp.uint8
+
+
+def bin_blocks(Xs, edges):
+    """Bin a worker's shard ``(blocks, F, S, 128)`` against ``edges`` ``(F,
+    n_bins - 1)`` (``+inf`` where a feature has fewer) into ``uint8`` of the
+    same layout, block by block: a value's bin is the number of its
+    feature's finite edges at or below it, as :func:`bin_data` counts, and
+    a NaN takes the bin past the last finite edge, where ``searchsorted``
+    puts it. Traceable."""
+    edges = edges.astype(Xs.dtype)
+    last = jnp.isfinite(edges).sum(1).astype(jnp.int32)[:, None, None]
+
+    def one(xb):
+        with jax.named_scope("gbdt_bin"):
+            at = (xb[:, None] >= edges[:, :, None, None]).sum(
+                1, dtype=jnp.int32)
+            return jnp.where(jnp.isnan(xb), last, at).astype(BIN_DTYPE)
+    return jax.lax.map(one, Xs)
 
 
 # ---------------------------------------------------------------------------
@@ -208,8 +245,17 @@ def level_hist(binned, stats, node_id, n_nodes: int, n_bins: int,
 #              with a one-time warning when lowering fails, on TPU a
 #              failed lowering raises (kernels/runtime.refuse_on_tpu).
 #
+# What the chip showed (PR 31, one v5e): both forms take the row-major
+# ``(n, F)`` int32 bins and ``(n, m)`` stats of ``build_tree``, and at the
+# airline table's size (115 million rows x 13, 32 nodes) the chip's
+# compiler REFUSES both for memory ("xla" asks for 18.85 GB of
+# temporaries, "pallas" for a lane-padded 58.9 GB copy of the ``(n, 13)``
+# int32 bins); neither has been timed at any size. The forests are what still reaches them; ``gbdt_train``
+# grows on ``build_tree_blocked`` below, which the flag does not touch.
+# Their deletion is a ``simplicity`` PR's (ROADMAP D1).
+#
 # The mode is resolved at TRACE time and folded into the engine
-# program-cache key by the tree trainers, so toggling recompiles instead
+# program-cache key by the forest trainer, so toggling recompiles instead
 # of serving a stale program. With the flag off, build_tree executes the
 # pre-existing statements unchanged — the lowered HLO is byte-identical
 # to pre-flag programs (pinned by tests/test_perf_kernels.py) and the
@@ -246,18 +292,38 @@ def _fused_hist_precompute(binned, stats, n_bins: int, onehot_dtype=None):
     level-invariant within one tree (the fused kernel builds them once;
     the default kernel calls this per level — ONE implementation).
 
-    Compensated bf16 split of the stats: hi + lo reconstructs f32 to
-    ~2^-16 relative, so the bf16 MXU path does not quantize grad/hess
-    per element (~0.4%) and near-tie splits agree with the exact CPU
-    scatter. One einsum over the stacked (hi|lo) stats downstream,
+    Compensated bf16 split of the stats (:func:`split_hi_lo`): hi + lo
+    reconstructs f32 to ~2^-16 relative, so the bf16 MXU path does not
+    quantize grad/hess per element (~0.4%) and near-tie splits agree
+    with the exact CPU scatter. One einsum over the stacked (hi|lo) stats downstream,
     halves summed in f32 after."""
     hdt = onehot_dtype or jnp.bfloat16
     ohB = (binned[..., None] == jnp.arange(n_bins)[None, None, :]).astype(hdt)
     s32 = stats.astype(jnp.float32)
-    s_hi = s32.astype(hdt)
-    s_lo = (s32 - s_hi.astype(jnp.float32)).astype(hdt)
+    if hdt == jnp.bfloat16:
+        s_hi, s_lo = split_hi_lo(s32)
+    else:
+        s_hi = s32.astype(hdt)
+        s_lo = (s32 - s_hi.astype(jnp.float32)).astype(hdt)
     s2 = jnp.concatenate([s_hi, s_lo], axis=1)               # (n, 2m)
     return ohB, s2
+
+
+def split_hi_lo(s32):
+    """float32 ``s32`` as a bfloat16 pair ``(hi, lo)`` with ``hi + lo``
+    equal to it to 2^-16: ``hi`` is the value with its low 16 bits
+    CLEARED (a mask on the bits), ``lo`` the rest, rounded. Not ``hi =
+    round(s)``: XLA:TPU folds the float32 -> bfloat16 -> float32 round
+    trip that form subtracts away, which leaves ``lo = 0`` and a plain
+    bfloat16 histogram (found by the ``gbdt-fit`` cell's ``correct`` on
+    its first full-size run, and in a one-block micro-run: the rounding
+    form reads exactly what ``hi`` alone reads, worst cell 1.6e-2 of a
+    gradient sum of ~8; this form 5.4e-5, what a three-pass float32
+    product gives; PERF.md PR 31)."""
+    bits = jax.lax.bitcast_convert_type(s32, jnp.uint32) \
+        & jnp.uint32(0xFFFF0000)
+    hi = jax.lax.bitcast_convert_type(bits, jnp.float32)
+    return hi.astype(jnp.bfloat16), (s32 - hi).astype(jnp.bfloat16)
 
 
 def _pallas_level_hist(binned, stats, node_id, n_nodes: int, n_bins: int):
@@ -267,8 +333,12 @@ def _pallas_level_hist(binned, stats, node_id, n_nodes: int, n_bins: int):
     ``(Q, blk) @ (blk, m)`` dot into its feature's output block. Exact
     f32 accumulation (no bf16 quantization, no hi/lo split); the only
     HBM traffic is the binned rows, the stats, and the output —
-    the one-hot never materializes outside VMEM. Compiles on a v5e and
-    agrees with :func:`level_hist` (PR 21 chip run); not timed."""
+    the one-hot never materializes outside VMEM. Compiles on a v5e at
+    the adult shape and agrees with :func:`level_hist` there (PR 21 chip
+    run); never timed. It contracts ``(Q, blk) @ (blk, m)`` with m = 3 of
+    the MXU's 128 columns at ``HIGHEST`` and reads every row block once a
+    FEATURE; at 115 million rows its row-major inputs alone do not fit
+    the chip and the compiler refuses it (PR 31 chip run)."""
     from jax.experimental import pallas as pl
 
     n, F = binned.shape
@@ -403,6 +473,72 @@ def _default_cat_order(hist):
     return jnp.where(cnt > 0, r, jnp.inf)
 
 
+def _cat_columns(cat_feats, F: int, cat_order_fn=None):
+    """What :func:`best_splits` needs of the categorical columns (static
+    column selection), or ``None`` where there is none."""
+    if cat_feats is None:
+        return None
+    cat_np = np.asarray(cat_feats, bool)
+    if not cat_np.any():
+        return None
+    cat_idx = np.flatnonzero(cat_np)
+    cat_pos = np.zeros(F, np.int32)        # F-index -> cat-slice index
+    cat_pos[cat_idx] = np.arange(len(cat_idx), dtype=np.int32)
+    return (cat_idx, jnp.asarray(cat_pos), jnp.asarray(cat_np),
+            cat_order_fn or _default_cat_order)
+
+
+def best_splits(hist, n_bins: int, gain_fn, min_samples_leaf, min_gain,
+                feature_mask=None, cat=None):
+    """The split search of one level over its (all-reduced) histogram
+    ``(n_nodes, F, n_bins, m)``: per node ``(feature or -1, split bin,
+    LEFT-membership mask over the bins, gain counted to the feature's
+    importance)``. A few KB of work whatever the table's size."""
+    n_nodes, F = hist.shape[0], hist.shape[1]
+    bins_ar = jnp.arange(n_bins)
+    cum = jnp.cumsum(hist, axis=2)
+    total = cum[:, :, -1:, :]
+    left = cum[:, :, :-1, :]                      # split "bin <= b"
+    right = total - left
+    gains = gain_fn(left, right, total, min_samples_leaf)  # (nodes,F,B-1)
+    if cat is not None:
+        cat_idx, cat_pos, cat_arr, cat_order_fn = cat
+        # sorted-by-score cumulation over ONLY the categorical columns
+        # (static gather — continuous features skip the second pass):
+        # cut position c sends the first c+1 bins (in score order) left
+        hist_c = hist[:, cat_idx]                          # (nodes,Fc,B,m)
+        total_c = total[:, cat_idx]
+        order = jnp.argsort(cat_order_fn(hist_c), axis=2)  # (nodes,Fc,B)
+        shist = jnp.take_along_axis(hist_c, order[..., None], 2)
+        scum = jnp.cumsum(shist, axis=2)
+        sleft = scum[:, :, :-1, :]
+        sright = total_c - sleft
+        sgains = gain_fn(sleft, sright, total_c, min_samples_leaf)
+        gains = gains.at[:, cat_idx].set(sgains)
+        # rank[bin] = position of bin in score order
+        rank_c = jnp.argsort(order, axis=2)                # (nodes,Fc,B)
+    if feature_mask is not None:
+        gains = jnp.where(feature_mask[None, :, None] > 0, gains, -jnp.inf)
+    flat_g = gains.reshape(n_nodes, F * (n_bins - 1))
+    best = jnp.argmax(flat_g, axis=1)
+    best_gain = jnp.take_along_axis(flat_g, best[:, None], 1)[:, 0]
+    best_f = (best // (n_bins - 1)).astype(jnp.int32)
+    best_b = (best % (n_bins - 1)).astype(jnp.int32)
+    split = best_gain > min_gain
+    # LEFT-membership mask per node over bins
+    if cat is not None:
+        brank = jnp.take_along_axis(
+            rank_c, cat_pos[best_f][:, None, None], 1)[:, 0, :]  # (nodes,B)
+        is_cat = cat_arr[best_f]
+        pos = jnp.where(is_cat[:, None], brank, bins_ar[None, :])
+    else:
+        pos = jnp.broadcast_to(bins_ar[None, :], (n_nodes, n_bins))
+    mask = pos <= best_b[:, None]                          # (nodes, B)
+    return (jnp.where(split, best_f, -1), jnp.where(split, best_b, 0),
+            mask & split[:, None],
+            jnp.where(split, best_gain, jnp.zeros_like(best_gain)))
+
+
 def build_tree(binned, stats, max_depth: int, n_bins: int,
                gain_fn, leaf_fn, min_samples_leaf: float = 1.0,
                min_gain: float = 1e-9, feature_mask=None, axis_name=None,
@@ -432,18 +568,7 @@ def build_tree(binned, stats, max_depth: int, n_bins: int,
     node_id = jnp.zeros(n, jnp.int32)
     feats_out, bins_out, masks_out = [], [], []
     importance = jnp.zeros((F,), dt)
-    cat_order_fn = cat_order_fn or _default_cat_order
-    bins_ar = jnp.arange(n_bins)
-    if cat_feats is not None:
-        cat_np = np.asarray(cat_feats, bool)       # static column selection
-        if not cat_np.any():
-            cat_feats = None
-        else:
-            cat_idx = np.flatnonzero(cat_np)
-            cat_pos = np.zeros(F, np.int32)        # F-index -> cat-slice index
-            cat_pos[cat_idx] = np.arange(len(cat_idx), dtype=np.int32)
-            cat_pos = jnp.asarray(cat_pos)
-            cat_arr = jnp.asarray(cat_np)
+    cat = _cat_columns(cat_feats, F, cat_order_fn)
 
     use_onehot = jax.default_backend() == "tpu"
     # ALINK_TPU_FUSED_HIST: resolved at trace time, folded into the
@@ -469,48 +594,13 @@ def build_tree(binned, stats, max_depth: int, n_bins: int,
             hist = jnp.asarray(manifest_psum(hist, axis_name,
                                              name="tree_hist",
                                              num_workers=num_workers))
-        cum = jnp.cumsum(hist, axis=2)
-        total = cum[:, :, -1:, :]
-        left = cum[:, :, :-1, :]                      # split "bin <= b"
-        right = total - left
-        gains = gain_fn(left, right, total, min_samples_leaf)  # (nodes,F,B-1)
-        if cat_feats is not None:
-            # sorted-by-score cumulation over ONLY the categorical columns
-            # (static gather — continuous features skip the second pass):
-            # cut position c sends the first c+1 bins (in score order) left
-            hist_c = hist[:, cat_idx]                          # (nodes,Fc,B,m)
-            total_c = total[:, cat_idx]
-            order = jnp.argsort(cat_order_fn(hist_c), axis=2)  # (nodes,Fc,B)
-            shist = jnp.take_along_axis(hist_c, order[..., None], 2)
-            scum = jnp.cumsum(shist, axis=2)
-            sleft = scum[:, :, :-1, :]
-            sright = total_c - sleft
-            sgains = gain_fn(sleft, sright, total_c, min_samples_leaf)
-            gains = gains.at[:, cat_idx].set(sgains)
-            # rank[bin] = position of bin in score order
-            rank_c = jnp.argsort(order, axis=2)                # (nodes,Fc,B)
-        if feature_mask is not None:
-            gains = jnp.where(feature_mask[None, :, None] > 0, gains, -jnp.inf)
-        flat_g = gains.reshape(n_nodes, F * (n_bins - 1))
-        best = jnp.argmax(flat_g, axis=1)
-        best_gain = jnp.take_along_axis(flat_g, best[:, None], 1)[:, 0]
-        best_f = (best // (n_bins - 1)).astype(jnp.int32)
-        best_b = (best % (n_bins - 1)).astype(jnp.int32)
-        split = best_gain > min_gain
-        feats_out.append(jnp.where(split, best_f, -1))
-        bins_out.append(jnp.where(split, best_b, 0))
-        # LEFT-membership mask per node over bins
-        if cat_feats is not None:
-            brank = jnp.take_along_axis(
-                rank_c, cat_pos[best_f][:, None, None], 1)[:, 0, :]  # (nodes,B)
-            is_cat = cat_arr[best_f]
-            pos = jnp.where(is_cat[:, None], brank, bins_ar[None, :])
-        else:
-            pos = jnp.broadcast_to(bins_ar[None, :], (n_nodes, n_bins))
-        mask = pos <= best_b[:, None]                          # (nodes, B)
-        masks_out.append(mask & split[:, None])
-        importance = importance.at[best_f].add(
-            jnp.where(split, best_gain, jnp.zeros_like(best_gain)))
+        feat, sbin, mask, gain = best_splits(
+            hist, n_bins, gain_fn, min_samples_leaf, min_gain, feature_mask,
+            cat)
+        feats_out.append(feat)
+        bins_out.append(sbin)
+        masks_out.append(mask)
+        importance = importance.at[jnp.maximum(feat, 0)].add(gain)
         # descend: right iff split and sample's bin is not in the left set
         nf = feats_out[-1][node_id]
         sample_bin = jnp.take_along_axis(binned, jnp.maximum(nf, 0)[:, None], 1)[:, 0]
@@ -529,6 +619,206 @@ def build_tree(binned, stats, max_depth: int, n_bins: int,
     split_masks = jnp.concatenate(masks_out, axis=0)
     return (features, split_bins, split_masks, leaf_fn(leaf_hist), node_id,
             leaf_hist, importance)
+
+
+# ---------------------------------------------------------------------------
+# the blocked builder: a table of deployment size, where it lies
+# ---------------------------------------------------------------------------
+#
+# The bins are a uint8 table ``(blocks, F, S, 128)`` laid out as the raw
+# ``DenseBlockColumn`` is, node ids and per-row stats ``(blocks, S, 128)``.
+# A level walks the worker's shard block by block (``lax.fori_loop``): the
+# rows descend by the level before, the block's histogram is ONE product
+# on the MXU, ``onehot(bin)^T (F * n_bins, rows)`` against ``(node one-hot
+# x stats) (n_nodes * 2m, rows)``, whose bin one-hot XLA fuses into the
+# product's operand (it is never written to memory: compiled and timed
+# on a v5e, PERF.md PR 31), and the block's result is Kahan-added to the
+# shard's. Nothing of shape ``(n, F, n_bins)`` or ``(n, m)`` exists. The
+# stats ride the bfloat16 product as a compensated (hi, lo) pair and are
+# summed in float32; a count of whole weights is exact however many rows
+# there are, because a block's sum is exact (under 2^24 rows) and the
+# Kahan pair ``(sum, lost)`` of whole numbers stays whole: it is read out
+# as ``int32(sum) - int32(lost)``. Off the TPU a block's histogram is the
+# scatter-add :func:`level_hist` uses there.
+
+#: a per-node table up to this long is looked up by a chain of selects
+#: (no gather); a longer one by ``jnp.take``
+_SELECT_CHAIN_MAX = 64
+
+
+def block_hist_path() -> str:
+    """Which histogram a blocked level builds: the ``"onehot"`` product
+    on a TPU, the ``"scatter"`` elsewhere. Chosen from the backend, as
+    :func:`build_tree`'s ``use_onehot`` is; the fit reports it."""
+    return "onehot" if jax.default_backend() == "tpu" else "scatter"
+
+
+def lookup(table, ids):
+    """``table[ids]`` for a small 1-D ``table`` and ids of any shape."""
+    n = table.shape[0]
+    if n > _SELECT_CHAIN_MAX:
+        return jnp.take(table, ids, axis=0)
+    out = jnp.zeros(ids.shape, table.dtype)
+    for i in range(n):
+        out = jnp.where(ids == i, table[i], out)
+    return out
+
+
+def _whole(acc, comp):
+    """The exact int32 value of a Kahan pair of whole numbers."""
+    return acc.astype(jnp.int32) - comp.astype(jnp.int32)
+
+
+def block_hist(bins_b, node_b, stats_b, n_nodes: int, n_bins: int,
+               path: str):
+    """(n_nodes, F, n_bins, m) float32 stat sums of ONE block: ``bins_b``
+    ``(F, S, 128)`` uint8, ``node_b`` ``(S, 128)`` int32, ``stats_b``
+    ``(m, S, 128)`` float32 (zero rows are inert)."""
+    F, m = bins_b.shape[0], stats_b.shape[0]
+    b = bins_b.reshape(F, -1).astype(jnp.int32)
+    nd = node_b.reshape(-1)
+    st = stats_b.reshape(m, -1).astype(jnp.float32)
+    if path == "onehot":
+        s2 = jnp.concatenate(split_hi_lo(st), 0)                # (2m, R)
+        oh_n = nd[None, :] == jnp.arange(n_nodes, dtype=jnp.int32)[:, None]
+        W = jnp.where(oh_n[:, None, :], s2[None], 0).reshape(
+            n_nodes * 2 * m, -1)
+        oh_b = (b[:, None, :] == jnp.arange(
+            n_bins, dtype=jnp.int32)[None, :, None]).astype(jnp.bfloat16)
+        h2 = jnp.einsum("fbr,qr->fbq", oh_b, W,
+                        preferred_element_type=jnp.float32)
+        h2 = h2.reshape(F, n_bins, n_nodes, 2, m)
+        return (h2[..., 0, :] + h2[..., 1, :]).transpose(2, 0, 1, 3)
+    flat = (nd[None, :] * F + jnp.arange(F, dtype=jnp.int32)[:, None]
+            ) * n_bins + b
+    hist = jnp.zeros((n_nodes * F * n_bins, m), jnp.float32)
+    hist = hist.at[flat.reshape(-1)].add(jnp.tile(st.T, (F, 1)))
+    return hist.reshape(n_nodes, F, n_bins, m)
+
+
+def descend_block(bins_b, node_b, feats, sbins, masks, continuous: bool):
+    """One level down for the rows of a block: ``node_b`` ``(S, 128)``
+    holds each row's node of the level whose splits ``feats``, ``sbins``
+    ``(n_nodes,)`` and ``masks`` ``(n_nodes, n_bins)`` are; a row goes
+    right iff its node split and its bin of the split feature is not in
+    the node's LEFT set. Where every feature is ``continuous`` that set
+    is ``bin <= split bin`` and no row reads a table by index: the node's
+    feature and split bin come by a chain of selects over the (few)
+    nodes, the row's bin of that feature by one over the features."""
+    f_row = lookup(feats, node_b)
+    sel = jnp.zeros(node_b.shape, jnp.int32)
+    for f in range(bins_b.shape[0]):
+        sel = jnp.where(f_row == f, bins_b[f].astype(jnp.int32), sel)
+    if continuous:
+        in_left = sel <= lookup(sbins, node_b)
+    else:
+        in_left = masks[node_b, sel]
+    return node_b * 2 + ((f_row >= 0) & ~in_left).astype(jnp.int32)
+
+
+def build_tree_blocked(bins, node_id, stats_at, max_depth: int, n_bins: int,
+                       gain_fn, leaf_fn, min_samples_leaf: float = 1.0,
+                       min_gain: float = 1e-9, feature_mask=None,
+                       axis_name=None, cat_feats=None, cat_order_fn=None,
+                       num_workers: int = 1, path: Optional[str] = None):
+    """:func:`build_tree` over a worker's shard of a blocked table.
+
+    ``bins``: ``(blocks, F, S, 128)`` uint8; ``node_id``: ``(blocks, S,
+    128)`` int32, the buffer the rows' nodes are written to (its content
+    is not read); ``stats_at(i)``: block ``i``'s per-row stats ``(m, S,
+    128)`` float32, the LAST of them the row's weight (rows of zero stats
+    are inert: padding, bagging), called once a level so that stats which
+    are cheap to recompute need no ``(n, m)`` array. The other arguments
+    are :func:`build_tree`'s; ``path`` is :func:`block_hist_path`'s word.
+
+    Returns ``(features, split_bins, split_masks, leaf_values, node_id,
+    leaf_hist, importance, counts)``: as :func:`build_tree`, with
+    ``node_id`` the rows' leaves in the blocked layout and ``counts``
+    ``(2^(max_depth+1) - 1,)`` int32 the summed weight of every node,
+    level by level and the leaves last, exact where the weights are whole
+    numbers (a float32 sum stops being exact at 2^24)."""
+    nbl, F = bins.shape[0], bins.shape[1]
+    path = path or block_hist_path()
+    cat = _cat_columns(cat_feats, F, cat_order_fn)
+    continuous = cat is None
+    m = jax.eval_shape(stats_at, jnp.asarray(0, jnp.int32)).shape[0]
+    at = block_at
+    put = jax.lax.dynamic_update_index_in_dim
+
+    def reduce_pair(acc, comp, name):
+        """The shard's Kahan pair summed over the mesh: float32 stats and
+        the exact weight a node (or leaf), which rides the same psum as
+        two halves."""
+        lead = acc.shape[0]
+        w_at = (slice(None), 0, slice(None), m - 1) if acc.ndim == 4 \
+            else (slice(None), m - 1)
+        cnt = _whole(acc[w_at], comp[w_at]).reshape(lead, -1).sum(1)
+        if axis_name is None:
+            return acc - comp, cnt
+        hi, lo = split_count(cnt)
+        buf = jnp.concatenate([acc.reshape(-1), comp.reshape(-1),
+                               hi.astype(jnp.float32),
+                               lo.astype(jnp.float32)])
+        buf = jnp.asarray(manifest_psum(buf, axis_name, name=name,
+                                        num_workers=num_workers))
+        k = acc.size
+        return ((buf[:k] - buf[k:2 * k]).reshape(acc.shape),
+                join_count(buf[2 * k:2 * k + lead], buf[2 * k + lead:]))
+
+    feats_out, bins_out, masks_out, counts = [], [], [], []
+    importance = jnp.zeros((F,), jnp.float32)
+    prev = None
+    for level in range(max_depth + 1):
+        n_nodes = 1 << level
+        leaves = level == max_depth
+
+        def body(i, c, n_nodes=n_nodes, prev=prev, leaves=leaves):
+            node_id, acc, comp = c
+            bins_b = at(bins, i)
+            if prev is None:
+                node_b = jnp.zeros(node_id.shape[1:], jnp.int32)
+            else:
+                with jax.named_scope("gbdt_descend"):
+                    node_b = descend_block(bins_b, at(node_id, i), *prev,
+                                           continuous)
+            stats_b = stats_at(i)
+            if leaves:
+                with jax.named_scope("gbdt_leaf"):
+                    oh = (node_b[None] == jnp.arange(
+                        n_nodes, dtype=jnp.int32)[:, None, None])
+                    blk = jnp.einsum(
+                        "lsr,msr->lm", oh.astype(jnp.float32),
+                        stats_b.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+            else:
+                with jax.named_scope("gbdt_hist"):
+                    blk = block_hist(bins_b, node_b, stats_b, n_nodes,
+                                     n_bins, path)
+            acc, comp = kahan_add(acc, comp, blk)
+            return put(node_id, node_b, i, 0), acc, comp
+
+        zero = jnp.zeros((n_nodes, m) if leaves
+                         else (n_nodes, F, n_bins, m), jnp.float32)
+        node_id, acc, comp = jax.lax.fori_loop(
+            0, nbl, body, (node_id, zero, zero))
+        hist, cnt = reduce_pair(acc, comp,
+                                "tree_leaf_hist" if leaves else "tree_hist")
+        counts.append(cnt)
+        if leaves:
+            leaf_hist = hist
+            break
+        with jax.named_scope("gbdt_split"):
+            feat, sbin, mask, gain = best_splits(
+                hist, n_bins, gain_fn, min_samples_leaf, min_gain,
+                feature_mask, cat)
+        feats_out.append(feat)
+        bins_out.append(sbin)
+        masks_out.append(mask)
+        importance = importance.at[jnp.maximum(feat, 0)].add(gain)
+        prev = (feat, sbin, mask)
+    return (jnp.concatenate(feats_out), jnp.concatenate(bins_out),
+            jnp.concatenate(masks_out, axis=0), leaf_fn(leaf_hist), node_id,
+            leaf_hist, importance, jnp.concatenate(counts))
 
 
 def tree_apply_binned(binned, features, split_bins, max_depth: int,
@@ -557,11 +847,29 @@ def tree_apply_binned(binned, features, split_bins, max_depth: int,
 
 def bins_to_thresholds(features: np.ndarray, split_bins: np.ndarray,
                        edges: np.ndarray) -> np.ndarray:
-    """Real-valued split thresholds for host-side serving: x > thr -> right."""
+    """Real-valued split thresholds for host-side serving: x > thr -> right.
+
+    A value's bin is the number of edges AT OR BELOW it, so a split at
+    bin ``b`` sends ``x >= edges[b]`` right; the threshold is the float64
+    just under that edge, so that the served rule ``x > thr`` sends a row
+    that TIES the edge (whole-number columns do) the way training did."""
     thr = np.zeros(features.shape, np.float64)
     for i, (f, b) in enumerate(zip(features, split_bins)):
-        thr[i] = edges[int(f), int(b)] if f >= 0 else 0.0
+        thr[i] = (np.nextafter(edges[int(f), int(b)], -np.inf)
+                  if f >= 0 else 0.0)
     return thr
+
+
+def thresholds_as(thresholds: np.ndarray, dtype) -> np.ndarray:
+    """Thresholds for a kernel that serves in ``dtype``: the largest
+    ``dtype`` number not above each, so that ``x > thr`` holds for exactly
+    the same ``dtype`` values ``x``. Rounding to the NEAREST float32 would
+    put the float64 just under an edge (:func:`bins_to_thresholds`) back
+    on the edge, and a row that ties it on the other side."""
+    thr = np.asarray(thresholds, np.float64)
+    out = thr.astype(dtype)
+    return np.where(out.astype(np.float64) > thr,
+                    np.nextafter(out, np.asarray(-np.inf, dtype)), out)
 
 
 def tree_apply_values(X: np.ndarray, features: np.ndarray, thresholds: np.ndarray,

@@ -803,9 +803,8 @@ def sweep_kmeans(X: np.ndarray, k: int, points: Sequence[Dict[str, Any]],
     ``max_iter``. Per-point centroids are bitwise identical to
     ``kmeans_train`` with that point's parameters."""
     from ..common.mlenv import MLEnvironmentFactory
-    from ..operator.common.clustering.kmeans import (as_block_column,
-                                                     block_weights,
-                                                     kmeans_parallel_init,
+    from ..common.columnar import as_block_column, block_weights
+    from ..operator.common.clustering.kmeans import (kmeans_parallel_init,
                                                      kmeans_plus_plus_init,
                                                      random_init)
     X = np.asarray(X)

@@ -235,10 +235,11 @@ def _host_blocks_ranked(col, cands, seed, l, rounds, nw):
     smallest of the worker's running best ``l``."""
     import jax
     import jax.numpy as jnp
+    from alink_tpu.common.columnar import block_weights
     from alink_tpu.operator.common.clustering import kmeans as K
 
     blocks = np.asarray(col.blocks)
-    w = np.asarray(K.block_weights(col))
+    w = np.asarray(block_weights(col))
     nbl = -(-blocks.shape[0] // nw)
     out = []
     for r in range(1, rounds + 1):

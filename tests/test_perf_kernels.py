@@ -206,8 +206,11 @@ def _gbdt_fixture(n=1500, F=6, seed=0):
 
 
 def _train_with_mode(mode, X, y, monkeypatch, interpret=False):
+    """A fit through ``hist.build_tree``, whose per-level histogram the
+    flag selects: a histogram-parallel forest since PR 31 (``gbdt_train``
+    grows on the blocked builder, which the flag does not reach)."""
     from alink_tpu.operator.common.tree.trainers import (TreeTrainParams,
-                                                         gbdt_train)
+                                                         forest_train)
     if mode is None:
         monkeypatch.delenv("ALINK_TPU_FUSED_HIST", raising=False)
     else:
@@ -215,10 +218,12 @@ def _train_with_mode(mode, X, y, monkeypatch, interpret=False):
     if interpret:
         monkeypatch.setenv("ALINK_TPU_PALLAS_INTERPRET", "1")
     p = TreeTrainParams(num_trees=3, max_depth=4, n_bins=16,
-                        learning_rate=0.3)
-    tf, tb, tm, tv, edges, base, curve, imp = gbdt_train(X, y, p, False)
+                        subsample_ratio=0.8)
+    stats = np.stack([y, y * y, np.ones_like(y)], 1)
+    tf, tb, tm, tv, edges, imp = forest_train(X, stats, p, "variance",
+                                              ensemble=False)
     return (np.asarray(tf), np.asarray(tb), np.asarray(tv),
-            np.asarray(curve))
+            np.asarray(imp))
 
 
 class TestFusedHistogram:
@@ -234,7 +239,7 @@ class TestFusedHistogram:
             assert (got[0] == off[0]).all(), name     # features
             assert (got[1] == off[1]).all(), name     # split bins
             np.testing.assert_allclose(got[3], off[3], rtol=1e-4,
-                                       err_msg=name)  # loss curve
+                                       err_msg=name)  # importances
 
     def test_mode_resolution_and_gating(self, monkeypatch):
         from alink_tpu.operator.common.tree.hist import fused_hist_mode
@@ -338,10 +343,11 @@ class TestFusedHistogram:
 
         def gbdt_keys():
             # cache keys are (user_key, stages_digest, mesh, ...): the
-            # trainers' tuple leads the composite
+            # trainers' tuple leads the composite (the forest's: see
+            # ``_train_with_mode``)
             return {k[0] for k in cq._PROGRAM_CACHE
                     if isinstance(k[0], tuple) and k[0]
-                    and k[0][0] == "gbdt"}
+                    and k[0][0] == "forest"}
 
         X, y = _gbdt_fixture(n=400, F=4, seed=2)
         _train_with_mode(None, X, y, monkeypatch)
