@@ -14,8 +14,12 @@ ONE availability/demotion contract they all ride (``runtime``):
   low-precision score path (``ALINK_TPU_SERVE_DTYPE``);
 * ``kmeans``   — the k-means|| candidate fold (ISSUE 30): distances,
   min, argmin and the ``(d2, nearest)`` update of a table block in one
-  streamed pass. It has no flag: a fit takes it where its input allows
-  (``kmeans.fold_path``) and says so (``init_fold``).
+  streamed pass; and Lloyd's superstep (ISSUE 32): distances, argmin and
+  every cluster's centred sums, weight and inertia of a block in
+  registers, Kahan-joined across the blocks inside the kernel. Neither
+  has a flag: a fit takes them where its input allows
+  (``kmeans.fold_path``, ``kmeans.lloyd_path``) and says so
+  (``init_fold``, ``lloyd_pass``).
 
 Every kernel is parity-pinned against its XLA path (bitwise where the
 contract demands it, pinned tolerance where association differs) and

@@ -1,4 +1,7 @@
-"""The k-means|| candidate fold as one streamed Pallas kernel (ISSUE 30).
+"""The two passes of KMeans over the blocked table, each as one streamed
+Pallas kernel: the k-means|| candidate fold (ISSUE 30) and Lloyd's
+superstep (ISSUE 32, :func:`lloyd_sums`, built like the fold; its own
+account is there).
 
 A k-means|| round folds its ``l`` new candidates into the per-row
 ``(d2, nearest)`` state. As XLA compiles the ``block_distances``
@@ -22,14 +25,16 @@ exists.
 The arithmetic is ``block_distances``' own (float32, the direct form,
 features summed in order, ties to the lowest candidate); a distance may
 differ from XLA's reduction in its last bits. Which path a fit takes is
-read from its input (:func:`fold_path`), never set.
+read from its input (:func:`fold_path`, :func:`lloyd_path`), never set.
 """
 
 from __future__ import annotations
 
+import functools
+
 from .runtime import interpret_mode, pallas_available
 
-__all__ = ["fold_path", "fold_candidates"]
+__all__ = ["fold_path", "fold_candidates", "lloyd_path", "lloyd_sums"]
 
 _LANES = 128
 #: sublanes of one float32 register
@@ -47,6 +52,16 @@ _TABLE_VMEM = 12 << 20
 #: candidates × features up to which the kernel runs: its arithmetic is
 #: unrolled over them, and each is held in VMEM spread over a register
 _UNROLLED = 1024
+#: registers a step of Lloyd's inner loop keeps from its distances to
+#: its sums (a cluster's masked weight, a register a tile of rows)
+_LLOYD_MASKS = 24
+#: clusters × features up to which Lloyd's kernel runs: its arithmetic is
+#: unrolled over them twice (distances, sums), and every sum is a
+#: register of partial sums, held in VMEM six times over
+_LLOYD_UNROLLED = 512
+#: stacks of rows a trip of Lloyd's inner loop takes, one after the
+#: other: the second's reads start under the first's arithmetic
+_LLOYD_STACKS = 2
 
 
 def fold_path(dtype, S: int, l: int, d: int) -> str:
@@ -166,3 +181,187 @@ def fold_candidates(Xs, Ws, d2, nearest, new, off):
       Xs.reshape((nbl, d) + tiled[1:]), Ws.reshape(tiled),
       d2.reshape(tiled), nearest.reshape(tiled))
     return d2.reshape(Ws.shape), nearest.reshape(Ws.shape)
+
+
+def lloyd_path(dtype, S: int, k: int, d: int, distance_type: str) -> str:
+    """``"kernel"`` where :func:`lloyd_sums` can run — as
+    :func:`fold_path`, for the squared Euclidean distance and ``k``
+    clusters of ``d`` features that the kernel can unroll and hold —
+    else ``"xla"``."""
+    import numpy as np
+    ok = pallas_available() and np.dtype(dtype) == np.float32 \
+        and S % _TILE == 0 and distance_type == "EUCLIDEAN" \
+        and k * d <= _LLOYD_UNROLLED
+    if ok:
+        import jax.experimental.pallas.tpu  # noqa: F401  (see fold_path)
+    return "kernel" if ok else "xla"
+
+
+def lloyd_sums(Xs, Ws, C):
+    """One Lloyd pass over a shard as ONE ``pallas_call``: every row
+    goes to its nearest centroid of ``C`` ``(k, d)`` (``block_distances``'
+    arithmetic, ties to the lowest) and into that cluster's sums. ``Xs``
+    is ``(nbl, d, S, 128)`` float32, ``Ws`` ``(nbl, S, 128)`` float32.
+    Returns ``(acc (k + 1, d + 1) float32, rows int32)``: ``acc[:k]``
+    holds ``sum w (x - c_j)`` and, last, the cluster's summed weight;
+    ``acc[k, 0]`` the weighted inertia; ``rows`` the rows of weight other
+    than 0.
+
+    The grid walks the blocks in order, block ``i + 1`` fetched under
+    block ``i``'s arithmetic. Per stack of register tiles of rows: the
+    ``k`` running distances feature by feature, ``(min, argmin)``, the
+    masked weight ``w [argmin = j]`` a cluster, and with it the stack's
+    share of every sum. Each sum is a register of 1,024 partial sums in
+    VMEM. Inside a grid step a row enters centred on ONE point, the mean
+    of the centroids (a multiplication and an addition a cluster and
+    feature; centred on its own centroid, a subtraction more); at the
+    step's end the partial sums move to their own centroids, ``- weight
+    (c_j - mean)`` on at most a few hundred rows each, and join the
+    pass's with a Kahan compensation a partial sum. XLA adds the 1,024
+    up once a pass. Nothing of shape ``(k, S, 128)`` exists."""
+    return _lloyd_call()(Xs, Ws, C, interpret=interpret_mode())
+
+
+# jitted: the init pass and the loop body of the Lloyd program call it on
+# the same shapes, so the kernel is traced and lowered once
+@functools.lru_cache(maxsize=None)
+def _lloyd_call():
+    import jax
+    return jax.jit(_lloyd_sums, static_argnames="interpret")
+
+
+def _lloyd_sums(Xs, Ws, C, interpret: bool):
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    nbl, d, S, L = Xs.shape
+    k = C.shape[0]
+    f32 = jnp.float32
+    Sb = _sublanes_per_step(d, S)
+    tiles = Sb // _TILE
+    # register tiles of rows a stack holds, and clusters one vector
+    # operation covers
+    U = max(u for u in range(1, max(_LLOYD_MASKS // k, 1) + 1)
+            if tiles % u == 0)
+    G = max(_OPERATION // U, 1)
+    groups = [(g, min(g + G, k)) for g in range(0, k, G)]
+
+    def kernel(c_ref, shift_ref, x_ref, w_ref, out_ref, comp_ref, part_ref):
+        # out_ref[f, j] is cluster j's sum over feature f (f = d: its
+        # weight); out_ref[0, k] the inertia, out_ref[1, k] the rows seen
+        @pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0))
+        def _():
+            out_ref[...] = jnp.zeros(out_ref.shape, f32)
+            comp_ref[...] = jnp.zeros(comp_ref.shape, f32)
+
+        part_ref[...] = jnp.zeros(part_ref.shape, f32)
+
+        def stack(t):
+            rows = pl.ds(t * U, U)
+
+            def over_rows(a):                  # (g, 8, 128) -> (g, U, 8, 128)
+                return lax.broadcast_in_dim(
+                    a, (a.shape[0], U, _TILE, L), (0, 2, 3))
+
+            def over_clusters(a, g):           # (U, 8, 128) -> (g, U, 8, 128)
+                return lax.broadcast_in_dim(a, (g, U, _TILE, L), (1, 2, 3))
+
+            def distance(f, dist):             # features in order
+                xf = x_ref[0, f, rows]
+                return [lax.add(acc, lax.square(lax.sub(
+                    over_clusters(xf, hi - lo), over_rows(c_ref[f, lo:hi]))))
+                    for (lo, hi), acc in zip(groups, dist)]
+
+            dist = lax.fori_loop(
+                0, d, distance,
+                [jnp.zeros((hi - lo, U, _TILE, L), f32)
+                 for lo, hi in groups], unroll=True)
+            dist = lax.concatenate(dist, 0)    # (k, U, 8, 128)
+            best = dist[0]
+            arg = jnp.zeros(best.shape, jnp.int32)
+            for j in range(1, k):              # ties to the lowest
+                closer = lax.lt(dist[j], best)
+                best = lax.select(closer, dist[j], best)
+                arg = lax.select(closer, jnp.full(arg.shape, j, jnp.int32),
+                                 arg)
+            w = w_ref[0, rows]
+            # a cluster's masked weight: w on its own rows
+            own = [lax.select(
+                lax.eq(lax.broadcasted_iota(
+                    jnp.int32, (hi - lo, U, _TILE, L), 0) + lo,
+                    over_clusters(arg, hi - lo)),
+                over_clusters(w, hi - lo),
+                jnp.zeros((hi - lo, U, _TILE, L), f32))
+                for lo, hi in groups]
+
+            def sums(f, _):
+                xf = lax.sub(x_ref[0, f, rows], lax.broadcast_in_dim(
+                    c_ref[f, k], (U, _TILE, L), (1, 2)))
+                for (lo, hi), m in zip(groups, own):
+                    part_ref[f, lo:hi] += lax.reduce_sum(
+                        lax.mul(m, over_clusters(xf, hi - lo)), (1,))
+
+            lax.fori_loop(0, d, sums, None, unroll=True)
+            for (lo, hi), m in zip(groups, own):
+                part_ref[d, lo:hi] += lax.reduce_sum(m, (1,))
+            part_ref[0, k] += lax.reduce_sum(lax.mul(w, best), (0,))
+            part_ref[1, k] += lax.reduce_sum(
+                jnp.where(w != 0, f32(1), f32(0)), (0,))
+
+        R = _LLOYD_STACKS if tiles // U % _LLOYD_STACKS == 0 else 1
+
+        def trip(t, _):
+            for r in range(R):
+                stack(t * R + r)
+
+        lax.fori_loop(jnp.int32(0), jnp.int32(tiles // U // R), trip, None)
+
+        def join(f, _):
+            # to the clusters' own centroids, then ``kahan_add``
+            y = part_ref[f] - part_ref[d] * shift_ref[f] - comp_ref[f]
+            acc = out_ref[f]
+            t = acc + y
+            comp_ref[f] = (t - acc) - y
+            out_ref[f] = t
+
+        lax.fori_loop(jnp.int32(0), jnp.int32(d + 1), join, None)
+
+    # each centroid coordinate spread over a register, fetched once: last
+    # the point a step's sums are centred on, and how far from it each
+    # centroid lies (nothing where a register holds no sum over a feature)
+    C = C.astype(f32)
+    mid = C.mean(0, keepdims=True)
+    held = (d + 1, k + 1, _TILE, L)
+    spread = jnp.broadcast_to(
+        jnp.concatenate([C, mid], 0).T[:, :, None, None],
+        (d, k + 1, _TILE, L))
+    shift = jnp.broadcast_to(
+        jnp.pad((C - mid).T, ((0, 1), (0, 1)))[:, :, None, None], held)
+    whole = pl.BlockSpec(held, lambda i, s: (0, 0, 0, 0))
+    out = pl.pallas_call(
+        kernel,
+        grid=(nbl, S // Sb),
+        in_specs=[pl.BlockSpec((d, k + 1, _TILE, L),
+                               lambda i, s: (0, 0, 0, 0)),
+                  whole,
+                  pl.BlockSpec((1, d, tiles, _TILE, L),
+                               lambda i, s: (i, 0, s, 0, 0)),
+                  pl.BlockSpec((1, tiles, _TILE, L),
+                               lambda i, s: (i, s, 0, 0))],
+        out_specs=whole,
+        out_shape=jax.ShapeDtypeStruct(held, f32),
+        scratch_shapes=[pltpu.VMEM(held, f32), pltpu.VMEM(held, f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=2 * _TABLE_VMEM
+            + 8 * (d + 1) * (k + 1) * _TILE * L * 4),
+        interpret=interpret,
+        name="lloyd_pass",
+    )(spread, shift, Xs.reshape(nbl, d, S // _TILE, _TILE, L),
+      Ws.reshape(nbl, S // _TILE, _TILE, L))
+    acc = out.sum((2, 3)).T                    # (k + 1, d + 1)
+    rows = out[1, k].astype(jnp.int32).sum()   # whole numbers: exact
+    return acc.at[k, 1].set(0), rows

@@ -41,7 +41,8 @@ from ....common.mlenv import MLEnvironment, MLEnvironmentFactory
 from ....common.tracing import trace_span
 from ....engine import AllReduce, IterativeComQueue
 from ....engine.communication import manifest_all_gather
-from ....kernels.kmeans import fold_candidates, fold_path
+from ....kernels.kmeans import (fold_candidates, fold_path, lloyd_path,
+                                lloyd_sums)
 from ..blocked import (block_at as _block_at, join_count as _join_count,
                        kahan_add as _kahan_add, split_count as _split_count)
 
@@ -447,10 +448,16 @@ def _lloyd_pass(Xs, Ws, C, distance_type: str):
     """One pass over a worker's shard: the ``(k + 2, d + 1)`` buffer the
     AllReduce sums. Rows ``:k`` hold ``sum w (x - c_j)`` and, last, the
     cluster's summed weight; row ``k`` the weighted inertia; row ``k + 1``
-    the rows seen, split so a float psum keeps the count exact."""
+    the rows seen, split so a float psum keeps the count exact. Who walks
+    the blocks is read from the input (``kernels.kmeans.lloyd_path``): the
+    one streamed ``"kernel"``, or ``"xla"``, ``block_distances`` block by
+    block."""
     nbl, d = Xs.shape[0], Xs.shape[1]
     k = C.shape[0]
     dt = Xs.dtype
+    if lloyd_path(dt, Xs.shape[2], k, d, distance_type) == "kernel":
+        acc, rows = lloyd_sums(Xs, Ws, C)
+        return _with_rows(acc, rows)
     Cb = C[:, :, None, None]
     ids = jnp.arange(k, dtype=jnp.int32)[:, None, None]
 
@@ -472,9 +479,16 @@ def _lloyd_pass(Xs, Ws, C, distance_type: str):
     zero = jnp.zeros((k + 1, d + 1), dt)
     acc, _, rows = jax.lax.fori_loop(
         0, nbl, body, (zero, zero, jnp.asarray(0, jnp.int32)))
+    return _with_rows(acc, rows)
+
+
+def _with_rows(acc, rows):
+    """``_lloyd_pass``'s buffer from its sums ``(k + 1, d + 1)`` and the
+    rows it saw."""
     hi, lo = _split_count(rows)
-    tail = jnp.zeros((1, d + 1), dt).at[0, 0].set(hi).at[0, 1].set(lo)
-    return jnp.concatenate([acc, tail.astype(dt)], 0)
+    tail = jnp.zeros((1, acc.shape[1]), acc.dtype).at[0, 0].set(hi) \
+        .at[0, 1].set(lo)
+    return jnp.concatenate([acc, tail], 0)
 
 
 def _lloyd_update(buf, C):
@@ -535,6 +549,7 @@ def kmeans_train(X, k: int, max_iter: int = 50, tol: float = 1e-4,
     else:  # K_MEANS_PLUS_PLUS / legacy host seeding on a bounded sample
         init_c = kmeans_plus_plus_init(col, k, seed)
     init_c = np.asarray(init_c, dt)
+    path = lloyd_path(dt, col.block_rows // LANES, k, d, distance_type)
 
     def assign(ctx):
         if ctx.is_init_step:
@@ -574,7 +589,7 @@ def kmeans_train(X, k: int, max_iter: int = 50, tol: float = 1e-4,
              .add(update)
              .set_compare_criterion(lambda ctx: ctx.get_obj("movement") < tol)
              .set_program_key((LLOYD_PROGRAM, k, d, distance_type, float(tol),
-                               str(dt))))
+                               str(dt), path)))
     if checkpoint_dir:
         # knob validation (every/keep_last >= 1) lives in CheckpointConfig
         queue.set_checkpoint(checkpoint_dir, every=int(checkpoint_every),
@@ -588,15 +603,19 @@ def kmeans_train(X, k: int, max_iter: int = 50, tol: float = 1e-4,
         warn_if_disabled("kmeans_train(health=...)", stacklevel=3)
         queue.set_health(health)
     with trace_span("kmeans.lloyd", cat="kmeans",
-                    args={"rows": n, "k": int(k), "max_iter": int(max_iter)}):
+                    args={"rows": n, "k": int(k), "max_iter": int(max_iter),
+                          "pass": path}):
         result = queue.exec()
         steps, rows_seen, cents, wts, hist_c, hist_w, hist_i = result.get_all(
             ["__step", "rows", "centroids", "cluster_weights",
              "hist_centroids", "hist_weights", "hist_inertia"])
         steps = int(steps)
     _count(rows_seen[:steps].sum(dtype=np.int64), steps)
+    if metrics_enabled():
+        get_registry().inc("alink_kmeans_lloyd_blocks_total",
+                           steps * col.row_blocks, {"path": path})
     if info is not None:
-        info.update(init_centroids=init_c, steps=steps,
+        info.update(init_centroids=init_c, steps=steps, lloyd_pass=path,
                     rows=np.asarray(rows_seen[:steps]),
                     centroids=np.asarray(hist_c[:steps]),
                     weights=np.asarray(hist_w[:steps]),

@@ -803,7 +803,8 @@ def sweep_kmeans(X: np.ndarray, k: int, points: Sequence[Dict[str, Any]],
     ``max_iter``. Per-point centroids are bitwise identical to
     ``kmeans_train`` with that point's parameters."""
     from ..common.mlenv import MLEnvironmentFactory
-    from ..common.columnar import as_block_column, block_weights
+    from ..common.columnar import LANES, as_block_column, block_weights
+    from ..kernels.kmeans import lloyd_path
     from ..operator.common.clustering.kmeans import (kmeans_parallel_init,
                                                      kmeans_plus_plus_init,
                                                      random_init)
@@ -860,7 +861,10 @@ def sweep_kmeans(X: np.ndarray, k: int, points: Sequence[Dict[str, Any]],
         res = _run_sweep_queue(
             kind="kmeans", stage=stage, parts=parts,
             bcast=bcast, env=env, max_iter=g_iter, seed=int(seed),
-            key_tail=(g_k, d, g_dist, str(dt)),
+            # the word ``_lloyd_pass`` reads from the same input: a program
+            # cached for one path is never run under the other
+            key_tail=(g_k, d, g_dist, str(dt),
+                      lloyd_path(dt, col.block_rows // LANES, g_k, d, g_dist)),
             num_points=P, asha=_resolve_asha(asha, g_iter),
             checkpoint_dir=ck_dir, checkpoint_keep=checkpoint_keep,
             resume_from=rs, rung_log=rung_log)

@@ -405,3 +405,109 @@ def test_kmeans_parallel_init_same_candidates_by_either_fold(monkeypatch, nw):
         assert np.array_equal(out["kernel"][name], out["xla"][name]), name
     assert np.abs(out["kernel"]["init_weights"]
                   - out["xla"]["init_weights"]).sum() <= 2
+
+
+# -- Lloyd's superstep as one streamed kernel (PR 32) ---------------------------
+
+@pytest.mark.parametrize("S, cut", [(8, False), (512, False), (512, True)],
+                         ids=["S8", "S512", "S512_cut"])
+@pytest.mark.parametrize("k", [1, 3, 10])
+def test_lloyd_kernel_is_the_xla_pass(monkeypatch, k, S, cut):
+    """The streamed kernel (interpreted here) against the ``xla``
+    ``_lloyd_pass``: a last block that is part padding (its rows hold
+    garbage), weights other than 1, two equal centroids, and a block
+    ``cut`` over its sublanes as one too wide for VMEM is. Rows whose two
+    smallest distances lie within 2 ulp are left out (weight 0), so
+    cluster weights and rows seen are EXACT; the last of two equal
+    centroids takes nothing; a cluster's sums agree within 2e-6 of its
+    weight times the table's range, the inertia within 1e-6."""
+    import jax
+    import jax.numpy as jnp
+    from alink_tpu.kernels import kmeans as kernel
+    from alink_tpu.operator.common.clustering import kmeans as K
+
+    d = 3
+    if cut:                      # two table blocks of 64 sublanes fit
+        monkeypatch.setattr(kernel, "_TABLE_VMEM", 2 * d * 64 * 128 * 4)
+        assert kernel._sublanes_per_step(d, S) == 64
+    lloyd_path = kernel.lloyd_path
+    assert lloyd_path(np.float32, S, k, d, "EUCLIDEAN") == "xla"    # the rig
+    monkeypatch.setenv("ALINK_TPU_PALLAS_INTERPRET", "1")
+    assert lloyd_path(np.float32, S, k, d, "EUCLIDEAN") == "kernel"
+    assert lloyd_path(np.float64, S, k, d, "EUCLIDEAN") == "xla"
+    assert lloyd_path(np.float32, 12, k, d, "EUCLIDEAN") == "xla"
+    assert lloyd_path(np.float32, S, k, d, "COSINE") == "xla"
+    assert lloyd_path(np.float32, S, 64, 16, "EUCLIDEAN") == "xla"  # too wide
+
+    rng = np.random.RandomState(k + S + cut)
+    nbl = 2
+    X = (rng.randn(nbl, d, S, 128) * 2 + 1).astype(np.float32)
+    W = rng.choice([0.5, 1.0, 2.0], (nbl, S, 128)).astype(np.float32)
+    W[-1, S // 2:] = 0                              # the ragged tail,
+    X[-1, :, S // 2:] = 1e3                         # whatever it holds
+    C = (rng.randn(k, d) * 2 + 1).astype(np.float32)
+    if k > 1:
+        C[k - 1] = C[0]                             # equal distances
+    D = np.sort(np.stack([np.asarray(K.block_distances(jnp.asarray(xb),
+                                                       C[:max(k - 1, 1)]))
+                          for xb in X], 1), 0)      # (k - 1 or 1, nbl, S, 128)
+    if k > 2:
+        near = (D[1] - D[0]) <= 2 * np.spacing(D[1])
+        assert near.mean() < 0.01
+        W[near] = 0
+    rows = int((W != 0).sum())
+
+    def run(path):
+        monkeypatch.setattr(K, "lloyd_path", lambda *a: path)
+        return np.asarray(jax.jit(
+            lambda *a: K._lloyd_pass(*a, "EUCLIDEAN"))(X, W, C))
+    got, want = run("kernel"), run("xla")
+    assert got.dtype == want.dtype == np.float32 and got.shape == (k + 2, d + 1)
+    assert int(K._join_count(got[k + 1, 0], got[k + 1, 1])) == rows \
+        == int(K._join_count(want[k + 1, 0], want[k + 1, 1]))
+    assert np.array_equal(got[:k, d], want[:k, d])  # cluster weights, exact
+    assert got[:k, d].sum() == W.sum()
+    if k > 1:
+        assert got[k - 1, d] == 0 and (got[k - 1] == 0).all()
+    wide = np.abs(X[:, :, W[0] != 0]).max() + np.abs(C).max()
+    assert (np.abs(got[:k, :d] - want[:k, :d])
+            <= 2e-6 * wide * want[:k, d:]).all()
+    assert abs(got[k, 0] - want[k, 0]) <= 1e-6 * want[k, 0]
+    assert (got[k, 1:] == 0).all() and (got[k + 1, 2:] == 0).all()
+
+
+@pytest.mark.parametrize("nw", [1, 4])
+def test_kmeans_train_same_fit_by_either_lloyd_pass(monkeypatch, nw):
+    """A whole ``kmeans_train`` by the ``xla`` pass (the rig's own) and by
+    the kernel (interpreted): the same steps, the same centroids and
+    cluster weights to float32 round-off; the path is read off
+    ``info["lloyd_pass"]`` and the counter of blocks walked."""
+    from alink_tpu.common.metrics import (MetricsRegistry, get_registry,
+                                          set_registry)
+    from alink_tpu.common.mlenv import MLEnvironment
+    from alink_tpu.operator.common.clustering.kmeans import kmeans_train
+
+    col = _kmpp_table(5, blocks=8)
+    out = {}
+    prev = set_registry(MetricsRegistry())
+    try:
+        for path in ("xla", "kernel"):
+            if path == "kernel":
+                monkeypatch.setenv("ALINK_TPU_PALLAS_INTERPRET", "1")
+            info = {}
+            C, w, steps = kmeans_train(col, 4, max_iter=6, tol=1e-6, seed=5,
+                                       env=MLEnvironment(parallelism=nw),
+                                       info=info)
+            out[path] = (np.asarray(C), np.asarray(w), steps, info)
+            assert info["lloyd_pass"] == path
+            assert get_registry().value(
+                "alink_kmeans_lloyd_blocks_total",
+                {"path": path}) == steps * 8
+    finally:
+        set_registry(prev)
+    (Ck, wk, sk, ik), (Cx, wx, sx, ix) = out["kernel"], out["xla"]
+    assert sk == sx and Ck.dtype == Cx.dtype == np.float32
+    assert np.array_equal(ik["rows"], ix["rows"])
+    assert np.abs(Ck - Cx).max() <= 1e-5 * np.abs(Cx).max()
+    assert np.abs(wk - wx).sum() <= 2
+    assert np.allclose(ik["inertia"], ix["inertia"], rtol=1e-5)

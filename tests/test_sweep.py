@@ -147,6 +147,43 @@ class TestBitwiseParity:
             assert steps == int(res.steps[i])
 
 
+    def test_kmeans_points_bitwise_on_the_lloyd_kernel(self, monkeypatch):
+        """Where the input allows the streamed Lloyd kernel (float32
+        rows; interpreted here) the sweep's lane takes it as the serial
+        trainer does, both through ``_lloyd_pass``: still bitwise. The
+        path rides the sweep program's key: the program the rig's own
+        ``xla`` sweep cached is not the one run under the other word."""
+        from alink_tpu.engine.comqueue import program_cache_stats
+        rng = np.random.RandomState(4)
+        X = np.concatenate([rng.randn(60, 4) + c
+                            for c in (0.0, 5.0)]).astype(np.float32)
+        pts = [{"seed": 0, "tol": 1e-4}, {"seed": 3, "tol": 1e-1}]
+
+        def sweep():
+            before = program_cache_stats()
+            res = sweep_kmeans(X, 2, pts, max_iter=10, init="RANDOM")
+            after = program_cache_stats()
+            return res, after["misses"] - before["misses"], \
+                after["hits"] - before["hits"]
+
+        sweep()                                  # the rig as it stands
+        _, missed, hit = sweep()
+        assert (missed, hit) == (0, 1)
+        monkeypatch.setenv("ALINK_TPU_PALLAS_INTERPRET", "1")
+        res, missed, hit = sweep()
+        assert (missed, hit) == (1, 0)
+        for i, pt in enumerate(pts):
+            info = {}
+            C, w, steps = kmeans_train(X, 2, max_iter=10, tol=pt["tol"],
+                                       init="RANDOM", seed=pt["seed"],
+                                       info=info)
+            assert info["lloyd_pass"] == "kernel"
+            assert np.array_equal(np.asarray(C),
+                                  res.values["centroids"][i])
+            assert np.array_equal(np.asarray(w),
+                                  res.values["cluster_weights"][i])
+            assert steps == int(res.steps[i])
+
     def test_kmeans_parity_health_off(self):
         """The sweep's always-on inertia lane (the ASHA signal must not
         flip with a telemetry flag) is one extra row on an elementwise

@@ -131,3 +131,88 @@ def test_kmpp_round_compiles_a_worker_on_four_chips(monkeypatch, topo):
     assert "output_to_operand_aliasing" in calls[0]
     table = 4 * nbl * d * S * 128 * 4
     assert compiled.memory_analysis().argument_size_in_bytes < 0.3 * table
+
+
+def test_lloyd_superstep_compiles_to_one_streamed_kernel(monkeypatch,
+                                                         one_chip):
+    """A Lloyd superstep of ``kmeans-fit`` (1,526 blocks of 20 × 512 ×
+    128 float32, k = 10): the pass is ONE ``tpu_custom_call``; no array of
+    a block's distances or masked weights ``(k, S, 128)`` or of a block's
+    copy ``(d, S, 128)`` exists; the table reaches the kernel as the
+    program's own parameter, uncopied."""
+    import jax
+    import jax.numpy as jnp
+    from alink_tpu.kernels import kmeans as kernel
+    from alink_tpu.operator.common.clustering import kmeans as K
+
+    # the rig's backend is the CPU: take the kernel, and compile it
+    monkeypatch.setattr(kernel, "interpret_mode", lambda: False)
+    monkeypatch.setattr(K, "lloyd_path", lambda *a: "kernel")
+    nbl, d, S, k = 1526, 20, 512, 10
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(Xs, Ws, C):
+        return K._lloyd_update(K._lloyd_pass(Xs, Ws, C, "EUCLIDEAN"), C)
+
+    with jax.enable_x64(False):                      # as on the chip
+        text = jax.jit(step).lower(
+            sd((nbl, d, S, 128), jnp.float32), sd((nbl, S, 128), jnp.float32),
+            sd((k, d), jnp.float32)).compile().as_text()
+
+    calls = _kernel_calls(text)
+    assert len(calls) == 1
+    assert "lloyd_pass" in calls[0]
+    for shape in (f"[{k},{S},128]", f"[{d},{S},128]"):
+        assert shape not in text, shape
+    table = f"f32[{nbl},{d},{S},128]"
+    made = [ln.strip() for ln in text.splitlines()
+            if re.search(rf"= {re.escape(table)}\S* (?!parameter)", ln)]
+    assert made == []
+    param = re.search(rf"(\S+) = {re.escape(table)}\S* parameter\(0\)", text)
+    tiled = re.escape(f"f32[{nbl},{d},{S // 8},8,128]")
+    made = re.findall(rf"(\S+) = {tiled}\S* (\w+)\((\S+?)\)", text)
+    assert made and all(op == "bitcast" and src == param.group(1)
+                        for _, op, src in made)
+    assert any(name in calls[0] for name, _, _ in made)
+
+
+def test_lloyd_superstep_compiles_a_worker_on_four_chips(monkeypatch, topo):
+    """The same superstep inside a ``shard_map`` as the engine makes it
+    (``check_vma=False``), the table's blocks split over a 2 x 2 v5e: one
+    kernel a worker over its own 382 blocks, the buffer summed by one
+    all-reduce, and a quarter of the table a chip."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from alink_tpu.common.compat import shard_map
+    from alink_tpu.kernels import kmeans as kernel
+    from alink_tpu.operator.common.clustering import kmeans as K
+
+    monkeypatch.setattr(kernel, "interpret_mode", lambda: False)
+    monkeypatch.setattr(K, "lloyd_path", lambda *a: "kernel")
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("d",))
+    nbl, d, S, k = 382, 20, 512, 10
+
+    def sd(shape, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    def step(Xs, Ws, C):
+        buf = jax.lax.psum(K._lloyd_pass(Xs, Ws, C, "EUCLIDEAN"), "d")
+        return K._lloyd_update(buf, C)
+
+    with jax.enable_x64(False):
+        compiled = jax.jit(shard_map(
+            step, mesh=mesh, in_specs=(P("d"), P("d"), P()),
+            out_specs=P(), check_vma=False)).lower(
+                sd((4 * nbl, d, S, 128), jnp.float32, P("d")),
+                sd((4 * nbl, S, 128), jnp.float32, P("d")),
+                sd((k, d), jnp.float32)).compile()
+    text = compiled.as_text()
+    calls = _kernel_calls(text)
+    assert len(calls) == 1 and f"f32[{nbl},{d},{S // 8},8,128]" in calls[0]
+    assert len(re.findall(r"= \S+ all-reduce(?:-start)?\(", text)) == 1
+    table = 4 * nbl * d * S * 128 * 4
+    assert compiled.memory_analysis().argument_size_in_bytes < 0.3 * table
