@@ -258,6 +258,34 @@ def as_block_column(X, num_workers: int = 1,
     return DenseBlockColumn(DenseBlockColumn.pack(X, B, nb), X.shape[0])
 
 
+def as_row_blocks(values, dtype, num_workers: int = 1, like=None):
+    """Per-row values as blocks ``(row_blocks, S, 128)`` of ``dtype``, the
+    form a trainer that walks plain columns (ids, ratings) takes: a
+    :class:`RowBlockColumn` is used where it lies (device-resident or
+    not), host values ``(n,)`` are packed once, their block count a
+    multiple of the workers. ``like``: blocks the result must be laid out
+    as (the table's other columns)."""
+    if isinstance(values, RowBlockColumn):
+        blocks = values.blocks
+        if blocks.dtype != np.dtype(dtype):
+            blocks = blocks.astype(dtype)
+    else:
+        v = np.asarray(values)
+        if v.ndim != 1:
+            raise ValueError("per-row values must be (n,)")
+        if like is not None:
+            B, nb = like.shape[1] * LANES, like.shape[0]
+        else:
+            B = DenseBlockColumn.block_rows_for(v.shape[0])
+            nb = -(-max(v.shape[0], 1) // B)
+            nb = -(-nb // num_workers) * num_workers
+        blocks = DenseBlockColumn.pack(v.astype(dtype), B, nb)
+    if like is not None and tuple(blocks.shape) != tuple(like.shape):
+        raise ValueError(f"columns of one table must be laid out alike: "
+                         f"{tuple(blocks.shape)} beside {tuple(like.shape)}")
+    return blocks
+
+
 def block_values(col: DenseBlockColumn, values):
     """Per-row ``values`` laid out like the table's rows, ``(row_blocks,
     S, 128)``: a :class:`RowBlockColumn` or an array already so laid out

@@ -7,8 +7,11 @@ same operator/IO fabric as data; converters define the row schema.
 
 Format (mirrors SimpleModelDataConverter): rows of
   (model_id LONG, model_info STRING [, label_value <labelType>])
-row 0 carries the meta Params JSON; subsequent rows carry data payload
-strings; label values (when present) ride a dedicated typed column.
+row 0 carries the meta Params JSON; subsequent rows carry data payloads;
+label values (when present) ride a dedicated typed column. A payload is a
+string, or an ARRAY as it is (:class:`ArrayPayload`: host or
+device-resident, never turned into text unless someone asks for the
+cell's ``str``, which is the JSON ``encode_array`` writes).
 """
 
 from __future__ import annotations
@@ -46,15 +49,19 @@ class SimpleModelDataConverter(ModelDataConverter):
 
     def save_model(self, model_data) -> MTable:
         meta, data = self.serialize_model(model_data)
-        rows = [(0, meta.to_json())] + [(i + 1, s) for i, s in enumerate(data)]
-        return MTable(rows, self.SCHEMA)
+        info = np.empty(len(data) + 1, object)
+        info[0] = meta.to_json()
+        info[1:] = data
+        return MTable({"model_id": np.arange(len(info), dtype=np.int64),
+                       "model_info": info}, self.SCHEMA)
 
     def load_model(self, table: MTable):
         ids = np.asarray(table.col("model_id"), dtype=np.int64)
         infos = table.col("model_info")
         order = np.argsort(ids, kind="stable")
         meta = Params.from_json(str(infos[order[0]]))
-        data = [str(infos[i]) for i in order[1:]]
+        data = [infos[i] if isinstance(infos[i], ArrayPayload)
+                else str(infos[i]) for i in order[1:]]
         return self.deserialize_model(meta, data)
 
 
@@ -101,12 +108,49 @@ def _is_nan(v) -> bool:
     return isinstance(v, float) and np.isnan(v)
 
 
+class ArrayPayload:
+    """A model_info cell that IS an array (``array``: ``numpy`` or a
+    device-resident ``jax.Array``), handed from trainer to predictor
+    without a copy and without text: factors of 1.6 million rows stay where
+    the fit left them. ``str(cell)`` is the JSON payload ``encode_array``
+    writes, so a sink that turns cells into text writes a table
+    ``decode_array`` reads back (that fetches and walks the array: for
+    export, not for the hand-over)."""
+
+    __slots__ = ("array",)
+
+    def __init__(self, array):
+        self.array = array
+
+    @property
+    def shape(self):
+        return tuple(self.array.shape)
+
+    def __str__(self):
+        return encode_array(self.array)
+
+    def __repr__(self):
+        return (f"ArrayPayload(shape={self.shape}, "
+                f"dtype={np.dtype(self.array.dtype)})")
+
+
 def encode_array(arr: np.ndarray) -> str:
     """Compact json payload for numeric arrays in model_info rows."""
     a = np.asarray(arr)
     return json.dumps({"shape": list(a.shape), "data": a.reshape(-1).tolist()})
 
 
-def decode_array(s: str, dtype=np.float64) -> np.ndarray:
+def decode_array(s, dtype=np.float64) -> np.ndarray:
+    """A payload as a host array of ``dtype``: an :class:`ArrayPayload`
+    (fetched if device-resident) or the JSON text of ``encode_array``."""
+    if isinstance(s, ArrayPayload):
+        return np.asarray(s.array, dtype=dtype)
     o = json.loads(s)
     return np.asarray(o["data"], dtype=dtype).reshape(o["shape"])
+
+
+def payload_array(s, dtype=np.float64):
+    """A payload's array WHERE IT LIES (an :class:`ArrayPayload`'s own
+    array, device-resident or not, in its own dtype), or a JSON text
+    payload decoded to ``dtype``."""
+    return s.array if isinstance(s, ArrayPayload) else decode_array(s, dtype)

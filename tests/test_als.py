@@ -189,9 +189,8 @@ def test_als_tol_early_stop():
 
 def test_als_one_sweep_matches_numpy_normal_equations():
     """One ALS sweep must match a numpy reference computing the same
-    normal equations densely — pins the sorted-run prefix math, the
-    symmetric tril packing/unpack, and the GJ solve EXACTLY (not just
-    reconstruction quality)."""
+    normal equations densely — pins the grouping, the chunked Gram sums
+    and the batched solve EXACTLY (not just reconstruction quality)."""
     from alink_tpu.operator.common.recommendation.als import (AlsTrainParams,
                                                               als_train)
     rng = np.random.RandomState(5)
@@ -201,13 +200,15 @@ def test_als_one_sweep_matches_numpy_normal_equations():
     ratings = rng.rand(nnz).astype(np.float32) * 4 + 1
     lam = 0.2
     p = AlsTrainParams(rank=r, num_iter=1, lambda_reg=lam, seed=3)
+    info = {}
     uf, if_, _ = als_train(users, items, ratings, p,
-                           num_users=U, num_items=I)
+                           num_users=U, num_items=I, info=info)
 
-    # numpy reference: same init (the seeded init is part of the API)
-    rr = np.random.RandomState(3)
-    uf0 = (rr.rand(U, r) / np.sqrt(r)).astype(np.float64)
-    if0 = (rr.rand(I, r) / np.sqrt(r)).astype(np.float64)
+    # numpy reference from the same init: the item factors the one user
+    # half-sweep read, drawn on the device from the seed (uniform over
+    # [0, 1/sqrt(rank)), the law it always was; the user init is never read)
+    if0 = np.asarray(info["items_read"], np.float64)[:, :r]
+    assert 0 <= if0.min() and if0.max() < 1 / np.sqrt(r) and if0.std() > 0
 
     def solve_ref(ids, oids, n_rows, ofac):
         out = np.zeros((n_rows, r))
@@ -283,7 +284,8 @@ class TestAlsShardSolve:
         p = AlsTrainParams(rank=4, num_iter=3, shard_solve=True)
         lowered = capture_lowered(
             lambda: als_train(users, items, ratings, p,
-                              num_users=U, num_items=I))
+                              num_users=U, num_items=I),
+            program="als_sweep")       # the grouping program runs first
         hlo = lowered.compile().as_text()
         assert re.search(r"reduce-scatter(?:-start)?\(", hlo), \
             "no reduce-scatter in compiled ALS shard_solve module"
@@ -330,3 +332,149 @@ print("shard_solve 32dev ok")
                            capture_output=True, text=True, timeout=600)
         assert r.returncode == 0, (r.stdout[-2000:], r.stderr[-2000:])
         assert "shard_solve 32dev ok" in r.stdout
+
+
+# -- the program against the benchmark's plain reference ----------------------
+
+def _blocked_table(U, I, n, seed, block_rows=1024):
+    """Seeded triples in the blocked layout: user 0 holds a quarter of the
+    ratings (they span every block and many chunks), user 1 exactly one,
+    the last ten users and five items none; pairs repeat; the last block
+    is ragged."""
+    from alink_tpu.common.columnar import DenseBlockColumn
+    rng = np.random.RandomState(seed)
+    users = rng.randint(2, U - 10, n)
+    users[rng.rand(n) < 0.25] = 0
+    users[users == 1] = 2
+    users[n // 2] = 1
+    items = rng.randint(0, I - 5, n)
+    ratings = np.round(rng.rand(n) * 100)
+    assert n % block_rows and (users == 1).sum() == 1
+    assert len(set(zip(users, items))) < n          # repeated pairs
+    return tuple(DenseBlockColumn.pack(v.astype(dt), block_rows) for v, dt in
+                 ((users, np.int32), (items, np.int32),
+                  (ratings, np.float32)))
+
+
+@pytest.mark.parametrize("case,rank,mode,solve_limit", [
+    ("explicit", 8, {}, 2e-5),
+    # confidence weights up to 51 on a ridge of 0.1 n: a condition number
+    # ~100 times the explicit case's
+    ("implicit", 8, {"implicit_prefs": True, "alpha": 0.5}, 1e-3),
+    # the program's projected gradient stops after 80 steps; the
+    # reference's active-set NNLS is exact
+    ("nonnegative", 8, {"nonnegative": True}, 5e-2),
+    # one small case at the cell's rank, so that the solve at n = 100 and
+    # the 128-lane rows with the rating's lane at 100 are guarded
+    ("rank_100", 100, {}, 2e-4)])
+def test_fit_against_the_plain_reference_teacher_forced(case, rank, mode,
+                                                        solve_limit):
+    """One path for every table: a host table's fit read by
+    ``benchmark/reference/als.py`` exactly as the cell's fit is, a
+    half-sweep at a time from the factors the program read."""
+    from alink_tpu.common.columnar import RowBlockColumn
+    from alink_tpu.operator.common.recommendation.als import (AlsTrainParams,
+                                                              als_train)
+    from benchmark.reference import als as ref
+    U, I, n = (60, 40, 3000) if rank == 8 else (40, 150, 5000)
+    table = _blocked_table(U, I, n, seed=len(case))
+    lam = 1.4 if not mode.get("implicit_prefs") else 0.1
+    p = AlsTrainParams(rank=rank, num_iter=2, lambda_reg=lam, seed=7, **mode)
+    info = {}
+    uf, if_, curve = als_train(*(RowBlockColumn(b, n) for b in table), p,
+                               num_users=U, num_items=I, info=info)
+    assert uf.shape == (U, rank) and if_.shape == (I, rank)
+    assert info["ratings"] == 4 * n and info["half_sweeps"] == 4
+    assert not np.asarray(uf)[-10:].any() and not np.asarray(if_)[-5:].any()
+    got = ref.gaps(info, table, n, {
+        "rank": rank, "lambda": lam, "sample_rows": 1000,
+        "implicit": bool(mode.get("implicit_prefs")),
+        "alpha": mode.get("alpha", 40.0),
+        "nonnegative": bool(mode.get("nonnegative"))}, seed=1)
+    assert got["count_gap"] == 0
+    assert got["user_solve_gap"] < solve_limit, got
+    assert got["item_solve_gap"] < solve_limit, got
+    assert got["rmse_gap"] < 1e-5, got
+    assert len(curve) == 2 and curve[1] < curve[0]
+
+
+def test_the_model_payload_is_an_array_and_an_old_text_payload_still_loads():
+    """ROADMAP M1's first half: the ALS model table carries its factors
+    and whole-number ids as arrays (no JSON text, nothing a row in Python);
+    its cells still turn into the text the old loader read; a table
+    written before that (ids in the meta row, factors as JSON) loads."""
+    from alink_tpu.common.mtable import MTable
+    from alink_tpu.common.params import Params
+    from alink_tpu.model.converters import (ArrayPayload, decode_array,
+                                            encode_array)
+    rows, _ = _ratings()
+    src = MemSourceBatchOp(rows, "user LONG, item LONG, rating DOUBLE")
+    train = AlsTrainBatchOp(user_col="user", item_col="item",
+                            rate_col="rating", rank=4,
+                            num_iter=2).link_from(src)
+    table = train.get_output_table()
+    cells = list(table.col("model_info"))
+    assert isinstance(cells[0], str)
+    assert all(isinstance(c, ArrayPayload) for c in cells[1:5])
+    m = AlsModelDataConverter().load_model(table)
+    assert m.user_factors is cells[1].array            # no copy, no text
+    assert m.user_ids.dtype == np.int64 and list(m.user_ids) == list(range(30))
+    # a sink that writes text writes what the loader reads back
+    as_text = MTable({"model_id": table.col("model_id"),
+                      "model_info": np.asarray([str(c) for c in cells],
+                                               object)}, table.schema)
+    t = AlsModelDataConverter().load_model(as_text)
+    np.testing.assert_array_equal(t.user_factors, m.user_factors)
+    np.testing.assert_array_equal(t.item_ids, m.item_ids)
+    # the layout before the array payload
+    meta = Params({"user_col": "user", "item_col": "item",
+                   "rate_col": "rating",
+                   "user_ids": [str(u) for u in m.user_ids],
+                   "item_ids": [str(i) for i in m.item_ids]})
+    old = MTable([(0, meta.to_json()), (1, encode_array(m.user_factors)),
+                  (2, encode_array(m.item_factors))],
+                 AlsModelDataConverter.SCHEMA)
+    o = AlsModelDataConverter().load_model(old)
+    np.testing.assert_array_equal(o.item_factors, m.item_factors)
+    np.testing.assert_array_equal(decode_array(cells[2]), m.item_factors)
+    data = MemSourceBatchOp([(3, 4), (0, 19), (777, 1)], "user LONG, item LONG")
+    want = (AlsPredictBatchOp(user_col="user", item_col="item",
+                              prediction_col="pred")
+            .link_from(train, data).collect_mtable().col("pred"))
+    got = (AlsPredictBatchOp(user_col="user", item_col="item",
+                             prediction_col="pred")
+           .link_from(MemSourceBatchOp(old), data).collect_mtable()
+           .col("pred"))
+    np.testing.assert_array_equal(np.asarray(want, float),
+                                  np.asarray(got, float))
+    assert np.isnan(want[2]) and not np.isnan(want[0])
+
+
+def test_a_blocked_id_column_is_its_own_index():
+    """Ids that are whole numbers in a blocked column are not walked in
+    Python: the column is the index, ids with no rating get rows of zeros,
+    and the predictors read the model."""
+    from alink_tpu.common.columnar import RowBlockColumn
+    from alink_tpu.common.mtable import MTable
+    from alink_tpu.common.types import TableSchema
+    rows, _ = _ratings()
+    u, i, r = (np.asarray(c) for c in zip(*rows))
+    host = MemSourceBatchOp(rows, "user LONG, item LONG, rating DOUBLE")
+    blocked = MemSourceBatchOp(MTable(
+        {"user": RowBlockColumn.from_values((u + 2).astype(np.int32)),
+         "item": RowBlockColumn.from_values(i.astype(np.int32)),
+         "rating": RowBlockColumn.from_values(r.astype(np.float32))},
+        TableSchema.parse("user INT, item INT, rating FLOAT")))
+    kw = dict(user_col="user", item_col="item", rate_col="rating", rank=4,
+              num_iter=3, seed=5)
+    a = AlsModelDataConverter().load_model(
+        AlsTrainBatchOp(**kw).link_from(host).get_output_table())
+    op = AlsTrainBatchOp(**kw).link_from(blocked)
+    b = AlsModelDataConverter().load_model(op.get_output_table())
+    assert list(b.user_ids) == list(range(32))         # 0 and 1: no rating
+    assert not np.asarray(b.user_factors)[:2].any()
+    np.testing.assert_allclose(np.asarray(b.user_factors)[2:],
+                               a.user_factors, rtol=1e-5, atol=1e-6)
+    info = op.get_train_info()
+    assert info["ratings"] == 6 * len(rows) and info["paths"]["group"] == "sort"
+    assert np.asarray(info["user_counts"])[:2].tolist() == [0, 0]

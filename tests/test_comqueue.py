@@ -453,12 +453,18 @@ def _gbdt_fit(env, r):
 
 
 _QN = [("AllReduce", "glw"), ("AllReduce", "line_losses")]
-_ALS_EQ = [("AllReduce", "als_eq_A"), ("AllReduce", "als_eq_b"),
-           ("AllReduce", "als_eq_cnt")]
-_ALS_EQ_SHARDED = [("ReduceScatter", "als_eq_A"),
-                   ("ReduceScatter", "als_eq_b"),
-                   ("ReduceScatter", "als_eq_cnt"),
-                   ("AllGather", "als_factors")]
+# a fit is two programs since PR 33. The grouping sums each side's
+# per-worker counts (whole numbers). A superstep's half-sweep walks its
+# ranked rows in batches; the batch loop is ONE traced body a tier (these
+# sizes have one tier), so a half-sweep asks once for the batch's summed
+# equations ``als_eq`` (Gram sums, right-hand sides and the residual's
+# lanes in one (rows, 128, 128) buffer; it runs once a batch), or with
+# shard_solve for their reduce-scatter and the solved rows' all-gather;
+# then the ratings folded and the squared error, once an iteration
+_ALS_GROUP = [("AllReduce", "als_count_u"), ("AllReduce", "als_count_i")]
+_ALS_TAIL = [("AllReduce", "als_seen"), ("AllReduce", "als_rmse")]
+_ALS_EQ = [("AllReduce", "als_eq")]
+_ALS_EQ_SHARDED = [("ReduceScatter", "als_eq"), ("AllGather", "als_factors")]
 _INLINE = ("InlineAllReduce", "<inline>")
 
 # trainer -> (fit, {program label: the (kind, name) list of ONE superstep})
@@ -473,12 +479,13 @@ _SUPERSTEP_COLLECTIVES = {
         "kmeans_init": [("AllGather", "kmpp_keys"),
                         ("AllGather", "kmpp_cands"), _INLINE],
         "kmeans_lloyd": [("AllReduce", "buf")]}),
-    # two half-sweeps of normal equations, then the rmse
-    "als": (_als_fit(False),
-            {"als": _ALS_EQ + _ALS_EQ + [("AllReduce", "als_rmse")]}),
+    # two half-sweeps of normal equations, then the count and the rmse
+    "als": (_als_fit(False), {
+        "als_group": _ALS_GROUP,
+        "als_sweep": _ALS_EQ + _ALS_EQ + _ALS_TAIL}),
     "als_shard_solve": (_als_fit(True), {
-        "als": _ALS_EQ_SHARDED + _ALS_EQ_SHARDED
-        + [("AllReduce", "als_rmse")]}),
+        "als_group": _ALS_GROUP,
+        "als_sweep": _ALS_EQ_SHARDED + _ALS_EQ_SHARDED + _ALS_TAIL}),
     "fm": (_fm_fit, {"fm": [("AllReduce", "avg"), ("AllReduce", "lw")]}),
     "word2vec": (_word2vec_fit, {"w2v": [("AllReduce", "emb")]}),
     "lda_online": (_lda_fit("online_lda_train"),

@@ -216,3 +216,43 @@ def test_lloyd_superstep_compiles_a_worker_on_four_chips(monkeypatch, topo):
     assert len(re.findall(r"= \S+ all-reduce(?:-start)?\(", text)) == 1
     table = 4 * nbl * d * S * 128 * 4
     assert compiled.memory_analysis().argument_size_in_bytes < 0.3 * table
+
+
+def test_als_half_sweep_compiles_with_the_solve_kernel_and_small_temps(
+        monkeypatch, one_chip):
+    """A user half-sweep of ``als-fit`` (252.8 million grouped ratings as
+    1,975,296 rows of 128, 624,961 item factor rows of 128 lanes, rank
+    100): the batches' solves are ``tpu_custom_call``s named ``als_solve``
+    (one a tier), and the program's temporaries stay a few batches wide. A
+    16-wide view of a grouped column, which the chip pads eightfold, once
+    asked for 10.2 GB here (PERF.md §6, PR 33)."""
+    import jax
+    import jax.numpy as jnp
+    from alink_tpu.kernels import smallsolve as kernel
+    from alink_tpu.operator.common.recommendation import als as A
+
+    monkeypatch.setattr(kernel, "interpret_mode", lambda: False)
+    monkeypatch.setattr(kernel, "pallas_available", lambda: True)
+    U, I, f, rows = 1_000_990, 624_961, 100, 3858 * 512
+    p = A.AlsTrainParams(rank=f, num_iter=1, lambda_reg=1.4)
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def half_sweep(other, ids, val, off, cnt, order, rank_of, old):
+        return A._half_sweep(other, ids, val, off, cnt, order, rank_of, U, p,
+                             1, 0, old=old)
+
+    with jax.enable_x64(False):                      # as on the chip
+        compiled = jax.jit(half_sweep).lower(
+            sd((I, 128), jnp.float32), sd((rows, 128), jnp.int32),
+            sd((rows, 128), jnp.float32), sd((U + 2,), jnp.int32),
+            sd((U,), jnp.int32), sd((U,), jnp.int32), sd((U,), jnp.int32),
+            sd((U, 128), jnp.float32)).compile()
+    calls = _kernel_calls(compiled.as_text())
+    assert len(calls) == len(A._tiers(U, 1)) == 2
+    assert all("als_solve" in c for c in calls)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2.5e9, mem.temp_size_in_bytes
+    # the grouped columns reach the loop as the parameters they are
+    assert mem.argument_size_in_bytes < 2 * rows * 128 * 4 + 1.0e9

@@ -265,15 +265,20 @@ class _Captured(Exception):
     pass
 
 
-def capture_lowered(fn):
+def capture_lowered(fn, program=None):
     """Run ``fn`` (which internally builds and execs an IterativeComQueue)
     with exec() patched to capture the LOWERED program instead of running
-    it. Re-raises the underlying error if fn never reached exec()."""
+    it. Re-raises the underlying error if fn never reached exec().
+    ``program``: the label of the queue to capture (the first word of its
+    program key) where ``fn`` runs several; the others execute."""
     import alink_tpu.engine.comqueue as cq
     captured = {}
     orig = cq.IterativeComQueue.exec
 
     def spy(queue_self):
+        if program is not None and cq._program_label(
+                queue_self._program_key) != program:
+            return orig(queue_self)
         captured["lowered"] = queue_self.lowered()
         raise _Captured()    # short-circuit: unwind out of fn
 
@@ -300,7 +305,8 @@ def _optimizer_queue(O, obj, data, params, env):
 def _capture_als_lowered(A, users, items, ratings, env):
     return capture_lowered(lambda: A.als_train(
         users, items, ratings,
-        A.AlsTrainParams(rank=10, num_iter=5, lambda_reg=0.1), env=env))
+        A.AlsTrainParams(rank=10, num_iter=5, lambda_reg=0.1), env=env),
+        program=A.SWEEP_PROGRAM)
 
 
 def audit(env):
