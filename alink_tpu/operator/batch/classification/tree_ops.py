@@ -324,10 +324,15 @@ class GbdtTrainBatchOp(BatchOperator, _TreeTrainParamsMixin):
 
     def get_train_info(self) -> dict:
         """What the last fit went through: which histogram ran
-        (``hist``: ``"onehot"`` on the MXU or ``"scatter"``), the bin
-        edges, the trees as the device grew them (``features``,
-        ``split_bins``, ``leaf_values``, every node's ``counts``), the
-        loss curve and the rows counted."""
+        (``hist``: ``"onehot"`` on the MXU or ``"scatter"``), how many
+        node histograms a tree's block loops built and how many it took
+        as ``parent - built`` (``hist_nodes``: ``{"built": 32, "derived":
+        31}`` at depth 6; the counter ``alink_gbdt_hist_nodes_total{how=}``
+        sums them over the trees, and the ``gbdt.grow`` span carries
+        ``sibling: "subtract"`` beside ``hist``), the bin edges, the trees
+        as the device grew them (``features``, ``split_bins``,
+        ``leaf_values``, every node's ``counts``), the loss curve and the
+        rows counted."""
         return self._train_info
 
     def get_model_info(self) -> MTable:

@@ -629,17 +629,23 @@ def build_tree(binned, stats, max_depth: int, n_bins: int,
 # ``DenseBlockColumn`` is, node ids and per-row stats ``(blocks, S, 128)``.
 # A level walks the worker's shard block by block (``lax.fori_loop``): the
 # rows descend by the level before, the block's histogram is ONE product
-# on the MXU, ``onehot(bin)^T (F * n_bins, rows)`` against ``(node one-hot
-# x stats) (n_nodes * 2m, rows)``, whose bin one-hot XLA fuses into the
-# product's operand (it is never written to memory: compiled and timed
-# on a v5e, PERF.md PR 31), and the block's result is Kahan-added to the
-# shard's. Nothing of shape ``(n, F, n_bins)`` or ``(n, m)`` exists. The
+# on the MXU, ``onehot(bin)^T (F * n_bins, rows)`` against ``(column
+# one-hot x stats) (columns * 2m, rows)``, whose bin one-hot XLA fuses
+# into the product's operand (it is never written to memory: compiled and
+# timed on a v5e, PERF.md PR 31), and the block's result is Kahan-added to
+# the shard's. Nothing of shape ``(n, F, n_bins)`` or ``(n, m)`` exists.
+# A node's histogram is the sum of its two children's, so from level 1
+# down a level builds ONE child of every parent (a row's column is its
+# parent, rows of the other child enter as zeros) and takes the sibling as
+# ``parent - built`` once, after the shard's sums are joined: the product
+# is half as wide (PR 34; what LightGBM and XGBoost's ``hist`` do). The
 # stats ride the bfloat16 product as a compensated (hi, lo) pair and are
 # summed in float32; a count of whole weights is exact however many rows
 # there are, because a block's sum is exact (under 2^24 rows) and the
 # Kahan pair ``(sum, lost)`` of whole numbers stays whole: it is read out
-# as ``int32(sum) - int32(lost)``. Off the TPU a block's histogram is the
-# scatter-add :func:`level_hist` uses there.
+# as ``int32(sum) - int32(lost)``, and a sibling's is an int32 difference.
+# Off the TPU a block's histogram is the scatter-add :func:`level_hist`
+# uses there.
 
 #: a per-node table up to this long is looked up by a chain of selects
 #: (no gather); a longer one by ``jnp.take``
@@ -669,31 +675,97 @@ def _whole(acc, comp):
     return acc.astype(jnp.int32) - comp.astype(jnp.int32)
 
 
-def block_hist(bins_b, node_b, stats_b, n_nodes: int, n_bins: int,
+def block_hist(bins_b, col_b, stats_b, n_cols: int, n_bins: int,
                path: str):
-    """(n_nodes, F, n_bins, m) float32 stat sums of ONE block: ``bins_b``
-    ``(F, S, 128)`` uint8, ``node_b`` ``(S, 128)`` int32, ``stats_b``
-    ``(m, S, 128)`` float32 (zero rows are inert)."""
+    """(n_cols, F, n_bins, m) float32 stat sums of ONE block: ``bins_b``
+    ``(F, S, 128)`` uint8, ``col_b`` ``(S, 128)`` int32 the row's column,
+    ``stats_b`` ``(m, S, 128)`` float32 (zero rows are inert). At the
+    root the one column is the node. Below it a column is a PARENT and
+    holds the one child :func:`build_tree_blocked` builds of it (the
+    smaller one; the other child's rows come with their stats zeroed), so
+    the product's operand ``W`` is ``(n_nodes / 2 * 2m, R)``: half the
+    level's width. The product is dense over the block's rows, so its
+    cost does not depend on WHICH child is built; the numbers do."""
     F, m = bins_b.shape[0], stats_b.shape[0]
     b = bins_b.reshape(F, -1).astype(jnp.int32)
-    nd = node_b.reshape(-1)
+    cl = col_b.reshape(-1)
     st = stats_b.reshape(m, -1).astype(jnp.float32)
     if path == "onehot":
         s2 = jnp.concatenate(split_hi_lo(st), 0)                # (2m, R)
-        oh_n = nd[None, :] == jnp.arange(n_nodes, dtype=jnp.int32)[:, None]
-        W = jnp.where(oh_n[:, None, :], s2[None], 0).reshape(
-            n_nodes * 2 * m, -1)
+        oh_c = cl[None, :] == jnp.arange(n_cols, dtype=jnp.int32)[:, None]
+        W = jnp.where(oh_c[:, None, :], s2[None], 0).reshape(
+            n_cols * 2 * m, -1)
         oh_b = (b[:, None, :] == jnp.arange(
             n_bins, dtype=jnp.int32)[None, :, None]).astype(jnp.bfloat16)
         h2 = jnp.einsum("fbr,qr->fbq", oh_b, W,
                         preferred_element_type=jnp.float32)
-        h2 = h2.reshape(F, n_bins, n_nodes, 2, m)
+        h2 = h2.reshape(F, n_bins, n_cols, 2, m)
         return (h2[..., 0, :] + h2[..., 1, :]).transpose(2, 0, 1, 3)
-    flat = (nd[None, :] * F + jnp.arange(F, dtype=jnp.int32)[:, None]
+    flat = (cl[None, :] * F + jnp.arange(F, dtype=jnp.int32)[:, None]
             ) * n_bins + b
-    hist = jnp.zeros((n_nodes * F * n_bins, m), jnp.float32)
+    hist = jnp.zeros((n_cols * F * n_bins, m), jnp.float32)
     hist = hist.at[flat.reshape(-1)].add(jnp.tile(st.T, (F, 1)))
-    return hist.reshape(n_nodes, F, n_bins, m)
+    return hist.reshape(n_cols, F, n_bins, m)
+
+
+def level_columns(level: int) -> Tuple[int, int]:
+    """``(built, derived)``: how many of a level's ``2^level`` node
+    histograms the blocked builder's block loop builds and how many it
+    takes as ``parent - built``. The root is built; below it one child of
+    every parent is."""
+    half = (1 << level) // 2
+    return (1 << level) - half, half
+
+
+def smaller_child(hist, feat, mask):
+    """Which child of every node of a level the next level builds: 1 the
+    right, 0 the left, ``(n_nodes,)`` int32, from the level's joined
+    histogram ``(n_nodes, F, n_bins, m)`` and its chosen splits
+    (:func:`best_splits`' ``feat`` and LEFT-membership ``mask``): the one
+    of the smaller weight (the LAST stat), the right one on a tie. An
+    unsplit node sends every row left, so its built child is the empty
+    right one and the left is the parent's own histogram.
+
+    The smaller, for the NUMBERS: ``large = parent - small`` carries the
+    parent's rounding into a node at least half its size; the other way
+    round a small node would inherit the rounding of a large one, and
+    its gains and ``min_samples_leaf`` would read it."""
+    w = jnp.take_along_axis(hist[..., -1],
+                            jnp.maximum(feat, 0)[:, None, None], 1)[:, 0]
+    total = w.sum(1)
+    left = jnp.where(feat >= 0, (w * mask).sum(1), total)
+    return (total - left <= left).astype(jnp.int32)
+
+
+def side_words(sides):
+    """``(n,)`` 0 / 1 sides packed 32 a ``uint32`` word, for
+    :func:`on_side`."""
+    s = jnp.pad(sides.astype(jnp.uint32), (0, -sides.shape[0] % 32))
+    return (s.reshape(-1, 32) << jnp.arange(32, dtype=jnp.uint32)).sum(
+        1, dtype=jnp.uint32)
+
+
+def on_side(words, node_b):
+    """Whether each row of ``node_b`` (its node of this level) lies on its
+    parent's packed side (:func:`side_words`): one select a word of 32
+    parents and a shift, where a :func:`lookup` of the sides would be a
+    select a parent and row (0.43 us a parent and block of 65,536 rows on
+    a v5e: 6.5 us at 16 parents, PERF.md PR 34)."""
+    parent = node_b >> 1
+    bit = lookup(words, parent >> 5) >> (parent & 31).astype(jnp.uint32)
+    return (bit & 1) == (node_b & 1).astype(jnp.uint32)
+
+
+def with_siblings(built, parent, right_built):
+    """A level's ``(n_nodes, ...)`` sums in node order from the
+    ``(n_nodes / 2, ...)`` sums of the child built a parent and the
+    parents' own: the sibling is ``parent - built`` (float32 histograms
+    and int32 counts alike)."""
+    sel = right_built.reshape((-1,) + (1,) * (built.ndim - 1)) > 0
+    other = parent - built
+    return jnp.stack([jnp.where(sel, other, built),
+                      jnp.where(sel, built, other)], 1).reshape(
+        (-1,) + built.shape[1:])
 
 
 def descend_block(bins_b, node_b, feats, sbins, masks, continuous: bool):
@@ -731,12 +803,24 @@ def build_tree_blocked(bins, node_id, stats_at, max_depth: int, n_bins: int,
     are cheap to recompute need no ``(n, m)`` array. The other arguments
     are :func:`build_tree`'s; ``path`` is :func:`block_hist_path`'s word.
 
+    The root's histogram is built. From level 1 down the block loop
+    builds the histogram of ONE child of every parent, the one of the
+    smaller weight by the level above's histogram at its chosen split
+    (:func:`smaller_child`, which says why the smaller), in ``n_nodes /
+    2`` columns: a row's column is its parent and its stats are zeroed
+    unless it went to the built side. The other half of the level is
+    ``parent - built``, taken once a level after the shard's sums are
+    joined (still ONE ``tree_hist`` all-reduce a level, half as long);
+    the split search sees ``(n_nodes, F, n_bins, m)`` in node order as if
+    every node had been built. ``node_id`` holds the rows' full nodes.
+
     Returns ``(features, split_bins, split_masks, leaf_values, node_id,
     leaf_hist, importance, counts)``: as :func:`build_tree`, with
     ``node_id`` the rows' leaves in the blocked layout and ``counts``
     ``(2^(max_depth+1) - 1,)`` int32 the summed weight of every node,
     level by level and the leaves last, exact where the weights are whole
-    numbers (a float32 sum stops being exact at 2^24)."""
+    numbers (a float32 sum stops being exact at 2^24; a derived node's
+    count is the int32 difference of two exact ones)."""
     nbl, F = bins.shape[0], bins.shape[1]
     path = path or block_hist_path()
     cat = _cat_columns(cat_feats, F, cat_order_fn)
@@ -747,7 +831,7 @@ def build_tree_blocked(bins, node_id, stats_at, max_depth: int, n_bins: int,
 
     def reduce_pair(acc, comp, name):
         """The shard's Kahan pair summed over the mesh: float32 stats and
-        the exact weight a node (or leaf), which rides the same psum as
+        the exact weight a column (or leaf), which rides the same psum as
         two halves."""
         lead = acc.shape[0]
         w_at = (slice(None), 0, slice(None), m - 1) if acc.ndim == 4 \
@@ -767,12 +851,19 @@ def build_tree_blocked(bins, node_id, stats_at, max_depth: int, n_bins: int,
 
     feats_out, bins_out, masks_out, counts = [], [], [], []
     importance = jnp.zeros((F,), jnp.float32)
-    prev = None
+    prev = None      # the level above's splits, which the rows descend by
+    above = None     # its joined (histogram, counts, which child is built)
     for level in range(max_depth + 1):
         n_nodes = 1 << level
         leaves = level == max_depth
+        n_cols = n_nodes if leaves else level_columns(level)[0]
+        halved = n_cols < n_nodes
+        if halved:
+            hist_above, cnt_above, right_built = above
+            sides = side_words(right_built)
 
-        def body(i, c, n_nodes=n_nodes, prev=prev, leaves=leaves):
+        def body(i, c, n_nodes=n_nodes, n_cols=n_cols, prev=prev,
+                 sides=sides if halved else None, leaves=leaves):
             node_id, acc, comp = c
             bins_b = at(bins, i)
             if prev is None:
@@ -792,17 +883,25 @@ def build_tree_blocked(bins, node_id, stats_at, max_depth: int, n_bins: int,
                         precision=jax.lax.Precision.HIGHEST)
             else:
                 with jax.named_scope("gbdt_hist"):
-                    blk = block_hist(bins_b, node_b, stats_b, n_nodes,
+                    col_b = node_b
+                    if sides is not None:
+                        col_b = node_b >> 1
+                        stats_b = jnp.where(on_side(sides, node_b)[None],
+                                            stats_b, 0)
+                    blk = block_hist(bins_b, col_b, stats_b, n_cols,
                                      n_bins, path)
             acc, comp = kahan_add(acc, comp, blk)
             return put(node_id, node_b, i, 0), acc, comp
 
-        zero = jnp.zeros((n_nodes, m) if leaves
-                         else (n_nodes, F, n_bins, m), jnp.float32)
+        zero = jnp.zeros((n_cols, m) if leaves
+                         else (n_cols, F, n_bins, m), jnp.float32)
         node_id, acc, comp = jax.lax.fori_loop(
             0, nbl, body, (node_id, zero, zero))
         hist, cnt = reduce_pair(acc, comp,
                                 "tree_leaf_hist" if leaves else "tree_hist")
+        if halved:
+            hist = with_siblings(hist, hist_above, right_built)
+            cnt = with_siblings(cnt, cnt_above, right_built)
         counts.append(cnt)
         if leaves:
             leaf_hist = hist
@@ -811,6 +910,7 @@ def build_tree_blocked(bins, node_id, stats_at, max_depth: int, n_bins: int,
             feat, sbin, mask, gain = best_splits(
                 hist, n_bins, gain_fn, min_samples_leaf, min_gain,
                 feature_mask, cat)
+            above = (hist, cnt, smaller_child(hist, feat, mask))
         feats_out.append(feat)
         bins_out.append(sbin)
         masks_out.append(mask)

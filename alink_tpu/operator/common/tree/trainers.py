@@ -32,8 +32,9 @@ from ..dataproc.quantile import (DEVICE_BINNING_MIN_CELLS, FINE_BINS,
 from ..blocked import block_at as at, kahan_add
 from .hist import (bin_blocks, bin_data, block_hist_path, build_tree,
                    build_tree_blocked, fused_hist_mode, gini_gain, gini_leaf,
-                   lookup, make_bin_edges, make_xgb_gain, make_xgb_leaf,
-                   tree_apply_binned, variance_gain, variance_leaf)
+                   level_columns, lookup, make_bin_edges, make_xgb_gain,
+                   make_xgb_leaf, tree_apply_binned, variance_gain,
+                   variance_leaf)
 
 
 def _feature_subsample_mask(key, F: int, ratio: float, dtype):
@@ -116,13 +117,14 @@ def gbdt_train(X, y, p: TreeTrainParams,
     ``cat_mask``: (F,) bool — categorical columns (integer category codes)
     bin by identity and split on category subsets (hist.build_tree).
     ``info``, when given, receives what the fit went through: the
-    histogram ``hist`` path (``"onehot"`` or ``"scatter"``), the edges,
-    every tree's node ``counts`` and the rows counted."""
+    histogram ``hist`` path (``"onehot"`` or ``"scatter"``), the node
+    histograms a tree's block loops ``built`` and those it ``derived`` as
+    ``parent - built`` (``hist_nodes``), the edges, every tree's node
+    ``counts`` and the rows counted."""
     import dataclasses
     env_ = env or MLEnvironmentFactory.get_default()
     col = as_block_column(X, env_.num_workers, BYTE_BLOCK_QUANTUM)
     n, F = col.n_rows, col.dim
-    dtype = np.float32
     path = block_hist_path()
     # the seed is data (the queue's "key"): the stage below closes over
     # the settings without it, so one program serves every seed
@@ -144,6 +146,42 @@ def gbdt_train(X, y, p: TreeTrainParams,
     yb = _as_f32(block_values(col, y))
     wb = _as_f32(block_weights(col, block_values(col, sample_weight)))
     base = float(_weighted_mean(yb, wb)) if is_regression else 0.0
+    d, T = p.max_depth, p.num_trees
+    queue = _grow_queue(env_, bins, yb, wb, base, seed, p, is_regression,
+                        F, col.block_rows, path, cat_mask)
+    with trace_span("gbdt.grow", cat="gbdt",
+                    args={"trees": int(T), "depth": int(d), "hist": path,
+                          "sibling": "subtract"}):
+        res = queue.exec()
+        tf, tb, tm, tv, curve, imp, counts = res.get_all(
+            ["trees_f", "trees_b", "trees_m", "trees_v", "loss_curve",
+             "importance", "counts"])
+    rows = int(np.asarray(counts)[:, 0].sum(dtype=np.int64))
+    # a tree's node histograms by how the builder came by them
+    built, derived = (sum(v) for v in zip(*map(level_columns, range(d))))
+    if metrics_enabled():
+        reg = get_registry()
+        reg.inc("alink_gbdt_rows_total", rows)
+        reg.inc("alink_gbdt_trees_total", int(T))
+        reg.inc("alink_gbdt_hist_nodes_total", built * T, {"how": "built"})
+        reg.inc("alink_gbdt_hist_nodes_total", derived * T,
+                {"how": "derived"})
+    if info is not None:
+        info.update(hist=path, hist_nodes={"built": built,
+                                           "derived": derived},
+                    edges=edges, counts=np.asarray(counts),
+                    rows=rows, block_rows=col.block_rows)
+    return (tf, tb, tm, tv, edges, base, np.asarray(curve), imp)
+
+
+def _grow_queue(env_, bins, yb, wb, base: float, seed: int,
+                p: TreeTrainParams, is_regression: bool, F: int,
+                block_rows: int, path: str, cat_mask):
+    """The engine program ``jit_gbdt_grow`` as a queue ready to run (or to
+    lower from shapes): a tree a superstep over ``bins`` ``(workers, blocks
+    a worker, F, S, 128)`` uint8 and the per-row ``yb``, ``wb`` ``(blocks,
+    S, 128)`` float32, all partitioned on their leading axis."""
+    dtype = np.float32
     d = p.max_depth
     T = p.num_trees
     gain_fn = make_xgb_gain(p.reg_lambda)
@@ -232,34 +270,19 @@ def gbdt_train(X, y, p: TreeTrainParams,
             t, 0))
 
     from ....engine.comqueue import freeze_config
-    queue = (IterativeComQueue(env=env_, max_iter=T)
-             .init_with_partitioned_data("bins", bins)
-             .init_with_partitioned_data("y", yb)
-             .init_with_partitioned_data("w", wb)
-             # the seed and the base score are data: one program for every
-             # seed and every table of these shapes
-             .init_with_broadcast_data("key", np.asarray(jax.random.key_data(
-                 jax.random.PRNGKey(seed))))
-             .init_with_broadcast_data("base", np.asarray(base, dtype))
-             .add(grow)
-             .set_program_key((GROW_PROGRAM, is_regression, F,
-                               col.block_rows, path,
-                               freeze_config(p), freeze_config(cat_mask))))
-    with trace_span("gbdt.grow", cat="gbdt",
-                    args={"trees": int(T), "depth": int(d), "hist": path}):
-        res = queue.exec()
-        tf, tb, tm, tv, curve, imp, counts = res.get_all(
-            ["trees_f", "trees_b", "trees_m", "trees_v", "loss_curve",
-             "importance", "counts"])
-    rows = int(np.asarray(counts)[:, 0].sum(dtype=np.int64))
-    if metrics_enabled():
-        reg = get_registry()
-        reg.inc("alink_gbdt_rows_total", rows)
-        reg.inc("alink_gbdt_trees_total", int(T))
-    if info is not None:
-        info.update(hist=path, edges=edges, counts=np.asarray(counts),
-                    rows=rows, block_rows=col.block_rows)
-    return (tf, tb, tm, tv, edges, base, np.asarray(curve), imp)
+    return (IterativeComQueue(env=env_, max_iter=T)
+            .init_with_partitioned_data("bins", bins)
+            .init_with_partitioned_data("y", yb)
+            .init_with_partitioned_data("w", wb)
+            # the seed and the base score are data: one program for every
+            # seed and every table of these shapes
+            .init_with_broadcast_data("key", np.asarray(jax.random.key_data(
+                jax.random.PRNGKey(seed))))
+            .init_with_broadcast_data("base", np.asarray(base, dtype))
+            .add(grow)
+            .set_program_key((GROW_PROGRAM, is_regression, F,
+                              block_rows, path,
+                              freeze_config(p), freeze_config(cat_mask))))
 
 
 def _as_f32(a):

@@ -256,3 +256,50 @@ def test_als_half_sweep_compiles_with_the_solve_kernel_and_small_temps(
     assert mem.temp_size_in_bytes < 2.5e9, mem.temp_size_in_bytes
     # the grouped columns reach the loop as the parameters they are
     assert mem.argument_size_in_bytes < 2 * rows * 128 * 4 + 1.0e9
+
+
+#: ``temp_size_in_bytes`` of the PARENT's ``jit_gbdt_grow`` (PR 33's tree,
+#: every node's histogram built), compiled the same way: one chip, and a
+#: worker of the 2 x 2. Mostly the margins' copy (460 MB on one chip).
+_GROW_TEMP_BEFORE_PR34 = {1: 474_684_928, 4: 808_208_896}
+
+
+@pytest.mark.parametrize("chips", [1, 4], ids=["one_chip", "worker_of_2x2"])
+def test_gbdt_grow_compiles_with_the_halved_histogram_product(topo, chips):
+    """``jit_gbdt_grow`` of ``gbdt-fit`` (1,755 blocks of 13 x 512 x 128
+    uint8 bins, depth 6, 128 bins), the whole engine program lowered from
+    shapes: the widest one-hot product has 16 built nodes x 6 = 96
+    right-hand columns where every node built asked 192; the longest
+    all-reduce is the 16 built nodes' Kahan pair; and the program asks
+    for the parent's temporaries to within 1 % (it keeps a level's joined
+    histogram for the next level's subtraction, at most 320 KB, and
+    nothing a row: 474.68 -> 475.35 MB on one chip, 808.2 -> 811.8 MB a
+    worker, compiled here for PR 34)."""
+    import jax
+    import jax.numpy as jnp
+    from alink_tpu.common.mlenv import MLEnvironment
+    from alink_tpu.operator.common.tree import trainers as T
+
+    env = MLEnvironment(parallelism=chips,
+                        devices=list(topo.devices[:chips]))
+    blocks, F, S, bins, depth = -(-1755 // chips), 13, 512, 128, 6
+    p = T.TreeTrainParams(num_trees=4, max_depth=depth, n_bins=bins,
+                          min_samples_leaf=100)
+    rows = jax.ShapeDtypeStruct((chips * blocks, S, 128), jnp.float32)
+    with jax.enable_x64(False):                      # as on the chip
+        compiled = T._grow_queue(
+            env, jax.ShapeDtypeStruct((chips, blocks, F, S, 128), jnp.uint8),
+            rows, rows, 0.0, 7, p, False, F, S * 128, "onehot",
+            None).lowered().compile()
+    text = compiled.as_text()
+    assert "jit_gbdt_grow" in text
+    widths = [int(np.prod([int(d) for d in dims.split(",")]))
+              for dims in re.findall(
+                  rf"= f32\[{F},{bins},([0-9,]+)\]\S* convolution\(", text)]
+    assert widths and max(widths) == (1 << depth - 2) * 6 == 96, widths
+    if chips > 1:
+        longest = max(int(n) for n in re.findall(
+            r"= f32\[(\d+)\]\S* all-reduce(?:-start)?\(", text))
+        assert longest == 2 * 16 * F * bins * 3 + 2 * 16
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= 1.01 * _GROW_TEMP_BEFORE_PR34[chips], temp

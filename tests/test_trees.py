@@ -642,3 +642,200 @@ def test_the_float32_serving_kernel_routes_a_tie_as_the_host_mapper_does():
     assert (np.asarray(got) == np.asarray(want)).all()
     assert np.asarray(want).astype(float).mean() == pytest.approx(
         y.mean(), abs=0.05), "and the model has learned the ties' rule"
+
+
+# -- the blocked builder's halved levels (one child built, the sibling taken
+# -- from the parent) against histograms built directly --------------------------
+
+def _blocked_fixture(seed, n_blocks=2, F=4, S=8, B=16, cat=False):
+    """Bins of a small blocked table, a label its columns explain, and
+    whole-number weights 1..4."""
+    rng = np.random.RandomState(seed)
+    bins = rng.randint(0, B, (n_blocks, F, S, 128)).astype(np.uint8)
+    if cat:                       # column 1 is categorical: 6 categories
+        bins[:, 1] = rng.randint(0, 6, (n_blocks, S, 128))
+    score = (bins[:, 0] > 7) * 1.0 - (bins[:, 2] > 4) * 0.7 \
+        + np.isin(bins[:, 1], (1, 4)) * 0.9 + 0.3 * rng.randn(n_blocks, S, 128)
+    y = (score > 0.4).astype(np.float32)
+    w = rng.randint(1, 5, (n_blocks, S, 128)).astype(np.float32)
+    return bins, y, w
+
+
+def _grow_and_record(monkeypatch, bins, stats, depth, B, path, cat_feats=None,
+                     min_leaf=1.0):
+    """``build_tree_blocked`` run eagerly, with every level's assembled
+    histogram as ``best_splits`` received it."""
+    import jax.numpy as jnp
+    from alink_tpu.operator.common.tree import hist as H
+    seen = []
+    real = H.best_splits
+
+    def spy(hist, *a, **k):
+        seen.append(np.asarray(hist))
+        return real(hist, *a, **k)
+    monkeypatch.setattr(H, "best_splits", spy)
+    stats_j = jnp.asarray(stats)
+    out = H.build_tree_blocked(
+        jnp.asarray(bins), jnp.zeros(bins.shape[:1] + bins.shape[2:],
+                                     jnp.int32),
+        lambda i: H.block_at(stats_j, i), depth, B, H.make_xgb_gain(1.0),
+        H.make_xgb_leaf(1.0), min_samples_leaf=min_leaf,
+        cat_feats=cat_feats, path=path)
+    return [np.asarray(o) for o in out], seen
+
+
+def _direct_levels(bins, stats, feats, masks, depth):
+    """Every level's full-width histogram and every node's summed weight,
+    built directly in float64 over the rows' nodes (descended through the
+    tree by the LEFT-membership masks), leaves last."""
+    F, B = bins.shape[1], masks.shape[1]
+    b = np.moveaxis(bins, 1, 0).reshape(F, -1).astype(np.int64)
+    st = np.moveaxis(stats, 1, 0).reshape(stats.shape[1], -1).astype(
+        np.float64)
+    node = np.zeros(b.shape[1], np.int64)
+    hists, counts, off = [], [], 0
+    for level in range(depth + 1):
+        n_nodes = 1 << level
+        counts.append(np.bincount(node, st[-1], n_nodes))
+        if level == depth:
+            break
+        h = np.zeros((n_nodes, F, B, st.shape[0]))
+        for f in range(F):
+            np.add.at(h, (node, f, b[f]), st.T)
+        hists.append(h)
+        f_row = feats[off + node]
+        in_left = masks[off + node, b[np.maximum(f_row, 0),
+                                      np.arange(b.shape[1])]]
+        node = node * 2 + ((f_row >= 0) & ~in_left)
+        off += n_nodes
+    return hists, np.concatenate(counts).astype(np.int64)
+
+
+@pytest.mark.parametrize("path", ["scatter", "onehot"])
+@pytest.mark.parametrize("bagged", [False, True], ids=["all_rows", "bagged"])
+@pytest.mark.parametrize("weights", ["unit", "whole"])
+@pytest.mark.parametrize("kind", ["continuous", "categorical"])
+@pytest.mark.parametrize("depth", [1, 2, 3, 4])
+def test_a_halved_level_is_the_full_width_histogram(monkeypatch, depth, kind,
+                                                    weights, bagged, path):
+    """From level 1 down the blocked builder builds one child of every
+    parent and takes the sibling from the parent. What the split search
+    receives is the level's full-width histogram to the product's 16 bits
+    (to float32's on the scatter-add), and every node's count is EQUAL to
+    the rows' own, on the derived side too."""
+    cat = kind == "categorical"
+    bins, y, w = _blocked_fixture(depth * 7 + cat, cat=cat)
+    if weights == "unit":
+        w = np.ones_like(w)
+    if bagged:
+        w = w * (np.random.RandomState(5).rand(*w.shape) < 0.7)
+    stats = np.stack([(0.5 - y) * w, 0.25 * w, w], 1).astype(np.float32)
+    cat_feats = np.array([False, True, False, False]) if cat else None
+    out, seen = _grow_and_record(monkeypatch, bins, stats, depth, 16, path,
+                                 cat_feats)
+    feats, masks, counts = out[0], out[2], out[7]
+    want_h, want_c = _direct_levels(bins, stats, feats, masks, depth)
+    assert len(seen) == depth
+    # a (hi, lo) bfloat16 pair holds 16 bits of each addend: of a bin's
+    # few dozen addends of size <= 4, so an absolute 2e-4; a derived bin
+    # adds its parent's and its sibling's rounding
+    atol = 6e-4 if path == "onehot" else 2e-5
+    for level, (got, want) in enumerate(zip(seen, want_h)):
+        assert got.shape == want.shape == (1 << level, 4, 16, 3)
+        np.testing.assert_allclose(got, want, atol=atol, err_msg=str(level))
+        assert (got[..., 2] == want[..., 2]).all(), "whole weights are exact"
+    assert counts.dtype.kind == "i" and (counts == want_c).all()
+    assert counts[0] == int(w.sum())
+
+
+@pytest.mark.parametrize("path", ["scatter", "onehot"])
+def test_a_derived_count_past_2_to_24_is_exact(monkeypatch, path):
+    """Nodes whose summed weight passes 2^24, where float32 stops holding
+    whole numbers: 53,248 rows of weight 1,023 / 1,024 / 1,025 (a block's
+    sum stays under 2^24, so it is exact). The built child's count is the
+    Kahan pair read out whole, the sibling's an int32 difference of two
+    such counts: both equal the rows' own."""
+    rng = np.random.RandomState(2)
+    bins, y, _ = _blocked_fixture(11, n_blocks=13, S=32)
+    w = rng.choice([1023.0, 1024.0, 1025.0], bins.shape[:1] + bins.shape[2:]
+                   ).astype(np.float32)
+    stats = np.stack([(0.5 - y) * w, 0.25 * w, w], 1).astype(np.float32)
+    out, seen = _grow_and_record(monkeypatch, bins, stats, 3, 16, path)
+    feats, masks, counts = out[0], out[2], out[7]
+    _, want_c = _direct_levels(bins, stats, feats, masks, 3)
+    assert (counts == want_c).all()
+    assert counts[0] == int(w.astype(np.int64).sum()) > 3 * 2 ** 24
+    # level 1's larger child is the derived one and is past 2^24; taken
+    # as a float32 difference it would be another number
+    assert counts[1:3].max() > 2 ** 24
+    assert (counts[1:3].sum(), counts[3:7].sum()) == (counts[0], counts[0])
+    small = counts[1:3].min()
+    assert int(np.float32(counts[0]) - np.float32(small)) != counts[0] - small
+
+
+@pytest.mark.parametrize("path", ["scatter", "onehot"])
+def test_an_unsplit_parent_keeps_its_histogram_on_the_left(monkeypatch, path):
+    """A node that does not split sends every row left: the child built
+    of it is the empty right one, and the left is ``parent - 0``, the
+    parent's own histogram bit for bit."""
+    bins, y, w = _blocked_fixture(3)
+    w = np.ones_like(w)
+    stats = np.stack([(0.5 - y) * w, 0.25 * w, w], 1).astype(np.float32)
+    # 2,048 rows: the root splits, no child can split into two of 800
+    out, seen = _grow_and_record(monkeypatch, bins, stats, 3, 16, path,
+                                 min_leaf=800.0)
+    feats, counts = out[0], out[7]
+    assert feats[0] >= 0 and (feats[1:3] == -1).all()
+    for p in (0, 1):
+        assert (seen[2][2 * p] == seen[1][p]).all()
+        assert (seen[2][2 * p + 1] == 0).all()
+    assert counts[3:7].tolist() == [counts[1], 0, counts[2], 0]
+    assert (feats[3:7] == -1).all()
+
+
+def test_four_workers_grow_the_tree_of_one_and_say_what_they_built(
+        monkeypatch, quiet_tracer):
+    """``gbdt_train`` on 4 virtual devices against 1: the same trees and
+    counts (a level's halves ride one psum, the sibling is taken after
+    it). The fit says how many node histograms a tree built and how many
+    it derived, in ``info``, in the counter and on the ``gbdt.grow``
+    span."""
+    import jax
+    from alink_tpu.common.metrics import (MetricsRegistry, get_registry,
+                                          set_registry)
+    from alink_tpu.common.mlenv import MLEnvironment
+    from alink_tpu.operator.common.tree.trainers import (TreeTrainParams,
+                                                         gbdt_train)
+    monkeypatch.setenv("ALINK_TPU_TRACE", "1")
+    X, y = _airline_like(9000, seed=2)
+    w = np.random.RandomState(3).randint(1, 4, len(y)).astype(np.float64)
+    p = TreeTrainParams(num_trees=3, max_depth=4, n_bins=32,
+                        min_samples_leaf=20, subsample_ratio=0.8)
+    fits = []
+    prev = set_registry(MetricsRegistry())
+    try:
+        for workers in (1, 4):
+            info = {}
+            out = gbdt_train(X, y, p, False, sample_weight=w, info=info,
+                             env=MLEnvironment(
+                                 parallelism=workers,
+                                 devices=jax.devices()[:workers]))
+            fits.append((out, info))
+            assert info["hist_nodes"] == {"built": 8, "derived": 7}
+        reg = get_registry()
+        assert reg.value("alink_gbdt_hist_nodes_total",
+                         {"how": "built"}) == 2 * 3 * 8
+        assert reg.value("alink_gbdt_hist_nodes_total",
+                         {"how": "derived"}) == 2 * 3 * 7
+    finally:
+        set_registry(prev)
+    grow = [e for e in quiet_tracer.events()
+            if e.get("ph") == "X" and e["name"] == "gbdt.grow"]
+    assert len(grow) == 2
+    assert all(e["args"]["sibling"] == "subtract" for e in grow)
+    (a, ia), (b, ib) = fits
+    # bagging draws by global block, so the workers see the one's rows
+    assert (np.asarray(a[0]) == np.asarray(b[0])).all()
+    assert (np.asarray(a[1]) == np.asarray(b[1])).all()
+    assert (ia["counts"] == ib["counts"]).all()
+    np.testing.assert_allclose(np.asarray(a[3]), np.asarray(b[3]), atol=2e-6)
