@@ -323,9 +323,11 @@ FLAGS.register(
     accessor="alink_tpu.common.profiling.step_log_enabled")
 FLAGS.register(
     "ALINK_TPU_TRACE", "bool", False,
-    "structured span tracer (flight recorder) + lazy XLA cost analysis",
+    "the flight recorder's FINE grade: per-micro-batch / per-request spans "
+    "and instant events outside a profiler session (the coarse grade: "
+    "session, jit.*, *.fit, comqueue.* records in every process)",
     "observability",
-    key_neutral="host-side span recording and a lazy post-hoc lowering; "
+    key_neutral="host-side span recording only; "
                 "lowered HLO byte-identical on/off (tests/test_tracing.py)",
     accessor="alink_tpu.common.tracing.tracing_enabled")
 FLAGS.register(

@@ -13,6 +13,7 @@ tensor parallelism, SURVEY §2.3).
 
 from __future__ import annotations
 
+import itertools
 import os
 import threading
 from typing import Dict, Optional, Tuple
@@ -20,6 +21,8 @@ from typing import Dict, Optional, Tuple
 import numpy as np
 
 from .lazy import LazyObjectsManager
+from .metrics import get_registry, metrics_enabled
+from .tracing import trace_complete, trace_span
 
 
 def mesh_device_request() -> int:
@@ -90,11 +93,70 @@ def place_compile_cache() -> str:
     return COMPILE_CACHE_DIR
 
 
+#: JAX's own account of what a program costs a process before it runs,
+#: as ``jax.monitoring`` reports it, under the names the ring gives it
+_JIT_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower",
+    "/jax/core/compile/backend_compile_duration": "jit.compile",
+}
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
+                 "/jax/compilation_cache/cache_misses": "miss"}
+_cache_outcome = threading.local()
+_sessions = itertools.count()       # sessions this process has started
+
+
+def _on_jax_event(event: str, **_kw) -> None:
+    outcome = _CACHE_EVENTS.get(event)
+    if outcome is not None:
+        _cache_outcome.last = outcome
+
+
+def _on_jax_duration(event: str, secs: float, **kw) -> None:
+    """One coarse retroactive event a trace, a lowering and a backend
+    compile (on a persistent-cache hit: the retrieval), parented to the
+    span open on the compiling thread. A function traced inside another's
+    trace reports too, so ``jit.trace`` events nest by their intervals;
+    a warm call fires none of the three."""
+    name = _JIT_EVENTS.get(event)
+    if name is None:
+        return
+    args = {"fun_name": str(kw.get("fun_name", "?"))}
+    if name == "jit.compile":
+        # the cache's verdict precedes the duration on the same thread;
+        # neither a hit nor a miss means the cache was not asked
+        cache = getattr(_cache_outcome, "last", "off")
+        _cache_outcome.last = "off"
+        args["cache"] = cache
+        if metrics_enabled():
+            get_registry().inc("alink_jit_compiles_total", 1,
+                               {"cache": cache})
+    trace_complete(name, secs, cat="jit", args=args, coarse=True)
+
+
+def _listen_to_jax() -> None:
+    from jax import monitoring
+    monitoring.register_event_listener(_on_jax_event)
+    monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
 class MLEnvironment:
     """One session: device mesh + lazy-objects manager + RNG seed stream."""
 
     def __init__(self, parallelism: Optional[int] = None, model_parallelism: int = 1,
                  devices=None):
+        # ``session`` counts from 0: the process's first session ends its
+        # boot (interpreter, imports, backend, device discovery)
+        nth = next(_sessions)
+        with trace_span("session.start", cat="session", coarse=True,
+                        args={"session": nth}) as sp:
+            if nth == 0:
+                _listen_to_jax()
+            self._start(parallelism, model_parallelism, devices)
+            sp.set(devices=len(self._devices),
+                   platform=str(self._devices[0].platform))
+
+    def _start(self, parallelism, model_parallelism, devices) -> None:
         import jax
 
         place_compile_cache()

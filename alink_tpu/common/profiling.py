@@ -136,14 +136,16 @@ class StepTimer:
     METRIC = "alink_step_timer_seconds"
 
     @contextlib.contextmanager
-    def span(self, name: str,
-             labels: Optional[Dict[str, str]] = None) -> Iterator[None]:
+    def span(self, name: str, labels: Optional[Dict[str, str]] = None,
+             coarse: bool = False) -> Iterator[None]:
         t0 = time.perf_counter()
         try:
-            # single source of truth: under ALINK_TPU_TRACE the same span
-            # also lands on the process tracer (nested via contextvars),
-            # so StepTimer call sites never need double-instrumentation
-            with trace_span(name, cat="steptimer", args=labels):
+            # single source of truth: the same span also lands on the
+            # process tracer (nested via contextvars; always where the
+            # call site says ``coarse``, else under ALINK_TPU_TRACE), so
+            # StepTimer call sites never need double-instrumentation
+            with trace_span(name, cat="steptimer", args=labels,
+                            coarse=coarse):
                 yield
         finally:
             dt = time.perf_counter() - t0

@@ -30,14 +30,28 @@ Two exporters:
     the run-log shape ``tools/trace.py`` and ``tools/run_report.py
     --trace`` consume.
 
+Two grades of event, decided at the call site:
+
+  * **coarse** (``trace_span(..., coarse=True)``) — fires a bounded number
+    of times a process or a fit: the session's start, JAX's trace / lower
+    / compile of a program (``jit.*``, written by the session layer's
+    ``jax.monitoring`` listener), a trainer's state set-up, an operator's
+    ``*.fit`` and its children, the engine's ``comqueue.*`` phases with
+    the wait for the device. Recorded in EVERY process: that is what the
+    ring is for. Budget: at most 40 a fit, none a micro-batch or request.
+  * **fine** (the default) — fires once a micro-batch or a request
+    (``ftrl.encode`` / ``.ship`` / ``.dispatch`` / ``.batch``,
+    ``prefetch.*``, ``serve.*``, ``comqueue.chunk``): recorded only under
+    the switch below or a profiler session.
+
 Switches (``common.metrics.env_flag`` parsing: unset -> default,
 ``0/false/off/no`` -> off):
 
-  * ``ALINK_TPU_TRACE``        — default OFF. Master switch for every
-    instrumented producer (``trace_span``/``trace_instant`` below are
-    no-ops without it, unless a profiler session runs: next paragraph).
-    Tracing never changes compiled programs — all events are host-side
-    (asserted by a lowered-HLO test).
+  * ``ALINK_TPU_TRACE``        — default OFF. Switch for the fine
+    producers and the instants (``trace_span``/``trace_instant`` below
+    are no-ops without it unless coarse, or unless a profiler session
+    runs: next paragraph). Tracing never changes compiled programs — all
+    events are host-side (asserted by a lowered-HLO test).
   * ``ALINK_TPU_TRACE_BUFFER`` — flight-recorder capacity in events
     (default 65536; ~200 bytes/event, so the default bounds memory at a
     few tens of MB).
@@ -45,8 +59,8 @@ Switches (``common.metrics.env_flag`` parsing: unset -> default,
 The bridge to the device's clock: while a ``jax.profiler`` session is
 active (``jax.profiler.start_trace`` / ``jax.profiler.trace``), every
 producer records as if ``ALINK_TPU_TRACE`` were on, every event carries
-``profiled: true``, and every real span (not the retroactive
-``complete``, whose ends are already past) also enters a
+``profiled: true`` (a coarse one too), and every real span (not the
+retroactive ``complete``, whose ends are already past) also enters a
 ``jax.profiler.TraceAnnotation`` named ``alink:<name>``. The span then
 sits in the profiler's ``.xplane.pb`` on its host thread's line, stamped
 by the profiler's clock, beside the device's ``XLA Ops``. This module
@@ -336,6 +350,12 @@ class Tracer:
         with self._lock:
             return self._dropped
 
+    @property
+    def origin_unix(self) -> float:
+        """``time.time()`` at ``ts`` 0: an event's ``ts`` / 1e6 later it
+        began, on the clock other processes and ``/proc`` share."""
+        return self._origin_unix
+
     def clear(self) -> None:
         with self._lock:
             self._events.clear()
@@ -450,12 +470,13 @@ def set_tracer(tracer: Tracer) -> Tracer:
 # -- instrumentation helpers (the call-site API) ----------------------------
 
 def trace_span(name: str, cat: str = "host",
-               args: Optional[Dict[str, Any]] = None):
-    """A span on the process tracer, or a shared no-op when neither
-    ``ALINK_TPU_TRACE`` is on nor a profiler session runs. The disabled
-    fast path costs one env lookup and one session test, and allocates
+               args: Optional[Dict[str, Any]] = None, coarse: bool = False):
+    """A span on the process tracer. A ``coarse`` one (module header)
+    records in every process; a fine one is a shared no-op when neither
+    ``ALINK_TPU_TRACE`` is on nor a profiler session runs, and that fast
+    path costs one env lookup and one session test, and allocates
     nothing."""
-    if not recording():
+    if not (coarse or recording()):
         return _NULL_SPAN
     return get_tracer().span(name, cat=cat, args=args)
 
@@ -469,9 +490,10 @@ def trace_instant(name: str, cat: str = "host",
 
 
 def trace_complete(name: str, dur_s: float, cat: str = "host",
-                   args: Optional[Dict[str, Any]] = None) -> None:
+                   args: Optional[Dict[str, Any]] = None,
+                   coarse: bool = False) -> None:
     """A retroactive span (ends now, lasted ``dur_s``) on the process
-    tracer; no-op when not :func:`recording`. See
+    tracer; unless ``coarse``, a no-op when not :func:`recording`. See
     :meth:`Tracer.complete`."""
-    if recording():
+    if coarse or recording():
         get_tracer().complete(name, dur_s, cat=cat, args=args)

@@ -43,7 +43,7 @@ import numpy as np
 from ..common.health import health_enabled
 from ..common.mlenv import MLEnvironment, MLEnvironmentFactory
 from ..common.profiling2 import hbm_snapshot, profile_window
-from ..common.tracing import trace_instant, trace_span, tracing_enabled
+from ..common.tracing import trace_instant, trace_span
 from .context import ComContext
 from .communication import CommunicateFunction
 
@@ -74,10 +74,6 @@ _PROGRAM_CACHE_JAXPRS: Dict[tuple, str] = {}
 # a program compiled under ALINK_TPU_METRICS=0 still carries its manifest
 # when a later metrics-on exec hits the cache.
 _PROGRAM_CACHE_MANIFESTS: Dict[tuple, dict] = {}
-# XLA static cost model per cached key (compat.compiled_cost_analysis on
-# the lowered program). Computed lazily and only under ALINK_TPU_TRACE —
-# the lowering costs a full re-trace, so the default path never pays it.
-_PROGRAM_CACHE_COSTS: Dict[tuple, dict] = {}
 
 # Engine phase wall-clock (prepare inputs / execute+compile / collect).
 # Spans mirror into the MetricsRegistry as alink_step_timer_seconds via
@@ -124,7 +120,6 @@ def clear_program_cache() -> None:
     _PROGRAM_CACHE.clear()
     _PROGRAM_CACHE_JAXPRS.clear()
     _PROGRAM_CACHE_MANIFESTS.clear()
-    _PROGRAM_CACHE_COSTS.clear()
 
 
 def _program_label(program_key) -> str:
@@ -185,25 +180,6 @@ class _AotMeshCall:
 
     def lower(self, *args, **kwargs):
         return self._fn.lower(*args, **kwargs)
-
-
-def _maybe_cost(ckey: Optional[tuple], lower_thunk: Callable) -> Optional[dict]:
-    """The cached program's static XLA cost dict, memoized per key.
-
-    Computed only under ``ALINK_TPU_TRACE`` (``lower_thunk`` re-traces the
-    program, seconds for the big optimizer programs); once computed it is
-    served from the memo so later traced execs pay a dict lookup. An
-    unavailable cost model memoizes as ``{}`` — a backend without one
-    must not re-pay the lowering on every traced exec just to learn
-    None again."""
-    if ckey is None:
-        return None
-    cost = _PROGRAM_CACHE_COSTS.get(ckey)
-    if cost is None and tracing_enabled():
-        from ..common.compat import compiled_cost_analysis
-        cost = compiled_cost_analysis(lower_thunk()) or {}
-        _PROGRAM_CACHE_COSTS[ckey] = cost
-    return cost or None
 
 
 def freeze_config(v):
@@ -571,7 +547,6 @@ def _lookup_programs(cache: str, key: Optional[tuple], plan, build: Callable,
             old_key, _ = _PROGRAM_CACHE.popitem(last=False)
             _PROGRAM_CACHE_JAXPRS.pop(old_key, None)
             _PROGRAM_CACHE_MANIFESTS.pop(old_key, None)
-            _PROGRAM_CACHE_COSTS.pop(old_key, None)
             compileledger.record_eviction(
                 "engine.chunked" if old_key and old_key[0] == "__ckpt__"
                 else "engine.program")
@@ -804,11 +779,11 @@ def _fetch_tree(tree):
     leaf flipped read-only (the memo contract above)."""
     import jax
     from ..common.compat import device_get_tree
-    # the result fetch is the engine's device->host leg: ONE span on the
-    # process tracer (recorded under ALINK_TPU_TRACE or a profiler
-    # session) carries its wall time and bytes. The fetch itself is one
-    # batched device_get; leaves stay read-only — memo contract.
-    with trace_span("comqueue.fetch", cat="engine") as sp:
+    # the result fetch is the engine's device->host leg: ONE coarse span
+    # on the process tracer carries its wall time and bytes. The fetch
+    # itself is one batched device_get; leaves stay read-only — memo
+    # contract.
+    with trace_span("comqueue.fetch", cat="engine", coarse=True) as sp:
         got = device_get_tree(tree)
         sp.set(nbytes=int(sum(getattr(leaf, "nbytes", 0) for leaf
                               in jax.tree_util.tree_leaves(got))))
@@ -890,7 +865,13 @@ class ComQueueResult:
             else:
                 on_device[n] = self._stacked[n]
         if on_device:
-            got = _fetch_tree(lazy_jit(_first_shards)(on_device))
+            # the compiled slice is a dispatch of its own (a third of a
+            # millisecond on a TPU host): a coarse span, so that neither
+            # the exec's nor the trainer's self time hides it and
+            # ``comqueue.fetch`` stays the transfer alone
+            with trace_span("comqueue.slice", cat="engine", coarse=True):
+                first = lazy_jit(_first_shards)(on_device)
+            got = _fetch_tree(first)
             for n, v in got.items():
                 self._fetched[("get", n)] = v
         return [self._fetched[("get", n)] for n in names]
@@ -1112,9 +1093,15 @@ class IterativeComQueue:
 
     def exec(self):
         # one root span per exec: every phase span (prepare / execute via
-        # StepTimer), chunk span and instant event below nests under it,
-        # so a trace file reads as one tree per fit
-        with trace_span("comqueue.exec", cat="engine") as sp:
+        # StepTimer, wait, fetch), chunk span and instant event below
+        # nests under it, so a trace file reads as one tree per fit. The
+        # phases are coarse (in the ring of every process): exec =
+        # prepare + plan (the ExecutionPlan and the program lookup) +
+        # execute (dispatch; on a miss the trace and compile are its
+        # ``jit.*`` children) + wait + slice + fetch (the step count's
+        # read) + account (the metrics tail) + a self time that should
+        # read near 0
+        with trace_span("comqueue.exec", cat="engine", coarse=True) as sp:
             sp.set(max_iter=int(self.max_iter),
                    program=_program_label(self._program_key)
                    if self._program_key is not None else "uncached")
@@ -1145,12 +1132,11 @@ class IterativeComQueue:
         bodies = _StepBodies(self, env, probes_on, donate)
         if lower_only:
             return bodies.lower(parts, bcast, chunked=lower_chunked)
-        splan, ckey = self._plan(bodies, plan_flags, parts, bcast)
         compileledger.subsystem_start("engine")
         execute = self._exec_chunked \
             if self._ckpt is not None or self._boundary is not None \
             else self._exec_plain
-        return execute(bodies, parts, totals, bcast, splan, ckey,
+        return execute(bodies, parts, totals, bcast, plan_flags,
                        metrics_enabled())
 
     def _prepare(self, nw: int):
@@ -1165,7 +1151,7 @@ class IterativeComQueue:
         # the StepTimer's, which lands on the process tracer as
         # ``comqueue.prepare`` under the exec's root span (and mirrors into
         # the registry). Host-side wall clock only.
-        with _ENGINE_TIMER.span("comqueue.prepare"):
+        with _ENGINE_TIMER.span("comqueue.prepare", coarse=True):
             for k, arr in self._partitioned.items():
                 if isinstance(arr, (jax.Array, jax.ShapeDtypeStruct)):
                     # already device-resident (a cached table, precomputed
@@ -1223,7 +1209,7 @@ class IterativeComQueue:
         return splan, ckey
 
     def _exec_chunked(self, bodies: _StepBodies, parts, totals, bcast,
-                      splan, ckey, mx: bool):
+                      plan_flags, mx: bool):
         """Durable chunked execution (engine/recovery.py): the ``first``
         + ``cont`` pair, a host boundary every ``every`` supersteps."""
         import jax
@@ -1251,21 +1237,24 @@ class IterativeComQueue:
             elif int(ck.every) != b_every:
                 import dataclasses
                 ck = dataclasses.replace(ck, every=b_every)
-        ckkey = ("__ckpt__", ckey) if ckey is not None else None
         site = _program_label(self._program_key) \
             if self._program_key is not None else None
-        cplan = splan.extend(("checkpoint_chunked", True))
-        aot = ()
-        if ckkey is not None and aotcache.active():
-            # the pair ships as two artifacts keyed off the same plan
-            # with a role dim
-            aot = ((cplan.extend(("role", "first")),
-                    ("shard", "repl", "repl")),
-                   (cplan.extend(("role", "cont")),
-                    ("shard", "repl", "shard", "repl")))
-        (first, cont), cache_status, manifest = _lookup_programs(
-            "engine.chunked", ckkey, cplan, bodies.jit_chunks,
-            bodies.manifest, site=site, mesh=bodies.mesh, mx=mx, aot=aot)
+        with trace_span("comqueue.plan", cat="engine", coarse=True):
+            splan, ckey = self._plan(bodies, plan_flags, parts, bcast)
+            ckkey = ("__ckpt__", ckey) if ckey is not None else None
+            cplan = splan.extend(("checkpoint_chunked", True))
+            aot = ()
+            if ckkey is not None and aotcache.active():
+                # the pair ships as two artifacts keyed off the same plan
+                # with a role dim
+                aot = ((cplan.extend(("role", "first")),
+                        ("shard", "repl", "repl")),
+                       (cplan.extend(("role", "cont")),
+                        ("shard", "repl", "shard", "repl")))
+            (first, cont), cache_status, manifest = _lookup_programs(
+                "engine.chunked", ckkey, cplan, bodies.jit_chunks,
+                bodies.manifest, site=site, mesh=bodies.mesh, mx=mx,
+                aot=aot)
         lim0 = jnp.asarray(bodies.max_iter, jnp.int32)
         if cache_status == "miss" and aot:
             # export BEFORE recovery.drive: export's trace runs the
@@ -1281,7 +1270,6 @@ class IterativeComQueue:
                                (parts, bcast, carry_av, lim0),
                                cache="engine.chunked", site=site,
                                manifest=manifest)
-        cost = _maybe_cost(ckkey, lambda: first.lower(parts, bcast, lim0))
         if ck.directory or ck.resume_from:
             part_sig = tuple(
                 (k, tuple(map(int, np.shape(parts[k]))),
@@ -1322,22 +1310,19 @@ class IterativeComQueue:
             def on_snapshot(host, step, _m=self._health):
                 self._ingest_probes(_m, host, step)
         with _ENGINE_TIMER.span("comqueue.execute",
-                                labels={"program": cache_status}):
+                                labels={"program": cache_status},
+                                coarse=True):
             stacked, ck_info = recovery.drive(
                 ck, first=first, cont=cont, parts=parts, bcast=bcast,
                 max_iter=bodies.max_iter, signature=signature,
                 resumed=resumed, on_snapshot=on_snapshot,
                 donate=bodies.donate, on_boundary=on_boundary)
-        # chunked path: the program runs once per chunk, so only the
-        # STATIC cost gauges are meaningful (no exec_t0 -> no achieved
-        # rates; see _finish)
         return self._finish(stacked, bodies.nw, totals, manifest, parts,
-                            bcast, mx, ck_info, cost=cost,
-                            prog_label=site,
+                            bcast, mx, ck_info,
                             probes_on=bodies.probes_on)
 
     def _exec_plain(self, bodies: _StepBodies, parts, totals, bcast,
-                    splan, ckey, mx: bool):
+                    plan_flags, mx: bool):
         """The whole superstep loop as ONE program, dispatched once."""
         import jax
 
@@ -1346,23 +1331,24 @@ class IterativeComQueue:
         verify = env_flag("ALINK_VERIFY_PROGRAM_CACHE", default=False)
         site = _program_label(self._program_key) \
             if self._program_key is not None else None
-        # verify mode is excluded from the AOT store: it compares fresh
-        # jaxprs against the trace recorded at compile time, and a
-        # deserialized program has no trace to baseline against
-        aot = ()
-        if (ckey is not None and not verify and jax.process_count() == 1
-                and aotcache.active()):
-            aot = ((splan, ("shard", "repl")),)
-        (compiled,), cache_status, manifest = _lookup_programs(
-            "engine.program", ckey, splan,
-            lambda: (jax.jit(bodies.mapped()),), bodies.manifest,
-            site=site, mesh=bodies.mesh, mx=mx, aot=aot,
-            fresh_jaxpr=(lambda: str(jax.make_jaxpr(bodies.mapped())(
-                parts, bcast))) if verify else None)
-        cost = _maybe_cost(ckey, lambda: compiled.lower(parts, bcast))
-        exec_t0 = time.perf_counter()
+        with trace_span("comqueue.plan", cat="engine", coarse=True):
+            splan, ckey = self._plan(bodies, plan_flags, parts, bcast)
+            # verify mode is excluded from the AOT store: it compares
+            # fresh jaxprs against the trace recorded at compile time, and
+            # a deserialized program has no trace to baseline against
+            aot = ()
+            if (ckey is not None and not verify
+                    and jax.process_count() == 1 and aotcache.active()):
+                aot = ((splan, ("shard", "repl")),)
+            (compiled,), cache_status, manifest = _lookup_programs(
+                "engine.program", ckey, splan,
+                lambda: (jax.jit(bodies.mapped()),), bodies.manifest,
+                site=site, mesh=bodies.mesh, mx=mx, aot=aot,
+                fresh_jaxpr=(lambda: str(jax.make_jaxpr(bodies.mapped())(
+                    parts, bcast))) if verify else None)
         with _ENGINE_TIMER.span("comqueue.execute",
-                                labels={"program": cache_status}):
+                                labels={"program": cache_status},
+                                coarse=True):
             # measured-profiling window (ALINK_TPU_PROFILE): dispatch =
             # time the compiled call held the host thread (includes
             # trace+compile on a cache miss — the label says which);
@@ -1401,9 +1387,7 @@ class IterativeComQueue:
                     multihost_utils.process_allgather(x, tiled=True)),
                 stacked)
         return self._finish(stacked, bodies.nw, totals, manifest, parts,
-                            bcast, mx, None, cost=cost, exec_t0=exec_t0,
-                            prog_label=site,
-                            probes_on=bodies.probes_on)
+                            bcast, mx, None, probes_on=bodies.probes_on)
 
     @staticmethod
     def _ingest_probes(monitor, host, step):
@@ -1417,18 +1401,60 @@ class IterativeComQueue:
             monitor.ingest(series)
             monitor.evaluate()
 
-    def _finish(self, stacked, nw, totals, manifest, parts, bcast, mx,
-                ck_info, cost=None, exec_t0=None, prog_label=None,
-                probes_on=False):
-        """Shared result assembly + metrics tail for the single-program
-        and checkpoint-chunked execution paths. ``ck_info`` is the
-        recovery driver's accounting (None on the single-program path).
-        ``cost`` is the program's static XLA cost dict (tracing-only, see
-        _maybe_cost); ``exec_t0`` the dispatch start on the single-program
-        path, used for achieved-rate gauges."""
+    @staticmethod
+    def _account(steps, nw, manifest, parts, bcast, ck_info):
+        """The metrics tail of an exec: executions, supersteps and the
+        collectives the traced manifest says each superstep ran."""
         import jax
 
         from ..common.metrics import get_registry
+        reg = get_registry()
+        # a resumed run only EXECUTED the supersteps past its snapshot
+        # (and no init pass); charge collectives/supersteps for those
+        if ck_info is None:
+            executed, init_runs = steps, 1
+        else:
+            init_runs = 1 if ck_info["init_ran"] else 0
+            executed = ck_info["steps_executed"]
+        reg.inc("alink_comqueue_execs_total", 1)
+        reg.inc("alink_comqueue_supersteps_total", executed)
+        # this exec's trace signature, computed on the HOST inputs
+        # exactly as static_sig sees them inside shard_map: parts are
+        # split on the leading axis by the worker count, bcast is
+        # replicated unchanged
+        items = []
+        for k in sorted(set(parts) | set(bcast)):
+            split = nw if k in parts else 1
+            for leaf in jax.tree_util.tree_leaves(
+                    parts[k] if k in parts else bcast[k]):
+                sh = tuple(map(int, leaf.shape))
+                if split > 1 and sh:
+                    sh = (sh[0] // split,) + sh[1:]
+                items.append((k, sh, str(leaf.dtype)))
+        per = manifest.get(tuple(items))
+        if per is None and len(manifest) == 1:
+            # defensive: a host/trace signature drift should not drop
+            # attribution when only one trace exists
+            per = next(iter(manifest.values()))
+        # the init pass executed at most once (superstep 1; not at all
+        # on a resumed run); the while-loop body executed the other
+        # supersteps (the body is TRACED even for runs whose criterion
+        # stops at step 1, so it must not be charged for supersteps it
+        # never ran)
+        if per is not None:
+            from .communication import record_manifest
+            if init_runs > 0:
+                record_manifest(per["init"], times=init_runs)
+            if executed - init_runs > 0:
+                record_manifest(per["body"],
+                                times=executed - init_runs)
+
+    def _finish(self, stacked, nw, totals, manifest, parts, bcast, mx,
+                ck_info, probes_on=False):
+        """Shared result assembly + metrics tail for the single-program
+        and checkpoint-chunked execution paths. ``ck_info`` is the
+        recovery driver's accounting (None on the single-program path)."""
+        import jax
 
         # single-process: leave leaves ON DEVICE — ComQueueResult fetches
         # per access, so a fit that only reads coef + loss_curve does not
@@ -1436,76 +1462,17 @@ class IterativeComQueue:
         # margins, ...) from the device to the host
         result = ComQueueResult(stacked, nw, totals)
         if mx:
-            reg = get_registry()
             # one scalar fetch; it waits for the (asynchronously
             # dispatched) run, which the caller's first result read would
             # have done anyway. The wait is taken here, outside
-            # ``comqueue.fetch``, so that span times the transfer alone
-            jax.block_until_ready(stacked["__step"])
+            # ``comqueue.fetch``, so that span times the transfer alone,
+            # and under a coarse span of its own: how long the host
+            # waited for the chip, in every process
+            with trace_span("comqueue.wait", cat="engine", coarse=True):
+                jax.block_until_ready(stacked["__step"])
             steps = int(result.step_count)
-            # a resumed run only EXECUTED the supersteps past its snapshot
-            # (and no init pass); charge collectives/supersteps for those
-            if ck_info is None:
-                executed, init_runs = steps, 1
-            else:
-                init_runs = 1 if ck_info["init_ran"] else 0
-                executed = ck_info["steps_executed"]
-            reg.inc("alink_comqueue_execs_total", 1)
-            reg.inc("alink_comqueue_supersteps_total", executed)
-            # this exec's trace signature, computed on the HOST inputs
-            # exactly as static_sig sees them inside shard_map: parts are
-            # split on the leading axis by the worker count, bcast is
-            # replicated unchanged
-            items = []
-            for k in sorted(set(parts) | set(bcast)):
-                split = nw if k in parts else 1
-                for leaf in jax.tree_util.tree_leaves(
-                        parts[k] if k in parts else bcast[k]):
-                    sh = tuple(map(int, leaf.shape))
-                    if split > 1 and sh:
-                        sh = (sh[0] // split,) + sh[1:]
-                    items.append((k, sh, str(leaf.dtype)))
-            per = manifest.get(tuple(items))
-            if per is None and len(manifest) == 1:
-                # defensive: a host/trace signature drift should not drop
-                # attribution when only one trace exists
-                per = next(iter(manifest.values()))
-            # the init pass executed at most once (superstep 1; not at all
-            # on a resumed run); the while-loop body executed the other
-            # supersteps (the body is TRACED even for runs whose criterion
-            # stops at step 1, so it must not be charged for supersteps it
-            # never ran)
-            if per is not None:
-                from .communication import record_manifest
-                if init_runs > 0:
-                    record_manifest(per["init"], times=init_runs)
-                if executed - init_runs > 0:
-                    record_manifest(per["body"],
-                                    times=executed - init_runs)
-            if cost is not None:
-                # XLA's static cost model for this program (ALINK_TPU_TRACE
-                # runs only — _maybe_cost). The step_count fetch above
-                # flushed the run, so elapsed-since-dispatch is an honest
-                # wall-clock bound for the achieved rates; NOTE the static
-                # model costs a while-loop body ONCE, so treat achieved
-                # figures as per-program-pass, not per-superstep totals.
-                plbl = {"program": prog_label or "?"}
-                flops = cost.get("flops")
-                acc_bytes = cost.get("bytes accessed")
-                if flops is not None:
-                    reg.set_gauge("alink_program_flops", flops, plbl)
-                if acc_bytes is not None:
-                    reg.set_gauge("alink_program_bytes_accessed",
-                                  acc_bytes, plbl)
-                if exec_t0 is not None:
-                    elapsed = time.perf_counter() - exec_t0
-                    if elapsed > 0:
-                        if flops:
-                            reg.set_gauge("alink_program_achieved_flops_per_s",
-                                          flops / elapsed, plbl)
-                        if acc_bytes:
-                            reg.set_gauge("alink_program_achieved_bytes_per_s",
-                                          acc_bytes / elapsed, plbl)
+            with trace_span("comqueue.account", cat="engine", coarse=True):
+                self._account(steps, nw, manifest, parts, bcast, ck_info)
         if self._health is not None and probes_on:
             # final pass (also re-runs after a chunked run's last
             # boundary ingest — alerts are deduped by the monitor). The

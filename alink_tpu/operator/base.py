@@ -20,7 +20,7 @@ from typing import Any, Callable, List, Optional, Sequence
 import numpy as np
 
 from ..common.metrics import get_registry, metrics_enabled
-from ..common.tracing import recording, trace_span
+from ..common.tracing import trace_span
 from ..common.mlenv import MLEnvironment, MLEnvironmentFactory
 from ..common.mtable import MTable
 from ..common.params import Params, WithParams
@@ -37,21 +37,21 @@ def _meter_link_from(fn: Callable) -> Callable:
     Reentrant links on the same instance (subclass delegating to a base
     link_from) record once, at the outermost frame.
 
-    Under ``ALINK_TPU_TRACE`` (or a profiler session) the same frame
-    also opens a tracer span (``link:<Op>``): composite operators link their sub-operators inside
-    their own link_from, so the spans nest into the pipeline DAG with no
-    per-operator instrumentation."""
+    The same frame opens a coarse tracer span (``link:<Op>``, one a
+    link, in every process): composite operators link their sub-operators
+    inside their own link_from, so the spans nest into the pipeline DAG
+    with no per-operator instrumentation."""
 
     @functools.wraps(fn)
     def metered(self, *inputs, **kwargs):
         mx = metrics_enabled()
-        if (not mx and not recording()) \
-                or getattr(self, "_in_metered_link", False):
+        if getattr(self, "_in_metered_link", False):
             return fn(self, *inputs, **kwargs)
         self._in_metered_link = True
         t0 = time.perf_counter()
         try:
-            with trace_span(f"link:{type(self).__name__}", cat="batch") as sp:
+            with trace_span(f"link:{type(self).__name__}", cat="batch",
+                            coarse=True) as sp:
                 res = fn(self, *inputs, **kwargs)
                 out_t = getattr(self, "_output", None)
                 if out_t is not None:

@@ -286,9 +286,9 @@ class GbdtTrainBatchOp(BatchOperator, _TreeTrainParamsMixin):
     IS_REGRESSION = False
 
     def link_from(self, in_op: BatchOperator):
-        with trace_span("gbdt.fit", cat="gbdt") as fit:
+        with trace_span("gbdt.fit", cat="gbdt", coarse=True) as fit:
             t = in_op.get_output_table()
-            with trace_span("gbdt.extract", cat="gbdt"):
+            with trace_span("gbdt.extract", cat="gbdt", coarse=True):
                 (X, y, w, labels, fc, vc, lt, cat_mask, cat_cols,
                  vocabs) = _extract_xy(t=t, op=self,
                                        regression=self.IS_REGRESSION)
@@ -300,7 +300,7 @@ class GbdtTrainBatchOp(BatchOperator, _TreeTrainParamsMixin):
             tf, tb, tm, tv, edges, base, curve, imp = gbdt_train(
                 X, y, p, self.IS_REGRESSION, sample_weight=w,
                 cat_mask=cat_mask, info=info)
-            with trace_span("gbdt.model", cat="gbdt"):
+            with trace_span("gbdt.model", cat="gbdt", coarse=True):
                 tf, tb, tv = np.asarray(tf), np.asarray(tb), np.asarray(tv)
                 thr = np.stack([bins_to_thresholds(tf[i], tb[i], edges)
                                 for i in range(p.num_trees)])

@@ -83,7 +83,7 @@ def _design_rows(table: MTable, feature_cols, vector_col, dtype):
 
 class KMeansTrainBatchOp(BatchOperator, _KMeansParams):
     def link_from(self, in_op: BatchOperator) -> "KMeansTrainBatchOp":
-        with trace_span("kmeans.fit", cat="kmeans") as fit:
+        with trace_span("kmeans.fit", cat="kmeans", coarse=True) as fit:
             t = in_op.get_output_table()
             vector_col = self.params._m.get("vector_col")
             feature_cols = self.params._m.get("feature_cols")
@@ -97,7 +97,7 @@ class KMeansTrainBatchOp(BatchOperator, _KMeansParams):
                 X, k=self.get_k(), max_iter=self.get_max_iter(),
                 tol=self.get_epsilon(), distance_type=self.get_distance_type(),
                 init=self.get_init_mode(), seed=self.get_seed(), info=info)
-            with trace_span("kmeans.model", cat="kmeans"):
+            with trace_span("kmeans.model", cat="kmeans", coarse=True):
                 model = KMeansModelData(np.asarray(cents, np.float64),
                                         np.asarray(wts, np.float64),
                                         self.get_distance_type(), vector_col,

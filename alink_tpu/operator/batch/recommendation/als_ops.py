@@ -125,11 +125,11 @@ class AlsTrainBatchOp(BatchOperator, HasSeed):
                                         "only the solved factors")
 
     def link_from(self, in_op: BatchOperator) -> "AlsTrainBatchOp":
-        with trace_span("als.fit", cat="als") as fit:
+        with trace_span("als.fit", cat="als", coarse=True) as fit:
             t = in_op.get_output_table()
             uc, ic, rc = (self.get_user_col(), self.get_item_col(),
                           self.get_rate_col())
-            with trace_span("als.extract", cat="als"):
+            with trace_span("als.extract", cat="als", coarse=True):
                 user_ids, users, n_users = _index_column(t.col(uc))
                 item_ids, items, n_items = _index_column(t.col(ic))
                 ratings = t.col(rc)
@@ -145,7 +145,7 @@ class AlsTrainBatchOp(BatchOperator, HasSeed):
             uf, if_, curve = als_train(users, items, ratings, p,
                                        num_users=n_users, num_items=n_items,
                                        info=info)
-            with trace_span("als.model", cat="als"):
+            with trace_span("als.model", cat="als", coarse=True):
                 if isinstance(uf, np.ndarray):     # a host table's: as ever
                     uf, if_ = uf.astype(np.float64), if_.astype(np.float64)
                 model = AlsModelData(user_ids, item_ids, uf, if_, uc, ic, rc)

@@ -398,7 +398,7 @@ def kmeans_parallel_init(X, k: int, seed: int = 0,
         ctx.put_obj("d2", d2)
         ctx.put_obj("nearest", nearest)
 
-    with trace_span("kmeans.init", cat="kmeans",
+    with trace_span("kmeans.init", cat="kmeans", coarse=True,
                     args={"rows": n, "rounds": rounds, "fold": path}) as span:
         res = (IterativeComQueue(env=env_, max_iter=rounds)
                .init_with_partitioned_data("X", col.blocks)
@@ -427,7 +427,7 @@ def kmeans_parallel_init(X, k: int, seed: int = 0,
     # candidates sampled in the final round carry no counted weight yet;
     # give them each weight 1 so the recluster can still use them
     weights[weights == 0] = 1.0
-    with trace_span("kmeans.recluster", cat="kmeans",
+    with trace_span("kmeans.recluster", cat="kmeans", coarse=True,
                     args={"candidates": int(cap)}):
         return _weighted_kmeans_pp(cands, weights, k, rng).astype(dt)
 
@@ -602,7 +602,7 @@ def kmeans_train(X, k: int, max_iter: int = 50, tol: float = 1e-4,
         from ....common.health import warn_if_disabled
         warn_if_disabled("kmeans_train(health=...)", stacklevel=3)
         queue.set_health(health)
-    with trace_span("kmeans.lloyd", cat="kmeans",
+    with trace_span("kmeans.lloyd", cat="kmeans", coarse=True,
                     args={"rows": n, "k": int(k), "max_iter": int(max_iter),
                           "pass": path}):
         result = queue.exec()

@@ -389,7 +389,7 @@ def als_train(users, items, ratings, p: AlsTrainParams,
     from ....engine.comqueue import freeze_config
     names = [f"{k}_{s}" for s in "ui"
              for k in ("ids", "val", "off", "cnt", "order", "rank")]
-    with trace_span("als.group", cat="als",
+    with trace_span("als.group", cat="als", coarse=True,
                     args={"ratings": n, "users": U, "items": I,
                           "path": "sort"}):
         grouped = (IterativeComQueue(env=env, max_iter=1)
@@ -456,7 +456,7 @@ def als_train(users, items, ratings, p: AlsTrainParams,
     paths = {"group": "sort", "gram": "einsum_highest",
              "solve": "nnls" if p.nonnegative
              else solve_path(jnp.float32, r)}
-    with trace_span("als.sweep", cat="als",
+    with trace_span("als.sweep", cat="als", coarse=True,
                     args={"rank": r, "num_iter": int(T),
                           "gram": paths["gram"], "solve": paths["solve"]}):
         res = queue.exec()

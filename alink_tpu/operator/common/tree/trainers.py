@@ -129,7 +129,7 @@ def gbdt_train(X, y, p: TreeTrainParams,
     # the seed is data (the queue's "key"): the stage below closes over
     # the settings without it, so one program serves every seed
     seed, p = p.seed, dataclasses.replace(p, seed=0)
-    with trace_span("gbdt.bin", cat="gbdt",
+    with trace_span("gbdt.bin", cat="gbdt", coarse=True,
                     args={"rows": n, "bins": int(p.n_bins),
                           "path": count_path(FINE_BINS)}):
         # a blocked table's edges come from the device pass over the
@@ -149,7 +149,7 @@ def gbdt_train(X, y, p: TreeTrainParams,
     d, T = p.max_depth, p.num_trees
     queue = _grow_queue(env_, bins, yb, wb, base, seed, p, is_regression,
                         F, col.block_rows, path, cat_mask)
-    with trace_span("gbdt.grow", cat="gbdt",
+    with trace_span("gbdt.grow", cat="gbdt", coarse=True,
                     args={"trees": int(T), "depth": int(d), "hist": path,
                           "sibling": "subtract"}):
         res = queue.exec()

@@ -1002,6 +1002,14 @@ class FtrlTrainStreamOp(StreamOperator, HasVectorCol, HasFeatureCols, HasLabelCo
         return LinearModelDataConverter.load_table(table)
 
     def link_from(self, data_op: StreamOperator) -> "FtrlTrainStreamOp":
+        # the trainer's set-up on the flight recorder (coarse, once a
+        # link): ``ftrl.link`` here with the warm start's ``ftrl.warm_hash``
+        # under it; ``ftrl.state_alloc`` / ``ftrl.state_ship`` when the
+        # drain's first micro-batch fixes the state's layout
+        with trace_span("ftrl.link", cat="stream", coarse=True):
+            return self._link(data_op)
+
+    def _link(self, data_op: StreamOperator) -> "FtrlTrainStreamOp":
         env = self.get_ml_env()
         mesh = env.mesh
         n_dev = env.num_workers * env.model_parallelism
@@ -1037,9 +1045,11 @@ class FtrlTrainStreamOp(StreamOperator, HasVectorCol, HasFeatureCols, HasLabelCo
         # fingerprinted at link time (resume assumes a deterministic
         # replayed source — docs/checkpointing.md)
         import hashlib as _hashlib
-        _warm_fp = _hashlib.blake2b(
-            np.ascontiguousarray(np.asarray(init.coef)).tobytes(),
-            digest_size=12).hexdigest()
+        with trace_span("ftrl.warm_hash", cat="stream", coarse=True,
+                        args={"bytes": int(np.asarray(init.coef).nbytes)}):
+            _warm_fp = _hashlib.blake2b(
+                np.ascontiguousarray(np.asarray(init.coef)).tobytes(),
+                digest_size=12).hexdigest()
         # ONE ExecutionPlan per drain (ROADMAP item 1): hyperparameters,
         # geometry and the key-folding flags — ALINK_TPU_FTRL_KERNEL
         # (the resolved tier mode the step factories fold into their lru
@@ -1241,10 +1251,13 @@ class FtrlTrainStreamOp(StreamOperator, HasVectorCol, HasFeatureCols, HasLabelCo
             # the mesh via the partition rules (io/sharding.py) — one
             # choke point instead of per-site NamedSharding literals
             def state_put(z_arr, n_arr):
-                sh = state_sharding(mesh, ftrl_state_rules(),
-                                    {"z": z_arr, "n": n_arr})
-                return (jax.device_put(z_arr, sh["z"]),
-                        jax.device_put(n_arr, sh["n"]))
+                with trace_span("ftrl.state_ship", cat="stream", coarse=True,
+                                args={"bytes": int(z_arr.nbytes
+                                                   + n_arr.nbytes)}):
+                    sh = state_sharding(mesh, ftrl_state_rules(),
+                                        {"z": z_arr, "n": n_arr})
+                    return (jax.device_put(z_arr, sh["z"]),
+                            jax.device_put(n_arr, sh["n"]))
             scale = beta / alpha + l2   # z = -w*(beta/alpha + l2) at n=0:
             # the warm start encodes the initial weights into z
 
@@ -1254,14 +1267,18 @@ class FtrlTrainStreamOp(StreamOperator, HasVectorCol, HasFeatureCols, HasLabelCo
                                  (fb_S if has_icpt else 0))
                 else:
                     dim_state = dim_pad
-                z0 = np.zeros(dim_state)
-                coef = np.asarray(init.coef)
-                if layout == "fb" and has_icpt:
-                    z0[0] = -coef[0] * scale
-                    z0[fb_S:fb_S + dim - 1] = -coef[1:] * scale
-                else:
-                    z0[:dim] = -coef * scale
-                return state_put(z0, np.zeros(dim_state))
+                with trace_span("ftrl.state_alloc", cat="stream",
+                                coarse=True) as sp:
+                    z0 = np.zeros(dim_state)
+                    coef = np.asarray(init.coef)
+                    if layout == "fb" and has_icpt:
+                        z0[0] = -coef[0] * scale
+                        z0[fb_S:fb_S + dim - 1] = -coef[1:] * scale
+                    else:
+                        z0[:dim] = -coef * scale
+                    n0 = np.zeros(dim_state)
+                    sp.set(bytes=int(z0.nbytes + n0.nbytes))
+                return state_put(z0, n0)
 
             def fb_to_std_state(z_fb, n_fb):
                 """Exact fb -> std state translation: the fb layout is

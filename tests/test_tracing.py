@@ -2,8 +2,10 @@
 
 Covers the Tracer contract (contextvars nesting, thread lanes, the bounded
 flight recorder, instant events, Chrome/JSONL exporters), the
-ALINK_TPU_TRACE gate (including StepTimer's single-source-of-truth
-emission), compat.compiled_cost_analysis's flat-dict-or-None contract,
+ALINK_TPU_TRACE gate for the fine spans and the coarse ones that record
+in every process (ISSUE 35; including StepTimer's single-source-of-truth
+emission and JAX's own jit.* events), compat.compiled_cost_analysis's
+flat-dict-or-None contract,
 and the end-to-end acceptance path: an L-BFGS train with tracing +
 checkpointing produces a Chrome trace whose span tree nests
 exec -> chunk -> superstep-phase spans with checkpoint instant events,
@@ -327,6 +329,27 @@ class TestGate:
             set_tracer(prev)
         assert tr.events() == []
 
+    def test_a_coarse_span_records_with_the_flag_unset_a_fine_one_does_not(
+            self, quiet_tracer):
+        """The two grades, decided at the call site: neither the flag nor
+        a profiler session is on, and the coarse span and the coarse
+        retroactive event are in the ring, nested as they ran."""
+        assert not recording() and not profiler_active()
+        with trace_span("kmeans.fit", cat="kmeans", coarse=True) as fit:
+            fit.set(rows=5)
+            with trace_span("ftrl.encode"):                  # fine
+                pass
+            trace_complete("ftrl.batch", 0.001)              # fine
+            trace_instant("comqueue.program_cache")          # instants: fine
+            trace_complete("jit.compile", 0.002, cat="jit", coarse=True,
+                           args={"fun_name": "jit(f)", "cache": "miss"})
+        evs = {e["name"]: e for e in quiet_tracer.events()}
+        assert set(evs) == {"kmeans.fit", "jit.compile"}
+        assert evs["jit.compile"]["parent"] == evs["kmeans.fit"]["id"]
+        assert evs["kmeans.fit"]["args"] == {"rows": 5}
+        assert "profiled" not in evs["kmeans.fit"]
+        assert quiet_tracer.origin_unix == pytest.approx(time.time(), abs=60)
+
     @pytest.mark.parametrize("val,expect", [
         ("0", False), ("off", False), ("false", False),
         ("1", True), ("on", True)])
@@ -360,10 +383,12 @@ class TestGate:
             t = StepTimer()
             with t.span("fit"):
                 pass
+            with t.span("wait", coarse=True):    # the engine's phases
+                pass
         finally:
             set_tracer(prev)
-        assert tr.events() == []
-        assert t.report()[0][1] == 1
+        assert [e["name"] for e in tr.events()] == ["wait"]
+        assert [r[:2] for r in t.report()] == [("fit", 1), ("wait", 1)]
 
 
 # ---------------------------------------------------------------------------
@@ -524,6 +549,12 @@ class TestCostShim:
 # instrumented engine
 # ---------------------------------------------------------------------------
 
+#: an execution's coarse children, in the order they run
+PHASES = ["comqueue.prepare", "comqueue.plan", "comqueue.execute",
+          "comqueue.wait", "comqueue.slice", "comqueue.fetch",
+          "comqueue.account"]
+
+
 def _make_queue(key, max_iter=4, **ck):
     import jax.numpy as jnp
     from alink_tpu.engine.communication import AllReduce
@@ -546,42 +577,61 @@ def _make_queue(key, max_iter=4, **ck):
 
 
 class TestEngineTracing:
-    def test_exec_span_tree_and_cost_gauges(self, fresh_tracer,
-                                            fresh_registry):
+    def test_exec_span_tree(self, fresh_tracer, fresh_registry):
         key = ("test_tracing_e2e", os.urandom(6).hex())
         r = _make_queue(key=key).exec()
         assert r.step_count == 4
         evs = fresh_tracer.events()
         byname = {e["name"]: e for e in evs}
-        # exec is the root; prepare/execute (StepTimer spans) nest under it
+        # exec is the root; prepare/execute (StepTimer spans), the plan and
+        # program lookup, the wait for the run, the step count's slice and
+        # fetch and the metrics tail nest under it
         assert byname["comqueue.exec"].get("parent") is None
-        for child in ("comqueue.prepare", "comqueue.execute"):
+        for child in PHASES:
             assert byname[child]["parent"] == byname["comqueue.exec"]["id"]
         cache = byname["comqueue.program_cache"]
         assert cache["ph"] == "i" and cache["args"]["result"] == "miss"
-        # per-program cost gauges (static + achieved), labelled by the
-        # program key's leading string
-        lbl = {"program": "test_tracing_e2e"}
-        assert fresh_registry.value("alink_program_flops", lbl) > 0
-        assert fresh_registry.value("alink_program_bytes_accessed", lbl) > 0
-        assert fresh_registry.value("alink_program_achieved_flops_per_s",
-                                    lbl) > 0
-        assert fresh_registry.value("alink_program_achieved_bytes_per_s",
-                                    lbl) > 0
+        # the program was new: its trace, lowering and compile are the
+        # execute span's children (the dispatch carried them)
+        mine = [e for e in evs if e["name"].startswith("jit.")
+                and "test_tracing_e2e" in e["args"]["fun_name"]]
+        assert {e["name"] for e in mine} == {"jit.trace", "jit.lower",
+                                             "jit.compile"}
+        assert all(e["parent"] == byname["comqueue.execute"]["id"]
+                   for e in mine)
+        # the second trace and lowering that filled the cost gauges under
+        # the flag is gone: the program is lowered once
+        assert sum(e["name"] == "jit.lower" for e in mine) == 1
+        assert not [r for r in fresh_registry.snapshot()
+                    if r["name"].startswith("alink_program_")]
 
-    def test_untraced_run_skips_cost_and_events(self, monkeypatch,
-                                                fresh_registry):
-        monkeypatch.delenv("ALINK_TPU_TRACE", raising=False)
-        tr = Tracer()
-        prev = set_tracer(tr)
-        try:
-            key = ("test_tracing_off", os.urandom(6).hex())
-            _make_queue(key=key).exec()
-        finally:
-            set_tracer(prev)
-        assert tr.events() == []
-        assert fresh_registry.value("alink_program_flops",
-                                    {"program": "test_tracing_off"}) == 0
+    def test_untraced_exec_records_the_coarse_phases_and_nothing_fine(
+            self, quiet_tracer, fresh_registry):
+        """With neither the flag nor a session, an exec leaves
+        ``comqueue.exec`` > prepare, plan, execute, wait, slice, fetch,
+        account in the ring (JAX's ``jit.*`` where the program was new) and
+        nothing else: no instant, no chunk."""
+        key = ("test_tracing_off", os.urandom(6).hex())
+        _make_queue(key=key).exec()
+        evs = quiet_tracer.events()
+        root = [e for e in evs if e["name"] == "comqueue.exec"]
+        assert len(root) == 1 and root[0].get("parent") is None
+        assert root[0]["args"]["program"] == "test_tracing_off"
+        phases = [e["name"] for e in evs if e.get("parent") == root[0]["id"]]
+        assert phases == PHASES
+        assert {e["name"] for e in evs} <= {
+            "comqueue.exec", *phases, "jit.trace", "jit.lower",
+            "jit.compile"}
+        assert all(e["ph"] == "X" and "profiled" not in e for e in evs)
+        # the account closes: the phases cover the exec but for a sliver
+        covered = sum(e["dur"] for e in evs
+                      if e.get("parent") == root[0]["id"])
+        assert 0 <= root[0]["dur"] - covered < 0.25 * root[0]["dur"] + 2e3
+        quiet_tracer.clear()
+        # a warm exec fires no ``jit.*`` at all: 8 always-on events
+        _make_queue(key=key).exec()
+        assert sorted(e["name"] for e in quiet_tracer.events()) == sorted(
+            ["comqueue.exec"] + phases)
 
     def test_lowered_hlo_unchanged_by_tracing(self, monkeypatch):
         """Tracing must add NOTHING to compiled programs: the lowered
@@ -602,8 +652,8 @@ class TestEngineTracing:
         never outgrows its bound."""
         key = ("test_tracing_overhead", os.urandom(6).hex())
         runs = 5
-        # warm under tracing so compile AND the one-off cost lowering are
-        # paid outside the measured window
+        # warm under tracing so the compile is paid outside the measured
+        # window
         monkeypatch.setenv("ALINK_TPU_TRACE", "1")
         tr = Tracer(capacity=16)
         prev = set_tracer(tr)
@@ -631,6 +681,171 @@ class TestEngineTracing:
         # wanted to land in a 16-slot buffer
         assert len(tr.events()) <= 16
         assert tr.dropped > 0
+
+
+# ---------------------------------------------------------------------------
+# the flight recorder with every switch off (ISSUE 35): the session, JAX's
+# compile events, a fit of each batch trainer
+# ---------------------------------------------------------------------------
+
+FINE = ("ftrl.encode", "ftrl.ship", "ftrl.dispatch", "ftrl.batch",
+        "prefetch.", "serve.", "comqueue.chunk", "comqueue.program_cache")
+
+
+def _fit_kmeans():
+    from alink_tpu.operator.batch.clustering.kmeans_ops import (
+        KMeansTrainBatchOp)
+    from alink_tpu.operator.batch.source import MemSourceBatchOp
+    r = np.random.RandomState(5)
+    rows = [tuple(p) for p in r.randn(96, 2) + 4.0 * r.randint(0, 3, (96, 1))]
+    KMeansTrainBatchOp(k=3, feature_cols=["x", "y"], max_iter=4).link_from(
+        MemSourceBatchOp(rows, "x DOUBLE, y DOUBLE"))
+
+
+def _fit_gbdt():
+    from alink_tpu.operator.batch.classification.tree_ops import (
+        GbdtTrainBatchOp)
+    from alink_tpu.operator.batch.source import MemSourceBatchOp
+    r = np.random.RandomState(6)
+    X = r.randn(128, 3)
+    rows = [(*x, int(x[0] + x[1] > 0)) for x in X]
+    GbdtTrainBatchOp(feature_cols=["a", "b", "c"], label_col="label",
+                     num_trees=2, max_depth=2).link_from(
+        MemSourceBatchOp(rows, "a DOUBLE, b DOUBLE, c DOUBLE, label LONG"))
+
+
+def _fit_als():
+    from alink_tpu.operator.batch.recommendation.als_ops import (
+        AlsTrainBatchOp)
+    from alink_tpu.operator.batch.source import MemSourceBatchOp
+    r = np.random.RandomState(7)
+    rows = [(int(u), int(i), float(r.rand())) for u in range(12)
+            for i in range(9) if r.rand() < 0.6]
+    AlsTrainBatchOp(user_col="user", item_col="item", rate_col="rating",
+                    rank=3, num_iter=2, lambda_=0.1).link_from(
+        MemSourceBatchOp(rows, "user LONG, item LONG, rating DOUBLE"))
+
+
+class TestFlightRecorder:
+    def test_session_start_is_in_the_ring_and_counts_the_sessions(
+            self, quiet_tracer):
+        from alink_tpu.common.mlenv import MLEnvironment
+        a, b = MLEnvironment(parallelism=2), MLEnvironment()
+        evs = quiet_tracer.events()
+        assert [e["name"] for e in evs] == ["session.start"] * 2
+        first, second = (e["args"] for e in evs)
+        # the process's first session (``session`` 0: the end of its boot)
+        # was the test session's own, long before this ring
+        assert 0 < first["session"] == second["session"] - 1
+        assert first["devices"] == 2 and second["devices"] == 8
+        assert first["platform"] == second["platform"] == "cpu"
+        assert a.num_workers == 2 and b.num_workers == 8
+
+    def test_jit_events_are_children_of_the_open_span_and_carry_cache(
+            self, quiet_tracer, fresh_registry):
+        import jax
+        import jax.numpy as jnp
+
+        def flight_recorder_probe(x):
+            return jnp.tanh(x) * 3.0
+
+        f = jax.jit(flight_recorder_probe)
+        with trace_span("probe.fit", coarse=True):
+            f(jnp.ones(7))
+        evs = quiet_tracer.events()
+        fit = next(e for e in evs if e["name"] == "probe.fit")
+        mine = [e for e in evs if e["name"].startswith("jit.")
+                and "flight_recorder_probe" in e["args"]["fun_name"]]
+        assert [e["name"] for e in mine] == ["jit.trace", "jit.lower",
+                                             "jit.compile"]
+        jits = [e for e in evs if e["name"].startswith("jit.")]
+        assert all(e["parent"] == fit["id"] and e["cat"] == "jit"
+                   and e["tid"] == fit["tid"] for e in jits)
+        # retroactive: each lies inside the span that was open, in order
+        lo, hi = fit["ts"], fit["ts"] + fit["dur"]
+        assert all(lo <= e["ts"] and e["ts"] + e["dur"] <= hi for e in jits)
+        assert mine[0]["ts"] < mine[1]["ts"] < mine[2]["ts"]
+        # the test process keeps the persistent cache off
+        assert mine[2]["args"]["cache"] == "off"
+        assert "cache" not in mine[0]["args"]
+        assert fresh_registry.value("alink_jit_compiles_total",
+                                    {"cache": "off"}) >= 1
+        # a warm call fires none of the three
+        quiet_tracer.clear()
+        with trace_span("probe.fit", coarse=True):
+            f(jnp.ones(7))
+        assert [e["name"] for e in quiet_tracer.events()] == ["probe.fit"]
+
+    def test_the_caches_verdict_rides_the_next_compile_of_its_thread(
+            self, quiet_tracer, fresh_registry):
+        """The listener, driven by hand with JAX's own event names: a hit
+        or a miss belongs to the compile that follows it on the same
+        thread, and to no other."""
+        from alink_tpu.common import mlenv
+        compile_ = "/jax/core/compile/backend_compile_duration"
+        mlenv._on_jax_event("/jax/compilation_cache/cache_hits")
+        other = threading.Thread(target=mlenv._on_jax_duration, args=(
+            compile_, 0.25), kwargs={"fun_name": "jit(elsewhere)"})
+        other.start()
+        other.join(timeout=10)
+        mlenv._on_jax_event(
+            "/jax/compilation_cache/compile_requests_use_cache")
+        mlenv._on_jax_duration(compile_, 0.5, fun_name="jit(loaded)")
+        mlenv._on_jax_duration(compile_, 0.5, fun_name="jit(uncached)")
+        mlenv._on_jax_event("/jax/compilation_cache/cache_misses")
+        mlenv._on_jax_duration("/jax/core/compile/jaxpr_trace_duration", 0.1,
+                               fun_name="f")
+        mlenv._on_jax_duration(compile_, 2.0, fun_name="jit(f)")
+        mlenv._on_jax_duration("/jax/some/other_duration", 1.0)
+        got = [(e["name"], e["args"].get("fun_name"), e["args"].get("cache"),
+                round(e["dur"] / 1e6, 3)) for e in quiet_tracer.events()]
+        assert sorted(got) == sorted([
+            ("jit.compile", "jit(elsewhere)", "off", 0.25),
+            ("jit.compile", "jit(loaded)", "hit", 0.5),
+            ("jit.compile", "jit(uncached)", "off", 0.5),
+            ("jit.trace", "f", None, 0.1),
+            ("jit.compile", "jit(f)", "miss", 2.0)])
+        for cache, n in (("hit", 1), ("miss", 1), ("off", 2)):
+            assert fresh_registry.value("alink_jit_compiles_total",
+                                        {"cache": cache}) == n
+
+    @pytest.mark.parametrize("fit,root,execs", [
+        # (a small host table's cut points are numpy's: no third program)
+        (_fit_kmeans, "kmeans.fit", 2), (_fit_gbdt, "gbdt.fit", 2),
+        (_fit_als, "als.fit", 2)], ids=["kmeans", "gbdt", "als"])
+    def test_a_warm_fit_leaves_its_coarse_tree_and_at_most_40_events(
+            self, quiet_tracer, fit, root, execs):
+        """The budget of the always-on grade: a fit with every switch off
+        leaves its ``*.fit`` tree with the engine's phases, ``jit.*`` only
+        while its programs are new, nothing fine, at most 40 events."""
+        fit()
+        cold = quiet_tracer.events()
+        assert {"jit.trace", "jit.lower", "jit.compile"} <= {
+            e["name"] for e in cold}
+        assert all(e["args"]["fun_name"] for e in cold
+                   if e["name"].startswith("jit."))
+        assert all(e["args"]["cache"] in ("hit", "miss", "off") for e in cold
+                   if e["name"] == "jit.compile")
+        quiet_tracer.clear()
+        fit()
+        evs = quiet_tracer.events()
+        assert quiet_tracer.dropped == 0
+        assert len(evs) <= 40, sorted(e["name"] for e in evs)
+        assert not [e["name"] for e in evs if e["name"].startswith("jit.")]
+        assert not [e["name"] for e in evs if e["name"].startswith(FINE)]
+        assert all(e["ph"] == "X" for e in evs)
+        (top,) = [e for e in evs if e["name"] == root]
+        assert _chain(evs, top)[1].startswith("link:")
+        runs = [e for e in evs if e["name"] == "comqueue.exec"]
+        assert len(runs) == execs
+        for run in runs:
+            assert root in _chain(evs, run)
+            assert [e["name"] for e in evs
+                    if e.get("parent") == run["id"]] == PHASES
+        # every wait is the engine's own, inside an execution
+        waits = [e for e in evs if e["name"] == "comqueue.wait"]
+        assert len(waits) == execs
+        assert all(_chain(evs, w)[1] == "comqueue.exec" for w in waits)
 
 
 # ---------------------------------------------------------------------------
@@ -683,13 +898,6 @@ class TestLbfgsTraceAcceptance:
         out = capsys.readouterr().out
         assert "comqueue.chunk" in out and "checkpoint.save" in out
         assert "Critical path" in out
-
-        # cost analysis attached to the cached chunk program ("qn" is the
-        # optimizer's program-key prefix)
-        assert fresh_registry.value("alink_program_flops",
-                                    {"program": "qn"}) > 0
-        assert fresh_registry.value("alink_program_bytes_accessed",
-                                    {"program": "qn"}) > 0
 
     def test_fault_injection_marker_lands_in_trace(self, fresh_tracer,
                                                    monkeypatch):
