@@ -261,48 +261,113 @@ def _kmpp_fold(Xs, Ws, d2, nearest, new, off, path: str):
         return jax.lax.fori_loop(0, Xs.shape[0], body, (d2, nearest))
 
 
+#: what the draw's maxima pass may hold at once, should the compiler write a
+#: group's noise out before it reduces it (four arrays a group: bits, noise,
+#: keys, and one to spare). A larger group buys nothing on the device once a
+#: launch is a hundredth of its work, and XLA:TPU compiles the fused pass
+#: slower the larger it is (0.9 s at 1 block of 65,536 rows, 1.9 at 64, 3.3
+#: at 512, 5.3 at 1,526: a described v5e, PR 36)
+_DRAW_TEMP_BYTES = 64 << 20
+
+
+def _blocks_per_group(nbl: int, block_bytes: int) -> int:
+    """Blocks the maxima pass keys at once: the whole shard where four
+    arrays of its size stay under ``_DRAW_TEMP_BYTES``, else the most
+    that do."""
+    return max(1, min(nbl, _DRAW_TEMP_BYTES // (4 * block_bytes)))
+
+
+def _topl_of_selected(keys_at, maxima, l: int):
+    """A shard's best ``l`` keys from its block ``maxima`` ``(nbl,)`` and
+    ``keys_at(b)``, block ``b``'s keys: the ``min(l, nbl)`` blocks of the
+    largest maxima (``lax.top_k``: equal maxima to the lower block), folded
+    in rising block order through ``_topl_fold``. That is ``lax.top_k`` over
+    all the shard's keys, ties to the lower row: the selected blocks hold
+    at least ``l`` keys >= tau, the smallest selected maximum; a key of any
+    other block is <= its block's maximum <= tau, so it could enter only by
+    EQUALLING tau, and of equal maxima the earlier block is the one
+    selected, as of equal keys the earlier row is the one kept. Returns
+    ``(run, selected blocks that hold a finite key)``."""
+    m = min(l, maxima.shape[0])
+    top, sel = jax.lax.top_k(maxima, m)
+    sel = jnp.sort(sel).astype(jnp.int32)
+    none = jnp.zeros((l,), jnp.int32)
+
+    def body(j, run):
+        b = sel[j]
+        return _topl_fold(run, keys_at(b), b)[0]
+
+    run = jax.lax.fori_loop(
+        0, m, body, (jnp.full((l,), -jnp.inf, maxima.dtype), none, none))
+    return run, (top > -jnp.inf).sum(dtype=jnp.int32)
+
+
 # jitted: the init pass and the loop body of the program call it on the same
-# shapes, so it is traced and lowered once (0.8 s of a process's first fit)
+# shapes, so it is traced once (0.3 s of a process's first fit; the program
+# still lowers it once a call site)
 @functools.partial(jax.jit, static_argnames=("cap", "l"))
 def _kmpp_draw(Ws, d2, nearest, key, block0, last, cap: int, l: int):
-    """One k-means|| round's draw over a worker's shard, block by block,
-    from the folded state: the shard's ``l`` proposals by Gumbel-top-l
-    over p ∝ d2 — a running best ``l`` carried across the blocks
-    (``_topl_fold``), so a block is ranked only when one of its keys can
-    still win — the rows seen and, in the ``last`` round, the row weights
-    summed under each of the ``cap`` candidates. Returns ``(proposals
-    (keys (l,), block (l,), position (l,)), blocks ranked, rows seen,
-    candidate weights (cap,))``."""
+    """One k-means|| round's draw over a worker's shard, from the folded
+    state: the shard's ``l`` proposals by Gumbel-top-l over p ∝ d2. ONE
+    pass keys every block (noise keyed by ``block0 +`` its number: one
+    ``fold_in`` for the whole shard, since lowering a threefry costs a
+    process's first fit a tenth of a second a call site) and keeps only
+    the blocks' maxima; the ``min(l, blocks)`` blocks of the largest
+    maxima are keyed again and folded into a running best ``l``
+    (``_topl_of_selected`` has why no other block can hold a winner). Also
+    the rows seen and, in the ``last`` round alone, the row weights summed
+    under each of the ``cap`` candidates, block by block. Returns
+    ``(proposals (keys (l,), block (l,), position (l,)), blocks ranked,
+    rows seen, candidate weights (cap,))``; ``blocks ranked`` are the
+    selected blocks that held a finite key, at most ``l``."""
     dt = d2.dtype
+    nbl = Ws.shape[0]
+
+    # the blocks' noise keys, by GLOBAL block number
+    noise = jax.vmap(lambda i: jax.random.fold_in(key, block0 + i))(
+        jnp.arange(nbl, dtype=jnp.int32))
+
+    def keys_of(d2b, k):
+        # this round's draw: Gumbel-top-l over p_i ∝ d2_i
+        g = jax.random.gumbel(k, d2b.shape, dt)
+        return jnp.where(d2b > 0,
+                         jnp.log(jnp.maximum(d2b, 1e-30)) + g, -jnp.inf)
+
+    group = _blocks_per_group(
+        nbl, int(np.prod(d2.shape[1:])) * dt.itemsize)
+
+    def group_maxima(j, maxima):
+        # the last group starts early enough to be whole: the blocks it
+        # shares with the one before read the same maxima again
+        lo = jnp.minimum(j * group, nbl - group)
+        mx = jax.vmap(lambda d2b, k: jnp.max(keys_of(d2b, k)))(
+            jax.lax.dynamic_slice_in_dim(d2, lo, group, 0),
+            jax.lax.dynamic_slice_in_dim(noise, lo, group, 0))
+        return jax.lax.dynamic_update_slice_in_dim(maxima, mx, lo, 0)
+
+    with jax.named_scope("kmpp_sample"):
+        maxima = jax.lax.fori_loop(0, -(-nbl // group), group_maxima,
+                                   jnp.full((nbl,), -jnp.inf, dt))
+        rows = (Ws != 0).sum(dtype=jnp.int32)
+    with jax.named_scope("kmpp_topk"):
+        run, ranked = _topl_of_selected(
+            lambda b: keys_of(_block_at(d2, b), _block_at(noise, b)),
+            maxima, l)
+
+    # candidate weights under the current nearest, the last round
     ids = jnp.arange(cap, dtype=jnp.int32)[:, None, None]
 
-    def body(i, c):
-        run, ranked, rows, acc, comp = c
-        wb, d2b = _block_at(Ws, i), _block_at(d2, i)
-        with jax.named_scope("kmpp_sample"):
-            # this round's draw: Gumbel-top-l over p_i ∝ d2_i
-            g = jax.random.gumbel(
-                jax.random.fold_in(key, block0 + i), d2b.shape, dt)
-            keys = jnp.where(d2b > 0,
-                             jnp.log(jnp.maximum(d2b, 1e-30)) + g, -jnp.inf)
-        with jax.named_scope("kmpp_topk"):
-            run, won = _topl_fold(run, keys, i)
-        # candidate weights under the current nearest, the last round
-        cnt = jax.lax.cond(
-            last,
-            lambda: jnp.where(_block_at(nearest, i)[None] == ids,
-                              wb[None], 0).sum((1, 2)),
-            lambda: jnp.zeros((cap,), dt))
-        acc, comp = _kahan_add(acc, comp, cnt)
-        return (run, ranked + won.astype(jnp.int32),
-                rows + (wb != 0).sum(dtype=jnp.int32), acc, comp)
+    def count(i, c):
+        cnt = jnp.where(_block_at(nearest, i)[None] == ids,
+                        _block_at(Ws, i)[None], 0).sum((1, 2))
+        return _kahan_add(*c, cnt)
 
     zero = jnp.zeros((cap,), dt)
-    none = jnp.zeros((l,), jnp.int32)
-    run, ranked, rows, counts, _ = jax.lax.fori_loop(
-        0, Ws.shape[0], body,
-        ((jnp.full((l,), -jnp.inf, dt), none, none),
-         jnp.asarray(0, jnp.int32), jnp.asarray(0, jnp.int32), zero, zero))
+    with jax.named_scope("kmpp_weights"):
+        counts = jax.lax.cond(
+            last,
+            lambda: jax.lax.fori_loop(0, nbl, count, (zero, zero))[0],
+            lambda: zero)
     return run, ranked, rows, counts
 
 
@@ -318,24 +383,28 @@ def kmeans_parallel_init(X, k: int, seed: int = 0,
     Each superstep samples ``l = oversample`` new candidates with
     probability proportional to the current squared distance to the
     candidate set (the exactly-l Gumbel-top-l variant of the per-point
-    Bernoulli draw). A worker keeps the best ``l`` keys of its shard as it
-    walks the blocks and ranks a block only when one of its keys is
-    STRICTLY greater than the smallest kept (``_topl_fold``; equal keys go
-    to the lower row, as a ``top_k`` over the whole shard gives them);
-    then an ``all_gather`` and a global ``top_k``. The per-row d2/nearest
-    state updates block by block against only the l new candidates, so
-    the work is O(rounds * n * l * d / workers). The Gumbel noise of a
-    block is keyed by (seed, round, GLOBAL block index), so any worker
-    count draws the same candidates. Candidate weights (summed row
-    weights under the nearest candidate) are counted in the last round;
-    the final weighted recluster to k runs on the O(rounds*l) candidate
-    set on the host. ``info``, when given, receives the candidate set,
-    its weights, the rows each round counted and the blocks each round
-    ranked (``init_blocks_ranked``, also the counter
-    ``alink_kmeans_init_blocks_ranked_total``: on rows in no particular
-    order about ``l * (1 + ln(blocks / l))`` a worker a round; near the
-    blocks walked, the table is ordered by distance and every block is
-    ranked).
+    Bernoulli draw). A worker keys its whole shard in one pass that keeps
+    only the blocks' largest keys, keys the ``l`` blocks of the largest
+    maxima again and folds them into its best ``l`` (``_kmpp_draw``: a key
+    of any other block is at most its block's maximum, which is at most
+    the smallest selected one, and of equal maxima the earlier block is
+    selected as of equal keys the lower row is kept, so the result is a
+    ``top_k`` over the whole shard); then an ``all_gather`` and a global
+    ``top_k``. The per-row d2/nearest state updates block by block against
+    only the l new candidates, so the work is O(rounds * n * l * d /
+    workers). The Gumbel noise of a block is keyed by (seed, round, GLOBAL
+    block index), so any worker count draws the same candidates. Candidate
+    weights (summed row weights under the nearest candidate) are counted
+    in the last round; the final weighted recluster to k runs on the
+    O(rounds*l) candidate set on the host. ``info``, when given, receives
+    the candidate set, its weights, the rows each round counted and the
+    blocks each round ranked (``init_blocks_ranked``, also the counter
+    ``alink_kmeans_init_blocks_ranked_total`` and the span argument
+    ``blocks_ranked``): the selected blocks that held a finite key, at
+    most ``l`` a worker a round whatever the table's order. (Until PR 36
+    it read the blocks whose keys beat a running threshold, near the
+    blocks walked on a table ordered by distance: a cost the draw no
+    longer has.)
     """
     env_ = env or MLEnvironmentFactory.get_default()
     nw = env_.num_workers

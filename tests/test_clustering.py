@@ -195,6 +195,166 @@ def test_running_topl_is_top_k_of_the_shard(case):
         assert not any(ranked)
 
 
+def _selection_cases():
+    """Layouts that stress WHICH blocks the draw keys a second time: the
+    ``l`` of the largest maxima, equal maxima to the lower block."""
+    rng = np.random.RandomState(11)
+    inf = np.inf
+    plain = rng.randn(9, 4, 8).astype(np.float32)
+    # tau = 2.0 is the maximum of blocks 1 (selected) and 6 (not), l = 3
+    tied = np.minimum(plain, 1.0)
+    tied[[1, 6], [2, 0], [3, 4]] = 2.0
+    tied[[3, 7], [1, 1], [0, 0]] = [5.0, 4.0]
+    # block 2 holds tau = 2.0 and is NOT selected: blocks 0 and 1 hold it
+    # too, earlier, and blocks 5 and 8 beat it (l = 4)
+    early = np.minimum(plain, 1.0)
+    early[[0, 1, 2], [0, 3, 1], [5, 5, 5]] = 2.0
+    early[[5, 8], [2, 2], [1, 1]] = [3.0, 6.0]
+    # tau held three times by the selected block, once by a later one
+    runs = np.minimum(plain, 1.0)
+    runs[4, 1, 2:5] = 2.0
+    runs[7, 0, 0] = 2.0
+    runs[0, 0, 0] = 3.0
+    # a selected block none of whose keys is kept: block 0 alone fills
+    # the best 5, block 3's maximum is the second largest
+    shadow = np.minimum(plain, 0.0)
+    shadow[0, 0, :5] = [9.0, 8.0, 7.0, 6.0, 5.0]
+    shadow[3, 2, 2] = 4.0
+    few = np.full((9, 4, 8), -inf, np.float32)     # 3 finite keys, l = 6,
+    few[[2, 2, 7], [0, 3, 1], [1, 1, 6]] = [1.0, 1.0, -3.0]  # in two blocks
+    none = np.full((9, 4, 8), -inf, np.float32)
+    last = np.sort(rng.randn(9 * 32).astype(np.float32)).reshape(9, 4, 8)
+    last[:8] = np.minimum(last[:8], last[8].min() - 1)
+    tops = plain.copy()                # +inf maxima, tied over three blocks
+    tops[[1, 4, 6], [0, 1, 2], [0, 1, 2]] = inf
+    return {"equal_maxima_selected_and_not": (tied, 3),
+            "tau_in_an_earlier_unselected_block": (early, 4),
+            "tau_inside_and_after_a_selected_block": (runs, 2),
+            "selected_block_keeps_nothing": (shadow, 5),
+            "few_finite": (few, 6), "none_finite": (none, 4),
+            "fewer_blocks_than_l": (plain[:3], 7), "l_is_one": (tied, 1),
+            "l_is_one_tied_maxima": (tops, 1),
+            "winners_in_last_block": (last, 7),
+            "infinite_maxima_tied": (tops, 2)}
+
+
+def _draw_cases():
+    return {**{"select:" + k: v for k, v in _selection_cases().items()},
+            **{"fold:" + k: v for k, v in _topl_cases().items()}}
+
+
+def _assert_top_k_of_shard(run, keys, l):
+    """``run`` (values, block, position) is ``lax.top_k`` over the blocks'
+    ``keys`` flattened, equal keys to the lower row; block and position of
+    every finite one. Returns the blocks of the finite ones."""
+    import jax
+    import jax.numpy as jnp
+
+    nb, per = keys.shape[0], int(np.prod(keys.shape[1:]))
+    flat = np.concatenate([keys.reshape(-1),
+                           np.full(max(l - keys.size, 0), -np.inf, keys.dtype)])
+    wv, wi = (np.asarray(a) for a in jax.lax.top_k(jnp.asarray(flat), l))
+    vals, blk, pos = (np.asarray(a) for a in run)
+    assert vals.dtype == keys.dtype and blk.dtype == pos.dtype == np.int32
+    assert np.array_equal(vals, wv)
+    held = wv > -np.inf
+    assert np.array_equal(blk[held], wi[held] // per)
+    assert np.array_equal(pos[held], wi[held] % per)
+    assert ((blk >= 0) & (blk < nb) & (pos >= 0) & (pos < per)).all()
+    return blk[held]
+
+
+@pytest.mark.parametrize("case", sorted(_draw_cases()))
+def test_selected_blocks_draw_is_top_k_of_the_shard(case):
+    """The draw as ``_kmpp_draw`` makes it, from the block maxima alone:
+    ``_topl_of_selected`` over the ``min(l, blocks)`` blocks of the largest
+    maxima gives what ``lax.top_k`` over all the shard's keys flattened
+    gives (values, and the block and position of every finite one, equal
+    keys to the lower row), whatever the other blocks hold; and counts the
+    selected blocks that hold a finite key."""
+    import jax
+    import jax.numpy as jnp
+    from alink_tpu.operator.common.clustering.kmeans import _topl_of_selected
+
+    keys, l = _draw_cases()[case]
+    nb = keys.shape[0]
+    maxima = keys.reshape(nb, -1).max(1)
+    K = jnp.asarray(keys)
+    run, ranked = jax.jit(lambda M: _topl_of_selected(
+        lambda b: jax.lax.dynamic_index_in_dim(K, b, 0, keepdims=False),
+        M, l))(jnp.asarray(maxima))
+    blk = _assert_top_k_of_shard(run, keys, l)
+    selected = np.argsort(-maxima, kind="stable")[:min(l, nb)]
+    assert int(ranked) == (maxima[selected] > -np.inf).sum() <= l
+    assert set(blk) <= set(selected)
+    if case == "select:equal_maxima_selected_and_not":
+        assert 1 in selected and 6 not in selected and 1 in blk
+    if case == "select:tau_in_an_earlier_unselected_block":
+        assert 2 not in selected and {5, 8} <= set(selected)
+    if case == "select:selected_block_keeps_nothing":
+        assert 3 in selected and (blk == 0).all()
+    if case == "select:winners_in_last_block":
+        assert (blk == nb - 1).all()
+    if case == "select:none_finite":
+        assert int(ranked) == 0 and blk.size == 0
+
+
+@pytest.mark.parametrize("nbl, block_bytes, want", [
+    (1526, 512 * 128 * 4, 64),         # kmeans-fit's shard: 24 groups
+    (382, 512 * 128 * 4, 64),          # a worker of four: 6
+    (23, 8 * 128 * 4, 23), (4, 1 << 30, 1), (1, 4096, 1)])
+def test_blocks_per_group_follows_the_shard(nbl, block_bytes, want):
+    """The maxima pass keys as many blocks at once as leave four arrays of
+    the group's size under 64 MB: computed from the shard's shape."""
+    from alink_tpu.operator.common.clustering import kmeans as K
+
+    got = K._blocks_per_group(nbl, block_bytes)
+    assert got == want and 1 <= got <= nbl
+    assert 4 * got * block_bytes <= K._DRAW_TEMP_BYTES or got == 1
+
+
+@pytest.mark.parametrize("last", [False, True], ids=["round", "last_round"])
+@pytest.mark.parametrize("group", [1, 2, 3, 7])
+def test_kmpp_draw_is_the_shard_s_top_k_by_any_grouping(monkeypatch, group,
+                                                        last):
+    """``_kmpp_draw`` on 7 blocks keyed 1, 2, 3 (the last group overlaps
+    the one before) or all 7 at once: the proposals are ``lax.top_k`` over
+    the shard's keys, each block keyed by its GLOBAL number; the rows seen
+    and, in the last round alone, the candidate weights are the host's
+    sums (whole-number weights: exact)."""
+    import jax
+    import jax.numpy as jnp
+    from alink_tpu.operator.common.clustering import kmeans as K
+
+    nbl, S, cap, l, block0 = 7, 8, 9, 5, 21
+    monkeypatch.setattr(K, "_DRAW_TEMP_BYTES", 4 * group * S * 128 * 4)
+    assert K._blocks_per_group(nbl, S * 128 * 4) == group
+    rng = np.random.RandomState(group)
+    W = rng.choice([1.0, 2.0], (nbl, S, 128)).astype(np.float32)
+    W[-1, S // 2:] = 0                              # the ragged tail
+    d2 = (rng.rand(nbl, S, 128) * 3).astype(np.float32) * (W != 0)
+    d2[2, 0, :9] = 0                                # rows ON a candidate
+    near = rng.randint(0, cap, (nbl, S, 128)).astype(np.int32)
+    key = jax.random.fold_in(jax.random.PRNGKey(group), 3)
+    # a function of its own a case: traced anew, with this case's grouping
+    traced = []
+
+    def draw(*a):
+        traced.append(K._blocks_per_group(nbl, S * 128 * 4))
+        return K._kmpp_draw.__wrapped__(*a, cap=cap, l=l)
+    run, ranked, rows, counts = jax.jit(draw)(W, d2, near, key, block0, last)
+    assert traced == [group]
+
+    keys = np.stack([np.asarray(jnp.where(
+        d2[i] > 0, jnp.log(jnp.maximum(d2[i], 1e-30)) + jax.random.gumbel(
+            jax.random.fold_in(key, block0 + i), d2[i].shape, jnp.float32),
+        -jnp.inf)) for i in range(nbl)])
+    _assert_top_k_of_shard(run, keys, l)
+    assert int(ranked) == l and int(rows) == (W != 0).sum()
+    want = [W[near == j].sum() for j in range(cap)] if last else np.zeros(cap)
+    assert np.array_equal(np.asarray(counts), np.asarray(want, np.float32))
+
+
 def _kmpp_table(seed, blocks=5, ragged=700, d=5):
     from alink_tpu.common.columnar import DenseBlockColumn
     rng = np.random.RandomState(100 + seed)
@@ -231,8 +391,9 @@ def test_kmeans_parallel_init_bitwise_the_per_block_top_k(seed, nw):
 
 def _host_blocks_ranked(col, cands, seed, l, rounds, nw):
     """Blocks a k-means|| run ranks, counted on the host from the same
-    keys: per round and worker, the blocks whose largest key beats the
-    smallest of the worker's running best ``l``."""
+    keys: per round and worker, the blocks among the worker's ``l``
+    largest block maxima that hold a finite key (a block of padding, or
+    one whose every row lies on a candidate, holds none)."""
     import jax
     import jax.numpy as jnp
     from alink_tpu.common.columnar import block_weights
@@ -247,18 +408,16 @@ def _host_blocks_ranked(col, cands, seed, l, rounds, nw):
         C = jnp.asarray(cands[:1 + (r - 1) * l])
         count = 0
         for task in range(nw):
-            best = np.full(l, -np.inf, np.float32)
+            maxima = np.full(nbl, -np.inf, np.float32)    # padding blocks
             for b in range(task * nbl, min((task + 1) * nbl, blocks.shape[0])):
                 d2 = jnp.where(w[b] != 0, jnp.min(
                     K.block_distances(jnp.asarray(blocks[b]), C), 0), 0)
                 g = jax.random.gumbel(jax.random.fold_in(key, b), d2.shape,
                                       d2.dtype)
-                keys = np.asarray(jnp.where(
-                    d2 > 0, jnp.log(jnp.maximum(d2, 1e-30)) + g, -jnp.inf))
-                if keys.max() > best[-1]:
-                    count += 1
-                    best = np.sort(np.concatenate(
-                        [best, keys.reshape(-1)]))[::-1][:l]
+                maxima[b - task * nbl] = float(jnp.max(jnp.where(
+                    d2 > 0, jnp.log(jnp.maximum(d2, 1e-30)) + g, -jnp.inf)))
+            selected = np.argsort(-maxima, kind="stable")[:min(l, nbl)]
+            count += int((maxima[selected] > -np.inf).sum())
         out.append(count)
     return np.asarray(out)
 
@@ -267,8 +426,9 @@ def _host_blocks_ranked(col, cands, seed, l, rounds, nw):
 @pytest.mark.parametrize("seed", [0, 5])
 def test_init_blocks_ranked_is_the_host_count(seed, nw):
     """``info["init_blocks_ranked"]`` (and the registry counter) is exactly
-    the number of blocks one of whose keys could still win, and well under
-    the blocks walked."""
+    the number of blocks the draw keyed a second time and folded: a
+    worker's blocks among its ``l`` largest maxima that hold a finite key,
+    so at most ``l`` a worker a round whatever the table's order."""
     from alink_tpu.common.metrics import (MetricsRegistry, get_registry,
                                           set_registry)
     from alink_tpu.common.mlenv import MLEnvironment
@@ -277,6 +437,7 @@ def test_init_blocks_ranked_is_the_host_count(seed, nw):
 
     col = _kmpp_table(seed, blocks=23, ragged=300)
     k, rounds = 2, 5
+    l = 2 * k
     info = {}
     prev = set_registry(MetricsRegistry())
     try:
@@ -287,14 +448,12 @@ def test_init_blocks_ranked_is_the_host_count(seed, nw):
         set_registry(prev)
     ranked = info["init_blocks_ranked"]
     assert ranked.shape == (rounds,) and ranked.dtype == np.int32
-    want = _host_blocks_ranked(col, info["init_candidates"], seed, 2 * k,
+    want = _host_blocks_ranked(col, info["init_candidates"], seed, l,
                                rounds, nw)
     assert np.array_equal(ranked, want)
     assert counted == ranked.sum()
-    # each worker ranks its first block that holds a row; few after it
-    assert (ranked >= nw).all() and ranked.sum() < rounds * 23
-    if nw == 1:
-        assert ranked.sum() < 0.6 * rounds * 23
+    # every worker holds more than l blocks with a row: l a worker a round
+    assert (ranked == nw * l).all() and ranked.sum() < rounds * 23
 
 
 def _ulps(a, b):

@@ -34,6 +34,27 @@ def _kernel_calls(text):
             if 'custom_call_target="tpu_custom_call"' in ln]
 
 
+def _only_the_fold_yields_a_shard(text, call, shape):
+    """The one array of ``shape`` (a float per row of the shard) that the
+    round makes outside a fused computation is the fold kernel's ``d2``,
+    seen through a bitcast of the tuple element of ``call``: the draw's
+    keys are never held. Parameters and tuple elements only name an array."""
+    made, fused = [], False
+    for ln in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(", ln)
+        if head:
+            fused = "fused_computation" in head.group(1)
+        got = re.search(rf"= {re.escape(shape)}\S* ([\w-]+)\(%?([\w.-]*)", ln)
+        if got and not fused and got.group(1) not in ("parameter",
+                                                      "get-tuple-element"):
+            made.append(got.groups())
+    (op, src), = made
+    kernel = re.match(r"\s*%(\S+) = ", call).group(1)
+    assert op == "bitcast"
+    assert re.search(rf"%{re.escape(src)} = \S+ get-tuple-element\("
+                     rf"%{re.escape(kernel)}\)", text)
+
+
 @pytest.mark.parametrize("l", [20, 1], ids=["later_round", "first_round"])
 def test_kmpp_round_compiles_to_one_streamed_kernel(monkeypatch, one_chip, l):
     """A k-means|| round of ``kmeans-fit`` (1,526 blocks of 20 × 512 ×
@@ -41,7 +62,11 @@ def test_kmpp_round_compiles_to_one_streamed_kernel(monkeypatch, one_chip, l):
     is ONE ``tpu_custom_call``, the state aliased through it; no array of
     a block's distances ``(l, S, 128)`` or of a block's copy ``(d, S,
     128)`` exists; the table reaches the kernel as the program's own
-    parameter, uncopied."""
+    parameter, uncopied. The draw keys the whole shard for its block
+    maxima and keeps none of it: the round's temporaries stay under
+    600 MB (645,120 bytes compiled here for PR 36; the per-block draw it
+    replaced read 612,864) and the one array of a shard's rows made
+    outside a fusion is the fold kernel's own ``d2``."""
     import jax
     import jax.numpy as jnp
     from alink_tpu.kernels import kmeans as kernel
@@ -60,16 +85,20 @@ def test_kmpp_round_compiles_to_one_streamed_kernel(monkeypatch, one_chip, l):
             Ws, d2, nearest, jax.random.wrap_key_data(key), 0, last, cap, 20)
 
     with jax.enable_x64(False):                      # as on the chip
-        text = jax.jit(round_, donate_argnums=(2, 3)).lower(
+        compiled = jax.jit(round_, donate_argnums=(2, 3)).lower(
             sd((nbl, d, S, 128), jnp.float32), sd((nbl, S, 128), jnp.float32),
             sd((nbl, S, 128), jnp.float32), sd((nbl, S, 128), jnp.int32),
             sd((l, d), jnp.float32), sd((), jnp.int32), sd((2,), jnp.uint32),
-            sd((), jnp.bool_)).compile().as_text()
+            sd((), jnp.bool_)).compile()
+    text = compiled.as_text()
 
     calls = _kernel_calls(text)
     assert len(calls) == 1
     assert "kmpp_fold" in calls[0]
     assert "output_to_operand_aliasing" in calls[0]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 600e6, temp
+    _only_the_fold_yields_a_shard(text, calls[0], f"f32[{nbl},{S},128]")
     for shape in {f"[{l},{S},128]", f"[{d},{S},128]"} - {f"[1,{S},128]"}:
         assert shape not in text, shape
     # the table: a parameter of the entry computation that reaches the
@@ -91,7 +120,9 @@ def test_kmpp_round_compiles_a_worker_on_four_chips(monkeypatch, topo):
     """The same round inside a ``shard_map`` as the engine makes it
     (``check_vma=False``), the table's blocks split over a 2 x 2 v5e: one
     kernel a worker over its own 382 blocks, the state aliased, and a
-    quarter of the table a chip."""
+    quarter of the table a chip; a worker's temporaries stay under 200 MB
+    (613,376 bytes compiled here for PR 36; 483,840 before) and its keys
+    are never held at the shard's size."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -126,11 +157,15 @@ def test_kmpp_round_compiles_a_worker_on_four_chips(monkeypatch, topo):
                 sd(rows, jnp.int32, P("d")), sd((l, d), jnp.float32),
                 sd((), jnp.int32), sd((2,), jnp.uint32),
                 sd((), jnp.bool_)).compile()
-    calls = _kernel_calls(compiled.as_text())
+    text = compiled.as_text()
+    calls = _kernel_calls(text)
     assert len(calls) == 1 and f"f32[{nbl},{d},{S // 8},8,128]" in calls[0]
     assert "output_to_operand_aliasing" in calls[0]
     table = 4 * nbl * d * S * 128 * 4
-    assert compiled.memory_analysis().argument_size_in_bytes < 0.3 * table
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes < 0.3 * table
+    assert mem.temp_size_in_bytes < 200e6, mem.temp_size_in_bytes
+    _only_the_fold_yields_a_shard(text, calls[0], f"f32[{nbl},{S},128]")
 
 
 def test_lloyd_superstep_compiles_to_one_streamed_kernel(monkeypatch,
