@@ -62,6 +62,13 @@ BYTE_BLOCK_QUANTUM = 32 * LANES
 DEFAULT_BLOCK_ROWS = 1 << 16
 
 
+def sublane_quantum(dtype) -> int:
+    """Sublanes a register tile of ``dtype`` is deep: 8 for four-byte
+    values, 16 for two, 32 for one. A block's ``S`` is a multiple of it,
+    so a block of bytes is whole 8-bit tiles."""
+    return 8 * max(1, 4 // np.dtype(dtype).itemsize)
+
+
 class DenseBlockColumn(ColumnarColumn):
     """A VECTOR column of ``n_rows`` dense rows of one width, held as ONE
     feature-major, lane-packed array ``blocks`` of shape ``(row_blocks,
@@ -79,10 +86,12 @@ class DenseBlockColumn(ColumnarColumn):
 
     def __init__(self, blocks, n_rows: int):
         shape = tuple(blocks.shape)
-        if len(shape) != 4 or shape[3] != LANES or shape[2] % 8:
+        sub = sublane_quantum(blocks.dtype)
+        if len(shape) != 4 or shape[3] != LANES or shape[2] % sub:
             raise ValueError(
-                f"DenseBlockColumn: blocks must be (row_blocks, dim, S, "
-                f"{LANES}) with S a multiple of 8, got {shape}")
+                f"DenseBlockColumn: blocks of {np.dtype(blocks.dtype)} must "
+                f"be (row_blocks, dim, S, {LANES}) with S a multiple of "
+                f"{sub}, got {shape}")
         if not 0 <= int(n_rows) <= shape[0] * shape[2] * LANES:
             raise ValueError(f"DenseBlockColumn: {n_rows} rows do not fit "
                              f"blocks of shape {shape}")
@@ -90,6 +99,12 @@ class DenseBlockColumn(ColumnarColumn):
         self.n_rows = int(n_rows)
 
     # -- geometry ---------------------------------------------------------
+    @property
+    def value_dtype(self) -> np.dtype:
+        """What a cell of the table is stored as: float32 (float64 on the
+        test mesh), or one byte a value (``uint8``: pixels, bins)."""
+        return np.dtype(self.blocks.dtype)
+
     @property
     def dim(self) -> int:
         return int(self.blocks.shape[1])
@@ -138,7 +153,8 @@ class DenseBlockColumn(ColumnarColumn):
         X = np.asarray(X)
         if X.ndim != 2:
             raise ValueError("DenseBlockColumn.from_rows: X must be (n, dim)")
-        B = cls.block_rows_for(X.shape[0], block_rows)
+        B = cls.block_rows_for(X.shape[0], block_rows,
+                               sublane_quantum(X.dtype) * LANES)
         return cls(cls.pack(X, B), X.shape[0])
 
     def to_rows(self) -> np.ndarray:
@@ -252,6 +268,7 @@ def as_block_column(X, num_workers: int = 1,
     X = np.asarray(X)
     if X.ndim != 2:
         raise ValueError("X must be (n, d) rows or a DenseBlockColumn")
+    quantum = max(quantum, sublane_quantum(X.dtype) * LANES)
     B = DenseBlockColumn.block_rows_for(X.shape[0], quantum=quantum)
     nb = -(-max(X.shape[0], 1) // B)
     nb = -(-nb // num_workers) * num_workers
@@ -308,7 +325,7 @@ def block_values(col: DenseBlockColumn, values):
 
 
 @functools.lru_cache(maxsize=None)
-def _unit_weights_fn():
+def _unit_weights_fn(dtype: str):
     import jax
     import jax.numpy as jnp
 
@@ -318,26 +335,28 @@ def _unit_weights_fn():
         at = jax.lax.broadcasted_iota(jnp.int32, (nb, S, L), 0) * (S * L) \
             + jax.lax.broadcasted_iota(jnp.int32, (nb, S, L), 1) * L \
             + jax.lax.broadcasted_iota(jnp.int32, (nb, S, L), 2)
-        return (at < n_rows).astype(blocks.dtype)
+        return (at < n_rows).astype(dtype)
     return unit_weights_like
 
 
-def block_weights(col: DenseBlockColumn, sample_weight=None):
+def block_weights(col: DenseBlockColumn, sample_weight=None, dtype=None):
     """Per-row weights laid out like the table's rows, ``(row_blocks, S,
     128)``, zero on the padding past ``n_rows`` — the mask every pass of a
     blocked trainer carries. Without ``sample_weight`` they are made where
     the table lives (no ``(n,)`` host array); weights already so laid out
-    pass through."""
+    pass through. ``dtype``: what the weights are held as where that is
+    not the table's own (a table of bytes is weighted in floats)."""
     nb, _, S, _ = col.blocks.shape
+    dt = np.dtype(dtype or col.blocks.dtype)
     if getattr(sample_weight, "shape", None) == (nb, S, LANES):
         return sample_weight              # already laid out (one fit, twice)
     if sample_weight is None:
         if col.on_device:
-            return _unit_weights_fn()(col.blocks, col.n_rows)
-        w = np.zeros(nb * S * LANES, col.blocks.dtype)
+            return _unit_weights_fn(dt.name)(col.blocks, col.n_rows)
+        w = np.zeros(nb * S * LANES, dt)
         w[:col.n_rows] = 1
         return w.reshape(nb, S, LANES)
-    w = np.asarray(sample_weight, col.blocks.dtype)
+    w = np.asarray(sample_weight, dt)
     if w.shape != (col.n_rows,):
         raise ValueError("sample_weight must be (n,)")
     return DenseBlockColumn.pack(w, col.block_rows, nb)

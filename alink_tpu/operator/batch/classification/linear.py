@@ -32,6 +32,9 @@ class BaseLinearTrainBatchOp(BatchOperator, _LinearTrainParams):
     MODEL_TYPE = LinearModelType.LR
 
     def link_from(self, in_op: BatchOperator) -> "BaseLinearTrainBatchOp":
+        """``get_train_info()`` (side output 0) is the (iter, loss) table
+        and answers by key for what the fit went through
+        (``common/linear/base.py`` ``LinearTrainInfo``)."""
         model, info = train_linear_model(in_op.get_output_table(), self, self.MODEL_TYPE)
         self._output = model
         self._side_outputs = [info]

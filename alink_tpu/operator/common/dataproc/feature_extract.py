@@ -110,12 +110,10 @@ def resolve_feature_cols(table: MTable, feature_cols, label_col=None,
 
 
 def add_intercept(design: Dict, dtype=np.float64) -> Dict:
-    """Prefix the constant-1 feature at index 0 (reference Vector.prefix(1.0))."""
-    if design["kind"] == "dense":
-        X = design["X"]
-        ones = np.ones((X.shape[0], 1), X.dtype)
-        return {"kind": "dense", "X": np.concatenate([ones, X], 1),
-                "dim": design["dim"] + 1}
+    """Prefix the constant-1 feature at index 0 of a SPARSE design
+    (reference Vector.prefix(1.0)). A dense table is never rewritten: its
+    trainers fold the intercept into the coefficients
+    (``optim/objfunc.py``)."""
     idx, val = design["idx"], design["val"]
     n = idx.shape[0]
     idx2 = np.concatenate([np.zeros((n, 1), idx.dtype), idx + 1], 1)

@@ -179,22 +179,6 @@ def _sweep_criterion(ctx):
     return jnp.all(ctx.get_obj("pt_conv") | ~ctx.get_obj("sw_alive"))
 
 
-def _reg_loss(obj, coef, l1, l2):
-    """``OptimObjFunc.regular_loss`` with (l1, l2) as traced per-point
-    lanes — same association order as the serial python-float path, so
-    the rounding is bitwise identical (0.5·l2 is an exact halving in
-    both)."""
-    import jax.numpy as jnp
-    m = obj._reg_mask(coef)
-    return (0.5 * l2 * ((coef * m) ** 2).sum()
-            + l1 * jnp.abs(coef * m).sum())
-
-
-def _l2_grad(obj, coef, l2):
-    """``OptimObjFunc.l2_grad`` with a traced l2 lane (same op order)."""
-    return l2 * coef * obj._reg_mask(coef)
-
-
 def _freeze_cond(active, step_fn, pc_p):
     """Per-point freeze: a pruned or converged point SKIPS its step
     (``lax.cond`` — the frozen branch returns the carry untouched, so
@@ -362,16 +346,16 @@ def _qn_point_step(obj, shard, pc, hyp, step, nw, axis, m, owlqn, dtype,
     from ..engine.communication import manifest_psum
     from ..operator.common.optim.optimizers import (_NUM_SEARCH_STEP,
                                                     _TINY, _pseudo_grad,
-                                                    _two_loop)
+                                                    _two_loop, shard_grad,
+                                                    shard_line)
     coef = pc["coef"]
-    g, loss, wsum, eta = obj.calc_grad_eta_shard(shard, coef)
-    glw = jnp.concatenate([g, jnp.stack([loss, wsum])])
+    glw, eta = shard_grad(obj, shard, coef, dtype)
     glw = jnp.asarray(manifest_psum(glw, axis, name="sweep_glw",
                                     num_workers=nw))
     l1, l2 = hyp["l1"], hyp["l2"]
     W = jnp.maximum(glw[dim + 1], _TINY)
-    g_plain = glw[:dim] / W + _l2_grad(obj, coef, l2)
-    loss_total = glw[dim] / W + _reg_loss(obj, coef, l1, l2)
+    g_plain = glw[:dim] / W + obj.l2_grad(coef, l2)
+    loss_total = glw[dim] / W + obj.regular_loss(coef, l1, l2)
     loss_curve = jax.lax.dynamic_update_index_in_dim(
         pc["loss_curve"], loss_total.astype(dtype), step - 1, 0)
     if owlqn:
@@ -400,11 +384,11 @@ def _qn_point_step(obj, shard, pc, hyp, step, nw, axis, m, owlqn, dtype,
     if owlqn:
         d = jnp.where(d * g_dir > 0, d, 0.0)
     steps = (hyp["lr"] * jnp.asarray(steps_base)) * pc["step_scale"]
-    line = obj.line_losses_shard(shard, coef, d, steps, eta0=eta)
+    line = shard_line(obj, shard, coef, d, steps, eta, dtype)
     line = jnp.asarray(manifest_psum(line, axis, name="sweep_line",
                                      num_workers=nw))
-    reg = jax.vmap(lambda s: _reg_loss(obj, coef - s * d, l1, l2))(steps)
-    total = line / W + reg
+    reg = jax.vmap(lambda s: obj.regular_loss(coef - s * d, l1, l2))(steps)
+    total = line[:steps.shape[0]] / W + reg
     best = jnp.argmin(total)
     s_best = steps[best]
     new_coef = coef - s_best * d
@@ -439,7 +423,7 @@ def _sgd_point_step(obj, shard, pc, hyp, step, key, nw, axis, dtype, dim):
     wsum = glw[dim + 1]
     nonempty = wsum > 0
     W = jnp.maximum(wsum, _TINY)
-    gg = glw[:dim] / W + _l2_grad(obj, coef, l2)
+    gg = glw[:dim] / W + obj.l2_grad(coef, l2)
     lr = hyp["lr"] / jnp.sqrt(step.astype(dtype))
     new_coef = coef - lr * gg
     # the serial path applies the L1 prox only when obj.l1 > 0 (a
@@ -450,7 +434,7 @@ def _sgd_point_step(obj, shard, pc, hyp, step, key, nw, axis, dtype, dim):
     soft = jnp.sign(new_coef) * jnp.maximum(jnp.abs(new_coef) - thr, 0.0)
     new_coef = jnp.where(l1 > 0, soft, new_coef)
     new_coef = jnp.where(nonempty, new_coef, coef)
-    loss_total = glw[dim] / W + _reg_loss(obj, coef, l1, l2)
+    loss_total = glw[dim] / W + obj.regular_loss(coef, l1, l2)
     conv = nonempty & (jnp.linalg.norm(lr * gg) <
                        hyp["eps"] * jnp.maximum(1.0, jnp.linalg.norm(coef)))
     return {"coef": new_coef,
@@ -476,12 +460,12 @@ def _newton_point_step(obj, shard, pc, hyp, step, nw, axis, dtype, dim):
                                     num_workers=nw))
     l1, l2 = hyp["l1"], hyp["l2"]
     W = jnp.maximum(glw[dim + 1], _TINY)
-    gg = glw[:dim] / W + _l2_grad(obj, coef, l2)
+    gg = glw[:dim] / W + obj.l2_grad(coef, l2)
     Hn = H / W
     reg_diag = l2 * obj._reg_mask(coef) + 1e-8
     Hn = Hn + jnp.diag(reg_diag.astype(Hn.dtype))
     d = jnp.linalg.solve(Hn, gg)
-    loss_total = glw[dim] / W + _reg_loss(obj, coef, l1, l2)
+    loss_total = glw[dim] / W + obj.regular_loss(coef, l1, l2)
     conv = jnp.linalg.norm(d) < \
         hyp["eps"] * jnp.maximum(1.0, jnp.linalg.norm(coef))
     return {"coef": coef - d,
@@ -490,7 +474,7 @@ def _newton_point_step(obj, shard, pc, hyp, step, nw, axis, dtype, dim):
             "conv": conv, "cur_loss": loss_total.astype(dtype)}
 
 
-def _make_optimizer_stage(obj, data_keys: Tuple[str, ...], P: int,
+def _make_optimizer_stage(obj, shard_keys: Tuple[str, ...], P: int,
                           dim: int, dtype, method: str, m: int,
                           max_iter: int, steps_base: np.ndarray):
     """One engine stage sweeping P points of one optimizer family.
@@ -511,7 +495,7 @@ def _make_optimizer_stage(obj, data_keys: Tuple[str, ...], P: int,
     hyp_names = ("lr", "eps", "l1", "l2") + (("frac",) if sgd else ())
 
     def stage(ctx):
-        shard = {k: ctx.get_obj(k) for k in data_keys}
+        shard = {k: ctx.get_obj(k) for k in shard_keys}
         hyp = {n: ctx.get_obj("swh_" + n) for n in hyp_names}
         step = ctx.step_no
         if ctx.is_init_step:
@@ -571,15 +555,6 @@ def _make_optimizer_stage(obj, data_keys: Tuple[str, ...], P: int,
     return stage
 
 
-def _optimize_dtype(data) -> np.dtype:
-    """The serial optimizer's dtype rule, verbatim."""
-    dtype = np.dtype(getattr(data["y"], "dtype", None)
-                     or np.asarray(data["y"]).dtype)
-    if dtype not in (np.float32, np.float64):
-        dtype = np.float32
-    return dtype
-
-
 def sweep_optimize(obj, data: Dict[str, np.ndarray], params, points:
                    Sequence[Dict[str, Any]], env=None, warm_starts=None,
                    asha=None, checkpoint_dir: Optional[str] = None,
@@ -602,17 +577,21 @@ def sweep_optimize(obj, data: Dict[str, np.ndarray], params, points:
     Per-point results are bitwise identical to ``optimize()`` with that
     point's parameters (the load-bearing tests in tests/test_sweep.py).
     """
-    from ..operator.common.optim.optimizers import (_HISTORY,
-                                                    _NUM_SEARCH_STEP,
-                                                    _fb_precompute_ok)
+    from ..operator.common.optim.optimizers import (LINE_LADDER, _HISTORY,
+                                                    _fb_precompute_ok,
+                                                    optimize_dtype)
     base_method = (params.method or "LBFGS").upper()
     plan = SweepPlan("optimizer", [dict(p) for p in points],
                      base={"method": base_method,
                            "max_iter": int(params.max_iter),
                            "seed": int(params.seed)})
     dim = obj.dim
-    dtype = _optimize_dtype(data)
-    data = dict(data)
+    from ..common.mlenv import MLEnvironmentFactory
+    env = env or MLEnvironmentFactory.get_default()
+    # the serial optimizers' input form, verbatim: a linear objective's
+    # dense table packed into blocks, its fold constants broadcast
+    data, consts = obj.prepare_data(data, env.num_workers)
+    dtype = optimize_dtype(data)
     if _fb_precompute_ok(obj, data):
         # the serial trainers' one-hot-factor precompute, mirrored so a
         # swept fit runs the identical program family (optimizers.py)
@@ -672,19 +651,17 @@ def sweep_optimize(obj, data: Dict[str, np.ndarray], params, points:
         # the serial line-search ladder WITHOUT its lr factor (lr is a
         # per-point lane); [0, 2^1, 2^0, ..., 2^-8] in data dtype —
         # multiplying the lane back in is a power-of-two scaling, exact
-        steps_base = np.concatenate(
-            [[0.0], np.power(2.0, 1 - np.arange(_NUM_SEARCH_STEP,
-                                                dtype=np.float64))]
-        ).astype(dtype)
-        stage = _make_optimizer_stage(obj, data_keys, P, dim, dtype,
-                                      method, m, max_iter, steps_base)
+        steps_base = LINE_LADDER.astype(dtype)
+        bcast.update(consts)
+        stage = _make_optimizer_stage(obj, data_keys + tuple(consts), P, dim,
+                                      dtype, method, m, max_iter, steps_base)
         rung_log: List[Dict[str, Any]] = []
         ck_dir, rs = _group_paths(checkpoint_dir, resume_from, gi,
                                   len(groups))
         res = _run_sweep_queue(
             kind=f"opt_{method.lower()}", stage=stage, parts=data,
             bcast=bcast, env=env, max_iter=max_iter, seed=seed,
-            key_tail=(m, str(dtype), data_keys, _freeze(obj)),
+            key_tail=(m, str(dtype), data_keys, tuple(consts), _freeze(obj)),
             num_points=P, asha=_resolve_asha(asha, max_iter),
             checkpoint_dir=ck_dir, checkpoint_keep=checkpoint_keep,
             resume_from=rs, rung_log=rung_log)

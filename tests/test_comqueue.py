@@ -393,6 +393,23 @@ def _optimizer_fit(method, l1=0.0):
     return fit
 
 
+def _softmax_fit(env, r):
+    """``SoftmaxTrainBatchOp``'s path on a table of bytes: the moments
+    program, then the quasi-Newton program over the blocked walk."""
+    import alink_tpu.operator.common.optim.optimizers as O
+    from alink_tpu.common.columnar import as_block_column, block_weights
+    from alink_tpu.operator.common.linear.base import linear_moments
+    from alink_tpu.operator.common.optim.objfunc import SoftmaxObjFunc
+    X = r.randint(0, 256, (96, 6)).astype(np.uint8)
+    col = as_block_column(X, env.num_workers)
+    mean, std, _ = linear_moments(col, block_weights(col, None, np.float64),
+                                  env)
+    O.optimize(SoftmaxObjFunc(3, 7, l2=1e-3, reg_free_cols=1),
+               {"X": col, "y": r.randint(0, 3, 96), "w": None,
+                "scale": 1 / std, "shift": mean / std},
+               O.OptimParams(max_iter=3, epsilon=0.0), env)
+
+
 def _kmeans_fit(env, r):
     from alink_tpu.operator.common.clustering.kmeans import kmeans_train
     kmeans_train(r.randn(64, 3).astype(np.float32), k=3, max_iter=4,
@@ -471,8 +488,15 @@ _INLINE = ("InlineAllReduce", "<inline>")
 _SUPERSTEP_COLLECTIVES = {
     # the line-search loss needs the direction built from the psummed
     # gradient: dependency-forced, 2 a superstep whatever the compiler does
-    "lbfgs": (_optimizer_fit("LBFGS"), {"qn": _QN}),
-    "owlqn": (_optimizer_fit("OWLQN", l1=1e-3), {"qn": _QN}),
+    "lbfgs": (_optimizer_fit("LBFGS"), {"linear_qn": _QN}),
+    "owlqn": (_optimizer_fit("OWLQN", l1=1e-3), {"linear_qn": _QN}),
+    # the table of bytes: its moments are gathered (a worker's mean, its
+    # squared deviations and its weight, joined pairwise) and its rows
+    # summed; a superstep then asks for the same two psums as any other
+    # quasi-Newton fit, the rows of each pass riding them
+    "softmax_bytes": (_softmax_fit, {
+        "linear_moments": [("AllGather", "linear_moments"), _INLINE],
+        "linear_qn": _QN}),
     "newton": (_optimizer_fit("Newton"),
                {"newton": [("AllReduce", "H"), ("AllReduce", "glw")]}),
     "kmeans": (_kmeans_fit, {
@@ -560,3 +584,30 @@ def test_manifest_wrapper_lowers_to_raw_op(op):
                                             **kwargs))
     assert wrapped == lowered(raw)
     assert manifest == [(kind, "v", 4 * 3 * 4 * 4)]  # a (4, 3) f32 shard x 4
+
+
+def test_prepare_passes_a_resident_byte_table_and_its_int_labels_untouched():
+    """A device-resident uint8 input and an int32 label column beside it
+    reach the program as they are: no cast, no second copy (``_prepare``
+    hands the very arrays on when the blocks divide over the workers)."""
+    from alink_tpu.common.mlenv import MLEnvironment
+    x = jnp.asarray(np.arange(4 * 3 * 32 * 128, dtype=np.uint8)
+                    .reshape(4, 3, 32, 128))
+    y = jnp.asarray(np.arange(4 * 32 * 128, dtype=np.int32)
+                    .reshape(4, 32, 128))
+    env = MLEnvironment(parallelism=4, devices=jax.devices()[:4])
+    q = (IterativeComQueue(env=env, max_iter=1)
+         .init_with_partitioned_data("x", x)
+         .init_with_partitioned_data("y", y))
+    parts, totals, _ = q._prepare(4)
+    assert parts["x"] is x and parts["y"] is y
+    assert totals == {"x": 4, "y": 4}
+
+    def stage(ctx):
+        xs, ys = ctx.get_obj("x"), ctx.get_obj("y")
+        assert xs.dtype == jnp.uint8 and ys.dtype == jnp.int32
+        ctx.put_obj("s", ctx.all_reduce_sum(
+            xs.astype(jnp.int32).sum() + ys.sum()))
+    res = q.add(stage).exec()
+    assert int(res.get("s")) == int(np.asarray(x, np.int64).sum()
+                                    + np.asarray(y, np.int64).sum())

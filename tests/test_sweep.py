@@ -393,10 +393,14 @@ class TestGeometry:
         ).astype(dtype)
         stage = _make_optimizer_stage(obj, ("X", "y", "w"), P, D, dtype,
                                       "LBFGS", _HISTORY, 4, steps_base)
+        # the dense table in the one form the passes walk
+        from alink_tpu.common.mlenv import MLEnvironmentFactory
+        parts, _ = obj.prepare_data(
+            data, MLEnvironmentFactory.get_default().num_workers)
         q = (IterativeComQueue(max_iter=4)
-             .init_with_partitioned_data("X", data["X"])
-             .init_with_partitioned_data("y", data["y"])
-             .init_with_partitioned_data("w", data["w"])
+             .init_with_partitioned_data("X", parts["X"])
+             .init_with_partitioned_data("y", parts["y"])
+             .init_with_partitioned_data("w", parts["w"])
              .init_with_broadcast_data("swh_lr", np.ones(P, dtype))
              .init_with_broadcast_data("swh_eps", np.zeros(P, dtype))
              .init_with_broadcast_data("swh_l1", np.zeros(P, dtype))

@@ -338,3 +338,46 @@ def test_gbdt_grow_compiles_with_the_halved_histogram_product(topo, chips):
         assert longest == 2 * 16 * F * bins * 3 + 2 * 16
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp <= 1.01 * _GROW_TEMP_BEFORE_PR34[chips], temp
+
+
+def test_linear_step_program_compiles_beside_the_byte_table(topo):
+    """``jit_linear_qn`` and ``jit_linear_moments`` of ``softmax-fit`` (124
+    blocks of 784 x 512 x 128 uint8, 9 coefficient rows, history 10, 20
+    supersteps), the whole engine programs lowered from shapes: the step
+    program's products are MXU products of the stacked bfloat16 parts (27
+    = 3 x 9 rows), the table is an argument and never copied (arguments
+    6.44 GB: the table, labels, weights), and everything a superstep makes
+    beside it stays under 1.5 GB (the kept logits, 292 MB, are the loop's
+    carry; compiled here for PR 37: 7 MB of temporaries)."""
+    import jax
+    import jax.numpy as jnp
+    from alink_tpu.common.mlenv import MLEnvironment
+    from alink_tpu.operator.common.linear import base as B
+    from alink_tpu.operator.common.optim import optimizers as O
+    from alink_tpu.operator.common.optim.objfunc import SoftmaxObjFunc
+
+    env = MLEnvironment(parallelism=1, devices=list(topo.devices[:1]))
+    nb, d, S, k = 124, 784, 512, 10
+    table = nb * d * S * 128
+    with jax.enable_x64(False):                      # as on the chip
+        parts = {"X": jax.ShapeDtypeStruct((nb, d, S, 128), jnp.uint8),
+                 "y": jax.ShapeDtypeStruct((nb, S, 128), jnp.int32),
+                 "w": jax.ShapeDtypeStruct((nb, S, 128), jnp.float32)}
+        consts = {"scale": np.ones(d, np.float32),
+                  "shift": np.zeros(d, np.float32)}
+        obj = SoftmaxObjFunc(k, d + 1, reg_free_cols=1)
+        hyp = {"hyp_l1": 0.0, "hyp_l2": 1e-6, "hyp_lr": 1.0, "hyp_eps": 1e-6}
+        step = O.qn_queue(obj, parts, consts, hyp,
+                          np.zeros(obj.dim, np.float32), 20, 0, env, False,
+                          10).lowered().compile()
+        moments = B.moments_queue(env, parts["X"],
+                                  parts["w"]).lowered().compile()
+    text = step.as_text()
+    assert "jit_linear_qn" in text and "jit_linear_moments" in moments.as_text()
+    assert re.search(r"= f32\[27,\d+\]\S* convolution\(", text)      # forward
+    assert re.search(r"= f32\[27,784\]\S* convolution\(", text)     # backward
+    mem = step.memory_analysis()
+    assert table < mem.argument_size_in_bytes < table + 0.1e9
+    assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 1.5e9, (
+        mem.temp_size_in_bytes, mem.output_size_in_bytes)
+    assert moments.memory_analysis().temp_size_in_bytes < 0.5e9
