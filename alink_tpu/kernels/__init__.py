@@ -19,7 +19,22 @@ ONE availability/demotion contract they all ride (``runtime``):
   registers, Kahan-joined across the blocks inside the kernel. Neither
   has a flag: a fit takes them where its input allows
   (``kmeans.fold_path``, ``kmeans.lloyd_path``) and says so
-  (``init_fold``, ``lloyd_pass``).
+  (``init_fold``, ``lloyd_pass``);
+* ``linear``   — the multinomial quasi-Newton superstep's two passes over
+  a blocked table of BYTES (ISSUE 38), one streamed kernel each
+  (``qn_grad_pass``, ``qn_line_pass``): the block read once as 32-bit
+  words with a sublane stride, widened to bfloat16 once, pass 1's two
+  MXU products (logits, gradient sums) and softmax, loss and residual
+  between them, pass 2's product and the whole ladder, fed from that
+  copy; sums Kahan-joined in block order inside. No flag:
+  ``linear.pass_path(dtype, d, S, m)`` reads ``kernel`` for one-byte
+  integers (signed ones widened with their sign), ``S % 32 == 0``,
+  ``m <= 40`` rows and ``2 <= d <= 8192`` where Pallas runs, else
+  ``xla``; a fit says which in
+  ``get_train_info()["paths"]["walk"]`` and counts
+  ``alink_linear_pass_blocks_total{pass=, walk=}``. Parity with the XLA
+  walk: ``2e-6`` of the largest logit / gradient entry, ``1e-6``
+  relative on the sums, rows exact (``tests/test_linear_kernel.py``).
 
 Every kernel is parity-pinned against its XLA path (bitwise where the
 contract demands it, pinned tolerance where association differs) and

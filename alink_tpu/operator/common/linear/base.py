@@ -53,7 +53,7 @@ from ..dataproc.feature_extract import add_intercept, extract_design
 from ..optim.objfunc import (HingeLossFunc, HuberLossFunc, LogLossFunc,
                              PerceptronLossFunc, SmoothHingeLossFunc,
                              SoftmaxObjFunc, SquareLossFunc, SvrLossFunc,
-                             UnaryLossObjFunc)
+                             UnaryLossObjFunc, walk_path)
 from ..optim.optimizers import OptimParams, optimize
 
 
@@ -471,13 +471,14 @@ def train_linear_model(data: MTable, op, model_type: str
         with trace_span("linear.optimize", cat="linear", coarse=True,
                         args={"method": method, "max_iter": optim.max_iter,
                               "classes": len(prep.labels), "dim": prep.dim,
-                              "pass": paths["pass"]}):
+                              "pass": paths["pass"],
+                              "walk": paths.get("walk", "none")}):
             coef, loss_curve, steps = optimize(obj, prep.train, optim,
                                                prep.env, info=went)
         with trace_span("linear.model", cat="linear", coarse=True):
             model_table, curve = prep.finish(coef, loss_curve)
         fit.set(rows=prep.rows, steps=int(steps))
-    _count_fit(prep, went, int(steps))
+    _count_fit(prep, went, int(steps), paths.get("walk"))
     went.update(loss_curve=np.asarray(loss_curve), steps=int(steps),
                 mean=prep.mean, std=prep.std, rows=prep.rows,
                 moments_rows=prep.moments_rows, paths=paths, l2=l2,
@@ -486,24 +487,33 @@ def train_linear_model(data: MTable, op, model_type: str
 
 
 def fit_paths(prep: LinearTrainPrep) -> Dict[str, str]:
-    """What ran a fit, by name: the design's form, who took the moments
-    and what walked the passes (``blocked:bf16x3`` a table of bytes as
-    exact bfloat16 against the coefficients split in three, ``blocked:
-    highest`` a table of floats at matmul precision highest)."""
+    """What ran a fit, by name: the design's form, who took the moments,
+    the passes' arithmetic (``blocked:bf16x3`` a table of bytes as exact
+    bfloat16 against the coefficients split in three, ``blocked:highest``
+    a table of floats at matmul precision highest) and what walked them
+    (``walk``: ``kernel`` the multinomial passes over a table of bytes as
+    one streamed Pallas kernel each, ``kernels/linear.py``; ``xla`` the
+    block loop; read from the input by ``objfunc.walk_path``, here and
+    again where the step program is traced)."""
     X = prep.train.get("X")
     if X is None:
         form = "fieldblock" if prep.fb_meta is not None else "sparse"
         return {"design": form, "moments": "host", "pass": form}
     byte = np.issubdtype(X.value_dtype, np.integer)
+    walk = walk_path(X.blocks, len(prep.labels) - 1) if prep.softmax \
+        else "xla"
     return {"design": f"blocks:{X.value_dtype.name}",
             "moments": MOMENTS_PROGRAM,
-            "pass": "blocked:bf16x3" if byte else "blocked:highest"}
+            "pass": "blocked:bf16x3" if byte else "blocked:highest",
+            "walk": walk}
 
 
-def _count_fit(prep: LinearTrainPrep, went: Dict, steps: int) -> None:
+def _count_fit(prep: LinearTrainPrep, went: Dict, steps: int,
+               walk: Optional[str]) -> None:
     """The fit's counters: rows each pass counted ON THE DEVICE (the
     moments pass and, a superstep, the gradient and the line-search
-    pass), supersteps, passes by kind, fits."""
+    pass), supersteps, passes by kind, the table blocks each kind of
+    pass went over by ``walk``, fits."""
     if not metrics_enabled():
         return
     reg = get_registry()
@@ -515,8 +525,11 @@ def _count_fit(prep: LinearTrainPrep, went: Dict, steps: int) -> None:
     if "rows_trace" in went:
         rows = np.asarray(went["rows_trace"], np.int64)
         reg.inc("alink_linear_rows_total", int(rows.sum()))
-        reg.inc("alink_linear_passes_total", len(rows), {"pass": "grad"})
-        reg.inc("alink_linear_passes_total", len(rows), {"pass": "line"})
+        blocks = len(rows) * int(prep.train["X"].blocks.shape[0])
+        for kind in ("grad", "line"):
+            reg.inc("alink_linear_passes_total", len(rows), {"pass": kind})
+            reg.inc("alink_linear_pass_blocks_total", blocks,
+                    {"pass": kind, "walk": walk})
 
 
 #: the engine names the moments program ``jit_<first word of its key>``

@@ -25,7 +25,11 @@ shape ``(n, ...)`` exists but the kept margins. Standardization and the
 intercept are FOLDED into the coefficients (``X_std . w = X . (w / std) -
 (mean / std) . w``: the shard's ``scale`` and ``shift``), so the table is
 never rewritten. ``OptimObjFunc.prepare_data`` packs host rows into that
-form; there is no other dense form.
+form; there is no other dense form. The multinomial objective's two
+passes over a table of BYTES are each one streamed Pallas kernel where
+the input allows (``kernels/linear.py``; :func:`walk_path` reads which
+from the shard, nothing sets it): the same arithmetic, the block read,
+widened and laid out for the MXU once a pass.
 """
 
 from __future__ import annotations
@@ -38,6 +42,8 @@ import jax.numpy as jnp
 
 from ....common.columnar import (as_block_column, block_values,
                                   block_weights)
+from ....kernels import linear as pass_kernel
+from ....kernels.linear import split3
 from ..blocked import block_at, kahan_add
 
 
@@ -202,26 +208,6 @@ def _fb_parts(data: Dict):
     return None
 
 
-def split3(a):
-    """float ``a`` ``(m, ...)`` as three bfloat16 parts stacked ``(3 m,
-    ...)`` whose sum is ``a`` to float32's 24 bits: each part is what is
-    left with its low 16 bits CLEARED (a mask on the bits, not a rounding:
-    XLA:TPU folds the float32 -> bfloat16 -> float32 round trip a rounding
-    form subtracts away; PERF.md, PR 31). One product of the stack against
-    an operand that is exact in bfloat16 (a byte) is a float32-grade
-    product at a quarter of the MXU's columns."""
-    a = a.astype(jnp.float32)
-    mask = jnp.uint32(0xFFFF0000)
-    bits = jax.lax.bitcast_convert_type
-
-    def top(v):
-        return bits(bits(v, jnp.uint32) & mask, jnp.float32)
-    hi = top(a)
-    mid = top(a - hi)
-    lo = (a - hi) - mid
-    return jnp.concatenate([hi, mid, lo], 0).astype(jnp.bfloat16)
-
-
 def _join3(p, m: int):
     return (p[2 * m:] + p[m:2 * m]) + p[:m]
 
@@ -323,6 +309,15 @@ def _walk(data: Dict, block_fn, sums, keep=None):
         0, nbl, body, (tuple(sums), tuple(jnp.zeros_like(a) for a in sums),
                        jnp.asarray(0, jnp.int32), keep))
     return acc, rows, keep
+
+
+def walk_path(X, m: int) -> str:
+    """Which walk the multinomial passes take over a dense shard's table
+    ``X`` (blocks ``(nbl, d, S, 128)``, or anything that has their
+    ``dtype`` and ``shape``) with ``m`` coefficient rows: ``"kernel"``
+    (``kernels/linear.py``) or ``"xla"`` (:func:`_walk`), read from the
+    input (``pass_kernel.pass_path``)."""
+    return pass_kernel.pass_path(X.dtype, X.shape[1], X.shape[2], m)
 
 
 def matvec(data: Dict, coef, fb_meta=None):
@@ -699,6 +694,13 @@ class SoftmaxObjFunc(OptimObjFunc):
         km1 = self.k - 1
         A, b = fold_coef(data, coef.reshape(km1, self.d))
         d = data["X"].shape[1]
+        if walk_path(data["X"], km1) == "kernel":
+            G, tail, rows, logits = pass_kernel.grad_pass(
+                data["X"], data["y"], data["w"], A, b)
+            tail = tail.astype(dt)
+            grad = unfold_grad(data, G.astype(dt), tail[:km1], self.d)
+            return (grad.reshape(-1), tail[km1], tail[km1 + 1],
+                    logits.astype(dt), rows)
 
         def block(i):
             xb = block_at(data["X"], i)
@@ -729,6 +731,11 @@ class SoftmaxObjFunc(OptimObjFunc):
         km1 = self.k - 1
         A, b = fold_coef(data, jnp.concatenate(
             [direction.reshape(km1, self.d), coef.reshape(km1, self.d)]))
+        if eta0 is not None and walk_path(data["X"], km1) == "kernel":
+            losses, rows = pass_kernel.line_pass(
+                data["X"], data["y"], data["w"], eta0, A[:km1], b[:km1],
+                steps)
+            return losses.astype(dt), rows
 
         def block(i):
             xb = block_at(data["X"], i)

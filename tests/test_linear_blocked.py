@@ -59,18 +59,22 @@ def _softmax(src, l2=1e-4, max_iter=5, **kw):
 
 # -- the program against the plain reference --------------------------------
 
+@pytest.mark.parametrize("walk", ["xla", "kernel"])
 @pytest.mark.parametrize("n", [8192, 6000], ids=["whole_blocks", "ragged"])
-def test_byte_table_fit_agrees_with_the_plain_reference(n):
+def test_byte_table_fit_agrees_with_the_plain_reference(monkeypatch, n, walk):
     """A few thousand rows x 784 uint8 through ``SoftmaxTrainBatchOp``:
     every checked superstep's loss, gradient, direction, chosen rung and
     update against the reference teacher-forced from the fit's own traces;
-    the moments exact; every pass counted every row."""
+    the moments exact; every pass counted every row. On either walk of
+    the passes: the block loop, and the Pallas kernels under the
+    interpreter (``kernels/linear.py``; the same limits)."""
     from benchmark.reference import softmax as ref
+    monkeypatch.setenv("ALINK_TPU_PALLAS_INTERPRET", str(int(walk == "kernel")))
     x, y = _pixels(n)
     info = dict(_softmax(_byte_source(x, y, n)).get_train_info())
     assert info["paths"] == {"design": "blocks:uint8",
                              "moments": "linear_moments",
-                             "pass": "blocked:bf16x3"}
+                             "pass": "blocked:bf16x3", "walk": walk}
     assert info["coef_trace"].shape == (5, 9 * 785) == info["grad_trace"].shape
     assert info["moments_rows"] == n and (info["rows_trace"] == n).all()
     assert np.all(np.diff(info["loss_curve"]) < 0)
@@ -328,13 +332,26 @@ def test_a_byte_block_column_is_whole_8_bit_tiles():
 
 # -- spans and counters ---------------------------------------------------------
 
+def _ring_mark():
+    """Where the tracer's ring stands, as the start of its newest event:
+    a time, not a place, since a ring that is full (a worker that has run
+    other files) no longer grows."""
+    from alink_tpu.common.tracing import get_tracer
+    events = get_tracer().events()
+    return events[-1]["ts"] if events else -1.0
+
+
+def _events_since(mark):
+    from alink_tpu.common.tracing import get_tracer
+    return [e for e in get_tracer().events() if e["ts"] > mark]
+
+
 def test_a_fit_records_its_spans_and_counts_its_rows():
     """Coarse spans under the operator's link (no flag set): ``linear.fit``
     over extract, moments, optimize and model, none a superstep, within
     the budget of 40 always-on events a fit; the counters carry the rows
     each pass counted on the device."""
     from alink_tpu.common.metrics import get_registry
-    from alink_tpu.common.tracing import get_tracer
     n = 4096
     x, y = _pixels(n)
     src = _byte_source(x, y, n)
@@ -346,9 +363,9 @@ def test_a_fit_records_its_spans_and_counts_its_rows():
     names = ("alink_linear_rows_total", "alink_linear_supersteps_total",
              "alink_linear_fits_total", "alink_linear_passes_total")
     before = [count(c) for c in names]
-    mark = len(get_tracer().events())
+    mark = _ring_mark()
     _softmax(src, max_iter=5)
-    events = [e for e in get_tracer().events()[mark:] if e.get("ph") == "X"]
+    events = [e for e in _events_since(mark) if e.get("ph") == "X"]
     got = [e["name"] for e in events]
     for want in ("link:SoftmaxTrainBatchOp", "linear.fit", "linear.extract",
                  "linear.moments", "linear.optimize", "linear.model"):

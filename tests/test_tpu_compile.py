@@ -381,3 +381,105 @@ def test_linear_step_program_compiles_beside_the_byte_table(topo):
     assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 1.5e9, (
         mem.temp_size_in_bytes, mem.output_size_in_bytes)
     assert moments.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_linear_step_program_compiles_with_the_pass_kernels(monkeypatch, topo,
+                                                            chips):
+    """The same step program where Pallas can run (``pass_path`` reads
+    ``kernel``; the rig's backend is the CPU, so the test says so in the
+    kernel module's place): the two passes are ``qn_grad_pass`` and
+    ``qn_line_pass``, one Mosaic call each in the first superstep and in
+    the loop's body, fed the table as the program's own argument; no
+    product of the stacked parts is left to XLA, no block is copied out
+    of the table (nothing of a block's 51 MB is made), and the program
+    asks for less beside its arguments than the XLA walk's 7 MB. On a
+    2 x 2 v5e a worker walks its own 31 blocks and a superstep's two
+    psums (7,069 and 13 floats; the first superstep's and the body's) are
+    the program's only collectives."""
+    import jax
+    import jax.numpy as jnp
+    from alink_tpu.common.mlenv import MLEnvironment
+    from alink_tpu.kernels import linear as kernel
+    from alink_tpu.operator.common.optim import optimizers as O
+    from alink_tpu.operator.common.optim.objfunc import SoftmaxObjFunc
+
+    monkeypatch.setattr(kernel, "pallas_available", lambda: True)
+    monkeypatch.setattr(kernel, "interpret_mode", lambda: False)
+    env = MLEnvironment(parallelism=chips, devices=list(topo.devices[:chips]))
+    nb, d, S, k = 124, 784, 512, 10
+    table = nb * d * S * 128
+    with jax.enable_x64(False):
+        parts = {"X": jax.ShapeDtypeStruct((nb, d, S, 128), jnp.uint8),
+                 "y": jax.ShapeDtypeStruct((nb, S, 128), jnp.int32),
+                 "w": jax.ShapeDtypeStruct((nb, S, 128), jnp.float32)}
+        consts = {"scale": np.ones(d, np.float32),
+                  "shift": np.zeros(d, np.float32)}
+        obj = SoftmaxObjFunc(k, d + 1, reg_free_cols=1)
+        hyp = {"hyp_l1": 0.0, "hyp_l2": 1e-6, "hyp_lr": 1.0, "hyp_eps": 1e-6}
+        step = O.qn_queue(obj, parts, consts, hyp,
+                          np.zeros(obj.dim, np.float32), 20, 0, env, False,
+                          10).lowered().compile()
+    text = step.as_text()
+    calls = _kernel_calls(text)
+    assert len(calls) == 4 and all(
+        f"u8[{nb // chips},{d},{S},128]" in c for c in calls), calls
+    assert not re.search(r"= f32\[27,\d+\]\S* convolution\(", text)
+    assert not re.search(rf"= \w+\[{d},{S},128\]", text)         # a block
+    mem = step.memory_analysis()
+    assert table / chips < mem.argument_size_in_bytes \
+        < table / chips + 0.1e9
+    assert mem.temp_size_in_bytes < 7.1e6, mem.temp_size_in_bytes
+    if chips > 1:
+        assert sorted(re.findall(r"= f32\[(\d+)\]\S* all-reduce(?:-start)?\(",
+                                 text)) == ["13", "13", "7069", "7069"]
+        assert len(re.findall(r"= \S+ all-reduce(?:-start)?\(", text)) == 4
+
+
+@pytest.mark.parametrize("d, S, m, dtype", [
+    (784, 512, 9, "int8"),
+    (100, 512, 9, "uint8"),
+    (129, 512, 9, "uint8"),
+    (1000, 512, 9, "uint8"),
+    (8192, 512, 9, "uint8"),
+    (8192, 32, 40, "uint8"),
+    (784, 512, 2, "uint8"),
+    (784, 512, 40, "uint8"),
+    (2, 32, 1, "uint8"),
+], ids=["signed_bytes", "one_run_of_100", "a_last_run_of_49",
+        "a_last_run_of_104", "the_widest", "the_widest_and_most_classes",
+        "two_classes_more", "the_most_classes", "the_smallest"])
+def test_pass_kernels_compile_over_pass_paths_envelope(one_chip, d, S, m,
+                                                       dtype):
+    """Mosaic takes both pass kernels at the corners of the envelope in
+    which ``pass_path`` answers ``kernel`` (on a TPU a kernel it refused
+    would raise where the XLA walk ran before): a run of features that is
+    not whole bfloat16 registers (100), a last run stored at an offset
+    that is (129: 80 + 49; 1,000: 7 x 128 + 104), the widest table (the
+    block cut to 32 sublanes a step, 67 MB of table buffers under a
+    107 MB limit), 1 to 40 coefficient rows, signed bytes, two features.
+    Every shape reads ``kernel`` from ``pass_path`` itself."""
+    import jax
+    import jax.numpy as jnp
+    from alink_tpu.kernels import linear as kernel
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(kernel, "pallas_available", lambda: True)
+        assert kernel.pass_path(dtype, d, S, m) == "kernel"
+    nbl = 2
+
+    def sd(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    with jax.enable_x64(False):
+        rows = sd((nbl, S, 128), jnp.float32)
+        shard = (sd((nbl, d, S, 128), dtype), sd((nbl, S, 128), jnp.int32),
+                 rows)
+        coef = (sd((m, d), jnp.float32), sd((m,), jnp.float32))
+        grad = jax.jit(lambda *a: kernel._grad_pass(*a, interpret=False)) \
+            .lower(*shard, *coef).compile()
+        line = jax.jit(lambda *a: kernel._line_pass(*a, interpret=False)) \
+            .lower(*shard, sd((nbl, m, S, 128), jnp.float32), *coef,
+                   sd((11,), jnp.float32)).compile()
+    for compiled, name in ((grad, "qn_grad_pass"), (line, "qn_line_pass")):
+        call, = _kernel_calls(compiled.as_text())
+        assert name in call and f"[{nbl},{d},{S},128]" in call, call
