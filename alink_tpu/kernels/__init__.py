@@ -34,7 +34,19 @@ ONE availability/demotion contract they all ride (``runtime``):
   ``get_train_info()["paths"]["walk"]`` and counts
   ``alink_linear_pass_blocks_total{pass=, walk=}``. Parity with the XLA
   walk: ``2e-6`` of the largest logit / gradient entry, ``1e-6``
-  relative on the sums, rows exact (``tests/test_linear_kernel.py``).
+  relative on the sums, rows exact (``tests/test_linear_kernel.py``);
+* ``mlp``      — layer 1 of the MLP trainer's two blocked passes over a
+  table of BYTES: the coefficients' product against a block
+  (``mlp_forward``: the line pass's ``[W1; D1]`` as two outputs) and the
+  residual's against its transpose (``mlp_backward``), the block read
+  where it lies through a scalar-prefetched index, widened once a word,
+  the three bfloat16 parts' products joined in VMEM. No flag:
+  ``mlp.walk_path(dtype, d, S, m)`` reads ``kernel`` for one-byte
+  integers, ``S % 32 == 0``, ``d >= 2`` and ``m`` rows within the VMEM
+  budget where Pallas runs, else ``xla``; a fit says which in
+  ``get_train_info()["paths"]["walk"]`` and counts
+  ``alink_mlp_pass_blocks_total{pass=, walk=}``. Parity with the XLA
+  walk: ``2e-6`` of the largest entry (``tests/test_mlp_kernel.py``).
 
 Every kernel is parity-pinned against its XLA path (bitwise where the
 contract demands it, pinned tolerance where association differs) and

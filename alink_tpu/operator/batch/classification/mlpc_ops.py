@@ -27,7 +27,7 @@ from ....params.shared import (HasEpsilonDefaultAs000001, HasFeatureCols,
                                HasPredictionCol, HasPredictionDetailCol,
                                HasReservedCols, HasSeed, HasVectorCol)
 from ...base import BatchOperator
-from ...common.ann.mlp import MlpObjFunc, mlp_forward
+from ...common.ann.mlp import MlpObjFunc, mlp_forward, walk_path
 from ...common.dataproc.feature_extract import extract_design, resolve_feature_cols
 from ...common.linear.base import (LinearTrainInfo, blocked_paths,
                                    count_fit, index_labels, linear_moments)
@@ -72,7 +72,9 @@ class MultilayerPerceptronTrainBatchOp(BatchOperator, HasLabelCol, HasFeatureCol
     """A vector column held as a ``DenseBlockColumn`` (floats or bytes,
     on the device or not) trains where it lies: moments by the linear
     trainers' one blocked pass, the standardization folded into layer 1,
-    two blocked passes a superstep in ``jit_mlp_qn`` (``common/ann/mlp.py``).
+    two blocked passes a superstep in ``jit_mlp_qn`` (``common/ann/mlp.py``;
+    layer 1's products over a table of bytes as the kernels of
+    ``kernels/mlp.py`` where the input allows: ``paths["walk"]``).
     Any other input is read into host rows, standardized there, and fitted
     by ``jax.grad`` over them. ``get_train_info()`` is the (iter, loss)
     table and answers by key for what the fit went through, as a linear
@@ -123,9 +125,11 @@ class MultilayerPerceptronTrainBatchOp(BatchOperator, HasLabelCol, HasFeatureCol
             rng = np.random.RandomState(self.get_seed())
             w0 = (rng.randn(obj.dim) * 0.5
                   / np.sqrt(max(layer_sizes[0], 1))).astype(dtype)
-            paths = blocked_paths(col) if blocked else {
-                "design": "rows", "moments": "host", "pass": "host:grad",
-                "walk": "none"}
+            if blocked:
+                paths = blocked_paths(col, walk_path(col.blocks, layer_sizes))
+            else:
+                paths = {"design": "rows", "moments": "host",
+                         "pass": "host:grad", "walk": "none"}
             went: Dict[str, Any] = {}
             # the seed draws the first weights alone: the program takes
             # them as data, so restarts over seeds compile once

@@ -16,9 +16,12 @@ hand-written backward; ``line_pass``: the line search's ladder), the
 standardization folded into layer 1 (``fold_coef`` / ``unfold_grad``), the
 blocks' sums Kahan-added, the rows counted as whole numbers. Activations
 are feature-major, ``(units, rows)``. The bytes meet layer 1 as exact
-bfloat16 against ``split3`` of the weights (``block_forward`` /
-``block_backward``: float32 grade); every product of two floats (forward,
-backward, along the ladder) is at matmul precision ``highest``
+bfloat16 against ``split3`` of the weights, float32 grade: on a table of
+bytes where Pallas runs, as the kernels of ``kernels/mlp.py`` (the block
+read where it lies and widened once, the parts' products joined in VMEM),
+else as ``block_forward`` / ``block_backward`` (:func:`walk_path` reads
+which from the table, nothing sets it); every product of two floats
+(forward, backward, along the ladder) is at matmul precision ``highest``
 (:func:`dot_highest`).
 Only layer 1 is linear in the step, ``z1(a) = z1 - a (X . D1 + d_b1)``, so
 the line pass takes ONE product of the stacked ``[W1; D1]`` against a block
@@ -34,6 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from ....common.columnar import DenseBlockColumn
+from ....kernels import mlp as layer1
 from ..blocked import block_at
 from ..optim.objfunc import (OptimObjFunc, _walk, block_backward,
                              block_forward, fold_coef, pack_dense,
@@ -73,6 +77,16 @@ def mlp_forward(coef, X, layer_sizes: Sequence[int]):
         z = h @ W + b
         h = z if i == len(layers) - 1 else jax.nn.sigmoid(z)
     return h
+
+
+def walk_path(X, layer_sizes: Sequence[int]) -> str:
+    """Which walk layer 1 of the blocked passes takes over a table ``X``
+    (blocks ``(nbl, d, S, 128)``, or anything that has their ``dtype``
+    and ``shape``): ``"kernel"`` (``kernels/mlp.py``) or ``"xla"``
+    (``block_forward`` / ``block_backward``), read from the input for the
+    line pass's stacked ``[W1; D1]``, the larger product."""
+    return layer1.walk_path(X.dtype, X.shape[1], X.shape[2],
+                            2 * layer_sizes[1])
 
 
 def dot_highest(spec: str, a, b):
@@ -142,11 +156,36 @@ class MlpObjFunc(OptimObjFunc):
         return jax.vmap(one)(steps)
 
     # -- a blocked table, walked ------------------------------------------
-    def _block(self, data, i):
-        xb = block_at(data["X"], i)
-        rows = xb.shape[1] * xb.shape[2]
-        return (xb, block_at(data["y"], i).reshape(rows),
-                block_at(data["w"], i).reshape(rows), rows)
+    def _rows(self, data, i):
+        """Block ``i``'s labels and weights a row each, and its rows."""
+        w = block_at(data["w"], i)
+        rows = w.shape[0] * w.shape[1]
+        return block_at(data["y"], i).reshape(rows), w.reshape(rows), rows
+
+    def _layer1(self, data, i, walk):
+        """Layer 1's two products over block ``i`` on ``walk``:
+        ``forward(A, b, cuts)``, ``A . x + b`` ``(m, rows)`` cut into
+        ``cuts`` equal runs of rows, and ``backward(C)`` ``(m, d)`` =
+        ``C . x^T``, ``C`` ``(m, rows)``."""
+        X = data["X"]
+        if walk == "kernel":
+            def forward(A, b, cuts=1):
+                return tuple(z.astype(A.dtype)
+                             for z in layer1.forward(X, i, A, b, cuts))
+
+            def backward(C):
+                return layer1.backward(X, i, C).astype(C.dtype)
+            return forward, backward
+        xb = block_at(X, i)
+
+        def forward(A, b, cuts=1):
+            z = block_forward(xb, A).reshape(A.shape[0], -1) + b[:, None]
+            h = A.shape[0] // cuts
+            return tuple(z[k * h:(k + 1) * h] for k in range(cuts))
+
+        def backward(C):
+            return block_backward(xb, C.reshape((-1,) + xb.shape[1:]))
+        return forward, backward
 
     def grad_pass(self, data, coef):
         """One walk of a blocked shard: ``(grad, loss, wsum, None, rows)``.
@@ -157,12 +196,13 @@ class MlpObjFunc(OptimObjFunc):
         W1, b1 = layers[0]
         A, fb = fold_coef(data, W1.T)
         d = data["X"].shape[1]
+        walk = walk_path(data["X"], self.layer_sizes)
 
         def block(i):
-            xb, y, w, rows = self._block(data, i)
+            y, w, rows = self._rows(data, i)
+            forward, backward = self._layer1(data, i, walk)
             with jax.named_scope("mlp_forward"):
-                z1 = block_forward(xb, A).reshape(-1, rows) \
-                    + (fb + b1)[:, None]
+                z1, = forward(A, fb + b1)
                 hs, z = _upper(layers, z1)
                 loss, delta = _softmax_loss(z, y, w)
             with jax.named_scope("mlp_backward"):
@@ -172,7 +212,7 @@ class MlpObjFunc(OptimObjFunc):
                             delta.sum(1)] + sums
                     delta = dot_highest("io,or->ir", W, delta) \
                         * (h * (1.0 - h))
-                G1 = block_backward(xb, delta.reshape((-1,) + xb.shape[1:]))
+                G1 = backward(delta)
             return ((G1, delta.sum(1), *sums, jnp.stack([loss, w.sum()])),
                     (w != 0).sum(dtype=jnp.int32), None)
 
@@ -196,15 +236,15 @@ class MlpObjFunc(OptimObjFunc):
         layers = unstack(coef, self.layer_sizes)
         dirs = unstack(direction, self.layer_sizes)
         (W1, b1), (D1, db1) = layers[0], dirs[0]
-        h1 = W1.shape[1]
         A, fb = fold_coef(data, jnp.concatenate([W1.T, D1.T]))
         bias = fb + jnp.concatenate([b1, db1])
+        walk = walk_path(data["X"], self.layer_sizes)
 
         def block(i):
-            xb, y, w, rows = self._block(data, i)
+            y, w, rows = self._rows(data, i)
+            forward, _ = self._layer1(data, i, walk)
             with jax.named_scope("mlp_line"):
-                z = block_forward(xb, A).reshape(-1, rows) + bias[:, None]
-                z1, zd1 = z[:h1], z[h1:]
+                z1, zd1 = forward(A, bias, 2)
 
                 def rung(s):
                     upper = layers[:1] + [

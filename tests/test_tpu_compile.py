@@ -383,45 +383,81 @@ def test_linear_step_program_compiles_beside_the_byte_table(topo):
     assert moments.memory_analysis().temp_size_in_bytes < 0.5e9
 
 
-def test_mlp_step_program_compiles_beside_the_byte_table(topo):
+def _mlp_step(monkeypatch, topo, h1):
     """``jit_mlp_qn`` of ``mlp-fit`` (124 blocks of 784 x 512 x 128 uint8,
-    layers 784-500-150-10, history 10, 5 supersteps), the whole engine
-    program lowered from shapes: layer 1's products are MXU products of
-    the three bfloat16 parts of the weights (1,500 rows) and, in the line
-    pass, of the stacked weights and direction (3,000 rows) against the
-    block; the table is an argument and never copied; everything a
-    superstep makes beside it stays under 2 GB (compiled here for PR 39:
-    1.28 GB of temporaries, the block's (500, 65,536) activations and the
-    split products before they are joined)."""
+    layers 784-``h1``-150-10, history 10, 5 supersteps) lowered from
+    shapes and compiled for one chip, where Pallas can run (the rig's
+    backend is the CPU, so the test says so in the kernel module's
+    place): ``(walk, text, memory analysis)``."""
     import jax
     import jax.numpy as jnp
     from alink_tpu.common.mlenv import MLEnvironment
-    from alink_tpu.operator.common.ann.mlp import MlpObjFunc
+    from alink_tpu.kernels import mlp as kernel
+    from alink_tpu.operator.common.ann.mlp import MlpObjFunc, walk_path
     from alink_tpu.operator.common.optim import optimizers as O
 
+    monkeypatch.setattr(kernel, "pallas_available", lambda: True)
+    monkeypatch.setattr(kernel, "interpret_mode", lambda: False)
     env = MLEnvironment(parallelism=1, devices=list(topo.devices[:1]))
     nb, d, S = 124, 784, 512
-    table = nb * d * S * 128
+    layers = [d, h1, 150, 10]
     with jax.enable_x64(False):                      # as on the chip
         parts = {"X": jax.ShapeDtypeStruct((nb, d, S, 128), jnp.uint8),
                  "y": jax.ShapeDtypeStruct((nb, S, 128), jnp.int32),
                  "w": jax.ShapeDtypeStruct((nb, S, 128), jnp.float32)}
         consts = {"scale": np.ones(d, np.float32),
                   "shift": np.zeros(d, np.float32)}
-        obj = MlpObjFunc([d, 500, 150, 10]).structure()
+        obj = MlpObjFunc(layers).structure()
         hyp = {"hyp_l1": 0.0, "hyp_l2": 1e-4, "hyp_lr": 1.0, "hyp_eps": 1e-6}
         step = O.qn_queue(obj, parts, consts, hyp,
                           np.zeros(obj.dim, np.float32), 5, 0, env, False,
                           10).lowered().compile()
     text = step.as_text()
     assert "jit_mlp_qn" in text
-    assert re.search(r"= f32\[1500,65536\]\S* convolution\(", text)
-    assert re.search(r"= f32\[3000,65536\]\S* convolution\(", text)
-    assert re.search(r"= f32\[1500,784\]\S* convolution\(", text)
+    table = nb * d * S * 128
     mem = step.memory_analysis()
     assert table < mem.argument_size_in_bytes < table + 0.1e9
-    assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 2e9, (
+    return walk_path(parts["X"], layers), text, mem
+
+
+def test_mlp_step_program_compiles_beside_the_byte_table(monkeypatch, topo):
+    """``jit_mlp_qn`` of ``mlp-fit`` on the kernel walk: layer 1's products
+    are the ``mlp_forward`` / ``mlp_backward`` Mosaic calls (gradient pass
+    forward and backward, line pass forward, each in the first superstep
+    and the loop's body), fed the table as the program's own argument; no
+    product of the three stacked parts is left to XLA (the XLA walk's
+    ``f32[1500,65536]`` / ``f32[3000,65536]`` / ``f32[1500,784]``), no
+    block is copied out of the table, and the line pass's ``[z1; zd1]``
+    come out of the kernel as two arrays that nothing slices. Everything a
+    superstep makes beside the table stays under 0.6 GB (compiled here: 492
+    MB of temporaries and 77 MB of outputs, where the XLA walk's asked for
+    1,279 MB of temporaries)."""
+    walk, text, mem = _mlp_step(monkeypatch, topo, 500)
+    assert walk == "kernel"
+    calls = _kernel_calls(text)
+    names = sorted(re.search(r"%(mlp_\w+?)\.\d+ = ", c).group(1)
+                   for c in calls)
+    assert names == ["mlp_backward"] * 2 + ["mlp_forward"] * 4, names
+    assert all("u8[124,784,512,128]" in c for c in calls)
+    for shape in ("1500,65536", "3000,65536", "1500,784"):
+        assert not re.search(rf"= f32\[{shape}\]\S* convolution\(", text)
+    assert not re.search(r"= \w+\[784,512,128\]", text)        # a block
+    assert not re.search(r"= f32\[500,65536\]\S* slice\(", text)
+    assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 0.6e9, (
         mem.temp_size_in_bytes, mem.output_size_in_bytes)
+
+
+def test_mlp_step_program_compiles_over_the_kernels_budget(monkeypatch,
+                                                           topo):
+    """A layer 1 of 1,000 units: the line pass's stack of 2,000 rows is
+    over the kernels' VMEM budget, so ``walk_path`` reads ``xla`` and the
+    step program lowers the XLA walk, products of the three stacked parts
+    against the block as before, no Mosaic call."""
+    walk, text, _ = _mlp_step(monkeypatch, topo, 1000)
+    assert walk == "xla"
+    assert _kernel_calls(text) == []
+    assert re.search(r"= f32\[6000,65536\]\S* convolution\(", text)
+    assert re.search(r"= f32\[3000,784\]\S* convolution\(", text)
 
 
 @pytest.mark.parametrize("chips", [1, 4])
@@ -522,5 +558,50 @@ def test_pass_kernels_compile_over_pass_paths_envelope(one_chip, d, S, m,
             .lower(*shard, sd((nbl, m, S, 128), jnp.float32), *coef,
                    sd((11,), jnp.float32)).compile()
     for compiled, name in ((grad, "qn_grad_pass"), (line, "qn_line_pass")):
+        call, = _kernel_calls(compiled.as_text())
+        assert name in call and f"[{nbl},{d},{S},128]" in call, call
+
+
+@pytest.mark.parametrize("d, S, m, cuts, dtype", [
+    (784, 512, 1000, 2, "uint8"),
+    (784, 512, 1712, 2, "uint8"),
+    (784, 512, 500, 1, "int8"),
+    (129, 64, 20, 1, "uint8"),
+    (1000, 512, 9, 1, "uint8"),
+    (6455, 32, 1, 1, "uint8"),
+    (2, 32, 1, 1, "uint8"),
+], ids=["the_cells_line_pass", "the_most_rows", "signed_bytes",
+        "a_last_run_of_17", "a_last_run_of_104", "the_widest", "the_smallest"])
+def test_mlp_kernels_compile_over_walk_paths_envelope(one_chip, d, S, m, cuts,
+                                                      dtype):
+    """Mosaic takes both layer-1 kernels at the corners of the envelope in
+    which ``kernels/mlp.walk_path`` answers ``kernel`` (on a TPU a kernel
+    it refused would raise where the XLA walk ran before): the cell's
+    line pass (1,000 rows cut in two outputs), the most rows the VMEM
+    budget holds at the cell's width, signed bytes, a last run of features
+    that is not whole registers, the widest table the budget holds, two
+    features and one row. Every shape reads ``kernel`` from ``walk_path``
+    itself."""
+    import jax
+    import jax.numpy as jnp
+    from alink_tpu.kernels import mlp as kernel
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(kernel, "pallas_available", lambda: True)
+        assert kernel.walk_path(dtype, d, S, m) == "kernel"
+    nbl = 2
+
+    def sd(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    with jax.enable_x64(False):
+        table, i = sd((nbl, d, S, 128), dtype), sd((), jnp.int32)
+        fwd = jax.jit(lambda X, i, A, b: kernel._forward(
+            X, i, A, b, cuts, interpret=False)).lower(
+                table, i, sd((m, d), jnp.float32),
+                sd((m,), jnp.float32)).compile()
+        bwd = jax.jit(lambda X, i, C: kernel._backward(
+            X, i, C, interpret=False)).lower(
+                table, i, sd((m // cuts, S * 128), jnp.float32)).compile()
+    for compiled, name in ((fwd, "mlp_forward"), (bwd, "mlp_backward")):
         call, = _kernel_calls(compiled.as_text())
         assert name in call and f"[{nbl},{d},{S},128]" in call, call
