@@ -46,7 +46,18 @@ ONE availability/demotion contract they all ride (``runtime``):
   budget where Pallas runs, else ``xla``; a fit says which in
   ``get_train_info()["paths"]["walk"]`` and counts
   ``alink_mlp_pass_blocks_total{pass=, walk=}``. Parity with the XLA
-  walk: ``2e-6`` of the largest entry (``tests/test_mlp_kernel.py``).
+  walk: ``2e-6`` of the largest entry (``tests/test_mlp_kernel.py``);
+* ``mlp_rungs`` — the MLP line search's ladder over one block
+  (``mlp_rungs``): a tile of layer 1's ``z1`` / ``zd1`` read into VMEM
+  once and every rung (sigmoid, the upper layers at ``W - s D``, the
+  softmax loss) run on it, the products at matmul precision ``highest``
+  as the line pass hands them in. No flag: ``mlp_rungs.rung_path(
+  layer_sizes, rows, dtype)`` reads ``kernel`` for float32, one hidden
+  layer or more, blocks of whole lane rows and widths within the VMEM
+  budget where Pallas runs, else ``xla``; a fit says which in
+  ``get_train_info()["paths"]["rungs"]`` and counts
+  ``alink_mlp_rung_blocks_total{walk=}``. Parity with the XLA rungs:
+  ``1e-6`` relative (``tests/test_mlp_rungs.py``).
 
 Every kernel is parity-pinned against its XLA path (bitwise where the
 contract demands it, pinned tolerance where association differs) and

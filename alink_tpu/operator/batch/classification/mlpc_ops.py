@@ -13,6 +13,7 @@ import jax
 import numpy as np
 
 from ....common.columnar import DenseBlockColumn, block_weights
+from ....common.metrics import get_registry, metrics_enabled
 from ....common.mlenv import MLEnvironmentFactory
 from ....common.mtable import MTable
 from ....common.tracing import trace_span
@@ -27,7 +28,7 @@ from ....params.shared import (HasEpsilonDefaultAs000001, HasFeatureCols,
                                HasPredictionCol, HasPredictionDetailCol,
                                HasReservedCols, HasSeed, HasVectorCol)
 from ...base import BatchOperator
-from ...common.ann.mlp import MlpObjFunc, mlp_forward, walk_path
+from ...common.ann.mlp import MlpObjFunc, mlp_forward, rung_path, walk_path
 from ...common.dataproc.feature_extract import extract_design, resolve_feature_cols
 from ...common.linear.base import (LinearTrainInfo, blocked_paths,
                                    count_fit, index_labels, linear_moments)
@@ -74,7 +75,10 @@ class MultilayerPerceptronTrainBatchOp(BatchOperator, HasLabelCol, HasFeatureCol
     trainers' one blocked pass, the standardization folded into layer 1,
     two blocked passes a superstep in ``jit_mlp_qn`` (``common/ann/mlp.py``;
     layer 1's products over a table of bytes as the kernels of
-    ``kernels/mlp.py`` where the input allows: ``paths["walk"]``).
+    ``kernels/mlp.py`` where the input allows: ``paths["walk"]``; the line
+    search's rungs of a block as the kernel of ``kernels/mlp_rungs.py``
+    where the input allows: ``paths["rungs"]``, counted by
+    ``alink_mlp_rung_blocks_total{walk=}``).
     Any other input is read into host rows, standardized there, and fitted
     by ``jax.grad`` over them. ``get_train_info()`` is the (iter, loss)
     table and answers by key for what the fit went through, as a linear
@@ -127,9 +131,10 @@ class MultilayerPerceptronTrainBatchOp(BatchOperator, HasLabelCol, HasFeatureCol
                   / np.sqrt(max(layer_sizes[0], 1))).astype(dtype)
             if blocked:
                 paths = blocked_paths(col, walk_path(col.blocks, layer_sizes))
+                paths["rungs"] = rung_path(col.blocks, layer_sizes, dtype)
             else:
                 paths = {"design": "rows", "moments": "host",
-                         "pass": "host:grad", "walk": "none"}
+                         "pass": "host:grad", "walk": "none", "rungs": "none"}
             went: Dict[str, Any] = {}
             # the seed draws the first weights alone: the program takes
             # them as data, so restarts over seeds compile once
@@ -139,7 +144,8 @@ class MultilayerPerceptronTrainBatchOp(BatchOperator, HasLabelCol, HasFeatureCol
                             args={"layers": layer_sizes,
                                   "max_iter": optim.max_iter,
                                   "pass": paths["pass"],
-                                  "walk": paths["walk"]}):
+                                  "walk": paths["walk"],
+                                  "rungs": paths["rungs"]}):
                 coef, curve, steps = optimize(obj, data, optim, env,
                                               warm_start=w0, info=went)
             with trace_span("mlp.model", cat="mlp", coarse=True):
@@ -151,6 +157,11 @@ class MultilayerPerceptronTrainBatchOp(BatchOperator, HasLabelCol, HasFeatureCol
             fit.set(rows=int(t.num_rows), steps=int(steps))
         count_fit("alink_mlp", moments_rows, went, int(steps), col,
                   paths["walk"])
+        if metrics_enabled() and "rows_trace" in went:
+            # the line passes' blocks by the path their rungs took
+            get_registry().inc("alink_mlp_rung_blocks_total",
+                               len(went["rows_trace"]) * col.blocks.shape[0],
+                               {"walk": paths["rungs"]})
         went.update(loss_curve=np.asarray(curve), steps=int(steps),
                     mean=mean, std=std, rows=int(t.num_rows),
                     moments_rows=moments_rows, paths=paths, l2=l2,

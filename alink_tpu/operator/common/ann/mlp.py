@@ -25,7 +25,10 @@ which from the table, nothing sets it); every product of two floats
 (:func:`dot_highest`).
 Only layer 1 is linear in the step, ``z1(a) = z1 - a (X . D1 + d_b1)``, so
 the line pass takes ONE product of the stacked ``[W1; D1]`` against a block
-and runs the ladder's rungs from there.
+and runs the ladder's rungs from there: where Pallas runs, as ONE kernel a
+block (``kernels/mlp_rungs.py``: a tile of ``z1`` and ``zd1`` read once,
+every rung run on it), else as a ``lax.map`` of a rung (:func:`rung_path`
+reads which from the input, nothing sets it).
 """
 
 from __future__ import annotations
@@ -38,6 +41,7 @@ import jax.numpy as jnp
 
 from ....common.columnar import DenseBlockColumn
 from ....kernels import mlp as layer1
+from ....kernels import mlp_rungs
 from ..blocked import block_at
 from ..optim.objfunc import (OptimObjFunc, _walk, block_backward,
                              block_forward, fold_coef, pack_dense,
@@ -87,6 +91,16 @@ def walk_path(X, layer_sizes: Sequence[int]) -> str:
     line pass's stacked ``[W1; D1]``, the larger product."""
     return layer1.walk_path(X.dtype, X.shape[1], X.shape[2],
                             2 * layer_sizes[1])
+
+
+def rung_path(X, layer_sizes: Sequence[int], dtype) -> str:
+    """Which path the line pass's ladder takes over a table ``X`` (blocks
+    ``(nbl, d, S, 128)``, or anything that has their ``shape``) in
+    ``dtype``: ``"kernel"`` (``kernels/mlp_rungs.py``, every rung of a
+    block from one read of its ``[z1; zd1]``) or ``"xla"`` (``lax.map`` of
+    a rung), read from the input whatever walk layer 1 takes: both hand
+    the rungs float ``z1`` and ``zd1``."""
+    return mlp_rungs.rung_path(layer_sizes, X.shape[2] * X.shape[3], dtype)
 
 
 def dot_highest(spec: str, a, b):
@@ -232,19 +246,27 @@ class MlpObjFunc(OptimObjFunc):
         direction (num_steps,), rows)``. Layer 1 is linear in the step, so
         a block's ``[z1; zd1]`` is one product of the stacked ``[W1;
         D1]``; each rung then runs the sigmoid, the upper layers at
-        ``coef - step * direction`` and the loss."""
+        ``coef - step * direction`` and the loss, all the rungs of a block
+        in one kernel call on the ``kernel`` path (:func:`rung_path`), which
+        takes its float product from :func:`dot_highest` as the line pass
+        is traced."""
         layers = unstack(coef, self.layer_sizes)
         dirs = unstack(direction, self.layer_sizes)
         (W1, b1), (D1, db1) = layers[0], dirs[0]
         A, fb = fold_coef(data, jnp.concatenate([W1.T, D1.T]))
         bias = fb + jnp.concatenate([b1, db1])
         walk = walk_path(data["X"], self.layer_sizes)
+        rungs = rung_path(data["X"], self.layer_sizes, coef.dtype)
 
         def block(i):
             y, w, rows = self._rows(data, i)
             forward, _ = self._layer1(data, i, walk)
             with jax.named_scope("mlp_line"):
                 z1, zd1 = forward(A, bias, 2)
+                if rungs == "kernel":
+                    losses = mlp_rungs.losses(z1, zd1, y, w, layers[1:],
+                                              dirs[1:], steps, dot_highest)
+                    return (losses,), (w != 0).sum(dtype=jnp.int32), None
 
                 def rung(s):
                     upper = layers[:1] + [

@@ -188,7 +188,8 @@ def test_a_byte_table_fit_through_the_operator_agrees_with_the_reference():
     info = dict(op.get_train_info())
     assert info["paths"] == {"design": "blocks:uint8",
                              "moments": "linear_moments",
-                             "pass": "blocked:bf16x3", "walk": "xla"}
+                             "pass": "blocked:bf16x3", "walk": "xla",
+                             "rungs": "xla"}
     dim = M.stack_sizes(LAYERS)
     assert info["coef_trace"].shape == (4, dim) == info["dir_trace"].shape
     assert info["moments_rows"] == n and (info["rows_trace"] == n).all()
@@ -304,7 +305,8 @@ def test_a_fit_records_its_spans_and_counts_its_rows():
     assert len(events) <= 40
     opt = next(e for e in events if e["name"] == "mlp.optimize")
     assert opt["args"] == {"layers": LAYERS, "max_iter": 5,
-                           "pass": "blocked:bf16x3", "walk": "xla"}
+                           "pass": "blocked:bf16x3", "walk": "xla",
+                           "rungs": "xla"}
     rows, steps, fits, passes = (count(c) - b for c, b in zip(names, before))
     assert (rows, steps, fits, passes) == (n * 11, 5, 1, 11)
 

@@ -204,7 +204,8 @@ def test_a_fit_says_which_walk_ran(fits):
         info, args, added = fits[walk]
         assert info["paths"] == {"design": "blocks:uint8",
                                  "moments": "linear_moments",
-                                 "pass": "blocked:bf16x3", "walk": walk}
+                                 "pass": "blocked:bf16x3", "walk": walk,
+                                 "rungs": "xla"}
         assert args["walk"] == walk
         assert added == {("grad", walk): 3 * 2, ("line", walk): 3 * 2}
 

@@ -387,17 +387,20 @@ def _mlp_step(monkeypatch, topo, h1):
     """``jit_mlp_qn`` of ``mlp-fit`` (124 blocks of 784 x 512 x 128 uint8,
     layers 784-``h1``-150-10, history 10, 5 supersteps) lowered from
     shapes and compiled for one chip, where Pallas can run (the rig's
-    backend is the CPU, so the test says so in the kernel module's
-    place): ``(walk, text, memory analysis)``."""
+    backend is the CPU, so the test says so in the kernel modules'
+    place): ``(walk, rungs, text, memory analysis)``."""
     import jax
     import jax.numpy as jnp
     from alink_tpu.common.mlenv import MLEnvironment
     from alink_tpu.kernels import mlp as kernel
-    from alink_tpu.operator.common.ann.mlp import MlpObjFunc, walk_path
+    from alink_tpu.kernels import mlp_rungs
+    from alink_tpu.operator.common.ann.mlp import (MlpObjFunc, rung_path,
+                                                   walk_path)
     from alink_tpu.operator.common.optim import optimizers as O
 
-    monkeypatch.setattr(kernel, "pallas_available", lambda: True)
-    monkeypatch.setattr(kernel, "interpret_mode", lambda: False)
+    for module in (kernel, mlp_rungs):
+        monkeypatch.setattr(module, "pallas_available", lambda: True)
+        monkeypatch.setattr(module, "interpret_mode", lambda: False)
     env = MLEnvironment(parallelism=1, devices=list(topo.devices[:1]))
     nb, d, S = 124, 784, 512
     layers = [d, h1, 150, 10]
@@ -417,7 +420,18 @@ def _mlp_step(monkeypatch, topo, h1):
     table = nb * d * S * 128
     mem = step.memory_analysis()
     assert table < mem.argument_size_in_bytes < table + 0.1e9
-    return walk_path(parts["X"], layers), text, mem
+    return (walk_path(parts["X"], layers),
+            rung_path(parts["X"], layers, np.float32), text, mem)
+
+
+def _mlp_kernel_names(text):
+    return sorted(re.search(r"%(mlp_\w+?)\.\d+ = ", c).group(1)
+                  for c in _kernel_calls(text))
+
+
+#: the temporaries of ``jit_mlp_qn`` at the cell's shapes with the ladder's
+#: rungs as XLA's ``lax.map`` of a rung, compiled the same way
+_MLP_TEMP_XLA_RUNGS = 492_090_880
 
 
 def test_mlp_step_program_compiles_beside_the_byte_table(monkeypatch, topo):
@@ -428,36 +442,91 @@ def test_mlp_step_program_compiles_beside_the_byte_table(monkeypatch, topo):
     product of the three stacked parts is left to XLA (the XLA walk's
     ``f32[1500,65536]`` / ``f32[3000,65536]`` / ``f32[1500,784]``), no
     block is copied out of the table, and the line pass's ``[z1; zd1]``
-    come out of the kernel as two arrays that nothing slices. Everything a
-    superstep makes beside the table stays under 0.6 GB (compiled here: 492
-    MB of temporaries and 77 MB of outputs, where the XLA walk's asked for
-    1,279 MB of temporaries)."""
-    walk, text, mem = _mlp_step(monkeypatch, topo, 500)
-    assert walk == "kernel"
+    come out of the kernel as two arrays that nothing slices. The ladder's
+    rungs are the ``mlp_rungs`` call, fed those two arrays: XLA writes no
+    rung's activations (of the fusions that make a ``(500, 65,536)``
+    float32 array, the gradient pass's four are left, where the XLA rungs
+    added one a rung loop) and takes no rung's products (the ``(10,
+    65,536)`` logits are the gradient pass's two, where the XLA rungs
+    added two). Everything a superstep makes beside the table stays under
+    0.6 GB, and the temporaries fall below the XLA rungs' (compiled here:
+    489.8 MB against 492.1, and 77 MB of outputs)."""
+    walk, rungs, text, mem = _mlp_step(monkeypatch, topo, 500)
+    assert (walk, rungs) == ("kernel", "kernel")
+    assert _mlp_kernel_names(text) == ["mlp_backward"] * 2 \
+        + ["mlp_forward"] * 4 + ["mlp_rungs"] * 2
     calls = _kernel_calls(text)
-    names = sorted(re.search(r"%(mlp_\w+?)\.\d+ = ", c).group(1)
-                   for c in calls)
-    assert names == ["mlp_backward"] * 2 + ["mlp_forward"] * 4, names
-    assert all("u8[124,784,512,128]" in c for c in calls)
+    assert all("u8[124,784,512,128]" in c for c in calls
+               if "mlp_rungs" not in c)
+    assert all(c.count("f32[500,65536]") >= 2 for c in calls
+               if "mlp_rungs" in c)
     for shape in ("1500,65536", "3000,65536", "1500,784"):
         assert not re.search(rf"= f32\[{shape}\]\S* convolution\(", text)
     assert not re.search(r"= \w+\[784,512,128\]", text)        # a block
     assert not re.search(r"= f32\[500,65536\]\S* slice\(", text)
+    assert len(re.findall(r"= f32\[500,65536\]\S* fusion\(", text)) == 4
+    assert len(re.findall(r"= f32\[10,65536\]\S* convolution\(", text)) == 2
     assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 0.6e9, (
         mem.temp_size_in_bytes, mem.output_size_in_bytes)
+    assert mem.temp_size_in_bytes < _MLP_TEMP_XLA_RUNGS, mem.temp_size_in_bytes
 
 
 def test_mlp_step_program_compiles_over_the_kernels_budget(monkeypatch,
                                                            topo):
     """A layer 1 of 1,000 units: the line pass's stack of 2,000 rows is
-    over the kernels' VMEM budget, so ``walk_path`` reads ``xla`` and the
-    step program lowers the XLA walk, products of the three stacked parts
-    against the block as before, no Mosaic call."""
-    walk, text, _ = _mlp_step(monkeypatch, topo, 1000)
-    assert walk == "xla"
-    assert _kernel_calls(text) == []
+    over the layer-1 kernels' VMEM budget, so ``walk_path`` reads ``xla``
+    and the step program lowers the XLA walk, products of the three
+    stacked parts against the block as before, no ``mlp_forward`` /
+    ``mlp_backward`` call. The rungs' rule does not ask which walk layer
+    1 took (both hand the rungs float ``z1`` and ``zd1``): they are the
+    ``mlp_rungs`` call here too."""
+    walk, rungs, text, _ = _mlp_step(monkeypatch, topo, 1000)
+    assert (walk, rungs) == ("xla", "kernel")
+    assert _mlp_kernel_names(text) == ["mlp_rungs"] * 2
     assert re.search(r"= f32\[6000,65536\]\S* convolution\(", text)
     assert re.search(r"= f32\[3000,784\]\S* convolution\(", text)
+
+
+@pytest.mark.parametrize("sizes, rows", [
+    ([500, 150, 10], 65536),
+    ([500, 10], 65536),
+    ([500, 2632, 10], 65536),
+    ([3183, 150, 10], 65536),
+    ([24, 12, 10], 384),
+], ids=["the_cell", "one_hidden_layer", "the_widest_layer_2",
+        "the_widest_layer_1", "tiles_of_one_lane_row"])
+def test_mlp_rung_kernel_compiles_over_rung_paths_envelope(one_chip, sizes,
+                                                           rows):
+    """Mosaic takes the rung kernel at the corners of the envelope in
+    which ``kernels/mlp_rungs.rung_path`` answers ``kernel`` (on a TPU a
+    kernel it refused would raise where the XLA rungs ran before): the
+    cell's 500-150-10 over a block of 65,536 rows, one hidden layer, the
+    widest layer 2 the VMEM budget holds at the cell's layer 1 (2,632
+    units; 2,640 reads ``xla``), the widest layer 1 at the cell's layer 2
+    (3,183), and a block of 384 rows (tiles of 128). Every shape reads
+    ``kernel`` from ``rung_path`` itself."""
+    import jax
+    import jax.numpy as jnp
+    from alink_tpu.kernels import mlp_rungs
+    from alink_tpu.operator.common.ann.mlp import dot_highest
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(mlp_rungs, "pallas_available", lambda: True)
+        assert mlp_rungs.rung_path([784] + sizes, rows, "float32") == "kernel"
+
+    def sd(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    pairs = list(zip(sizes[:-1], sizes[1:]))
+    uppers = tuple((sd((o, i)), sd((o, 1)), sd((o, i)), sd((o, 1)))
+                   for i, o in pairs)
+    with jax.enable_x64(False):
+        compiled = jax.jit(lambda z1, zd1, y, w, up, s: mlp_rungs._losses(
+            z1, zd1, y, w, up, s, dot=dot_highest, interpret=False)).lower(
+                sd((sizes[0], rows)), sd((sizes[0], rows)),
+                sd((1, rows), jnp.int32), sd((1, rows)), uppers,
+                sd((11,))).compile()
+    call, = _kernel_calls(compiled.as_text())
+    assert "mlp_rungs" in call and f"f32[{sizes[0]},{rows}]" in call, call
 
 
 @pytest.mark.parametrize("chips", [1, 4])
