@@ -279,16 +279,6 @@ def _fused_hist_parse(raw: str) -> str:
     return "xla"
 
 
-def _ftrl_kernel_parse(raw: str) -> str:
-    """Normalize ``ALINK_TPU_FTRL_KERNEL``: falsy OR "xla" -> "off"
-    (the XLA gather/scatter IS the flag-off path, and the sibling
-    ``ALINK_TPU_FUSED_HIST`` taught users that "xla" names it); any
-    other truthy value -> "pallas". Backend gating stays with
-    ``kernels/ftrl.ftrl_kernel_mode``."""
-    v = raw.strip().lower()
-    return "off" if v in _FALSY or v == "xla" else "pallas"
-
-
 def _serve_dtype_parse(raw: str) -> str:
     """Normalize ``ALINK_TPU_SERVE_DTYPE``: falsy -> "f32" (the full
     ship precision); bf16/bfloat16 -> "bf16"; int8/i8 -> "int8";
@@ -522,18 +512,9 @@ FLAGS.register(
     "availability gate of the whole kernel tier (kernels/runtime.py)",
     "performance",
     key_neutral="only shifts the RESOLVED kernel modes (fused-hist, "
-                "FTRL kernel, fused serve), and the resolved modes are "
-                "what fold into the program/step/serving cache keys",
+                "fused serve), and the resolved modes are what fold "
+                "into the program/serving cache keys",
     accessor="alink_tpu.kernels.runtime.pallas_interpret")
-FLAGS.register(
-    "ALINK_TPU_FTRL_KERNEL", "mode", "off",
-    "Pallas FTRL kernel tier: off | pallas — VMEM-resident (z, n) "
-    "state gather / duplicate-safe scatter-add in the per-sample and "
-    "staleness step programs, triangular chained-correction matvec in "
-    "the chained step program", "performance",
-    folds_into=frozenset({STEP_LRU, CHECKPOINT_SIGNATURE}),
-    parser=_ftrl_kernel_parse,
-    accessor="alink_tpu.kernels.ftrl.ftrl_kernel_mode")
 FLAGS.register(
     "ALINK_TPU_AOT_CACHE", "bool", True,
     "persistent AOT executable store (common/aotcache.py): serve "

@@ -261,29 +261,11 @@ def ftrl_plan(*, mesh: Any, alpha: float, beta: float, l1: float,
               staleness: int, chunk_size: int, has_intercept: bool,
               warm_fp: str) -> ExecutionPlan:
     """The FTRL drain's plan: hyperparameters + geometry + the resolved
-    key-folding flags (``ALINK_TPU_FTRL_KERNEL`` mode,
-    ``ALINK_TPU_DONATE``), latched ONCE per drain at this single
-    ENV-KEY-FOLD-checked site.
-
-    ``kernel_resolved`` is the availability-probed tier the chained
-    signature folds ("pallas" only when the triangular kernel can
-    actually run at this chunk length/dtype — the probe-demoted drain
-    keeps the flag-off signature, same numbers, interchangeable
-    snapshots)."""
+    key-folding flag ``ALINK_TPU_DONATE``, latched ONCE per drain at this
+    single ENV-KEY-FOLD-checked site."""
     from ..engine.comqueue import donation_enabled
-    from ..kernels.ftrl import chained_kernel_available, ftrl_kernel_mode
 
     chained = update_mode == "chained"
-    kern = ftrl_kernel_mode()
-    resolved = "off"
-    if chained and kern == "pallas":
-        import jax as _jx
-        import numpy as _np
-        if chained_kernel_available(
-                int(chunk_size),
-                _np.float64 if _jx.config.jax_enable_x64
-                else _np.float32):
-            resolved = "pallas"
     return ExecutionPlan("ftrl", (
         ("mesh", mesh),
         ("alpha", alpha), ("beta", beta), ("l1", l1), ("l2", l2),
@@ -294,8 +276,6 @@ def ftrl_plan(*, mesh: Any, alpha: float, beta: float, l1: float,
         ("chunk_size", int(chunk_size) if chained else None),
         ("has_intercept", bool(has_intercept)),
         ("warm_coef_blake2b", str(warm_fp)),
-        ("ALINK_TPU_FTRL_KERNEL", kern),
-        ("kernel_resolved", resolved),
         ("ALINK_TPU_DONATE", donation_enabled()),
     ))
 
@@ -303,9 +283,9 @@ def ftrl_plan(*, mesh: Any, alpha: float, beta: float, l1: float,
 def ftrl_checkpoint_signature(plan: ExecutionPlan) -> Dict[str, Any]:
     """The FTRL stream's resume signature, derived from the plan —
     content IDENTICAL to the historical hand-built ``ck_signature``
-    dict, including the conditional keys (chained-only ``chunk_size`` /
-    ``ftrl_kernel``), so every pre-existing snapshot keeps its exact
-    signature and stays resumable."""
+    dict, including the conditional chained-only ``chunk_size``, so every
+    pre-existing snapshot keeps its exact signature and stays
+    resumable."""
     sig: Dict[str, Any] = {
         "kind": "ftrl_state",
         "alpha": plan.get("alpha"), "beta": plan.get("beta"),
@@ -318,8 +298,6 @@ def ftrl_checkpoint_signature(plan: ExecutionPlan) -> Dict[str, Any]:
     }
     if plan.get("update_mode") == "chained":
         sig["chunk_size"] = plan.get("chunk_size")
-        if plan.get("kernel_resolved") == "pallas":
-            sig["ftrl_kernel"] = "pallas"
     return sig
 
 

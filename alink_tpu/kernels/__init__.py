@@ -6,9 +6,6 @@ ONE availability/demotion contract they all ride (``runtime``):
 
 * ``runtime``  — availability (TPU or ``ALINK_TPU_PALLAS_INTERPRET=1``),
   one-time-warn demotion, eager shape-class probing;
-* ``ftrl``     — the sparse FTRL state gather / duplicate-safe
-  scatter-add kernels (VMEM-resident (z, n) slot tiles) and the
-  chained-correction triangular matvec (``ALINK_TPU_FTRL_KERNEL``);
 * ``serve``    — the fused encode-gather -> dot -> link serving score
   kernel (``ALINK_TPU_SERVE_FUSED``) and the opt-in bf16/int8
   low-precision score path (``ALINK_TPU_SERVE_DTYPE``);

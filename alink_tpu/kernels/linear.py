@@ -164,14 +164,43 @@ def _spread(b, mp: int):
         jnp.pad(b.astype(jnp.float32), (0, mp - m))[:, None], (mp, _LANES))
 
 
+def _widen_run(flat, q, f, words: int, width: int, signed: bool):
+    """Word ``q`` of features ``f … f + width - 1`` of a byte block
+    widened to bfloat16, ``(width, 512)``: rows ``4 q … 4 q + 3`` of the
+    block side by side, a row 128 lanes. ``flat`` is the block seen as
+    32-bit words, ``(d · words, 128)``, ``words`` of them a feature: the
+    run is ONE strided load (a register of eight features), then a byte
+    is shifted out of each word, unsigned by a logical shift and a mask,
+    signed by a shift to the word's top and an arithmetic shift back
+    (which extends its sign), and widened. Both byte kernels' walks
+    (``_word_product`` here, ``kernels/mlp.py``) read a block through
+    it."""
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental import pallas as pl
+    W = flat[pl.ds(q + f * words, width, stride=words), :]
+    top = _PACK - 1                     # a word's highest byte
+    parts = []
+    for j in range(_PACK):
+        if signed:
+            v = W if j == top else lax.shift_left(
+                W, jnp.int32(8 * (top - j)))
+            v = lax.shift_right_arithmetic(v, jnp.int32(8 * top))
+        else:
+            v = W if j == 0 else lax.shift_right_logical(
+                W, jnp.int32(8 * j))
+            if j < top:
+                v = v & 0xFF
+        parts.append(v.astype(jnp.float32).astype(jnp.bfloat16))
+    return jnp.concatenate(parts, 1)
+
+
 def _word_product(x_ref, a_ref, q, xs_ref=None):
     """``a . x`` over the four row groups of word ``q`` of a byte block:
     ``x_ref`` ``(1, d, Sb, 128)`` bytes, ``a_ref`` ``(runs, rows, size)``
     bfloat16 (:func:`_stack`); returns ``(rows, 512)`` float32, a row
-    group 128 lanes. The block is seen as 32-bit words, flat: a run of
-    features is ONE strided load a register of eight, then two bit
-    operations and a widening a byte (signed where the table's bytes
-    are), ``(size, 512)`` bfloat16, kept in ``xs_ref`` ``(d, 512)`` where
+    group 128 lanes. A run of features is widened by :func:`_widen_run`,
+    ``(size, 512)`` bfloat16, kept in ``xs_ref`` ``(d, 512)`` where
     given. The loop over the runs is traced once and unrolled when it is
     lowered (``kernels/kmeans.py``)."""
     import jax.numpy as jnp
@@ -182,24 +211,10 @@ def _word_product(x_ref, a_ref, q, xs_ref=None):
     flat = x_ref.bitcast(jnp.int32).reshape(d * words, L)
     signed = jnp.issubdtype(x_ref.dtype, jnp.signedinteger)
     runs, size, last = _chunks(d)
-    top = _PACK - 1                     # a word's highest byte
 
     def run(c, p, width):
         f = pl.multiple_of(c * size, 2 * _TILE) if runs > 1 else 0
-        W = flat[pl.ds(q + f * words, width, stride=words), :]
-        parts = []
-        for j in range(_PACK):
-            if signed:
-                v = W if j == top else lax.shift_left(
-                    W, jnp.int32(8 * (top - j)))
-                v = lax.shift_right_arithmetic(v, jnp.int32(8 * top))
-            else:
-                v = W if j == 0 else lax.shift_right_logical(
-                    W, jnp.int32(8 * j))
-                if j < top:
-                    v = v & 0xFF
-            parts.append(v.astype(jnp.float32).astype(jnp.bfloat16))
-        x = jnp.concatenate(parts, 1)              # (width, 512)
+        x = _widen_run(flat, q, f, words, width, signed)
         if xs_ref is not None:
             xs_ref[pl.ds(f, width), :] = x
         return p + jnp.dot(a_ref[c][:, :width], x,

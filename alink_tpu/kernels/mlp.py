@@ -38,7 +38,7 @@ from __future__ import annotations
 
 import functools
 
-from .linear import _chunks, split3
+from .linear import _chunks, _widen_run, split3
 from .runtime import interpret_mode, pallas_available
 
 __all__ = ["walk_path", "forward", "backward"]
@@ -103,11 +103,8 @@ def walk_path(dtype, d: int, S: int, m: int) -> str:
 
 def _widen(x_ref, xs_ref) -> None:
     """The grid step's word ``q`` of a byte tile ``x_ref`` ``(1, d, 32,
-    128)`` widened to bfloat16 into ``xs_ref`` ``(d, 512)``: rows ``4 q …
-    4 q + 3`` of the tile side by side, a row 128 lanes, in the table's
-    row order. A run of features is ONE strided load of the tile seen as
-    32-bit words (a register of eight features), then two bit operations
-    and a widening a byte (signed where the table's bytes are)."""
+    128)`` widened to bfloat16 into ``xs_ref`` ``(d, 512)``, a run of
+    features at a time (``kernels/linear._widen_run``)."""
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
@@ -117,24 +114,11 @@ def _widen(x_ref, xs_ref) -> None:
     flat = x_ref.bitcast(jnp.int32).reshape(d * words, L)
     signed = jnp.issubdtype(x_ref.dtype, jnp.signedinteger)
     runs, size, last = _chunks(d)
-    top = _PACK - 1                     # a word's highest byte
 
     def run(c, carry, width):
         f = pl.multiple_of(c * size, _ROWS) if runs > 1 else 0
-        W = flat[pl.ds(q + f * words, width, stride=words), :]
-        parts = []
-        for j in range(_PACK):
-            if signed:
-                v = W if j == top else lax.shift_left(
-                    W, jnp.int32(8 * (top - j)))
-                v = lax.shift_right_arithmetic(v, jnp.int32(8 * top))
-            else:
-                v = W if j == 0 else lax.shift_right_logical(
-                    W, jnp.int32(8 * j))
-                if j < top:
-                    v = v & 0xFF
-            parts.append(v.astype(jnp.float32).astype(jnp.bfloat16))
-        xs_ref[pl.ds(f, width), :] = jnp.concatenate(parts, 1)
+        xs_ref[pl.ds(f, width), :] = _widen_run(flat, q, f, words, width,
+                                               signed)
         return carry
 
     whole = runs if last == size else runs - 1

@@ -178,49 +178,6 @@ def _ftrl_step_factory(mesh, alpha, beta, l1, l2, donate=False):
                  role="weights", in_specs=(P("d"), P("d")), **_hp))
 
 
-def _kernel_check_vma(kernel: str):
-    """``shard_map``'s ``check_vma`` for a step program under the
-    RESOLVED kernel mode. A ``pl.pallas_call`` traces its kernel body
-    outside the manual-axes context — loaded blocks are typed varying,
-    values computed from them are not — so the varying-axes check cannot
-    type a kernel-tier program (jax 0.9.0); the XLA path keeps the
-    default check."""
-    return None if kernel == "off" else False
-
-
-def _state_kernels(kernel: str):
-    """The state gather / duplicate-safe scatter-add pair under the
-    RESOLVED FTRL kernel mode (``kernels/ftrl.py``, ISSUE 13).
-
-    ``"off"`` returns the verbatim XLA ops — routing through these
-    thunks stages the exact pre-kernel-tier primitive sequence, so the
-    flag-off lowered HLO stays byte-identical (tests/test_kernels.py).
-    ``"pallas"`` returns the VMEM-resident Pallas kernels, with an
-    eager shape-class probe at trace time: a probe failure demotes THIS
-    shape class to the XLA ops (one-time warning via
-    ``kernels/runtime.demote_once``) — bitwise-identical output either
-    way, so a demoted program can never poison the lru cache."""
-    if kernel == "pallas":
-        from ....kernels.ftrl import (gather_rows, probe_scatter,
-                                      scatter_add_rows)
-
-        def _gather(st, flat):
-            C = st.shape[1] if st.ndim > 1 else 1
-            if probe_scatter(st.shape[0], C, st.dtype):
-                return gather_rows(st, flat)
-            return st[flat]
-
-        def _scatter(st, flat, upd):
-            C = st.shape[1] if st.ndim > 1 else 1
-            if probe_scatter(st.shape[0], C, st.dtype):
-                return scatter_add_rows(st, flat, upd)
-            return st.at[flat].add(upd)
-
-        return _gather, _scatter
-    return (lambda st, flat: st[flat],
-            lambda st, flat, upd: st.at[flat].add(upd))
-
-
 @functools.lru_cache(maxsize=64)
 def _ftrl_sparse_step_factory(mesh, alpha, beta, l1, l2, donate=False,
                               kernel="off"):
@@ -249,7 +206,14 @@ def _ftrl_sparse_step_factory(mesh, alpha, beta, l1, l2, donate=False,
     its own slots with ONE contiguous update; the state is read once
     before the rounds and written once after them, at distinct
     coordinates only (``ftrl_workset`` / ``ftrl_scatter``).
+
+    ``kernel`` takes ``"off"`` alone, the one path there is: the
+    benchmark's planted-fault test (``tests/benchmark_suite/
+    test_bench_cells.py``) still passes it.
     """
+    if kernel != "off":
+        raise ValueError(f"kernel={kernel!r}: the sparse FTRL step has "
+                         f"one path, 'off'")
     import jax
     import jax.numpy as jnp
     from ....common.compat import shard_map
@@ -261,7 +225,6 @@ def _ftrl_sparse_step_factory(mesh, alpha, beta, l1, l2, donate=False,
 
     K = 4        # samples per scan round
     BLK = 2048   # distinct coordinates a block of the state gather / write-back
-    _sgather, _ = _state_kernels(kernel)
     HI = jax.lax.Precision.HIGHEST
 
     def shard_fn(idx, val, y, z, n):
@@ -320,9 +283,9 @@ def _ftrl_sparse_step_factory(mesh, alpha, beta, l1, l2, donate=False,
                 at, ci = block(b)
                 ci = jnp.minimum(ci, shard - 1)
                 return (jax.lax.dynamic_update_slice(
-                            Tz, _sgather(z, ci), (E + at,)),
+                            Tz, z[ci], (E + at,)),
                         jax.lax.dynamic_update_slice(
-                            Tn, _sgather(n, ci), (E + at,)))
+                            Tn, n[ci], (E + at,)))
 
             T = jax.lax.fori_loop(0, nblk, load, (T0, T0))
 
@@ -401,17 +364,16 @@ def _ftrl_sparse_step_factory(mesh, alpha, beta, l1, l2, donate=False,
 
     fn = shard_map(shard_fn, mesh=mesh,
                    in_specs=(P(), P(), P(), P("d"), P("d")),
-                   out_specs=(P("d"), P("d"), P()),
-                   check_vma=_kernel_check_vma(kernel))
+                   out_specs=(P("d"), P("d"), P()))
     return _aot(jax.jit(fn, donate_argnums=(3, 4) if donate else ()),
                 "_ftrl_sparse_step_factory", mesh,
                 in_specs=(P(), P(), P(), P("d"), P("d")), alpha=alpha,
-                beta=beta, l1=l1, l2=l2, donate=donate, kernel=kernel)
+                beta=beta, l1=l1, l2=l2, donate=donate)
 
 
 @functools.lru_cache(maxsize=64)
 def _ftrl_sparse_chained_step_factory(mesh, alpha, beta, l1, l2, K=16,
-                                      donate=False, kernel="off"):
+                                      donate=False):
     """Chained-correction strict FTRL — EXACT strict semantics at chunked
     throughput (``update_mode="chained"``).
 
@@ -456,10 +418,6 @@ def _ftrl_sparse_chained_step_factory(mesh, alpha, beta, l1, l2, K=16,
     def weights(z, n):
         return _ftrl_weights(z, n, alpha, beta, l1, l2)
 
-    _sgather, _sscatter = _state_kernels(kernel)
-    if kernel == "pallas":
-        from ....kernels.ftrl import chained_corr, chained_kernel_available
-
     def shard_fn(idx, val, y, z, n):
         shard = z.shape[0]
         lo = jax.lax.axis_index("d") * shard
@@ -469,12 +427,6 @@ def _ftrl_sparse_chained_step_factory(mesh, alpha, beta, l1, l2, K=16,
             idx = jnp.concatenate([idx, jnp.zeros((Bp - B, w), idx.dtype)])
             val = jnp.concatenate([val, jnp.zeros((Bp - B, w), val.dtype)])
             y = jnp.concatenate([y, jnp.zeros((Bp - B,), y.dtype)])
-        # resolved at the CANONICAL probe width, never per batch width:
-        # the chained checkpoint signature folds on exactly this
-        # predicate, and a width-dependent demotion would change the
-        # accumulation association mid-stream under one signature
-        use_tri = kernel == "pallas" and chained_kernel_available(
-            K, val.dtype)
 
         def body(carry, xvy):
             z, n = carry
@@ -482,8 +434,8 @@ def _ftrl_sparse_chained_step_factory(mesh, alpha, beta, l1, l2, K=16,
             local = (xi >= lo) & (xi < lo + shard)
             li = jnp.clip(xi - lo, 0, shard - 1)
             flat = li.reshape(-1)
-            zs = jnp.where(local, _sgather(z, flat).reshape(K, w), 0.0)
-            ns = jnp.where(local, _sgather(n, flat).reshape(K, w), 0.0)
+            zs = jnp.where(local, z[flat].reshape(K, w), 0.0)
+            ns = jnp.where(local, n[flat].reshape(K, w), 0.0)
             # collision tensor, built once per chunk in parallel (not on
             # the dependent chain)
             M = ((xi[:, None, :, None] == xi[None, :, None, :])
@@ -493,17 +445,9 @@ def _ftrl_sparse_chained_step_factory(mesh, alpha, beta, l1, l2, K=16,
             margins = []
             for k in range(K):
                 # HIGHEST: bf16 MXU rounding of the f32 deltas would
-                # break the exact-strict-semantics claim under collisions.
-                # The triangular Pallas kernel contracts over exactly the
-                # k live delta rows (rows j >= k are structurally zero —
-                # dead flops the dense einsum pays every sample) in full
-                # input precision; association-only difference, inside
-                # the pinned chained tolerance
-                if use_tri:
-                    corr = chained_corr(M[k], D, k)
-                else:
-                    corr = jnp.einsum("jab,jbc->ac", M[k], D,
-                                      precision=jax.lax.Precision.HIGHEST)
+                # break the exact-strict-semantics claim under collisions
+                corr = jnp.einsum("jab,jbc->ac", M[k], D,
+                                  precision=jax.lax.Precision.HIGHEST)
                 zk = zs[k] + corr[:, 0]
                 nk = ns[k] + corr[:, 1]
                 wk = jnp.where(local[k], weights(zk, nk), 0.0)
@@ -517,8 +461,8 @@ def _ftrl_sparse_chained_step_factory(mesh, alpha, beta, l1, l2, K=16,
                     [jnp.where(local[k], g - sigma * wk, 0.0),
                      jnp.where(local[k], g * g, 0.0)], axis=-1))
                 margins.append(margin)
-            z = _sscatter(z, flat, D[..., 0].reshape(-1))
-            n = _sscatter(n, flat, D[..., 1].reshape(-1))
+            z = z.at[flat].add(D[..., 0].reshape(-1))
+            n = n.at[flat].add(D[..., 1].reshape(-1))
             return (z, n), jnp.stack(margins)
 
         (z, n), margins = jax.lax.scan(
@@ -529,18 +473,16 @@ def _ftrl_sparse_chained_step_factory(mesh, alpha, beta, l1, l2, K=16,
 
     fn = shard_map(shard_fn, mesh=mesh,
                    in_specs=(P(), P(), P(), P("d"), P("d")),
-                   out_specs=(P("d"), P("d"), P()),
-                   check_vma=_kernel_check_vma(kernel))
+                   out_specs=(P("d"), P("d"), P()))
     return _aot(jax.jit(fn, donate_argnums=(3, 4) if donate else ()),
                 "_ftrl_sparse_chained_step_factory", mesh,
                 in_specs=(P(), P(), P(), P("d"), P("d")), alpha=alpha,
-                beta=beta, l1=l1, l2=l2, K=K, donate=donate,
-                kernel=kernel)
+                beta=beta, l1=l1, l2=l2, K=K, donate=donate)
 
 
 @functools.lru_cache(maxsize=64)
 def _ftrl_sparse_staleness_step_factory(mesh, alpha, beta, l1, l2, K,
-                                        donate=False, kernel="off"):
+                                        donate=False):
     """Bounded-staleness sparse FTRL — the reference's ACTUAL feedback-edge
     semantics, made explicit and measured.
 
@@ -572,8 +514,6 @@ def _ftrl_sparse_staleness_step_factory(mesh, alpha, beta, l1, l2, K,
     def weights(z, n):
         return _ftrl_weights(z, n, alpha, beta, l1, l2)
 
-    _sgather, _sscatter = _state_kernels(kernel)
-
     def shard_fn(idx, val, y, z, n):
         shard = z.shape[0]
         lo = jax.lax.axis_index("d") * shard
@@ -590,7 +530,7 @@ def _ftrl_sparse_staleness_step_factory(mesh, alpha, beta, l1, l2, K,
             local = (xi >= lo) & (xi < lo + shard)
             li = jnp.clip(xi - lo, 0, shard - 1)
             flat = li.reshape(-1)
-            s = _sgather(zn, flat).reshape(K, w, 2)
+            s = zn[flat].reshape(K, w, 2)
             zj = jnp.where(local, s[..., 0], 0.0)
             nj = jnp.where(local, s[..., 1], 0.0)
             wj = jnp.where(local, weights(zj, nj), 0.0)
@@ -602,9 +542,8 @@ def _ftrl_sparse_staleness_step_factory(mesh, alpha, beta, l1, l2, K,
             sigma = (jnp.sqrt(nj + g * g) - jnp.sqrt(nj)) / alpha
             dz = jnp.where(local, g - sigma * wj, 0.0)
             dn = jnp.where(local, g * g, 0.0)
-            zn = _sscatter(zn, flat,
-                           jnp.stack([dz.reshape(-1), dn.reshape(-1)],
-                                     axis=-1))
+            zn = zn.at[flat].add(
+                jnp.stack([dz.reshape(-1), dn.reshape(-1)], axis=-1))
             return zn, margins
 
         zn, margins = jax.lax.scan(
@@ -615,13 +554,11 @@ def _ftrl_sparse_staleness_step_factory(mesh, alpha, beta, l1, l2, K,
 
     fn = shard_map(shard_fn, mesh=mesh,
                    in_specs=(P(), P(), P(), P("d"), P("d")),
-                   out_specs=(P("d"), P("d"), P()),
-                   check_vma=_kernel_check_vma(kernel))
+                   out_specs=(P("d"), P("d"), P()))
     return _aot(jax.jit(fn, donate_argnums=(3, 4) if donate else ()),
                 "_ftrl_sparse_staleness_step_factory", mesh,
                 in_specs=(P(), P(), P(), P("d"), P("d")), alpha=alpha,
-                beta=beta, l1=l1, l2=l2, K=K, donate=donate,
-                kernel=kernel)
+                beta=beta, l1=l1, l2=l2, K=K, donate=donate)
 
 
 @functools.lru_cache(maxsize=64)
@@ -1051,19 +988,13 @@ class FtrlTrainStreamOp(StreamOperator, HasVectorCol, HasFeatureCols, HasLabelCo
                 np.ascontiguousarray(np.asarray(init.coef)).tobytes(),
                 digest_size=12).hexdigest()
         # ONE ExecutionPlan per drain (ROADMAP item 1): hyperparameters,
-        # geometry and the key-folding flags — ALINK_TPU_FTRL_KERNEL
-        # (the resolved tier mode the step factories fold into their lru
-        # keys, so toggling never serves a stale step program; the
-        # chained signature folds the availability-PROBED tier, so a
-        # probe-demoted drain keeps the flag-off signature and its
-        # snapshots stay interchangeable) and ALINK_TPU_DONATE (the
-        # (z, n) buffer-aliasing contract rides every lru key) — both
-        # latched ONCE at the plan derivation site (common/plan.ftrl_plan, the
+        # geometry and the key-folding flag ALINK_TPU_DONATE (the (z, n)
+        # buffer-aliasing contract rides every lru key), latched ONCE at
+        # the plan derivation site (common/plan.ftrl_plan, the
         # ENV-KEY-FOLD checked site).  The resume signature derives from
         # the same plan, content-identical to the historical dict
-        # (conditional chained-mode keys included), so every
-        # pre-existing snapshot keeps its exact signature and stays
-        # resumable.
+        # (the chained-mode key included), so every pre-existing
+        # snapshot keeps its exact signature and stays resumable.
         from ....common import compileledger
         from ....common import plan as planlib
         fplan = planlib.ftrl_plan(
@@ -1072,7 +1003,6 @@ class FtrlTrainStreamOp(StreamOperator, HasVectorCol, HasFeatureCols, HasLabelCo
             staleness=staleness, chunk_size=chunk_size,
             has_intercept=bool(has_icpt), warm_fp=_warm_fp)
         ck_signature = planlib.ftrl_checkpoint_signature(fplan)
-        kern = fplan.get("ALINK_TPU_FTRL_KERNEL")
         compileledger.subsystem_start("ftrl")
         allow_fb = [True]    # cleared once the state commits to std layout
         sparse_step = [None]                # built lazily (sparse input only)
@@ -1618,7 +1548,7 @@ class FtrlTrainStreamOp(StreamOperator, HasVectorCol, HasFeatureCols, HasLabelCo
                           sparse_step[0] = _step_lookup(
                               _ftrl_sparse_staleness_step_factory,
                               (mesh, alpha, beta, l1, l2, staleness),
-                              "sparse_staleness", donate=don, kernel=kern)
+                              "sparse_staleness", donate=don)
                       elif update_mode == "chained":
                           # strict semantics through the chained-
                           # correction chunk kernel; dense rows keep the
@@ -1627,12 +1557,12 @@ class FtrlTrainStreamOp(StreamOperator, HasVectorCol, HasFeatureCols, HasLabelCo
                           sparse_step[0] = _step_lookup(
                               _ftrl_sparse_chained_step_factory,
                               (mesh, alpha, beta, l1, l2, chunk_size),
-                              "sparse_chained", donate=don, kernel=kern)
+                              "sparse_chained", donate=don)
                       else:
                           sparse_step[0] = _step_lookup(
                               _ftrl_sparse_step_factory,
                               (mesh, alpha, beta, l1, l2),
-                              "sparse", donate=don, kernel=kern)
+                              "sparse", donate=don)
                   z, n, mg = run_step(sparse_step[0], idx, val, y, z, n)
               if mon_on:
                   # progressive validation on the device scalars; real

@@ -919,7 +919,7 @@ class FtrlSweepResult:
 
 
 @_functools.lru_cache(maxsize=16)
-def _ftrl_sweep_staleness_factory(mesh, K, P_pts, kernel="off"):
+def _ftrl_sweep_staleness_factory(mesh, K, P_pts):
     """The bounded-staleness FTRL step with a ``(points,)`` lane: the
     per-point body mirrors ``_ftrl_sparse_staleness_step_factory``'s
     shard_fn OP-FOR-OP with the hyperparameters as traced per-point
@@ -933,18 +933,13 @@ def _ftrl_sweep_staleness_factory(mesh, K, P_pts, kernel="off"):
     bitwise is population independence: a lane's result never depends
     on which other points share the sweep (same program, same lane
     shapes). One psum per chunk per point (the
-    serial program's collective set, times P). ``kernel`` is the
-    RESOLVED Pallas kernel-tier mode riding the lru key (the
-    gather/scatter kernels are bitwise, so parity holds either way)."""
+    serial program's collective set, times P)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
     from ..common.compat import shard_map
     from ..engine.communication import manifest_psum
-    from ..operator.stream.onlinelearning.ftrl import (_ftrl_weights,
-                                                       _state_kernels)
-
-    _sgather, _sscatter = _state_kernels(kernel)
+    from ..operator.stream.onlinelearning.ftrl import _ftrl_weights
 
     def shard_fn(idx, val, y, hyp, Z, N):
         # hyp: (P_pts, 4) = [alpha, beta, l1, l2] lanes; Z/N:
@@ -971,7 +966,7 @@ def _ftrl_sweep_staleness_factory(mesh, K, P_pts, kernel="off"):
                 local = (xi >= lo) & (xi < lo + shard)
                 li = jnp.clip(xi - lo, 0, shard - 1)
                 flat = li.reshape(-1)
-                s = _sgather(zn, flat).reshape(K, w, 2)
+                s = zn[flat].reshape(K, w, 2)
                 zj = jnp.where(local, s[..., 0], 0.0)
                 nj = jnp.where(local, s[..., 1], 0.0)
                 wj = jnp.where(local,
@@ -985,9 +980,8 @@ def _ftrl_sweep_staleness_factory(mesh, K, P_pts, kernel="off"):
                 sigma = (jnp.sqrt(nj + g * g) - jnp.sqrt(nj)) / alpha
                 dz = jnp.where(local, g - sigma * wj, 0.0)
                 dn = jnp.where(local, g * g, 0.0)
-                zn = _sscatter(zn, flat,
-                               jnp.stack([dz.reshape(-1), dn.reshape(-1)],
-                                         axis=-1))
+                zn = zn.at[flat].add(
+                    jnp.stack([dz.reshape(-1), dn.reshape(-1)], axis=-1))
                 return zn, margins
 
             zn, margins = jax.lax.scan(body, zn, (xi3, xv3, yy2))
@@ -1033,7 +1027,6 @@ def sweep_ftrl(batches, dim: int, points, base=None, env=None,
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
     from ..common.mlenv import MLEnvironmentFactory
-    from ..kernels.ftrl import ftrl_kernel_mode
     from ..operator.stream.onlinelearning.ftrl import (
         _ftrl_sparse_staleness_step_factory)
 
@@ -1102,8 +1095,7 @@ def sweep_ftrl(batches, dim: int, points, base=None, env=None,
             Ki = int(plan.points[i].get("staleness", base["staleness"]))
             step = _ftrl_sparse_staleness_step_factory(
                 mesh, resolved(i, "alpha"), resolved(i, "beta"),
-                resolved(i, "l1"), resolved(i, "l2"), Ki,
-                kernel=ftrl_kernel_mode())
+                resolved(i, "l1"), resolved(i, "l2"), Ki)
             progs.add((resolved(i, "alpha"), resolved(i, "beta"),
                        resolved(i, "l1"), resolved(i, "l2"), Ki))
             z = jax.device_put(z0_for(i), sh)
@@ -1119,8 +1111,7 @@ def sweep_ftrl(batches, dim: int, points, base=None, env=None,
         Mh = np.stack(ms)
         return _finish_ftrl(plan, batches, Zh, Nh, Mh, len(progs), True)
 
-    step = _ftrl_sweep_staleness_factory(mesh, K, P_pts,
-                                         kernel=ftrl_kernel_mode())
+    step = _ftrl_sweep_staleness_factory(mesh, K, P_pts)
     state_sh = NamedSharding(mesh, P(None, "d"))
     Z = jax.device_put(np.stack([z0_for(i) for i in range(P_pts)]),
                        state_sh)

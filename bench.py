@@ -2138,103 +2138,6 @@ def quick_gbdt_hist(h: Harness):
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernel tier (alink_tpu/kernels, ISSUE 13): ftrl_pallas row
-# ---------------------------------------------------------------------------
-
-def _bench_ftrl_pallas(h: Harness, dim, B, n_pool, spans, reps):
-    """The sparse FTRL scatter-update kernel (ALINK_TPU_FTRL_KERNEL)
-    vs the XLA gather/scatter step, staleness mode, with a bitwise
-    parity field. HONEST RIG NOTE: off-TPU the kernel executes in
-    Pallas interpret mode — a simulated grid of XLA ops, which
-    measures correctness economics, not the VMEM-resident win; the
-    row's winner field records which kernel is faster on THIS rig
-    (XLA wins interpret-mode CPU; the pallas win is the physical-TPU
-    recapture, where XLA's serialized gather/scatter ~5M elem/s wall
-    is the bound — docs/performance.md "Pallas kernel tier")."""
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    from alink_tpu.operator.stream.onlinelearning.ftrl import (
-        _ftrl_sparse_staleness_step_factory)
-    nnz = 16
-    n_dev = h.chips
-    dim_pad = -(-dim // n_dev) * n_dev
-    width = -(-(nnz + 1) // 8) * 8
-
-    def make_batch(seed):
-        r = np.random.RandomState(seed)
-        idx = np.zeros((B, width), np.int32)
-        val = np.zeros((B, width), np.float64)
-        idx[:, 0], val[:, 0] = 0, 1.0
-        idx[:, 1:nnz + 1] = r.randint(1, dim, size=(B, nnz))
-        val[:, 1:nnz + 1] = 1.0
-        y = (r.rand(B) < 0.5).astype(np.float64)
-        return idx, val, y
-
-    pool = [make_batch(s) for s in range(n_pool)]
-    mesh = h.env.mesh
-    shard = NamedSharding(mesh, P("d"))
-    sp_idx = h.put(np.stack([p[0] for p in pool]))
-    sp_val = h.put(np.stack([p[1] for p in pool]))
-    sp_y = h.put(np.stack([p[2] for p in pool]))
-    zrng = np.random.RandomState(3)
-    z0 = zrng.randn(dim_pad) * 1e-8
-    rates = {}
-    finals = {}
-    for kern in ("off", "pallas"):
-        step = _ftrl_sparse_staleness_step_factory(
-            mesh, 0.05, 1.0, 1e-5, 1e-5, 32, kernel=kern)
-
-        @jax.jit
-        def pool_fn(sp_idx, sp_val, sp_y, z, nacc, step=step):
-            def body(carry, xs):
-                z, nacc = carry
-                z, nacc, m = step(xs[0], xs[1], xs[2], z, nacc)
-                return (z, nacc), m[0]
-            (z, nacc), _ = jax.lax.scan(body, (z, nacc),
-                                        (sp_idx, sp_val, sp_y))
-            return z, nacc
-
-        def run(n_pools, pool_fn=pool_fn):
-            st = [jax.device_put(z0, shard),
-                  jax.device_put(np.zeros(dim_pad), shard)]
-
-            def step_once():
-                st[0], st[1] = pool_fn(sp_idx, sp_val, sp_y, st[0], st[1])
-            _kernel_loop("ftrl.pallas", n_pools, step_once,
-                         lambda: np.asarray(st[0]))
-            finals[kern] = np.asarray(st[0])
-
-        dt = h.delta(run, spans, reps=reps)
-        rates[kern] = B * n_pool * spans / dt / h.chips
-    parity = "bitwise" if np.array_equal(
-        finals["off"].view(np.int64), finals["pallas"].view(np.int64)) \
-        else "MISMATCH"
-    winner = "pallas" if rates["pallas"] >= rates["off"] else "xla"
-    return {"samples_per_sec_per_chip": round(rates["pallas"], 1),
-            "xla_samples_per_sec_per_chip": round(rates["off"], 1),
-            "pallas_vs_xla": round(rates["pallas"]
-                                   / max(rates["off"], 1e-9), 3),
-            "scatter_kernel": winner,
-            "parity": parity,
-            "bound": "latency",
-            "rig_note": ("interpret-mode Pallas (no TPU): measures "
-                         "correctness economics only; recapture on a "
-                         "physical slice for the VMEM-resident win"
-                         if jax.default_backend() != "tpu"
-                         else "native Mosaic kernels")}
-
-
-def bench_ftrl_pallas(h: Harness):
-    return _bench_ftrl_pallas(h, dim=16_384, B=512, n_pool=4, spans=3,
-                              reps=2)
-
-
-def quick_ftrl_pallas(h: Harness):
-    return _bench_ftrl_pallas(h, dim=4_096, B=128, n_pool=2, spans=2,
-                              reps=2)
-
-
-# ---------------------------------------------------------------------------
 # Serving tier (alink_tpu/serving): micro-batched compiled predict rows
 # ---------------------------------------------------------------------------
 
@@ -3345,7 +3248,6 @@ QUICK_WORKLOADS = (("logreg_criteo", quick_logreg),
                    ("ftrl_criteo", quick_ftrl),
                    ("ftrl_stream_drain", quick_ftrl_drain),
                    ("gbdt_hist_fused", quick_gbdt_hist),
-                   ("ftrl_pallas", quick_ftrl_pallas),
                    ("logreg_from_disk", quick_from_disk),
                    ("tuning_sweep", quick_tuning_sweep),
                    ("serve_logreg", quick_serve_logreg),
@@ -3459,7 +3361,6 @@ def main(argv=None):
                      ("kmeans_iris", bench_kmeans),
                      ("softmax_mnist", bench_softmax),
                      ("ftrl_criteo", bench_ftrl),
-                     ("ftrl_pallas", bench_ftrl_pallas),
                      ("logreg_from_disk", bench_logreg_from_disk),
                      ("gbdt_adult", bench_gbdt),
                      ("gbdt_adult_large", bench_gbdt_large),

@@ -3,11 +3,6 @@
 The load-bearing contracts, all runnable on the CPU tier-1 rig via
 ``ALINK_TPU_PALLAS_INTERPRET=1``:
 
-* **FTRL scatter kernel** — the per-sample and staleness step programs
-  with ``ALINK_TPU_FTRL_KERNEL=pallas`` are BITWISE-identical to the
-  XLA gather/scatter programs (state, margins), duplicates included;
-* **chained-correction triangular matvec** — inside the pinned 1e-12
-  chained tolerance (association-only difference vs the dense einsum);
 * **fused serving score kernel** — bitwise vs the ``seq_chunk_sum``
   XLA programs at every bucket, dense AND sparse; sharded mesh 1/4/8
   parity survives the flag (fused demotes to the sharded path,
@@ -15,7 +10,7 @@ The load-bearing contracts, all runnable on the CPU tier-1 rig via
 * **bf16/int8 score path** — label-exact + pinned-tolerance vs the f32
   host mapper; fused and XLA low-precision twins bitwise-equal;
 * **flag-off byte-identity + key folds** — every new flag's off-path
-  lowers byte-identically, and every toggle is a program/step/serving
+  lowers byte-identically, and every toggle is a program/serving
   cache MISS, never a stale hit;
 * **demotion is never silent** — one RuntimeWarning per (kernel,
   reason) + the alink_kernel_demotions_total / serve-fallback
@@ -29,45 +24,12 @@ import pytest
 
 from alink_tpu.common.metrics import MetricsRegistry, set_registry
 from alink_tpu.kernels import runtime as kr
-from alink_tpu.kernels.ftrl import ftrl_kernel_mode
 from alink_tpu.kernels.serve import (lowp_model_arrays, quantize_int8,
                                      serve_dtype)
 
 
-def _mesh():
-    from alink_tpu.common.mlenv import MLEnvironmentFactory
-    return MLEnvironmentFactory.get_default().mesh
-
-
 def _interp(monkeypatch):
     monkeypatch.setenv("ALINK_TPU_PALLAS_INTERPRET", "1")
-
-
-def _coo(B, dim, nnz, width, seed, dup_rows=0):
-    """Padded COO batch; ``dup_rows`` rows at the top share ONE feature
-    block so chunks collide (the duplicate-accumulation path)."""
-    rng = np.random.RandomState(seed)
-    idx = np.zeros((B, width), np.int32)
-    val = np.zeros((B, width))
-    for i in range(B):
-        if i < dup_rows:
-            idx[i, :nnz] = np.arange(nnz)      # shared slots -> collisions
-        else:
-            idx[i, :nnz] = rng.choice(dim, nnz, replace=False)
-    val[:, :nnz] = rng.randn(B, nnz)
-    y = (rng.rand(B) < 0.5).astype(np.float64)
-    return idx, val, y
-
-
-def _state(dim, seed=3):
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    rng = np.random.RandomState(seed)
-    sh = NamedSharding(_mesh(), P("d"))
-    z = rng.randn(dim) * 0.1
-    z[5] = -0.0                                # the signed-zero edge
-    return (jax.device_put(z, sh),
-            jax.device_put(np.abs(rng.randn(dim)) * 0.1, sh))
 
 
 def _bits(a):
@@ -109,33 +71,6 @@ class TestRuntime:
             set_registry(old)
             kr.reset_demotions()
 
-    def test_ftrl_mode_demotes_without_backend(self, monkeypatch):
-        import jax
-        if jax.default_backend() == "tpu":
-            pytest.skip("TPU backend: the kernel is genuinely available")
-        monkeypatch.delenv("ALINK_TPU_PALLAS_INTERPRET", raising=False)
-        monkeypatch.setenv("ALINK_TPU_FTRL_KERNEL", "1")
-        kr.reset_demotions()
-        with warnings.catch_warnings(record=True) as caught:
-            warnings.simplefilter("always")
-            assert ftrl_kernel_mode() == "off"
-            assert ftrl_kernel_mode() == "off"      # second call silent
-        demote = [c for c in caught
-                  if "backend-unavailable" in str(c.message)]
-        assert len(demote) == 1
-        kr.reset_demotions()
-
-    def test_ftrl_mode_resolves(self, monkeypatch):
-        monkeypatch.delenv("ALINK_TPU_FTRL_KERNEL", raising=False)
-        assert ftrl_kernel_mode() == "off"
-        monkeypatch.setenv("ALINK_TPU_FTRL_KERNEL", "0")
-        assert ftrl_kernel_mode() == "off"
-        _interp(monkeypatch)
-        monkeypatch.setenv("ALINK_TPU_FTRL_KERNEL", "pallas")
-        assert ftrl_kernel_mode() == "pallas"
-        monkeypatch.setenv("ALINK_TPU_FTRL_KERNEL", "1")
-        assert ftrl_kernel_mode() == "pallas"
-
     def test_eager_probe_memoizes_failure_and_demotes(self):
         calls = []
 
@@ -156,239 +91,7 @@ class TestRuntime:
 
 
 # ---------------------------------------------------------------------------
-# (1) the sparse FTRL scatter-update kernel — bitwise vs the XLA step
-# ---------------------------------------------------------------------------
-
-class TestFtrlScatterKernel:
-    DIM, NNZ, B, W = 512, 12, 64, 16
-
-    def _run(self, factory, kernel, data, **kw):
-        step = factory(_mesh(), 0.05, 1.0, 1e-5, 1e-5, **kw,
-                       kernel=kernel)
-        z, n = _state(self.DIM)
-        return step(*data, z, n)
-
-    def test_staleness_bitwise(self, monkeypatch):
-        """Collision-free AND colliding chunks through the SAME
-        compiled step pair (the shapes match, so the second dataset
-        reuses both programs)."""
-        _interp(monkeypatch)
-        from alink_tpu.operator.stream.onlinelearning.ftrl import (
-            _ftrl_sparse_staleness_step_factory as fac)
-        for dup_rows in (0, 24):
-            data = _coo(self.B, self.DIM, self.NNZ, self.W, seed=0,
-                        dup_rows=dup_rows)
-            off = self._run(fac, "off", data, K=16)
-            on = self._run(fac, "pallas", data, K=16)
-            for a, b in zip(off, on):
-                assert np.array_equal(_bits(a), _bits(b)), dup_rows
-
-    def test_per_sample_bitwise(self, monkeypatch):
-        _interp(monkeypatch)
-        from alink_tpu.operator.stream.onlinelearning.ftrl import (
-            _ftrl_sparse_step_factory as fac)
-        data = _coo(32, self.DIM, 6, 8, seed=1, dup_rows=8)
-        off = self._run(fac, "off", data)
-        on = self._run(fac, "pallas", data)
-        for a, b in zip(off, on):
-            assert np.array_equal(_bits(a), _bits(b))
-
-    def test_gather_scatter_units(self, monkeypatch):
-        """The kernels in isolation: gather bitwise; scatter-add with
-        DUPLICATE indices bitwise vs ``.at[].add``; untouched slots
-        keep their bits (-0.0 survives)."""
-        _interp(monkeypatch)
-        import jax.numpy as jnp
-        from alink_tpu.kernels.ftrl import gather_rows, scatter_add_rows
-        rng = np.random.RandomState(0)
-        st = rng.randn(300, 2)
-        st[7] = [-0.0, 0.0]
-        idx = rng.randint(0, 300, 50).astype(np.int32)
-        idx[3] = idx[9] = idx[11]                 # duplicates
-        idx = idx[idx != 7] if (idx == 7).any() else idx
-        upd = rng.randn(idx.size, 2)
-        ref = jnp.asarray(st).at[jnp.asarray(idx)].add(jnp.asarray(upd))
-        out = scatter_add_rows(jnp.asarray(st), jnp.asarray(idx),
-                               jnp.asarray(upd))
-        assert np.array_equal(_bits(ref), _bits(out))
-        assert np.signbit(np.asarray(out)[7, 0])  # -0.0 survived
-        g_ref = jnp.asarray(st)[jnp.asarray(idx)]
-        g_out = gather_rows(jnp.asarray(st), jnp.asarray(idx))
-        assert np.array_equal(_bits(g_ref), _bits(g_out))
-
-    def test_probe_failure_demotes_to_bitwise_xla(self, monkeypatch):
-        """A failing shape-class probe keeps the step usable: the XLA
-        ops run instead, the result is unchanged, and the demotion
-        warns exactly once."""
-        _interp(monkeypatch)
-        from alink_tpu.kernels import ftrl as kf
-        from alink_tpu.operator.stream.onlinelearning.ftrl import (
-            _ftrl_sparse_staleness_step_factory as fac)
-        monkeypatch.setattr(kf, "_scatter_call",
-                            lambda *a, **k: (_ for _ in ()).throw(
-                                RuntimeError("mosaic says no")))
-        kr.reset_demotions()
-        kr._PROBED.clear()
-        data = _coo(self.B, self.DIM, self.NNZ, self.W, seed=2)
-        off = self._run(fac, "off", data, K=8)
-        with warnings.catch_warnings(record=True) as caught:
-            warnings.simplefilter("always")
-            on = self._run(fac, "pallas", data, K=8)
-        assert sum("probe-failed" in str(c.message) for c in caught) == 1
-        for a, b in zip(off, on):
-            assert np.array_equal(_bits(a), _bits(b))
-        kr._PROBED.clear()
-        kr.reset_demotions()
-
-    def test_kernel_mode_rides_step_lru_key(self, monkeypatch):
-        _interp(monkeypatch)
-        from alink_tpu.operator.stream.onlinelearning.ftrl import (
-            _ftrl_sparse_staleness_step_factory as fac)
-        off = fac(_mesh(), 0.05, 1.0, 0.0, 0.0, 16, kernel="off")
-        off2 = fac(_mesh(), 0.05, 1.0, 0.0, 0.0, 16, kernel="off")
-        on = fac(_mesh(), 0.05, 1.0, 0.0, 0.0, 16, kernel="pallas")
-        assert off is off2                       # same mode: lru HIT
-        assert on is not off                     # toggle => new program
-
-    def test_flag_off_hlo_byte_identical(self, monkeypatch):
-        """Env unset and =0 resolve to the SAME factory program (lru
-        hit) whose lowered HLO contains no pallas call; the pallas
-        program's lowering differs (the lru key must fold it, which
-        test_kernel_mode_rides_step_lru_key pins)."""
-        _interp(monkeypatch)
-        import jax
-        from alink_tpu.common.compat import lowered_text
-        from alink_tpu.operator.stream.onlinelearning.ftrl import (
-            _ftrl_sparse_staleness_step_factory as fac)
-
-        def lowered(kernel):
-            step = fac(_mesh(), 0.07, 1.0, 0.0, 0.0, 8, kernel=kernel)
-            args = [jax.ShapeDtypeStruct((16, 8), np.int32),
-                    jax.ShapeDtypeStruct((16, 8), np.float64),
-                    jax.ShapeDtypeStruct((16,), np.float64),
-                    jax.ShapeDtypeStruct((512,), np.float64),
-                    jax.ShapeDtypeStruct((512,), np.float64)]
-            return lowered_text(step.lower(*args))
-
-        monkeypatch.delenv("ALINK_TPU_FTRL_KERNEL", raising=False)
-        assert ftrl_kernel_mode() == "off"
-        monkeypatch.setenv("ALINK_TPU_FTRL_KERNEL", "0")
-        assert ftrl_kernel_mode() == "off"       # same resolved mode ->
-        off = lowered("off")                     # same lru program
-        on = lowered("pallas")
-        assert off != on
-
-    def test_chained_signature_fold(self, monkeypatch):
-        """ALINK_TPU_FTRL_KERNEL folds into the CHAINED-mode checkpoint
-        signature only when on — pre-existing snapshots of every mode
-        keep their exact signature."""
-        _interp(monkeypatch)
-        import alink_tpu.operator.stream.onlinelearning.ftrl as fmod
-
-        captured = {}
-        orig = fmod.load_latest_validated
-
-        def capture(ck_dir, signature, **kw):
-            captured["sig"] = dict(signature)
-            return None
-
-        monkeypatch.setattr(fmod, "load_latest_validated", capture)
-        from alink_tpu.common.mtable import MTable
-        from alink_tpu.common.vector import DenseVector
-        from alink_tpu.operator.batch.classification.linear import (
-            LogisticRegressionTrainBatchOp)
-        from alink_tpu.operator.batch.source.sources import MemSourceBatchOp
-        from alink_tpu.operator.stream.onlinelearning.ftrl import (
-            FtrlTrainStreamOp)
-        from alink_tpu.operator.stream.source.sources import (
-            MemSourceStreamOp)
-        rng = np.random.RandomState(0)
-        n, d = 16, 4
-        X = rng.randn(n, d)
-        y = (X @ rng.randn(d) > 0).astype(np.int64)
-        vecs = np.empty(n, object)
-        vecs[:] = [DenseVector(X[i]) for i in range(n)]
-        tbl = MTable({"vec": vecs, "label": y}, "vec VECTOR, label LONG")
-        warm = LogisticRegressionTrainBatchOp(
-            vector_col="vec", label_col="label", max_iter=1).link_from(
-            MemSourceBatchOp(tbl))
-
-        def sig(tmpdir, env_val):
-            if env_val is None:
-                pytest.MonkeyPatch().delenv("ALINK_TPU_FTRL_KERNEL",
-                                            raising=False)
-            captured.clear()
-            op = FtrlTrainStreamOp(
-                warm, vector_col="vec", label_col="label",
-                update_mode="chained", chunk_size=4,
-                checkpoint_dir=str(tmpdir),
-                checkpoint_every_batches=1).link_from(
-                MemSourceStreamOp(tbl, batch_size=16))
-            # link_from resolves the signature before the drain runs;
-            # trigger the resume probe by iterating one step
-            next(iter(op.micro_batches()), None)
-            return captured["sig"]
-
-        import tempfile
-        with tempfile.TemporaryDirectory() as td:
-            monkeypatch.delenv("ALINK_TPU_FTRL_KERNEL", raising=False)
-            s_off = sig(td, None)
-            monkeypatch.setenv("ALINK_TPU_FTRL_KERNEL", "pallas")
-            s_on = sig(td, "pallas")
-        assert "ftrl_kernel" not in s_off
-        assert s_on.get("ftrl_kernel") == "pallas"
-        assert {k: v for k, v in s_on.items() if k != "ftrl_kernel"} \
-            == s_off
-
-
-# ---------------------------------------------------------------------------
-# (2) the chained-correction triangular matvec kernel
-# ---------------------------------------------------------------------------
-
-class TestChainedMatvecKernel:
-    def test_chained_step_within_pinned_tolerance(self, monkeypatch):
-        """Colliding chunks through the triangular kernel stay inside
-        the chained contract's pinned 1e-12 tolerance (association-only
-        difference vs the dense HIGHEST einsum)."""
-        _interp(monkeypatch)
-        from alink_tpu.operator.stream.onlinelearning.ftrl import (
-            _ftrl_sparse_chained_step_factory as fac)
-        dim, B, w = 256, 32, 12
-        data = _coo(B, dim, 8, w, seed=5, dup_rows=16)   # heavy collisions
-        off_step = fac(_mesh(), 0.05, 1.0, 1e-5, 1e-5, K=8)
-        on_step = fac(_mesh(), 0.05, 1.0, 1e-5, 1e-5, K=8,
-                      kernel="pallas")
-        z, n = _state(dim)
-        zo, no, mo = off_step(*data, z, n)
-        z, n = _state(dim)
-        zp, npx, mp = on_step(*data, z, n)
-        np.testing.assert_allclose(np.asarray(zo), np.asarray(zp),
-                                   rtol=1e-12, atol=1e-14)
-        np.testing.assert_allclose(np.asarray(mo), np.asarray(mp),
-                                   rtol=1e-12, atol=1e-14)
-
-    def test_corr_unit_matches_einsum(self, monkeypatch):
-        """``chained_corr`` vs the dense einsum with rows j >= k
-        zeroed: the kernel contracts over exactly the live triangle."""
-        _interp(monkeypatch)
-        import jax
-        import jax.numpy as jnp
-        from alink_tpu.kernels.ftrl import chained_corr
-        rng = np.random.RandomState(0)
-        K, w = 8, 10
-        M = jnp.asarray((rng.rand(K, w, w) < 0.1).astype(np.float64))
-        D = jnp.asarray(rng.randn(K, w, 2))
-        for k in (0, 1, K - 1):
-            Dk = D.at[k:].set(0.0)          # rows j >= k structurally zero
-            ref = jnp.einsum("jab,jbc->ac", M, Dk,
-                             precision=jax.lax.Precision.HIGHEST)
-            out = chained_corr(M, Dk, k)
-            np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
-                                       rtol=1e-12, atol=1e-14)
-
-
-# ---------------------------------------------------------------------------
-# (3) the fused serving score kernel + (4) bf16/int8
+# the fused serving score kernel + bf16/int8
 # ---------------------------------------------------------------------------
 
 def _serve_fixture(seed=0, n=96, d=20, detail=False):
@@ -683,24 +386,9 @@ class TestLowPrecisionServing:
 
 class TestFlagRegistration:
     def test_new_flags_declared(self):
-        from alink_tpu.common.flags import FLAGS, STEP_LRU, \
-            CHECKPOINT_SIGNATURE
-        f = FLAGS.get("ALINK_TPU_FTRL_KERNEL")
-        assert f is not None
-        assert STEP_LRU in f.folds_into
-        assert CHECKPOINT_SIGNATURE in f.folds_into
+        from alink_tpu.common.flags import FLAGS
         for name in ("ALINK_TPU_SERVE_FUSED", "ALINK_TPU_SERVE_DTYPE",
                      "ALINK_TPU_PALLAS_INTERPRET"):
             fl = FLAGS.get(name)
             assert fl is not None and fl.key_neutral
 
-    def test_ftrl_kernel_parse(self, monkeypatch):
-        from alink_tpu.common.flags import flag_value
-        for raw, want in (("0", "off"), ("off", "off"),
-                          # "xla" names the flag-off path (the
-                          # ALINK_TPU_FUSED_HIST convention)
-                          ("xla", "off"), ("XLA", "off"),
-                          ("1", "pallas"), ("pallas", "pallas"),
-                          ("true", "pallas")):
-            monkeypatch.setenv("ALINK_TPU_FTRL_KERNEL", raw)
-            assert flag_value("ALINK_TPU_FTRL_KERNEL") == want

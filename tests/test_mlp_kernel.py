@@ -80,16 +80,20 @@ def _gaps(X, A, b, C, cuts=1):
     (np.int8, 129, 32, 20, 1),
     (np.uint8, 300, 32, 2 * 11, 2),
     (np.uint8, 784, 32, 1, 1),
+    (np.int8, 784, 32, 2 * 5, 2),
 ], ids=["two_tiles_a_block_12_rows", "signed_ragged_run_20_rows",
-        "stacked_W_and_D_three_runs", "one_row_at_the_cells_width"])
+        "stacked_W_and_D_three_runs", "one_row_at_the_cells_width",
+        "signed_stacked_W_and_D_at_the_cells_width"])
 def test_the_kernels_agree_with_the_xla_walk(dtype, d, S, m, cuts):
     """Unsigned and signed bytes, ``m`` not a multiple of 8 (the edge rows
     of a part's registers are padding), a stacked ``[W; D]`` cut into its
     two outputs as the line pass asks, a block of two byte tiles (16 grid
     steps) and blocks other than the first: both products to the
-    tolerance."""
+    tolerance. Signed bytes take the whole range, −128 … 127, in a ragged
+    last run and in the unrolled loop over whole runs (784: 7 × 112)."""
     X, A, b, C = _block(dtype, d, S, m)
     if dtype == np.int8:
+        assert np.asarray(X).min() == -128 and np.asarray(X).max() == 127
         assert all((np.asarray(X)[:, :, j::4] < 0).any() for j in range(4))
     fwd, bwd = _gaps(X, A, b, C, cuts)
     assert fwd <= TOL and bwd <= TOL, (fwd, bwd)

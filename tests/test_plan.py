@@ -177,7 +177,7 @@ class TestEnginePlan:
 
 def _legacy_ftrl_signature(*, alpha, beta, l1, l2, dim, dim_pad,
                            update_mode, staleness, chunk_size,
-                           has_icpt, warm_fp, kern_resolved_pallas):
+                           has_icpt, warm_fp):
     """The pre-ISSUE-19 hand-built ck_signature, verbatim."""
     sig = {"kind": "ftrl_state", "alpha": alpha, "beta": beta,
            "l1": l1, "l2": l2, "dim": dim, "dim_pad": dim_pad,
@@ -188,24 +188,53 @@ def _legacy_ftrl_signature(*, alpha, beta, l1, l2, dim, dim_pad,
            "warm_coef_blake2b": warm_fp}
     if update_mode == "chained":
         sig["chunk_size"] = chunk_size
-        if kern_resolved_pallas:
-            sig["ftrl_kernel"] = "pallas"
     return sig
 
 
 class TestFtrlPlan:
     @pytest.mark.parametrize("mode", ["dense", "staleness", "chained"])
-    def test_signature_content_identical(self, mode, monkeypatch):
-        monkeypatch.delenv("ALINK_TPU_FTRL_KERNEL", raising=False)
+    def test_signature_content_identical(self, mode):
         kw = dict(alpha=0.1, beta=1.0, l1=0.01, l2=0.05, dim=33,
                   dim_pad=64, update_mode=mode, staleness=4,
                   chunk_size=128)
         p = planlib.ftrl_plan(mesh=None, has_intercept=True,
                               warm_fp="abc123", **kw)
         want = _legacy_ftrl_signature(
-            has_icpt=True, warm_fp="abc123",
-            kern_resolved_pallas=False, **kw)
+            has_icpt=True, warm_fp="abc123", **kw)
         assert planlib.ftrl_checkpoint_signature(p) == want
+
+    @pytest.mark.parametrize("mode, want", [
+        ("sample", {
+            "kind": "ftrl_state", "alpha": 0.05, "beta": 1.0,
+            "l1": 1e-05, "l2": 0.001, "dim": 1001, "dim_pad": 1008,
+            "update_mode": "sample", "staleness": None,
+            "has_intercept": True,
+            "warm_coef_blake2b": "5f0c9a1e2b3d4c6e7f8091a2"}),
+        ("staleness", {
+            "kind": "ftrl_state", "alpha": 0.05, "beta": 1.0,
+            "l1": 1e-05, "l2": 0.001, "dim": 1001, "dim_pad": 1008,
+            "update_mode": "staleness", "staleness": 32,
+            "has_intercept": True,
+            "warm_coef_blake2b": "5f0c9a1e2b3d4c6e7f8091a2"}),
+        ("chained", {
+            "kind": "ftrl_state", "alpha": 0.05, "beta": 1.0,
+            "l1": 1e-05, "l2": 0.001, "dim": 1001, "dim_pad": 1008,
+            "update_mode": "chained", "staleness": None,
+            "has_intercept": True,
+            "warm_coef_blake2b": "5f0c9a1e2b3d4c6e7f8091a2",
+            "chunk_size": 16}),
+    ], ids=["sparse", "staleness", "chained"])
+    def test_signature_pinned_literally(self, mode, want):
+        """The resume signature of each sparse step's mode, written out:
+        a snapshot taken by an earlier release resumes only while these
+        dicts stay equal, key for key and in order."""
+        p = planlib.ftrl_plan(
+            mesh=None, alpha=0.05, beta=1.0, l1=1e-5, l2=0.001, dim=1001,
+            dim_pad=1008, update_mode=mode, staleness=32, chunk_size=16,
+            has_intercept=True, warm_fp="5f0c9a1e2b3d4c6e7f8091a2")
+        sig = planlib.ftrl_checkpoint_signature(p)
+        assert sig == want
+        assert list(sig) == list(want)
 
 
 # ---------------------------------------------------------------------------

@@ -672,6 +672,63 @@ def test_ftrl_strict_chunked_scan_exact_under_collisions():
     assert len(np.asarray(margins)) == B
 
 
+@pytest.mark.parametrize("factory, kw", [
+    ("_ftrl_sparse_step_factory", {}),
+    ("_ftrl_sparse_staleness_step_factory", {"K": 8}),
+    ("_ftrl_sparse_chained_step_factory", {"K": 8}),
+], ids=["sparse", "staleness", "chained"])
+def test_ftrl_sparse_step_leaves_untouched_coordinates_bitwise(factory, kw):
+    """A sparse step writes the state only where its batch reads it: every
+    coordinate of z and n that no entry of the batch names (padding
+    included) keeps its bits after the step, a -0.0 among them, while
+    24 rows share one block of coordinates. The chunked modes clip an
+    entry outside a shard's range to the shard's first or last
+    coordinate and add 0.0 there, so those two coordinates of a shard
+    read ``x + 0.0``: the same bits, but a -0.0 turns to 0.0."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from alink_tpu.common.mlenv import MLEnvironmentFactory
+    from alink_tpu.operator.stream.onlinelearning import ftrl as F
+
+    env = MLEnvironmentFactory.get_default()
+    shard = 64
+    dim = shard * env.num_workers
+    rng = np.random.RandomState(4)
+    B, w, nnz = 48, 8, 6
+    idx = np.zeros((B, w), np.int32)
+    for i in range(B):
+        idx[i, :nnz] = (np.arange(nnz) if i < 24
+                        else rng.choice(dim, nnz, replace=False))
+    val = np.zeros((B, w))
+    val[:, :nnz] = rng.randn(B, nnz)
+    y = (rng.rand(B) < 0.5).astype(np.float64)
+    untouched = np.setdiff1d(np.arange(dim), idx)
+    edge = np.isin(untouched % shard, (0, shard - 1))
+    assert untouched.size > dim // 2 and edge.any() and (~edge).any()
+    z0 = rng.randn(dim) * 0.1
+    n0 = np.abs(rng.randn(dim)) * 0.1
+    zeroed = untouched[::3]               # edge and inner coordinates
+    z0[zeroed] = n0[zeroed] = -0.0
+    expect_z, expect_n = z0.copy(), n0.copy()
+    if factory != "_ftrl_sparse_step_factory":
+        at = untouched[edge]
+        expect_z[at] += 0.0
+        expect_n[at] += 0.0
+
+    step = getattr(F, factory)(env.mesh, 0.05, 1.0, 1e-5, 1e-5, **kw)
+    sh = NamedSharding(env.mesh, P("d"))
+    z, n, _ = step(idx, val, y, jax.device_put(z0, sh),
+                   jax.device_put(n0, sh))
+    z, n = np.asarray(z), np.asarray(n)
+    for got, want in ((z, expect_z), (n, expect_n)):
+        assert np.array_equal(got[untouched].view(np.int64),
+                              want[untouched].view(np.int64))
+    inner = np.setdiff1d(zeroed, untouched[edge])
+    assert np.signbit(z[inner]).all() and np.signbit(n[inner]).all()
+    assert not np.array_equal(z[:nnz], z0[:nnz])
+
+
 def _strict_case(name, dim_pad):
     """(idx, val, y) of one micro-batch for a case of the strict step's
     working table (K = 4 rows a round, width 6)."""

@@ -218,10 +218,6 @@ def _display_name(name: str) -> str:
         # whole-table scoring through the fused Pallas kernel, not the
         # micro-batcher: rows scored per second (ISSUE 13)
         return f"{name} (rows/s)"
-    if name == "ftrl_pallas":
-        # the Pallas-path staleness kernel rate — interpret-mode on CPU
-        # rigs (the row's rig_note), native Mosaic on TPU (ISSUE 13)
-        return f"{name} (samples/s, kernel tier)"
     if name.startswith("serve_") and name.endswith("_sharded"):
         # multi-chip serving rows report per-chip throughput at the
         # widest measured mesh (ISSUE 11)

@@ -513,15 +513,10 @@ def _fixes(name: str, attr: Dict[str, Any], fr: Dict[str, float],
             roof = max(ach.get("pct_peak_flops", 0.0),
                        ach.get("pct_peak_hbm", 0.0))
             if roof < 15.0:
-                tier = (" — scatter-bound FTRL belongs on the Pallas "
-                        "kernel tier (ALINK_TPU_FTRL_KERNEL=pallas: "
-                        "VMEM-resident (z, n) tiles instead of XLA's "
-                        "serialized gather/scatter)"
-                        if name.startswith("ftrl") else "")
                 cands.append((dev, f"device-busy {dev:.0%} but only "
                                    f"{roof:.1f}% of the chip roof: fuse "
                                    f"kernels (ALINK_TPU_FUSED_HIST, "
-                                   f"Pallas) or grow the shapes{tier}"))
+                                   f"Pallas) or grow the shapes"))
             else:
                 cands.append((dev * 0.5,
                               f"device compute at {roof:.0f}% of the "

@@ -2,23 +2,12 @@
 """Pallas kernel-tier smoke (perf_gate leg, ISSUE 13) — exit 7 on
 failure.
 
-The load-bearing kernel contracts, cheap enough for every gate run,
-executed in a fresh 4-virtual-device f64 child with
+The fused serving score kernel's contract, cheap enough for every gate
+run, executed in a fresh 4-virtual-device f64 child with
 ``ALINK_TPU_PALLAS_INTERPRET=1`` (interpret mode is the CPU rig's
 availability gate — the same programs run unchanged as Mosaic kernels
-on a physical TPU):
-
-  1. FTRL scatter kernel: the staleness AND per-sample step programs
-     with ``kernel=pallas`` are BITWISE-identical to the XLA
-     gather/scatter steps (state + margins, colliding rows included);
-  2. chained-correction triangular matvec: inside the pinned 1e-12
-     chained tolerance;
-  3. fused serving score kernel: BITWISE vs the seq_chunk_sum XLA
-     programs at buckets 1/4/16, and bf16/int8 label-exact on
-     boundary-safe rows;
-  4. demotion is never silent: with the backend unavailable, the
-     one-time warning fires EXACTLY once and the resolved mode
-     demotes to the XLA path.
+on a physical TPU): BITWISE vs the seq_chunk_sum XLA programs at
+buckets 1/4/16, and bf16/int8 label-exact on boundary-safe rows.
 """
 
 import os
@@ -46,81 +35,15 @@ def main() -> int:
                              cwd=ROOT, env=env, timeout=900)
         return out.returncode
 
-    import warnings
-
     import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from alink_tpu.common.mlenv import MLEnvironmentFactory
-    from alink_tpu.kernels import runtime as kr
-    from alink_tpu.kernels.ftrl import ftrl_kernel_mode
-    from alink_tpu.operator.stream.onlinelearning.ftrl import (
-        _ftrl_sparse_chained_step_factory, _ftrl_sparse_staleness_step_factory,
-        _ftrl_sparse_step_factory)
-
-    env = MLEnvironmentFactory.get_default()
-    mesh = env.mesh
-    bad = []
-
-    # -- 1+2: FTRL kernels ------------------------------------------------
-    dim, nnz, B, width = 512, 10, 48, 16
-    rng = np.random.RandomState(0)
-    idx = np.zeros((B, width), np.int32)
-    val = np.zeros((B, width))
-    for i in range(B):
-        if i < 16:                      # colliding rows: shared slots
-            idx[i, :nnz] = np.arange(nnz)
-        else:
-            idx[i, :nnz] = rng.choice(dim, nnz, replace=False)
-    val[:, :nnz] = rng.randn(B, nnz)
-    y = (rng.rand(B) < 0.5).astype(np.float64)
-    sh = NamedSharding(mesh, P("d"))
-
-    def state():
-        r = np.random.RandomState(3)
-        return (jax.device_put(r.randn(dim) * 0.1, sh),
-                jax.device_put(np.abs(r.randn(dim)) * 0.1, sh))
-
-    def bits(a):
-        return np.asarray(a).view(np.int64)
-
-    for name, fac, kw in (
-            ("staleness", _ftrl_sparse_staleness_step_factory, {"K": 16}),
-            ("per-sample", _ftrl_sparse_step_factory, {})):
-        off = fac(mesh, 0.05, 1.0, 1e-5, 1e-5, **kw, kernel="off")
-        on = fac(mesh, 0.05, 1.0, 1e-5, 1e-5, **kw, kernel="pallas")
-        z, n = state()
-        ro = off(idx, val, y, z, n)
-        z, n = state()
-        rp = on(idx, val, y, z, n)
-        for a, b in zip(ro, rp):
-            if not np.array_equal(bits(a), bits(b)):
-                bad.append(f"{name} scatter kernel NOT bitwise vs the "
-                           f"XLA step")
-                break
-
-    off = _ftrl_sparse_chained_step_factory(mesh, 0.05, 1.0, 1e-5, 1e-5,
-                                            K=16, kernel="off")
-    on = _ftrl_sparse_chained_step_factory(mesh, 0.05, 1.0, 1e-5, 1e-5,
-                                           K=16, kernel="pallas")
-    z, n = state()
-    zo, no, mo = off(idx, val, y, z, n)
-    z, n = state()
-    zp, npx, mp = on(idx, val, y, z, n)
-    if not (np.allclose(np.asarray(zo), np.asarray(zp), rtol=1e-12,
-                        atol=1e-14)
-            and np.allclose(np.asarray(mo), np.asarray(mp), rtol=1e-12,
-                            atol=1e-14)):
-        bad.append("chained triangular matvec outside the pinned 1e-12 "
-                   "tolerance")
-
-    # -- 3: fused serving score kernel ------------------------------------
     import jax.numpy as jnp
 
     from alink_tpu.kernels.serve import (lowp_model_arrays,
                                          make_fused_score_fns,
                                          make_xla_score_fns)
     from alink_tpu.serving.sharded import seq_chunk_sum
+    bad = []
+    rng = np.random.RandomState(0)
     dim8 = 128
     w = rng.randn(dim8)
     b = 0.25
@@ -155,37 +78,12 @@ def main() -> int:
         if not np.allclose(sx, ref, atol=tol):
             bad.append(f"{dt} scores outside the pinned tolerance")
 
-    # -- 4: demotion fires exactly once -----------------------------------
-    interp = os.environ.pop("ALINK_TPU_PALLAS_INTERPRET", None)
-    os.environ["ALINK_TPU_FTRL_KERNEL"] = "1"
-    kr.reset_demotions()
-    try:
-        with warnings.catch_warnings(record=True) as caught:
-            warnings.simplefilter("always")
-            m1 = ftrl_kernel_mode()
-            m2 = ftrl_kernel_mode()
-        demote = [c for c in caught
-                  if "backend-unavailable" in str(c.message)]
-        if jax.default_backend() != "tpu":
-            if (m1, m2) != ("off", "off"):
-                bad.append(f"unavailable backend resolved {m1!r} "
-                           f"(want demotion to 'off')")
-            if len(demote) != 1:
-                bad.append(f"demotion warning fired {len(demote)} times "
-                           f"(want exactly once)")
-    finally:
-        if interp is not None:
-            os.environ["ALINK_TPU_PALLAS_INTERPRET"] = interp
-        del os.environ["ALINK_TPU_FTRL_KERNEL"]
-        kr.reset_demotions()
-
     if bad:
         print("kernel_smoke: FAILED:", file=sys.stderr)
         for m in bad:
             print(f"  {m}", file=sys.stderr)
         return EXIT
-    print("kernel_smoke: clean (FTRL scatter bitwise, chained <= 1e-12, "
-          "fused serve bitwise + bf16/int8 parity, demotion warned once)")
+    print("kernel_smoke: clean (fused serve bitwise + bf16/int8 parity)")
     return 0
 
 

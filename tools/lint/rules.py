@@ -95,15 +95,11 @@ def default_config() -> LintConfig:
         # the Pallas kernel tier (ISSUE 13): the serving-kernel build
         # resolves ALINK_TPU_SERVE_FUSED/_DTYPE into the ServingKernel
         # signature (the serving program-cache key, which ServingPlan /
-        # serving_event_plan consume as an opaque value), and the FTRL
-        # kernel-mode resolution rides the step factories' lru keys
-        # (the sweep's staleness lane calls it outside ftrl_plan)
+        # serving_event_plan consume as an opaque value)
         FactoryRoot("alink_tpu/operator/common/linear/mapper.py",
                     "LinearModelMapper.serving_kernel", frozenset({_PC})),
         FactoryRoot("alink_tpu/kernels/serve.py",
                     "resolve_serve_kernel", frozenset({_PC})),
-        FactoryRoot("alink_tpu/kernels/ftrl.py",
-                    "ftrl_kernel_mode", frozenset({_LRU, _CKS})),
     ]
     return LintConfig(
         package_dirs=("alink_tpu",),
